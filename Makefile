@@ -241,9 +241,8 @@ bench: native
 bench-micro: native
 	$(PY) bench_micro.py
 
-# regenerate the PERF.md A/B ledger tables from the committed bench
-# artifact (VERDICT r5 item 4: every headline claim traceable to
-# BENCH_DETAIL.json — run after each bench capture)
+# regenerate the PERF.md A/B ledger tables from the bench artifact
+# (every table traceable to BENCH_DETAIL.json — run after a bench capture)
 perf-ledger:
 	$(PY) tools/perf_ledger.py
 
@@ -252,3 +251,8 @@ perf-ledger-check:
 
 dryrun:
 	$(PY) __graft_entry__.py
+
+# the main path on the real TPU, one process (fails without a chip;
+# `$(PY) chip_smoke.py --rehearse-cpu` is the tiny-size CPU rehearsal)
+chip-smoke:
+	$(PY) chip_smoke.py

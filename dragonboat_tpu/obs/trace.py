@@ -82,7 +82,7 @@ plog = get_logger("trace")
 _T = "dragonboat_trace_"
 
 #: seconds-scale stage/e2e histogram buckets: sub-ms direct-path stages
-#: at the bottom, a wedged WAL or tunnel stall at the top
+#: at the bottom, a wedged WAL or dispatch stall at the top
 STAGE_BUCKETS_S = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0,

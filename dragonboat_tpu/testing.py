@@ -91,9 +91,8 @@ def run_sharded_stack_check(
                 ),
             )))
         for nh in nhs:
-            # defensive: SingleDeviceSharding has no .spec, and the
-            # coordinator silently falls back to unsharded on 1-device
-            # hosts — fail with the diagnostic, not an AttributeError
+            # SingleDeviceSharding has no .spec — fail with the
+            # diagnostic, not an AttributeError
             spec = getattr(
                 nh.quorum_coordinator.eng.dev.match.sharding, "spec", None
             )

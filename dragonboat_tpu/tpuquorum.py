@@ -79,39 +79,38 @@ class TpuQuorumCoordinator:
         # by the old _MULTIDEV_MU class lock (zero dispatch concurrency
         # from mesh hardware); the GSPMD path remains available by
         # constructing BatchedQuorumEngine(sharding=...) directly.
-        mesh_n = 0  # effective shard count (0 = unsharded)
+        mesh_n = 0  # shard count (0 = unsharded)
         mesh_devs = None
         if mesh_devices > 1:
             import jax
 
             devs = jax.devices()
-            n = min(mesh_devices, len(devs))
-            if n > 1:
-                capacity = ((capacity + n - 1) // n) * n
-                mesh_devs = devs[:n]
-                mesh_n = n
-                plog.info(
-                    "quorum engine mesh-sharded over %d devices "
-                    "(%d rows, %d per shard)", n, capacity, capacity // n,
+            if len(devs) < mesh_devices:
+                raise ValueError(
+                    f"engine_mesh_devices={mesh_devices} but jax.devices() "
+                    f"holds {len(devs)}: {devs}"
                 )
+            mesh_n = mesh_devices
+            capacity = ((capacity + mesh_n - 1) // mesh_n) * mesh_n
+            mesh_devs = devs[:mesh_n]
+            plog.info(
+                "quorum engine mesh-sharded over %d devices "
+                "(%d rows, %d per shard)", mesh_n, capacity,
+                capacity // mesh_n,
+            )
         self.mesh_devices = mesh_n
         # persistent XLA compilation cache (ISSUE 7): enabled BEFORE any
-        # program compiles so even the single-round warm misses persist;
-        # the directory is versioned by kernel-source hash inside
-        # enable_persistent_compilation_cache.  Env fallback lets ops
-        # point every process at a shared cache without config plumbing.
-        if compilation_cache_dir is None:
-            compilation_cache_dir = (
-                os.environ.get("DBTPU_COMPILATION_CACHE") or None
-            )
+        # program compiles so even the single-round warm misses persist.
+        # JAX_COMPILATION_CACHE_DIR decides where it is set; otherwise the
+        # configured directory, else the fixed in-checkout default (see
+        # enable_persistent_compilation_cache).
         self.compilation_cache_dir = None
-        if compilation_cache_dir:
-            try:
-                self.compilation_cache_dir = (
-                    enable_persistent_compilation_cache(compilation_cache_dir)
-                )
-            except OSError as e:
-                plog.warning("compilation cache unavailable: %r", e)
+        try:
+            self.compilation_cache_dir = enable_persistent_compilation_cache(
+                compilation_cache_dir or ""
+            )
+        except OSError as e:
+            plog.warning("compilation cache unavailable: %r", e)
         if mesh_n > 1:
             from .ops.mesh import MeshQuorumEngine
 
@@ -798,7 +797,7 @@ class TpuQuorumCoordinator:
         fuse_skip = None
         with self._mu:
             seq = self._tick_seq
-            # catch up missed ticks (a slow round — tunneled dispatch,
+            # catch up missed ticks (a slow round — first-use compile,
             # contended host — can span several host ticks; the scalar
             # path replays every LOCAL_TICK the same way).  Fused-ready
             # rounds replay up to fused_k_max ticks in ONE dispatch;
@@ -922,7 +921,15 @@ class TpuQuorumCoordinator:
                     pad_rounds_to=self._k_bucket(deficit),
                     tick_rounds=deficit,
                 )
-                self.fused_dispatches += 1
+                if res is None:
+                    # a mesh engine dispatches nothing while no shard
+                    # owns a group (a tick backlog before registration /
+                    # after teardown)
+                    from .ops.engine import StepResult
+
+                    res = StepResult()
+                else:
+                    self.fused_dispatches += 1
                 self._collect_read_confirms(res, read_confirms)
             else:
                 # per-step replay keeps the historical 4-tick cap even
@@ -1052,7 +1059,7 @@ class TpuQuorumCoordinator:
                 self.lease_table.publish(obs.registry, self._tick_seen)
             # the recorder's stall check on wall_ms IS the round-gate
             # watchdog: a round outlasting stall_ms (wedged dispatch,
-            # first-compile storm, tunnel stall) auto-dumps the ring
+            # first-compile storm) auto-dumps the ring
             # with this span as the trigger
             obs.round(
                 wall_ms=(time.perf_counter() - t0) * 1e3,

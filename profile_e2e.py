@@ -84,7 +84,7 @@ def main() -> None:
     os.environ.setdefault("E2E_PROCS", "1")
     import bench_e2e
 
-    bench_e2e._force_cpu_for_engine()
+    bench_e2e._decide_platform()
     s = Sampler()
     s.start()
     res = bench_e2e.run_quick()

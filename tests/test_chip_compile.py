@@ -1,0 +1,187 @@
+"""The live path's kernels compile for a v5e — checked without one.
+
+The TPU compiler is installed in CI; it compiles for a *described*
+``v5e:2x2`` chip (nothing is attached, nothing runs).  Every jitted entry
+point of ``ops/kernels.py`` is compiled at the BASELINE ladder's size
+(65,536 groups x 5 peers) and at the live coordinator's default
+(1,024 x 8), with the static flags the live path turns on
+(``has_reads`` / ``has_kv`` / ``has_telem`` / ``has_hier``); one mesh case
+partitions the state over the four described devices.  The three live
+entry points take their arguments from the engine's own
+``_variant_args`` builder, so what compiles here is what the coordinator
+dispatches.
+
+The topology is described inside a module-scoped fixture: only one
+process may load the TPU library, so it must never happen at import (every
+xdist worker imports every test file) and these tests must stay in ONE
+file.  The persistent compilation cache is switched off around them: an
+executable compiled for a described chip is written to the cache but can
+never be read back without the chip.
+"""
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from dragonboat_tpu.ops import kernels  # noqa: E402
+from dragonboat_tpu.ops.engine import BatchedQuorumEngine  # noqa: E402
+from dragonboat_tpu.ops.sharding import GROUP_AXIS, state_sharding  # noqa: E402
+from dragonboat_tpu.ops.state import make_state  # noqa: E402
+
+#: (groups, peers): BASELINE.json's ladder size, and tpuquorum.py's default
+SIZES = {"ladder": (65536, 5), "live": (1024, 8)}
+
+#: warm-plan variants (kind, arg, has_reads, has_kv) — the closed set the
+#: live coordinator dispatches, at its largest K bucket
+LIVE_VARIANTS = [
+    ("sparse", True, False, False),
+    ("sparse_votes", True, False, False),
+    ("dense", True, True, False),
+    ("dense", True, True, True),
+    ("fused", 16, False, False),
+    ("fused", 16, True, False),
+    ("fused", 16, True, True),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax._src import compilation_cache as _jcc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    _jcc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    _jcc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda s: None if s is None else jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding
+        ),
+        tree,
+        is_leaf=lambda x: x is None,
+    )
+
+
+def _state(groups, peers, sharding):
+    return _on(sharding, jax.eval_shape(lambda: make_state(groups, peers)))
+
+
+def _engine(groups, peers):
+    """A live-shaped engine (the coordinator's event_cap rule) with the
+    telemetry and hier latches up, as a fully featured NodeHost runs it."""
+    eng = BatchedQuorumEngine(
+        groups, peers, event_cap=max(4 * groups, 4096), device_ticks=True
+    )
+    eng.enable_telem()
+    eng._hier_used = True
+    return eng
+
+
+def _compile(fn, st, args, statics):
+    compiled = fn.lower(st, *args, **statics).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0
+    return compiled
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize(
+    "variant", LIVE_VARIANTS,
+    ids=[BatchedQuorumEngine.variant_label(*v) for v in LIVE_VARIANTS],
+)
+def test_live_program_compiles_for_v5e(topo, no_compile_cache, size, variant):
+    groups, peers = SIZES[size]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    fn, args, statics = _engine(groups, peers)._variant_args(
+        *variant, abstract=True
+    )
+    assert statics["has_telem"] and statics["has_hier"]
+    _compile(fn, _state(groups, peers, one_chip), _on(one_chip, args), statics)
+
+
+def _multistep_args(groups, peers, rounds, dense):
+    if dense:
+        return (
+            jax.ShapeDtypeStruct((rounds, groups, peers), jnp.int32),
+            jax.ShapeDtypeStruct((rounds, groups, peers), bool),
+            jax.ShapeDtypeStruct((rounds, groups, peers), jnp.int8),
+        )
+    cap = max(4 * groups, 4096)
+    i32 = jax.ShapeDtypeStruct((rounds, cap), jnp.int32)
+    valid = jax.ShapeDtypeStruct((rounds, cap), bool)
+    grant = jax.ShapeDtypeStruct((rounds, cap), jnp.int8)
+    return (i32, i32, i32, valid, i32, i32, grant, valid)
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("dense", [False, True], ids=["sparse", "dense"])
+def test_multistep_compiles_for_v5e(topo, no_compile_cache, size, dense):
+    groups, peers = SIZES[size]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    fn = kernels.quorum_multistep_dense if dense else kernels.quorum_multistep
+    _compile(
+        fn,
+        _state(groups, peers, one_chip),
+        _on(one_chip, _multistep_args(groups, peers, 4, dense)),
+        dict(do_tick=True, track_contact=True, has_votes=True, has_hier=True),
+    )
+
+
+def test_group_sharded_program_compiles_for_v5e_2x2(topo, no_compile_cache):
+    """The GSPMD form: state split over the four described devices on the
+    group axis, the fused K-round block split the same way."""
+    groups, peers = 4 * SIZES["ladder"][0], SIZES["ladder"][1]
+    mesh = Mesh(np.array(topo.devices), (GROUP_AXIS,))
+    assert mesh.devices.size == 4
+    st = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(lambda: make_state(groups, peers)),
+        state_sharding(mesh),
+    )
+    fn, args, statics = _engine(groups, peers)._variant_args(
+        "fused", 16, True, False, abstract=True
+    )
+    replicated = NamedSharding(mesh, P())
+    by_group = NamedSharding(mesh, P(None, GROUP_AXIS))
+    args = tuple(
+        None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=by_group
+            if a.ndim >= 2 and a.shape[1] == groups else replicated,
+        )
+        for a in args
+    )
+    compiled = _compile(fn, st, args, statics)
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(
+        int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
+        for s in jax.tree_util.tree_leaves(st)
+    )
+    assert per_device < whole, "state was not partitioned across the mesh"

@@ -296,7 +296,7 @@ def test_engine_stall_autodump_names_blocked_dispatch(monkeypatch, tmp_path):
 
     real_get = jax.device_get
 
-    def slow_get(x):  # a wedged egress (tunnel stall, device hang)
+    def slow_get(x):  # a wedged egress (device hang)
         time.sleep(0.05)
         return real_get(x)
 

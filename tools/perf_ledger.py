@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Regenerate the PERF.md A/B ledger tables from BENCH_DETAIL.json.
 
-VERDICT round 5 item 4: headline claims in PERF.md must be traceable to a
-committed artifact — hand-transcribed tables drifted from the record they
-cite (the round-5 commit claimed a lead `BENCH_r05.json` refutes).  This
+Tables in PERF.md must be traceable to a bench artifact — hand-transcribed
+tables drifted from the record they cited.  This
 script derives the ledger tables MECHANICALLY from the bench artifact and
 splices them between the ``perf-ledger`` markers in PERF.md, so the table
 and the artifact cannot disagree.  Run it at capture time
@@ -1178,17 +1177,9 @@ def render(detail: dict, source: str) -> str:
 
 
 def _default_artifact(root: str) -> str:
-    """Freshest capture wins: the working-tree BENCH_DETAIL.json (written
-    by the bench run, deliberately untracked) when present, else the
-    NEWEST committed round record (BENCH_r0N.json) — so a clean checkout
-    regenerates the tables from the committed artifact of record."""
-    detail = os.path.join(root, "BENCH_DETAIL.json")
-    if os.path.exists(detail):
-        return detail
-    import glob
-
-    rounds = sorted(glob.glob(os.path.join(root, "BENCH_r[0-9]*.json")))
-    return rounds[-1] if rounds else detail
+    """The working-tree BENCH_DETAIL.json (written by the bench run,
+    deliberately untracked) is the only artifact of record."""
+    return os.path.join(root, "BENCH_DETAIL.json")
 
 
 def _unwrap(rec: dict) -> dict:
@@ -1212,7 +1203,7 @@ def main() -> int:
     ap.add_argument(
         "--detail", default=None,
         help="bench artifact to derive the tables from (default: "
-        "BENCH_DETAIL.json if present, else the newest BENCH_r0N.json)",
+        "BENCH_DETAIL.json)",
     )
     ap.add_argument("--perf", default=os.path.join(root, "PERF.md"))
     ap.add_argument(
