@@ -1142,11 +1142,8 @@ def _run_mesh_axis(groups: int = 512, rounds: int = 4, k: int = 8,
     for s in rec.spans():
         if s.get("shard") is None or "egress_ms" not in s:
             continue
-        start = s["ts"]
-        end = start + (
-            (s.get("dispatch_ms") or 0.0) + (s["egress_ms"] or 0.0)
-        ) / 1e3
-        spans.append((start, end, s["shard"]))
+        # the span's own interval (perf_counter)
+        spans.append((s["t0"], s["t1"], s["shard"]))
     peak = 0
     for start, end, shard in spans:
         live = {

@@ -13,6 +13,7 @@ import threading
 from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 
 from .logger import get_logger
+from .obs.recorder import OFF as _OFF, annotate as _annotate
 from .queue import ReadyCluster
 from .server.partition import FixedPartitioner
 
@@ -119,20 +120,24 @@ class _Committer:
 
         t0 = _time.perf_counter()
         merged = [ud for _, updates in batch for ud in updates]
+        tr = self.engine.tracer
         if merged:
             hp = self.engine.hostplane
-            if hp is not None:
-                # cross-shard group-commit tier: the shared flusher merges
-                # this committer's batch with every other committer's into
-                # one fsync cycle; returns only once durable, then the
-                # post-fsync half below runs here, concurrently with the
-                # other committers' halves (per-group ordering untouched —
-                # a group only ever rides its owning committer)
-                hp.wal.flush(merged)
-            else:
-                self.engine.logdb.save_raft_state(merged)
+            # the log save + fsync, named for the profiler while the
+            # tracer is on (an idle device gap then reads "wal_sync")
+            with (_annotate("wal_sync") if tr is not None else _OFF):
+                if hp is not None:
+                    # cross-shard group-commit tier: the shared flusher
+                    # merges this committer's batch with every other
+                    # committer's into one fsync cycle; returns only once
+                    # durable, then the post-fsync half below runs here,
+                    # concurrently with the other committers' halves
+                    # (per-group ordering untouched — a group only ever
+                    # rides its owning committer)
+                    hp.wal.flush(merged)
+                else:
+                    self.engine.logdb.save_raft_state(merged)
         t1 = _time.perf_counter()
-        tr = self.engine.tracer
         if tr is not None and merged:
             # the merged batch is durable here — whichever tier fsynced
             # it (group-commit WAL or the classic per-committer save)
@@ -305,9 +310,15 @@ class Engine:
 
                     st = self._step_stats[idx]
                     t0 = _time.perf_counter()
-                    stepped, skipped = self.process_steps(
-                        active, self._committers[idx]
-                    )
+                    # the step batch, named for the profiler while the
+                    # tracer is on
+                    with (
+                        _annotate("raft_step") if self.tracer is not None
+                        else _OFF
+                    ):
+                        stepped, skipped = self.process_steps(
+                            active, self._committers[idx]
+                        )
                     st[0] += 1
                     st[1] += stepped
                     st[2] += skipped
@@ -366,8 +377,9 @@ class Engine:
                     n.commit_inflight = True
                 committer.submit(persist, updates)
             else:
-                self.logdb.save_raft_state(updates)
                 tr = self.tracer
+                with (_annotate("wal_sync") if tr is not None else _OFF):
+                    self.logdb.save_raft_state(updates)
                 if tr is not None:
                     tr.mark_updates(updates, "wal")
                 for n, ud in persist:
@@ -410,14 +422,22 @@ class Engine:
             )
             ready = self.apply_ready.get_ready(idx)
             self._rearm_unknown(ready, nodes, self.apply_ready)
-            for cid in ready:
-                n = nodes.get(cid)
-                if n is None:
-                    continue
-                try:
-                    n.handle_apply_tasks()
-                except Exception:
-                    plog.exception("apply worker %d failed on %d", idx, cid)
+            # the apply batch, named for the profiler while the tracer
+            # is on
+            with (
+                _annotate("apply") if ready and self.tracer is not None
+                else _OFF
+            ):
+                for cid in ready:
+                    n = nodes.get(cid)
+                    if n is None:
+                        continue
+                    try:
+                        n.handle_apply_tasks()
+                    except Exception:
+                        plog.exception(
+                            "apply worker %d failed on %d", idx, cid
+                        )
 
     def submit_snapshot(self, fn) -> None:
         """Queue snapshot save/stream work onto the dedicated pool."""

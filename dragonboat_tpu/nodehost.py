@@ -254,6 +254,20 @@ class NodeHost:
             os.environ.get("DBTPU_HEALTH_AGGREGATE", "")
             in ("1", "true", "on")
         )
+        # request tracing (obs/trace.py, ISSUE 9) is resolved BEFORE the
+        # coordinator too: a host whose tracer is on also attaches the
+        # coordinator's and engine's instruments (ISSUE 26: one switch),
+        # so the tracer's device_round stamp links a flight-recorder span
+        # and the spans exist in every traced run.
+        trace_n = nhconfig.trace_sample_every
+        if not trace_n:
+            try:
+                trace_n = int(os.environ.get("DBTPU_TRACE_SAMPLE", "0") or 0)
+            except ValueError:
+                # degrade like DBTPU_TRACE_STALL_MS: a malformed env var
+                # must not fail every NodeHost construction
+                plog.warning("malformed DBTPU_TRACE_SAMPLE; tracing off")
+                trace_n = 0
         if engine_choice == "tpu":
             from .tpuquorum import TpuQuorumCoordinator
 
@@ -266,14 +280,16 @@ class NodeHost:
                 ),
                 telem=health_aggregate,
             )
-            if nhconfig.enable_metrics:
+            if nhconfig.enable_metrics or trace_n > 0:
                 # device-plane observability rides the same flag as the
-                # raft event metrics: the flight recorder plus the
-                # engine/coordinator instrument families land in this
-                # host's registry, so write_health_metrics exposes
-                # device-plane health next to the node/transport counters
+                # raft event metrics, and the tracer's: the flight
+                # recorder plus the engine/coordinator instrument
+                # families land in this host's registry, so
+                # write_health_metrics exposes device-plane health next
+                # to the node/transport counters
                 self.quorum_coordinator.enable_obs(
-                    registry=self.raft_events.registry
+                    registry=self.raft_events.registry,
+                    host=nhconfig.raft_address,
                 )
             if expert.engine_warm_fused:
                 # AOT warm-compile of the fused program set, AFTER the
@@ -353,15 +369,6 @@ class NodeHost:
         # bit-identical trace=None latch.
         self.tracer = None
         self.replattr = None
-        trace_n = nhconfig.trace_sample_every
-        if not trace_n:
-            try:
-                trace_n = int(os.environ.get("DBTPU_TRACE_SAMPLE", "0") or 0)
-            except ValueError:
-                # degrade like DBTPU_TRACE_STALL_MS: a malformed env var
-                # must not fail every NodeHost construction
-                plog.warning("malformed DBTPU_TRACE_SAMPLE; tracing off")
-                trace_n = 0
         if trace_n > 0:
             from .obs.trace import Tracer
 

@@ -14,7 +14,16 @@ handling lives on cheap continuous telemetry"):
   bytes, dispatch/egress wall time, multidev-mutex wait, egress rows and
   reads released, gate reason), dumpable as JSON on demand and
   AUTO-dumped when a span trips the stall threshold — the round-gate
-  watchdog and the multi-device dispatch-lock wait feed the same check;
+  watchdog and the multi-device dispatch-lock wait feed the same check.
+  A span is an interval with a cause: ``t0``/``t1`` on
+  ``perf_counter`` (the tracer's clock), ``host``, ``parent`` (a
+  dispatch names its ``coord_round``), and its phases as ``*_ms``
+  fields that are also ``jax.profiler`` annotations
+  (``recorder.ANNOTATIONS``: ``dbtpu:round``, ``dbtpu:drain``,
+  ``dbtpu:step``, ``dbtpu:stage``, ``dbtpu:transfer``,
+  ``dbtpu:launch``, ``dbtpu:egress_wait``, ``dbtpu:decode``,
+  ``dbtpu:fanout``, …); ``ops.engine.compilation_log()`` names every
+  compile beside it;
 - :mod:`instruments` — ``EngineObs`` / ``CoordObs``: counters, gauges
   and latency histograms published into the existing
   :class:`dragonboat_tpu.events.MetricsRegistry`, so
@@ -57,8 +66,11 @@ obs-off engine keeps a bit-identical host path and eager-op set
 (regression axis: ``bench._run_obs_axis`` asserts obs-on throughput
 within 5% of obs-off).  The module-level latch below flips newly
 constructed engines/coordinators on (tests, bench axes); live wiring
-goes through ``NodeHostConfig.enable_metrics`` →
-``TpuQuorumCoordinator.enable_obs``.
+goes through ``NodeHostConfig.enable_metrics`` — or the request tracer
+(``trace_sample_every`` / ``DBTPU_TRACE_SAMPLE``): recorder and tracer
+share one switch and one clock — → ``TpuQuorumCoordinator.enable_obs``.
+With both off ``_obs`` and ``tracer`` stay ``None``: no annotation is
+built, no clock read, no dict written.
 """
 from __future__ import annotations
 

@@ -40,16 +40,31 @@ Coordinator plane (``CoordObs``): ``dragonboat_coord_rounds_total``,
 ``…tick_deficit_total``, ``…commits_offloaded_total``,
 ``…reads_confirmed_total``, ``…fused_dispatch_total`` /
 ``…fused_rounds_total`` (adaptive-K live batching); gauges
-``…staged_depth``, ``…read_fallbacks``.  Node offload application
-counts under ``dragonboat_node_offload_applied_total{kind=…}``
-(node.py).
+``…staged_depth``; ``…read_fallbacks_total{cause}`` — heartbeat read
+echoes tallied scalar-side, by cause (``slot_overflow`` /
+``after_confirm`` / ``purged``) — and ``…read_acks_total``, those the
+device tallied.  Node offload application counts under
+``dragonboat_node_offload_applied_total{kind=…}`` (node.py).
+
+Spans (ISSUE 26): every dispatch / round span is an interval
+(``t0``/``t1`` on ``perf_counter``) with ``host`` and ``parent``, and
+carries its phases as ``*_ms`` fields — ``EngineObs.phase`` /
+``CoordObs.phase`` time one occurrence of a phase, annotate it for the
+profiler (``recorder.ANNOTATIONS``) and add it to the span being built.
 """
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from ..events import DEFAULT_BUCKETS, DEFAULT_REGISTRY, MetricsRegistry
-from .recorder import FlightRecorder
+from .recorder import OFF, FlightRecorder, Phase, annotate
+
+#: the phases of one engine step, by the span field they sum into
+DISPATCH_PHASES = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms")
+EGRESS_PHASES = ("egress_wait_ms", "decode_ms")
+#: why a heartbeat read echo was tallied scalar-side (tpuquorum.py)
+READ_FALLBACK_CAUSES = ("slot_overflow", "after_confirm", "purged")
 
 #: log-spaced dispatch/egress/round latency buckets (ms): the live
 #: coordinator's single-round dispatches sit near the bottom decade, a
@@ -124,7 +139,11 @@ _HELP = {
     _COORD + "fused_dispatch_total": "rounds served by one fused dispatch",
     _COORD + "fused_rounds_total": "rounds carried by fused dispatches",
     _COORD + "staged_depth": "ops staged for the next round",
-    _COORD + "read_fallbacks": "read echoes tallied scalar-side",
+    _COORD + "read_fallbacks_total": "heartbeat read echoes tallied "
+    "scalar-side, by cause: slot_overflow (the context never got a "
+    "device slot), after_confirm (its context was already confirmed or "
+    "prefix-released), purged (a transition dropped the group's FIFO)",
+    _COORD + "read_acks_total": "heartbeat read echoes the device tallied",
     _HOST + "ingress_submitted_total": "commands accepted into ingress rings",
     _HOST + "ingress_drains_total": "ingress batcher drain cycles",
     _HOST + "ingress_drained_total": "commands drained by the batcher",
@@ -270,6 +289,36 @@ def _describe(registry: MetricsRegistry, names) -> None:
             registry.describe(name, text)
 
 
+class _StepScope:
+    """One engine call as a ``dbtpu:step`` annotation (not re-entered);
+    the span the call opened gets the call's wall time as ``step_ms``."""
+
+    __slots__ = ("obs", "ann", "t")
+
+    def __init__(self, obs):
+        self.obs = obs
+        self.ann = annotate("step")
+
+    def __enter__(self):
+        obs = self.obs
+        obs._in_step = True
+        obs._scope_span = None
+        self.ann.__enter__()
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        obs = self.obs
+        span, obs._scope_span = obs._scope_span, None
+        if span is not None:
+            span["step_ms"] = round(
+                (time.perf_counter() - self.t) * 1e3, 4
+            )
+        self.ann.__exit__(*exc)
+        obs._in_step = False
+        return False
+
+
 class EngineObs:
     """Device-plane instruments for one ``BatchedQuorumEngine``.
 
@@ -278,7 +327,8 @@ class EngineObs:
     obs-off host path stays bit-identical (module docstring contract).
     """
 
-    __slots__ = ("recorder", "registry", "shard")
+    __slots__ = ("recorder", "registry", "shard", "host", "parent", "t0",
+                 "ph", "_in_step", "_scope_span")
 
     _COUNTERS = (
         _DEV + "dispatch_total",
@@ -313,6 +363,7 @@ class EngineObs:
         recorder: FlightRecorder,
         registry: Optional[MetricsRegistry] = None,
         shard: Optional[int] = None,
+        host: Optional[str] = None,
     ):
         self.recorder = recorder
         self.registry = registry or DEFAULT_REGISTRY
@@ -320,6 +371,17 @@ class EngineObs:
         #: plane — stamped into dispatch spans so the ring shows which
         #: stream launched what (the span-overlap evidence keys on it)
         self.shard = shard
+        #: the owning NodeHost (raft address) and the seq of the
+        #: coordinator round driving the engine (None: a bare engine) —
+        #: stamped into every span as ``host`` / ``parent``
+        self.host = host
+        self.parent: Optional[int] = None
+        #: perf_counter at the current step's start and the phase
+        #: milliseconds accumulated since (``begin_step`` resets both)
+        self.t0 = 0.0
+        self.ph: dict = {}
+        self._in_step = False  # inside a dbtpu:step scope
+        self._scope_span = None  # the span that scope's call opened
         r = self.registry
         _describe(r, self._COUNTERS + (
             _DEV + "staged_rounds", _DEV + "read_slots_in_use",
@@ -338,6 +400,38 @@ class EngineObs:
             _DEV + "egress_latency_ms", buckets=LATENCY_BUCKETS_MS
         )
 
+    def begin_step(self) -> None:
+        """A step starts here (after the previous block's harvest): the
+        span ``dispatch`` opens carries this ``t0`` and the dispatch
+        phases accumulated from now on."""
+        ph = self.ph
+        for k in DISPATCH_PHASES:
+            ph[k] = 0.0
+        self.t0 = time.perf_counter()
+
+    def step_scope(self):
+        """``with obs.step_scope():`` around one ``step`` /
+        ``step_rounds`` call: the ``dbtpu:step`` annotation that holds
+        the call's phases in a capture, so that what lies between them
+        (the round thread waiting for the interpreter, the bookkeeping
+        here) has a name there too, and its wall time lands on the span the call opened
+        as ``step_ms``.  ``step`` rerouting into ``step_rounds`` stays
+        one scope."""
+        return OFF if self._in_step else _StepScope(self)
+
+    def phase(self, name: str) -> Phase:
+        """``with obs.phase("stage"):`` — one occurrence of a phase of
+        the current step (annotation + ``<name>_ms`` accumulation)."""
+        return Phase(self.ph, name)
+
+    def _take(self, keys) -> dict:
+        """The accumulated phase fields ``keys``, rounded, zeroed."""
+        ph = self.ph
+        out = {k: round(ph.get(k, 0.0), 4) for k in keys}
+        for k in keys:
+            ph[k] = 0.0
+        return out
+
     def warmup(self, *, variant: str, seconds: float) -> dict:
         """One AOT-warmed device program (engine ``_warmup_main``):
         accumulate ``dragonboat_device_warmup_seconds`` and record a
@@ -348,8 +442,11 @@ class EngineObs:
         r = self.registry
         r.counter_add(_DEV + "warmup_seconds", seconds)
         r.counter_add(_DEV + "warmup_programs_total")
+        now = time.perf_counter()
         return self.recorder.record(
             "warmup",
+            t0=now - seconds,
+            host=self.host,
             variant=variant,
             compile_ms=round(seconds * 1e3, 4),
         )
@@ -372,6 +469,8 @@ class EngineObs:
         r.gauge_set(_DEVSM + "slot_occupancy", slot_occupancy)
         return self.recorder.record(
             "apply_kernel",
+            host=self.host,
+            parent=self.parent,
             ops=ops,
             reads=reads,
             rounds=rounds,
@@ -449,6 +548,9 @@ class EngineObs:
             extra["shard"] = self.shard
         span = self.recorder.record(
             kind,
+            t0=self.t0,
+            host=self.host,
+            parent=self.parent,
             gate=gate,
             rounds=rounds,
             **extra,
@@ -459,10 +561,12 @@ class EngineObs:
             echoes=echoes,
             upload_bytes=upload_bytes,
             dispatch_ms=round(dispatch_ms, 4),
+            **self._take(DISPATCH_PHASES),
             mu_wait_ms=round(mu_wait_ms, 4),
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")
+        self._scope_span = span
         return span
 
     def egress(
@@ -483,6 +587,7 @@ class EngineObs:
         self.recorder.update(
             span,
             egress_ms=round(egress_ms, 4),
+            **self._take(EGRESS_PHASES),
             egress_rows=egress_rows,
             reads_released=reads_released,
         )
@@ -1155,7 +1260,7 @@ class MeshObs:
 class CoordObs:
     """Round-loop instruments for one ``TpuQuorumCoordinator``."""
 
-    __slots__ = ("recorder", "registry")
+    __slots__ = ("recorder", "registry", "host", "ph")
 
     _COUNTERS = (
         _COORD + "rounds_total",
@@ -1168,45 +1273,76 @@ class CoordObs:
         # ratio to rounds_total is the live fused duty cycle
         _COORD + "fused_dispatch_total",
         _COORD + "fused_rounds_total",
+        _COORD + "read_acks_total",
     )
 
     def __init__(
-        self, recorder: FlightRecorder, registry: Optional[MetricsRegistry] = None
+        self, recorder: FlightRecorder,
+        registry: Optional[MetricsRegistry] = None,
+        host: Optional[str] = None,
     ):
         self.recorder = recorder
         self.registry = registry or DEFAULT_REGISTRY
+        self.host = host
+        #: phase milliseconds of the round in progress (drain, fanout)
+        self.ph: dict = {}
         r = self.registry
         _describe(r, self._COUNTERS + (
-            _COORD + "staged_depth", _COORD + "read_fallbacks",
+            _COORD + "staged_depth", _COORD + "read_fallbacks_total",
             _COORD + "round_latency_ms",
         ))
         for name in self._COUNTERS:
             r.counter_add(name, 0)
+        for cause in READ_FALLBACK_CAUSES:
+            r.counter_add(
+                _COORD + "read_fallbacks_total", 0, {"cause": cause}
+            )
         r.gauge_set(_COORD + "staged_depth", 0)
-        r.gauge_set(_COORD + "read_fallbacks", 0)
         r.histogram_declare(
             _COORD + "round_latency_ms", buckets=LATENCY_BUCKETS_MS
         )
 
+    def phase(self, name: str) -> Phase:
+        """``with obs.phase("drain"):`` — a phase of the round in
+        progress (annotation + ``<name>_ms`` accumulation)."""
+        return Phase(self.ph, name)
+
+    def round_open(self, *, t0: float, gate: str, wait_ms: float) -> dict:
+        """A round that WILL dispatch opens its span before its first
+        dispatch (quiet early-return rounds are not recorded), so the
+        engine's spans can name it as ``parent`` and the tracer's
+        ``device_round`` stamp links a span with an interval.  ``t0`` is
+        the round's start on ``perf_counter``; ``wait_ms`` how long its
+        oldest staged op or tick had waited by then."""
+        return self.recorder.record(
+            "coord_round",
+            t0=t0,
+            host=self.host,
+            gate=gate,
+            wait_ms=round(wait_ms, 4),
+        )
+
     def round(
         self,
+        span: dict,
         *,
-        wall_ms: float,
-        gate: str,
         ops: int,
         deficit: int,
         commits: int,
         reads_confirmed: int,
-        read_fallbacks: int,
         staged_depth: int,
         k_rounds: int = 1,
         fused: bool = False,
         fuse_skip: Optional[str] = None,
+        read_acks: int = 0,
+        read_fallbacks: Optional[dict] = None,
+        reads_staged: int = 0,
+        reads_refused: int = 0,
     ) -> dict:
-        """One dispatched coordinator round (quiet early-return rounds are
-        not recorded).  The recorder's stall check on ``wall_ms`` IS the
-        round-gate watchdog: a round outlasting ``stall_ms`` auto-dumps
-        the ring with this span as the trigger.
+        """Close a dispatched round's span (``round_open``).  The
+        recorder's stall check on ``wall_ms`` IS the round-gate watchdog:
+        a round outlasting ``stall_ms`` auto-dumps the ring with this
+        span as the trigger.
 
         ``k_rounds`` is the adaptive K the round chose (1 = the
         single-round path); ``fused`` marks a fused multi-round dispatch;
@@ -1216,8 +1352,13 @@ class CoordObs:
         recycles/pre-staged rounds in the backlog, ``"mesh_warmup"`` —
         a mesh coordinator's per-shard program sets still warming) so
         the warmup gate can assert proposals never blocked on
-        compilation."""
+        compilation.  ``read_acks`` / ``read_fallbacks`` (cause -> count)
+        are this round's heartbeat read echoes, tallied on the device /
+        scalar-side; ``reads_staged`` / ``reads_refused`` the ReadIndex
+        contexts given / refused a device slot."""
         r = self.registry
+        t1 = time.perf_counter()
+        wall_ms = (t1 - span["t0"]) * 1e3
         r.counter_add(_COORD + "rounds_total")
         if ops:
             r.counter_add(_COORD + "ops_drained_total", ops)
@@ -1230,8 +1371,9 @@ class CoordObs:
         if fused:
             r.counter_add(_COORD + "fused_dispatch_total")
             r.counter_add(_COORD + "fused_rounds_total", k_rounds)
+        if read_acks:
+            r.counter_add(_COORD + "read_acks_total", read_acks)
         r.gauge_set(_COORD + "staged_depth", staged_depth)
-        r.gauge_set(_COORD + "read_fallbacks", read_fallbacks)
         r.histogram_observe(
             _COORD + "round_latency_ms", wall_ms, buckets=LATENCY_BUCKETS_MS
         )
@@ -1240,14 +1382,27 @@ class CoordObs:
             extra["fused"] = True
         if fuse_skip:
             extra["fuse_skip"] = fuse_skip
-        return self.recorder.record(
-            "coord_round",
-            gate=gate,
+        for cause, n in (read_fallbacks or {}).items():
+            if n:
+                r.counter_add(
+                    _COORD + "read_fallbacks_total", n, {"cause": cause}
+                )
+            extra["read_fallback_" + cause] = n
+        ph = self.ph
+        self.recorder.update(
+            span,
+            t1=t1,
             wall_ms=round(wall_ms, 4),
+            drain_ms=round(ph.pop("drain_ms", 0.0), 4),
+            fanout_ms=round(ph.pop("fanout_ms", 0.0), 4),
             ops=ops,
             deficit=deficit,
             k_rounds=k_rounds,
             commits=commits,
             reads_confirmed=reads_confirmed,
+            read_acks=read_acks,
+            reads_staged=reads_staged,
+            reads_refused=reads_refused,
             **extra,
         )
+        return span

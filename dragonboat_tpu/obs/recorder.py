@@ -10,6 +10,18 @@ egress fields land at harvest time), so a dump taken mid-flight shows
 the in-flight dispatch with its egress still pending — exactly the span
 a stall investigation needs.
 
+A span is an INTERVAL with a cause (ISSUE 26): ``t0``/``t1`` on
+``time.perf_counter()`` — the clock of the request tracer's stamps
+(``obs/trace.py``) — ``host`` (the owning coordinator's NodeHost, so
+co-hosted NodeHosts can share ``obs.default_recorder()``) and ``parent``
+(the ``seq`` of the span that caused it: a ``dispatch``/``fused`` span
+names the ``coord_round`` that ran it).  The same phases are also
+``jax.profiler.TraceAnnotation`` events (``ANNOTATIONS`` below), so a
+profiler capture shows them on the device trace's clock.  The ring and
+the tracer share one switch: a NodeHost whose tracer is on
+(``trace_sample_every`` / ``DBTPU_TRACE_SAMPLE``) attaches the ring
+exactly as ``enable_metrics`` does.
+
 The stall watchdog rides the same records: any span whose wall fields
 (``wall_ms`` / ``dispatch_ms`` / ``egress_ms`` / ``mu_wait_ms``) reach
 ``stall_ms`` is marked ``stalled`` and triggers an automatic dump —
@@ -23,13 +35,87 @@ import json
 import os
 import threading
 import time
+from contextlib import nullcontext
 from typing import List, Optional
 
 from ..logger import get_logger
 
 plog = get_logger("obs")
 
-DEFAULT_CAPACITY = 512
+#: a span is a small dict and the ring exists only while obs is on: at
+#: ~10 rounds/s on three co-hosted NodeHosts with a span per round and per
+#: dispatch, 16k spans hold several minutes
+DEFAULT_CAPACITY = 16384
+
+#: the profiler-annotation vocabulary: constant names, one per phase of a
+#: coordinator round / engine dispatch / execution-engine batch.  Phases
+#: that are also ``*_ms`` span fields map to the field name.
+ANNOTATIONS = {
+    "round_idle": "dbtpu:round_idle",    # round thread waiting for work
+    "round": "dbtpu:round",              # coord_round t0..t1
+    "drain": "dbtpu:drain",              # coord_round drain_ms
+    "fanout": "dbtpu:fanout",            # coord_round fanout_ms
+    "step": "dbtpu:step",                # one engine step()/step_rounds()
+                                         # call: holds the phases below;
+                                         # its self time is step_ms less
+                                         # the phases
+    "row_sync": "dbtpu:row_sync",        # dispatch/fused row_sync_ms
+    "stage": "dbtpu:stage",              # dispatch/fused stage_ms
+    "transfer": "dbtpu:transfer",        # dispatch/fused transfer_ms
+    "launch": "dbtpu:launch",            # dispatch/fused launch_ms
+    "egress_wait": "dbtpu:egress_wait",  # dispatch/fused egress_wait_ms
+    "decode": "dbtpu:decode",            # dispatch/fused decode_ms
+    "compile": "dbtpu:compile",          # ops.engine.compilation_log()
+    "raft_step": "dbtpu:raft_step",      # execution engine step batch
+    "wal_sync": "dbtpu:wal_sync",        # log save + fsync
+    "apply": "dbtpu:apply",              # apply batch
+}
+
+#: what ``with (obs.phase(...) if obs is not None else OFF):`` enters while
+#: observability is off: nothing is built, timed or written
+OFF = nullcontext()
+
+_TRACE_ANNOTATION = None
+
+
+def annotate(phase: str):
+    """A ``jax.profiler.TraceAnnotation`` context for one phase of the
+    vocabulary (a TraceMe: near-free while no profiler is capturing).
+    Callers sit behind their ``_obs`` / ``tracer`` latch — nothing is
+    built while observability is off."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(ANNOTATIONS[phase])
+
+
+class Phase:
+    """``with Phase(acc, "stage"):`` — one occurrence of a phase: a
+    profiler annotation plus its wall milliseconds added to
+    ``acc["stage_ms"]`` (a span's phase field is the sum over the span's
+    occurrences; a step may chunk into several programs)."""
+
+    __slots__ = ("acc", "key", "ann", "t")
+
+    def __init__(self, acc: dict, phase: str):
+        self.acc = acc
+        self.key = phase + "_ms"
+        self.ann = annotate(phase)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        acc = self.acc
+        acc[self.key] = acc.get(self.key, 0.0) + (
+            time.perf_counter() - self.t
+        ) * 1e3
+        self.ann.__exit__(*exc)
+        return False
 
 #: span fields the stall watchdog inspects, in attribution order
 _STALL_KEYS = ("wall_ms", "dispatch_ms", "egress_ms", "mu_wait_ms")
@@ -56,7 +142,18 @@ class FlightRecorder:
                             ``"fused"`` (engine, K-round block),
                             ``"coord_round"`` (tpuquorum round loop),
                             ``"warmup"`` (one AOT-warmed program)
-    ``ts``                  wall-clock time the span was recorded
+    ``t0`` ``t1``           the span's interval on ``time.perf_counter()``
+                            (the tracer's clock): opened at the round's
+                            / step's start, ``t1`` moved by every
+                            ``update`` until the span is final
+    ``host``                the owning coordinator's NodeHost (its raft
+                            address); None for a bare engine
+    ``parent``              ``seq`` of the span that caused this one (a
+                            dispatch names its ``coord_round``); None at
+                            the top
+    ``ts``                  wall-clock time the record was WRITTEN — for
+                            a human reading a JSON dump only; nothing
+                            orders or overlaps spans by it (use ``t0``)
     ``gate``                why the dispatch fired: ``+``-joined subset of
                             ``tick``/``acks``/``reads``/``churn``/``dirty``,
                             or ``drain``
@@ -76,9 +173,40 @@ class FlightRecorder:
     ``recycles``            in-program membership recycles in the block
     ``reads`` ``echoes``    staged ReadIndex batches / heartbeat echoes
     ``upload_bytes``        host→device event-tensor bytes
-    ``dispatch_ms``         host wall time staging + launching the program
+    ``dispatch_ms``         host wall time staging + launching the
+                            program, from the step's start: the sum of
+                            ``row_sync_ms`` (dirty-row upload, row pulls,
+                            committed-cache refresh: every
+                            gather/scatter-rows program), ``stage_ms``
+                            (event gathering, padding, the K-round block
+                            build), ``transfer_ms`` (the ``jnp.asarray``
+                            puts), ``launch_ms`` (the jitted call
+                            until it returns), and what is left over:
+                            time inside no phase, the round thread
+                            waiting for the interpreter between two
     ``egress_ms``           blocking device→host egress wall time (set at
-                            harvest; an in-flight span lacks it)
+                            harvest; an in-flight span lacks it): the sum
+                            of ``egress_wait_ms`` (the ``device_get``)
+                            and ``decode_ms`` (egress translation)
+    ``step_ms``             the whole ``step`` / ``step_rounds`` call that
+                            opened the span (what a caller sees): its
+                            phases, the time between them and the span
+                            bookkeeping
+    ``wait_ms``             coord spans: how long the round's oldest
+                            staged op / tick waited for the round thread
+    ``drain_ms``            coord spans: draining staged ops into the
+                            engine (its row syncs and scalar read-echo
+                            fallbacks included)
+    ``fanout_ms``           coord spans: everything after the engine
+                            returned (trace stamps, commit / read-confirm
+                            / tick-flag offloads, election term reads)
+    ``read_acks``           coord spans: heartbeat echoes the device
+                            tallied this round; ``read_fallback_<cause>``
+                            those tallied scalar-side, by cause
+                            (``slot_overflow``/``after_confirm``/
+                            ``purged``); ``reads_staged`` /
+                            ``reads_refused`` contexts given / refused a
+                            device slot
     ``egress_rows``         rows whose commit watermark advanced
     ``reads_released``      client reads released by confirmed slots
     ``mu_wait_ms``          time spent waiting on the engine's multi-
@@ -126,7 +254,9 @@ class FlightRecorder:
     def record(self, kind: str, **fields) -> dict:
         """Append a span; returns the (mutable) span dict so the producer
         can finalize it later (``update``)."""
-        span = {"kind": kind, "ts": time.time()}
+        now = time.perf_counter()
+        span = {"kind": kind, "ts": time.time(), "t0": now, "t1": now,
+                "host": None, "parent": None}
         span.update(fields)
         with self._mu:
             span["seq"] = self._n
@@ -136,7 +266,9 @@ class FlightRecorder:
         return span
 
     def update(self, span: dict, **fields) -> None:
-        """Finalize a span in place (egress fields land at harvest)."""
+        """Finalize a span in place (egress fields land at harvest);
+        ``t1`` follows unless the caller passes its own."""
+        span["t1"] = time.perf_counter()
         span.update(fields)
         self._stall_check(span)
 

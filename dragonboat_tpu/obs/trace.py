@@ -46,7 +46,10 @@ stage           stamped when
 ``device_round``the coordinator round whose dispatch released the
                 group's commit (tpu engine only; replace-style — the
                 LAST such round before apply wins — and the recorder
-                span seq is linked into ``Trace.spans``)
+                seqs of the dispatch span and of its ``coord_round``
+                span are linked into ``Trace.spans``; the round span's
+                ``t0``/``t1`` are on this module's clock and hold the
+                stamp)
 ``read_confirm``the ReadIndex ctx was quorum-confirmed (reads only)
 ``lease_read``  the read was served locally under a valid leader lease
                 (ISSUE 10) — replaces ``read_confirm``; no confirmation
@@ -92,6 +95,16 @@ STAGE_BUCKETS_S = (
 #: request token carries its owning tracer, so completions never route
 #: through this global.
 _ACTIVE: Optional["Tracer"] = None
+#: every tracer constructed and not yet closed, oldest first
+#: (``live()``): co-hosted NodeHosts each own one
+_LIVE: List["Tracer"] = []
+
+#: request kinds as ``outcomes()`` names them
+_OUTCOME_KIND = {"write": "propose", "read": "read"}
+#: non-``COMPLETED`` completions remembered for ``outcomes()["events"]``
+OUTCOME_EVENTS_KEEP = 65536
+#: whole seconds of ``outcomes()["by_second"]`` kept (oldest dropped)
+OUTCOME_SECONDS_KEEP = 900
 
 
 def _default_stall_ms() -> float:
@@ -141,7 +154,7 @@ class Trace:
             self.applied = True
 
     def add_round(self, span_seq: Optional[int], now: float,
-                  thread: str) -> None:
+                  thread: str, round_seq: Optional[int] = None) -> None:
         """Replace-style ``device_round`` stamp: a request can sit through
         several coordinator rounds while waiting for apply — the LAST
         round before apply is the one whose dispatch released its commit,
@@ -155,10 +168,11 @@ class Trace:
             # already applied: a later round touching this group can no
             # longer be the one that released this request
             return
-        if span_seq is not None and (
-            not self.spans or self.spans[-1] != span_seq
-        ):
-            self.spans.append(span_seq)
+        spans = self.spans
+        if span_seq is not None and span_seq not in spans[-2:]:
+            spans.append(span_seq)
+        if round_seq is not None and round_seq not in spans[-2:]:
+            spans.append(round_seq)
         ev = self._round_ev
         if ev is not None:
             ev[1] = now
@@ -258,6 +272,15 @@ class Tracer:
         self._pend_requests = 0
         self._pend_sampled = 0
         self._pend_completed = 0
+        # ---- attempt outcomes by result code (ISSUE 26) --------------
+        # every completed request, sampled or not: (kind, CODE) -> count
+        # since construction, the part not yet flushed to the registry,
+        # and the non-COMPLETED ones as (perf_counter, kind, CODE) so a
+        # reader can take a window's (bounded; guarded by _mu)
+        self._outcomes: Dict[tuple, int] = {}
+        self._pend_outcomes: Dict[tuple, int] = {}
+        self._outcome_events: deque = deque(maxlen=OUTCOME_EVENTS_KEEP)
+        self._outcome_secs: Dict[int, Dict[tuple, int]] = {}
         # clock anchor: stamps are perf_counter (monotonic); the export
         # maps them onto the wall clock the recorder spans already use
         self._wall0 = time.time()
@@ -266,6 +289,11 @@ class Tracer:
         r.describe(
             _T + "requests_total",
             "requests that entered the traced pipeline (sampled or not)",
+        )
+        r.describe(
+            _T + "requests_done_total",
+            "requests completed while tracing is on (sampled or not), by "
+            "kind (propose/read) and result code",
         )
         r.describe(_T + "sampled_total", "requests allocated a full trace")
         r.describe(_T + "completed_total", "sampled traces completed")
@@ -292,6 +320,7 @@ class Tracer:
         r.histogram_declare(_T + "e2e_seconds", buckets=STAGE_BUCKETS_S)
         global _ACTIVE
         _ACTIVE = self
+        _LIVE.append(self)
 
     # ------------------------------------------------------------------
     # allocation (propose / read time)
@@ -307,10 +336,11 @@ class Tracer:
         store per future — nothing else."""
         n = self.sample_every
         nstates = len(states)
-        tok = (self, t0)  # ONE shared token per burst: non-sampled
-        # futures carry (tracer, t0) so completion observes e2e into the
-        # tracer that owns them (a module-global sink misattributed
-        # multi-NodeHost processes), at zero per-request allocation
+        tok = (self, t0, kind)  # ONE shared token per burst: non-sampled
+        # futures carry (tracer, t0, kind) so completion observes e2e
+        # and its result code into the tracer that owns them (a
+        # module-global sink misattributed multi-NodeHost processes), at
+        # zero per-request allocation
         with self._mu:
             base = self._n
             self._n = base + nstates
@@ -409,10 +439,13 @@ class Tracer:
                 if t is not None and not t.done:
                     t.add(stage)
 
-    def mark_clusters(self, cids, span_seq: Optional[int] = None) -> None:
+    def mark_clusters(self, cids, span_seq: Optional[int] = None,
+                      round_seq: Optional[int] = None) -> None:
         """The coordinator round released commits/read-confirms for these
         groups: stamp ``device_round`` (replace-style) on every in-flight
-        trace of those groups and link the dispatch span seq."""
+        trace of those groups and link the dispatch span seq and the
+        round's own ``coord_round`` span seq (whose interval holds the
+        stamp)."""
         if not self._by_cluster:
             return
         now = time.perf_counter()
@@ -428,7 +461,7 @@ class Tracer:
             get = bc.get
             for cid in cids:
                 for t in get(cid, ()):
-                    t.add_round(span_seq, now, thread)
+                    t.add_round(span_seq, now, thread, round_seq)
 
     # ------------------------------------------------------------------
     # replication legs (ISSUE 14, follower side)
@@ -468,9 +501,49 @@ class Tracer:
         acc[1] += seconds
         acc[2] += 1
 
-    def observe_e2e(self, seconds: float) -> None:
+    def _count_outcome(self, kind: str, code: str, now: float) -> None:
+        """One completed request of ``kind`` with result ``code``; caller
+        holds ``_mu``."""
+        key = (_OUTCOME_KIND.get(kind, kind), code)
+        self._outcomes[key] = self._outcomes.get(key, 0) + 1
+        self._pend_outcomes[key] = self._pend_outcomes.get(key, 0) + 1
+        secs = self._outcome_secs
+        sec = secs.get(int(now))
+        if sec is None:
+            sec = secs[int(now)] = {}
+            if len(secs) > OUTCOME_SECONDS_KEEP:
+                del secs[min(secs)]
+        sec[key] = sec.get(key, 0) + 1
+        if code != "COMPLETED":
+            self._outcome_events.append((now, key[0], code))
+
+    def observe_done(self, t0: float, kind: str, code: str) -> None:
+        """A non-sampled request completed: its e2e latency and its
+        ``(kind, code)`` count, under one lock."""
+        now = time.perf_counter()
         with self._mu:
-            self._acc(self._e2e_acc, seconds)
+            self._acc(self._e2e_acc, now - t0)
+            self._count_outcome(kind, code, now)
+
+    def outcomes(self) -> dict:
+        """Attempt outcomes by result code since construction:
+        ``{"counts": {(kind, CODE): n}, "events": [(perf_counter, kind,
+        CODE), ...], "by_second": {int(perf_counter): {(kind, CODE):
+        n}}}`` with kind ``propose`` / ``read``, CODE the
+        ``RequestResultCode`` name, ``events`` the non-``COMPLETED``
+        completions (bounded, oldest first) and ``by_second`` every
+        completion by the whole second it fell in (the newest
+        ``OUTCOME_SECONDS_KEEP``), so a reader can take a window's.
+        Counts every request notified while tracing is on, sampled or
+        not."""
+        with self._mu:
+            return {
+                "counts": dict(self._outcomes),
+                "events": list(self._outcome_events),
+                "by_second": {
+                    s: dict(c) for s, c in self._outcome_secs.items()
+                },
+            }
 
     def finish(self, trace: Trace, outcome: str) -> None:
         """Trace completes (future notified): final ``egress`` stamp,
@@ -487,6 +560,7 @@ class Tracer:
         trace.add("egress")
         evs = sorted(trace.events, key=lambda e: e[1])
         with self._mu:
+            self._count_outcome(trace.kind, outcome.upper(), evs[-1][1])
             if trace.key:
                 self._by_key.pop(trace.key, None)
             s = self._by_cluster.get(trace.cluster_id)
@@ -526,8 +600,14 @@ class Tracer:
             reqs, self._pend_requests = self._pend_requests, 0
             samp, self._pend_sampled = self._pend_sampled, 0
             comp, self._pend_completed = self._pend_completed, 0
+            outs, self._pend_outcomes = self._pend_outcomes, {}
             inflight = sum(len(v) for v in self._by_cluster.values())
         reg = self.registry
+        for (kind, code), n in outs.items():
+            reg.counter_add(
+                _T + "requests_done_total", n,
+                {"kind": kind, "code": code},
+            )
         if reqs:
             reg.counter_add(_T + "requests_total", reqs)
         if samp:
@@ -553,6 +633,10 @@ class Tracer:
         global _ACTIVE
         if _ACTIVE is self:
             _ACTIVE = None
+        try:
+            _LIVE.remove(self)
+        except ValueError:
+            pass
         self.flush_metrics()
 
     # ------------------------------------------------------------------
@@ -567,7 +651,10 @@ class Tracer:
         count.  Doubles as the metric-flush cadence.  The fast path —
         nothing sampled in flight, nothing pending — is a few
         truthiness checks."""
-        if self._pend_requests or self._pend_completed or self._e2e_acc[2]:
+        if (
+            self._pend_requests or self._pend_completed
+            or self._e2e_acc[2] or self._pend_outcomes
+        ):
             self.flush_metrics()
         if not self._by_cluster and not self._by_key:
             return 0
@@ -788,24 +875,25 @@ class Tracer:
         if include_recorder and self.recorder is not None:
             dev_tid = tid_of("device-plane")
             for span in self.recorder.spans():
-                ts = span.get("ts")
-                if ts is None:
+                t0 = span.get("t0")
+                if t0 is None:
                     continue
-                dur_ms = span.get("wall_ms") or (
-                    (span.get("dispatch_ms") or 0.0)
-                    + (span.get("egress_ms") or 0.0)
-                )
+                if self.host and span.get("host") not in (None, self.host):
+                    continue  # a co-hosted NodeHost's span (shared ring)
+                # the span's own interval, on the stamps' clock
                 events.append({
                     "name": span.get("kind", "span"),
                     "cat": "device",
                     "ph": "X",
                     "pid": 1,
                     "tid": dev_tid,
-                    "ts": round(ts * 1e6, 1),
-                    "dur": round(max(dur_ms, 0.001) * 1e3, 1),
+                    "ts": round(self._wall_us(t0), 1),
+                    "dur": round(
+                        max(span.get("t1", t0) - t0, 1e-6) * 1e6, 1
+                    ),
                     "args": {
                         k: v for k, v in span.items()
-                        if k not in ("ts",)
+                        if k not in ("ts", "t0", "t1")
                     },
                 })
         ra = self.replattr
@@ -891,16 +979,24 @@ def _outcome_name(result) -> str:
 
 def request_done(token, result) -> None:
     """Called by ``RequestState.notify`` when the future carries a trace
-    token.  A ``(tracer, t0)`` tuple is the always-on enqueue timestamp
-    of a non-sampled request: observe e2e into its owning tracer.  A
-    :class:`Trace` completes into the tracer that allocated it."""
+    token.  A ``(tracer, t0, kind)`` tuple is the always-on enqueue
+    timestamp of a non-sampled request: observe e2e and count its
+    ``(kind, result code)`` into its owning tracer.  A :class:`Trace`
+    completes into the tracer that allocated it (which counts it the
+    same way)."""
     if token.__class__ is Trace:
         token.tracer.finish(token, _outcome_name(result))
         return
-    tracer, t0 = token
-    tracer.observe_e2e(time.perf_counter() - t0)
+    tracer, t0, kind = token
+    tracer.observe_done(t0, kind, _outcome_name(result).upper())
 
 
 def active() -> Optional[Tracer]:
     """The newest-enabled tracer (None when tracing is off)."""
     return _ACTIVE
+
+
+def live() -> List[Tracer]:
+    """Every tracer of this process that is on (constructed, not yet
+    closed), oldest first: co-hosted NodeHosts each own one."""
+    return list(_LIVE)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from .. import obs as _obs
 from ..logger import get_logger
+from ..obs.recorder import OFF as _OFF, annotate as _annotate
 from .kernels import TELEM_TOPK, quorum_step
 from .state import (
     CANDIDATE,
@@ -50,6 +51,7 @@ from .state import (
 )
 
 elog = get_logger("ops.engine")
+
 
 # Event batches are padded to fixed sizes so jit compiles once.
 DEFAULT_EVENT_CAP = 4096
@@ -145,10 +147,37 @@ DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
 )
 
 
+#: every compile-or-load that reached jax's one compile entry point since
+#: the persistent cache was enabled: (perf_counter t0, t1, program name,
+#: thread name, "hit" | "miss" | "uncached" of the persistent cache).
+#: Bounded; compiles are rare, so always on.
+_CC_LOG: deque = deque(maxlen=4096)
+
+
+def _program_name(computation) -> str:
+    """The program's name as jax lowered it (``jit_quorum_step_impl``)."""
+    try:
+        return str(
+            computation.operation.attributes["sym_name"]
+        ).strip('"')
+    except Exception:
+        return "?"
+
+
 def _locked_compile_or_get_cached(orig):
-    def locked(*a, **k):
+    def locked(backend, computation, *a, **k):
         with _CC_COMPILE_MU:
-            return orig(*a, **k)
+            hits, misses = _CC["hits"], _CC["misses"]
+            t0 = time.perf_counter()
+            with _annotate("compile"):
+                out = orig(backend, computation, *a, **k)
+            _CC_LOG.append((
+                t0, time.perf_counter(), _program_name(computation),
+                threading.current_thread().name,
+                "miss" if _CC["misses"] != misses
+                else "hit" if _CC["hits"] != hits else "uncached",
+            ))
+            return out
 
     return locked
 
@@ -216,6 +245,16 @@ def compilation_cache_stats() -> dict:
     """Persistent-cache telemetry: the directory in use plus process-
     lifetime hit/miss counts (None dir = cache never enabled here)."""
     return {"dir": _CC["dir"], "hits": _CC["hits"], "misses": _CC["misses"]}
+
+
+def compilation_log() -> List[Tuple[float, float, str, str, str]]:
+    """Every program compiled or loaded from the persistent cache since
+    it was enabled, oldest first: ``(t0, t1, program, thread, "hit" |
+    "miss" | "uncached")`` with ``t0``/``t1`` on
+    ``time.perf_counter()`` — the name behind each count of
+    :func:`compilation_cache_stats`, and the other side of a ``warmup``
+    span's ``variant``."""
+    return list(_CC_LOG)
 
 
 @jax.jit
@@ -695,7 +734,8 @@ class BatchedQuorumEngine:
             "cache_hits": 0, "cache_misses": 0, "error": None,
         }
 
-    def enable_obs(self, recorder=None, registry=None, shard=None):
+    def enable_obs(self, recorder=None, registry=None, shard=None,
+                   host=None):
         """Attach device-plane instruments (``obs.instruments.EngineObs``):
         per-dispatch flight-recorder spans plus the ``dragonboat_device_*``
         metric families in ``registry`` (default: the process registry
@@ -707,8 +747,12 @@ class BatchedQuorumEngine:
         into ITS registry would otherwise silently publish to the default
         one and expose nothing).  ``shard`` tags this engine's dispatch
         spans with its mesh shard index (``ops/mesh.py`` wiring — all
-        shards share ONE recorder, the tag tells their streams apart)."""
+        shards share ONE recorder, the tag tells their streams apart);
+        ``host`` with the owning NodeHost (co-hosted NodeHosts share one
+        recorder too)."""
         if self._obs is not None and recorder is None and registry is None:
+            if host is not None:
+                self._obs.host = host
             return self._obs
         from ..obs.instruments import EngineObs
 
@@ -719,11 +763,24 @@ class BatchedQuorumEngine:
                 self._obs.recorder if self._obs is not None
                 else _obs.default_recorder()
             )
-        self._obs = EngineObs(recorder, registry=registry, shard=shard)
+        if host is None and self._obs is not None:
+            host = self._obs.host
+        self._obs = EngineObs(
+            recorder, registry=registry, shard=shard, host=host
+        )
         return self._obs
 
     def disable_obs(self) -> None:
         self._obs = None
+
+    def set_span_parent(self, seq: Optional[int]) -> None:
+        """The coordinator round now driving this engine (its
+        ``coord_round`` span's ``seq``): stamped as ``parent`` into the
+        dispatch spans recorded until the next call.  Callers gate on
+        their own obs latch."""
+        obs = self._obs
+        if obs is not None:
+            obs.parent = seq
 
     def enable_devprof(self, devprof) -> None:
         """Attach a :class:`dragonboat_tpu.obs.devprof.DevProf` plane:
@@ -2374,9 +2431,10 @@ class BatchedQuorumEngine:
         with self._dispatch_mu:
             if timed:
                 self._obs_mu_wait += (time.perf_counter() - t0) * 1e3
-            return self._step_rounds_locked(
-                do_tick, pipelined, pad_rounds_to, tick_rounds
-            )
+            with obs.step_scope():
+                return self._step_rounds_locked(
+                    do_tick, pipelined, pad_rounds_to, tick_rounds
+                )
 
     def _step_rounds_locked(
         self, do_tick: bool, pipelined: bool, pad_rounds_to: int,
@@ -2401,6 +2459,9 @@ class BatchedQuorumEngine:
         tick_mask = np.zeros((len(blocks),), bool)
         tick_mask[:tick_rounds] = True
         prev = self._harvest_inflight()
+        obs = self._obs
+        if obs is not None:
+            obs.begin_step()
         self._upload_dirty()
         self._refresh_committed_cache()
         out = self._dispatch_multiround(
@@ -2443,55 +2504,59 @@ class BatchedQuorumEngine:
         span, self._obs_span = self._obs_span, None
         kv_span, self._obs_kv_span = self._obs_kv_span, None
         t_eg = time.perf_counter() if obs is not None else 0.0
-        (
-            committed, won, lost, elect, hb, demote, rdc, rdi,
-            kvv, kvi, kva,
-        ) = jax.device_get(
+        with (obs.phase("egress_wait") if obs is not None else _OFF):
             (
-                out.committed,
-                out.won,
-                out.lost,
-                out.flags.elect_due,
-                out.flags.hb_due,
-                out.flags.checkq_demote,
-                out.read_done_count,
-                out.read_done_index,
-                out.kv_read_val,
-                out.kv_read_index,
-                out.kv_applied,
+                committed, won, lost, elect, hb, demote, rdc, rdi,
+                kvv, kvi, kva,
+            ) = jax.device_get(
+                (
+                    out.committed,
+                    out.won,
+                    out.lost,
+                    out.flags.elect_due,
+                    out.flags.hb_due,
+                    out.flags.checkq_demote,
+                    out.read_done_count,
+                    out.read_done_index,
+                    out.kv_read_val,
+                    out.kv_read_index,
+                    out.kv_applied,
+                )
             )
-        )
-        if out.telem is not None:
-            # dispatch-time row_cid snapshot: a re-registration while the
-            # block was in flight must not mislabel a drill-down row.
-            # The device arrays stay resident until telem_snapshot pulls
-            # them — the fold must not add a per-dispatch readback
-            self._stage_telem(out.telem, row_cid, rounds=n_rounds)
-        res = MultiRoundResult(n_rounds)
-        if rdc is not None:
-            self._translate_reads(res, rdc, rdi, row_cid, row_base)
-        committed = np.asarray(committed)
-        res.committed_rel = committed
-        self._committed_cache = np.array(committed, dtype=np.int32)
-        if self._churn_pending:
-            # recycles staged while this block was in flight: their rows'
-            # host watermark is the mirror's (new tenant) until THEIR
-            # block lands — the harvested vector still shows the old one
-            rows = np.fromiter(self._churn_pending, dtype=np.int64)
-            self._committed_cache[rows] = (
-                self.mirror.arrays["committed"][rows]
+        with (obs.phase("decode") if obs is not None else _OFF):
+            if out.telem is not None:
+                # dispatch-time row_cid snapshot: a re-registration while
+                # the block was in flight must not mislabel a drill-down
+                # row.  The device arrays stay resident until
+                # telem_snapshot pulls them — the fold must not add a
+                # per-dispatch readback
+                self._stage_telem(out.telem, row_cid, rounds=n_rounds)
+            res = MultiRoundResult(n_rounds)
+            if rdc is not None:
+                self._translate_reads(res, rdc, rdi, row_cid, row_base)
+            committed = np.asarray(committed)
+            res.committed_rel = committed
+            self._committed_cache = np.array(committed, dtype=np.int32)
+            if self._churn_pending:
+                # recycles staged while this block was in flight: their
+                # rows' host watermark is the mirror's (new tenant) until
+                # THEIR block lands — the harvested vector still shows
+                # the old one
+                rows = np.fromiter(self._churn_pending, dtype=np.int64)
+                self._committed_cache[rows] = (
+                    self.mirror.arrays["committed"][rows]
+                )
+            if kvi is not None:
+                self._translate_kv(res, kvv, kvi, kva, row_cid, row_base)
+                if self.kv_egress_hook is not None:
+                    self.kv_egress_hook(res)
+            if self._devsm_used:
+                self._kv_free_applied()
+            res.commit_rows = self._translate_egress(
+                res, committed, prev_committed, row_cid, row_base,
+                (("won", won), ("lost", lost), ("elect", elect),
+                 ("heartbeat", hb), ("demote", demote)),
             )
-        if kvi is not None:
-            self._translate_kv(res, kvv, kvi, kva, row_cid, row_base)
-            if self.kv_egress_hook is not None:
-                self.kv_egress_hook(res)
-        if self._devsm_used:
-            self._kv_free_applied()
-        res.commit_rows = self._translate_egress(
-            res, committed, prev_committed, row_cid, row_base,
-            (("won", won), ("lost", lost), ("elect", elect),
-             ("heartbeat", hb), ("demote", demote)),
-        )
         if obs is not None and span is not None:
             obs.egress(
                 span,
@@ -2585,133 +2650,145 @@ class BatchedQuorumEngine:
         from .kernels import quorum_multiround
 
         obs = self._obs
-        t_disp = time.perf_counter() if obs is not None else 0.0
-        k = len(blocks)
-        g, p = self.n_groups, self.n_peers
-        # -1 = untouched sentinel: one tensor instead of (max, touched) —
-        # halves both the host staging stores and the upload bytes
-        ack_max = np.full((k, g, p), -1, np.int32)
-        flat = ack_max.reshape(-1)
-        stride = g * p
-        for r, b in enumerate(blocks):
-            if b.rows.size:
-                if b.cells is not None:  # shared-geometry fast path
-                    cell = r * stride + b.cells
-                else:
-                    cell = (r * g + b.rows.astype(np.int64)) * p + b.slots
-                np.maximum.at(flat, cell, b.rels)
-        has_votes = any(b.votes for b in blocks)
-        if has_votes:
-            vote_new = np.full((k, g, p), VOTE_NONE, np.int8)
+        with (obs.phase("stage") if obs is not None else _OFF):
+            k = len(blocks)
+            g, p = self.n_groups, self.n_peers
+            # -1 = untouched sentinel: one tensor instead of (max,
+            # touched) — halves both the host staging stores and the
+            # upload bytes
+            ack_max = np.full((k, g, p), -1, np.int32)
+            flat = ack_max.reshape(-1)
+            stride = g * p
             for r, b in enumerate(blocks):
-                if b.votes:
-                    cols = np.array(b.votes, dtype=np.int64).T
-                    vote_new[r, cols[0], cols[1]] = cols[2].astype(np.int8)
-        else:
-            vote_new = np.zeros((1, 1, 1), np.int8)  # unused dummy
-        has_churn = any(b.churn for b in blocks)
-        if has_churn:
-            # pad the per-round churn width to a power of two so the jit
-            # cache stays bounded at ~log2(G) entries per K (the same
-            # shape-bucketing rationale as _pad_rows)
-            cmax = max(len(b.churn) for b in blocks)
-            cap = 1 << max(0, cmax - 1).bit_length()
-            cap = max(cap, 1)
-            churn_row = np.full((k, cap), g, np.int32)  # g = padding (drops)
-            churn_term = np.zeros((k, cap), np.int32)
-            churn_start = np.zeros((k, cap), np.int32)
-            churn_last = np.zeros((k, cap), np.int32)
-            for r, b in enumerate(blocks):
-                if b.churn:
-                    cols = np.array(b.churn, dtype=np.int64).T
-                    n = cols.shape[1]
-                    churn_row[r, :n] = cols[0]
-                    churn_term[r, :n] = cols[1]
-                    churn_start[r, :n] = cols[2]
-                    churn_last[r, :n] = cols[3]
-        else:
-            z = np.zeros((1, 1), np.int32)
-            churn_row = churn_term = churn_start = churn_last = z
-        has_reads = any(
-            b.reads is not None or b.racks is not None for b in blocks
-        )
-        if has_reads:
-            s = self.n_read_slots
-            stage_idx = np.full((k, g, s), -1, np.int32)
-            stage_cnt = np.zeros((k, g, s), np.int32)
-            echo = np.zeros((k, g, s, p), bool)
-            for r, b in enumerate(blocks):
-                if b.reads is not None and b.reads[0].size:
-                    rr, sl, v, c = b.reads
-                    stage_idx[r, rr, sl] = v
-                    stage_cnt[r, rr, sl] = c
-                if b.racks is not None and b.racks[0].size:
-                    rr, sl, pe = b.racks
-                    echo[r, rr, sl, pe] = True
-            read_args = (
-                jnp.asarray(stage_idx), jnp.asarray(stage_cnt),
-                jnp.asarray(echo),
+                if b.rows.size:
+                    if b.cells is not None:  # shared-geometry fast path
+                        cell = r * stride + b.cells
+                    else:
+                        cell = (
+                            r * g + b.rows.astype(np.int64)
+                        ) * p + b.slots
+                    np.maximum.at(flat, cell, b.rels)
+            has_votes = any(b.votes for b in blocks)
+            if has_votes:
+                vote_new = np.full((k, g, p), VOTE_NONE, np.int8)
+                for r, b in enumerate(blocks):
+                    if b.votes:
+                        cols = np.array(b.votes, dtype=np.int64).T
+                        vote_new[r, cols[0], cols[1]] = cols[2].astype(
+                            np.int8
+                        )
+            else:
+                vote_new = np.zeros((1, 1, 1), np.int8)  # unused dummy
+            has_churn = any(b.churn for b in blocks)
+            if has_churn:
+                # pad the per-round churn width to a power of two so the
+                # jit cache stays bounded at ~log2(G) entries per K (the
+                # same shape-bucketing rationale as _pad_rows)
+                cmax = max(len(b.churn) for b in blocks)
+                cap = 1 << max(0, cmax - 1).bit_length()
+                cap = max(cap, 1)
+                # g = padding (drops)
+                churn_row = np.full((k, cap), g, np.int32)
+                churn_term = np.zeros((k, cap), np.int32)
+                churn_start = np.zeros((k, cap), np.int32)
+                churn_last = np.zeros((k, cap), np.int32)
+                for r, b in enumerate(blocks):
+                    if b.churn:
+                        cols = np.array(b.churn, dtype=np.int64).T
+                        n = cols.shape[1]
+                        churn_row[r, :n] = cols[0]
+                        churn_term[r, :n] = cols[1]
+                        churn_start[r, :n] = cols[2]
+                        churn_last[r, :n] = cols[3]
+            else:
+                z = np.zeros((1, 1), np.int32)
+                churn_row = churn_term = churn_start = churn_last = z
+            has_reads = any(
+                b.reads is not None or b.racks is not None for b in blocks
             )
-        else:
-            read_args = (None, None, None)
-        has_kv = any(
-            b.kvents is not None or b.kvreads is not None for b in blocks
-        ) or self._kv_ents_buffered()  # fold runs while ops sit buffered
-        if has_kv:
-            e, rk = self.n_kv_ents, self.n_kv_reads
-            kv_ei = np.full((k, g, e), -1, np.int32)
-            kv_ek = np.zeros((k, g, e), np.int32)
-            kv_ev = np.zeros((k, g, e), np.int32)
-            kv_rk = np.full((k, g, rk), -1, np.int32)
-            for r, b in enumerate(blocks):
-                if b.kvents is not None and b.kvents[0].size:
-                    rr, sl, rel, key, val = b.kvents
-                    kv_ei[r, rr, sl] = rel
-                    kv_ek[r, rr, sl] = key
-                    kv_ev[r, rr, sl] = val
-                if b.kvreads is not None and b.kvreads[0].size:
-                    rr, sl, key = b.kvreads
-                    kv_rk[r, rr, sl] = key
-            kv_args = (
-                jnp.asarray(kv_ei), jnp.asarray(kv_ek),
-                jnp.asarray(kv_ev), jnp.asarray(kv_rk),
+            read_np = (None, None, None)
+            if has_reads:
+                s = self.n_read_slots
+                stage_idx = np.full((k, g, s), -1, np.int32)
+                stage_cnt = np.zeros((k, g, s), np.int32)
+                echo = np.zeros((k, g, s, p), bool)
+                for r, b in enumerate(blocks):
+                    if b.reads is not None and b.reads[0].size:
+                        rr, sl, v, c = b.reads
+                        stage_idx[r, rr, sl] = v
+                        stage_cnt[r, rr, sl] = c
+                    if b.racks is not None and b.racks[0].size:
+                        rr, sl, pe = b.racks
+                        echo[r, rr, sl, pe] = True
+                read_np = (stage_idx, stage_cnt, echo)
+            # the fold runs while ops sit buffered
+            has_kv = any(
+                b.kvents is not None or b.kvreads is not None
+                for b in blocks
+            ) or self._kv_ents_buffered()
+            kv_np = (None, None, None, None)
+            if has_kv:
+                e, rk = self.n_kv_ents, self.n_kv_reads
+                kv_ei = np.full((k, g, e), -1, np.int32)
+                kv_ek = np.zeros((k, g, e), np.int32)
+                kv_ev = np.zeros((k, g, e), np.int32)
+                kv_rk = np.full((k, g, rk), -1, np.int32)
+                for r, b in enumerate(blocks):
+                    if b.kvents is not None and b.kvents[0].size:
+                        rr, sl, rel, key, val = b.kvents
+                        kv_ei[r, rr, sl] = rel
+                        kv_ek[r, rr, sl] = key
+                        kv_ev[r, rr, sl] = val
+                    if b.kvreads is not None and b.kvreads[0].size:
+                        rr, sl, key = b.kvreads
+                        kv_rk[r, rr, sl] = key
+                kv_np = (kv_ei, kv_ek, kv_ev, kv_rk)
+        # the host->device puts, in a block of their own: one transfer
+        # per staged array today (ROADMAP A1)
+        with (obs.phase("transfer") if obs is not None else _OFF):
+            args = tuple(
+                jnp.asarray(a) for a in (
+                    ack_max, vote_new, churn_row, churn_term, churn_start,
+                    churn_last, tick_mask,
+                )
             )
-        else:
-            kv_args = (None, None, None, None)
-        out = quorum_multiround(
-            self._dev,
-            jnp.asarray(ack_max),
-            jnp.asarray(vote_new),
-            jnp.asarray(churn_row),
-            jnp.asarray(churn_term),
-            jnp.asarray(churn_start),
-            jnp.asarray(churn_last),
-            jnp.asarray(tick_mask),
-            *read_args,
-            *kv_args,
-            do_tick=do_tick,
-            track_contact=self.device_ticks or do_tick,
-            has_votes=has_votes,
-            has_churn=has_churn,
-            has_reads=has_reads,
-            # a never-used read plane is all-zero: compile its recycle
-            # purges out (measured ~40% of rung-5 churn throughput).
-            # Normalized to False when the block carries no churn — the
-            # flag is only consumed inside _apply_recycle, but as a
-            # static it keys the jit cache, and letting it flip with
-            # _read_plane_used would recompile the live coordinator's
-            # fused program the moment the first read stages (exactly
-            # the first-use stall the warmup pass exists to kill)
-            purge_reads=self._read_plane_used and has_churn,
-            has_kv=has_kv,
-            # the devsm twin of purge_reads, same normalization rationale
-            purge_kv=self._devsm_used and has_churn,
-            has_hier=self._hier_used,
-            has_telem=self._telem_used,
-            # the telem twin of purge_reads, same normalization rationale
-            purge_telem=self._telem_used and has_churn,
-            telem_k=self.n_telem_topk,
-        )
+            read_args = tuple(
+                None if a is None else jnp.asarray(a) for a in read_np
+            )
+            kv_args = tuple(
+                None if a is None else jnp.asarray(a) for a in kv_np
+            )
+        with (obs.phase("launch") if obs is not None else _OFF):
+            out = quorum_multiround(
+                self._dev,
+                *args,
+                *read_args,
+                *kv_args,
+                do_tick=do_tick,
+                track_contact=self.device_ticks or do_tick,
+                has_votes=has_votes,
+                has_churn=has_churn,
+                has_reads=has_reads,
+                # a never-used read plane is all-zero: compile its recycle
+                # purges out (measured ~40% of rung-5 churn throughput).
+                # Normalized to False when the block carries no churn —
+                # the flag is only consumed inside _apply_recycle, but as
+                # a static it keys the jit cache, and letting it flip with
+                # _read_plane_used would recompile the live coordinator's
+                # fused program the moment the first read stages (exactly
+                # the first-use stall the warmup pass exists to kill)
+                purge_reads=self._read_plane_used and has_churn,
+                has_kv=has_kv,
+                # the devsm twin of purge_reads, same normalization
+                # rationale
+                purge_kv=self._devsm_used and has_churn,
+                has_hier=self._hier_used,
+                has_telem=self._telem_used,
+                # the telem twin of purge_reads, same normalization
+                # rationale
+                purge_telem=self._telem_used and has_churn,
+                telem_k=self.n_telem_topk,
+            )
         self._dev = out.state
         if obs is not None:
             n_acks = int(sum(b.rows.size for b in blocks))
@@ -2756,7 +2833,7 @@ class BatchedQuorumEngine:
                 reads=n_reads,
                 echoes=n_echo,
                 upload_bytes=int(up),
-                dispatch_ms=(time.perf_counter() - t_disp) * 1e3,
+                dispatch_ms=(time.perf_counter() - obs.t0) * 1e3,
                 gate=self._obs_gate(
                     do_tick, n_acks, n_votes, n_rec, n_reads, n_echo
                 ),
@@ -2795,15 +2872,17 @@ class BatchedQuorumEngine:
         holds the old tenant until the block dispatches)."""
         if not self._cache_stale:
             return
-        self._committed_cache = np.array(
-            np.asarray(self._dev.committed), dtype=np.int32
-        )
-        if self._churn_pending:
-            rows = np.fromiter(self._churn_pending, dtype=np.int64)
-            self._committed_cache[rows] = (
-                self.mirror.arrays["committed"][rows]
+        obs = self._obs
+        with (obs.phase("row_sync") if obs is not None else _OFF):
+            self._committed_cache = np.array(
+                np.asarray(self._dev.committed), dtype=np.int32
             )
-        self._cache_stale = False
+            if self._churn_pending:
+                rows = np.fromiter(self._churn_pending, dtype=np.int64)
+                self._committed_cache[rows] = (
+                    self.mirror.arrays["committed"][rows]
+                )
+            self._cache_stale = False
 
     def committed_view(self) -> np.ndarray:
         """Absolute committed watermark per ROW as one (G,) int64 vector —
@@ -2868,9 +2947,11 @@ class BatchedQuorumEngine:
 
     def _pull_rows(self, idx: np.ndarray) -> None:
         """Device rows ``idx`` -> mirror, every sync field."""
-        got = self._gather_sync_rows(self.dev, self._sync_keys(), idx)
-        for k, v in got.items():
-            self.mirror.arrays[k][idx] = v
+        obs = self._obs
+        with (obs.phase("row_sync") if obs is not None else _OFF):
+            got = self._gather_sync_rows(self.dev, self._sync_keys(), idx)
+            for k, v in got.items():
+                self.mirror.arrays[k][idx] = v
 
     _READ_KEYS = ("read_index", "read_count", "read_acks")
     _KV_KEYS = ("kv_value", "kv_ent_index", "kv_ent_key", "kv_ent_val")
@@ -2961,14 +3042,19 @@ class BatchedQuorumEngine:
         if not self._dirty:
             return
         self._harvest_inflight()
-        rows = self._pad_rows(np.fromiter(self._dirty, dtype=np.int32))
-        self._dev = self._scatter_sync_rows(
-            self.dev, rows,
-            {k: self.mirror.arrays[k][rows] for k in self._sync_keys()},
-        )
-        # keep the host committed twin coherent with the rows just written
-        self._committed_cache[rows] = self.mirror.arrays["committed"][rows]
-        self._dirty.clear()
+        obs = self._obs
+        with (obs.phase("row_sync") if obs is not None else _OFF):
+            rows = self._pad_rows(np.fromiter(self._dirty, dtype=np.int32))
+            self._dev = self._scatter_sync_rows(
+                self.dev, rows,
+                {k: self.mirror.arrays[k][rows] for k in self._sync_keys()},
+            )
+            # keep the host committed twin coherent with the rows just
+            # written
+            self._committed_cache[rows] = (
+                self.mirror.arrays["committed"][rows]
+            )
+            self._dirty.clear()
 
     def _pad(self, events, width):
         cap = self.event_cap
@@ -3005,20 +3091,26 @@ class BatchedQuorumEngine:
         with self._dispatch_mu:
             if timed:
                 self._obs_mu_wait += (time.perf_counter() - t0) * 1e3
-            return self._step_locked(do_tick)
+            with obs.step_scope():
+                return self._step_locked(do_tick)
 
     def _step_locked(self, do_tick: bool) -> StepResult:
         if self._round_blocks or self._churn:
             return self.step_rounds(do_tick=do_tick)
         self._harvest_inflight()
-        # stale-epoch votes (staged before a row transition) drop here;
-        # surviving entries shed the epoch column for the dispatch path
-        if self._votes:
-            self._votes = [
-                (r, s, v)
-                for r, s, v, ep in self._votes
-                if ep == self._row_epoch[r]
-            ]
+        obs = self._obs
+        if obs is not None:
+            obs.begin_step()
+        with (obs.phase("stage") if obs is not None else _OFF):
+            # stale-epoch votes (staged before a row transition) drop
+            # here; surviving entries shed the epoch column for the
+            # dispatch path
+            if self._votes:
+                self._votes = [
+                    (r, s, v)
+                    for r, s, v, ep in self._votes
+                    if ep == self._row_epoch[r]
+                ]
         self._upload_dirty()
         # host twin, not a device readback (a full extra round trip per
         # step on a network-attached chip); _upload_dirty and the egress
@@ -3027,23 +3119,23 @@ class BatchedQuorumEngine:
         self._refresh_committed_cache()
         prev_committed = self._committed_cache
 
-        obs = self._obs
-        t_disp = time.perf_counter() if obs is not None else 0.0
         n_dispatches = 1
-        ack_g, ack_p, ack_v = self._gather_acks()
-        reads, racks = self._gather_reads()
-        kvents, kvreads = self._gather_kv()
-        n_votes = len(self._votes) if obs is not None else 0
-        has_reads = reads is not None or racks is not None
-        # the apply fold must ALSO run while any entry sits buffered on
-        # device: its commit may land in this (otherwise kv-free)
-        # dispatch, and a fold-free program would leave it unapplied —
-        # stale for kv_values and unsafe for the host slot-free rule.
-        # Empties back to event-driven the moment the buffers drain.
-        has_kv = (
-            kvents is not None or kvreads is not None
-            or self._kv_ents_buffered()
-        )
+        with (obs.phase("stage") if obs is not None else _OFF):
+            ack_g, ack_p, ack_v = self._gather_acks()
+            reads, racks = self._gather_reads()
+            kvents, kvreads = self._gather_kv()
+            n_votes = len(self._votes) if obs is not None else 0
+            has_reads = reads is not None or racks is not None
+            # the apply fold must ALSO run while any entry sits buffered
+            # on device: its commit may land in this (otherwise kv-free)
+            # dispatch, and a fold-free program would leave it unapplied
+            # — stale for kv_values and unsafe for the host slot-free
+            # rule.  Empties back to event-driven the moment the buffers
+            # drain.
+            has_kv = (
+                kvents is not None or kvreads is not None
+                or self._kv_ents_buffered()
+            )
         # dense mode collapses ANY number of acks/votes into (G,P)
         # matrices — no cap, no chunk loop (votes are already first-wins
         # deduped per cell, so a dense matrix holds a whole round).
@@ -3109,7 +3201,7 @@ class BatchedQuorumEngine:
                 echoes=n_echo,
                 upload_bytes=upload,
                 n_dispatches=n_dispatches,
-                dispatch_ms=(time.perf_counter() - t_disp) * 1e3,
+                dispatch_ms=(time.perf_counter() - obs.t0) * 1e3,
                 gate=self._obs_gate(
                     do_tick, ack_g.size, n_votes, 0, n_reads, n_echo
                 ),
@@ -3126,48 +3218,53 @@ class BatchedQuorumEngine:
         res = StepResult()
         # one batched device→host transfer for the whole egress set (a
         # network-attached chip pays the full round trip per readback)
-        (
-            committed, won, lost, elect, hb, demote, rdc, rdi,
-            kvv, kvi, kva,
-        ) = jax.device_get(
+        with (obs.phase("egress_wait") if obs is not None else _OFF):
             (
-                out.committed,
-                out.won,
-                out.lost,
-                out.flags.elect_due,
-                out.flags.hb_due,
-                out.flags.checkq_demote,
-                out.read_done_count,
-                out.read_done_index,
-                out.kv_read_val,
-                out.kv_read_index,
-                out.kv_applied,
+                committed, won, lost, elect, hb, demote, rdc, rdi,
+                kvv, kvi, kva,
+            ) = jax.device_get(
+                (
+                    out.committed,
+                    out.won,
+                    out.lost,
+                    out.flags.elect_due,
+                    out.flags.hb_due,
+                    out.flags.checkq_demote,
+                    out.read_done_count,
+                    out.read_done_index,
+                    out.kv_read_val,
+                    out.kv_read_index,
+                    out.kv_applied,
+                )
             )
-        )
-        if out.telem is not None:
-            # deferred readback: stage the device aggregate, pull it at
-            # snapshot (sampler) cadence, not dispatch cadence
-            self._stage_telem(
-                out.telem, self._row_cid.copy(), rounds=1
+        with (obs.phase("decode") if obs is not None else _OFF):
+            if out.telem is not None:
+                # deferred readback: stage the device aggregate, pull it
+                # at snapshot (sampler) cadence, not dispatch cadence
+                self._stage_telem(
+                    out.telem, self._row_cid.copy(), rounds=1
+                )
+            if rdc is not None:
+                self._translate_reads(
+                    res, rdc, rdi, self._row_cid, self._row_base
+                )
+            # device_get arrays are read-only; the cache must stay
+            # writable for _upload_dirty's row sync
+            self._committed_cache = np.array(committed, dtype=np.int32)
+            if kvi is not None:
+                self._translate_kv(
+                    res, kvv, kvi, kva, self._row_cid, self._row_base
+                )
+                if self.kv_egress_hook is not None:
+                    self.kv_egress_hook(res)
+            if self._devsm_used:
+                self._kv_free_applied()
+            changed = self._translate_egress(
+                res, committed, prev_committed, self._row_cid,
+                self._row_base,
+                (("won", won), ("lost", lost), ("elect", elect),
+                 ("heartbeat", hb), ("demote", demote)),
             )
-        if rdc is not None:
-            self._translate_reads(res, rdc, rdi, self._row_cid, self._row_base)
-        # device_get arrays are read-only; the cache must stay writable
-        # for _upload_dirty's row sync
-        self._committed_cache = np.array(committed, dtype=np.int32)
-        if kvi is not None:
-            self._translate_kv(
-                res, kvv, kvi, kva, self._row_cid, self._row_base
-            )
-            if self.kv_egress_hook is not None:
-                self.kv_egress_hook(res)
-        if self._devsm_used:
-            self._kv_free_applied()
-        changed = self._translate_egress(
-            res, committed, prev_committed, self._row_cid, self._row_base,
-            (("won", won), ("lost", lost), ("elect", elect),
-             ("heartbeat", hb), ("demote", demote)),
-        )
         if obs is not None:
             obs.egress(
                 span,
@@ -3236,48 +3333,58 @@ class BatchedQuorumEngine:
         return og, op, ov, valid
 
     def _dispatch(self, acks, votes, do_tick: bool):
-        if isinstance(acks, tuple):
-            ag, ap, av, avalid = self._pad_ack_arrays(*acks)
-        else:
-            ag, ap, av, avalid = self._pad(acks, 3)
-        if votes:
-            vg, vp, vv, vvalid = self._pad(votes, 1)
-        else:
-            # vote-free round: the has_votes=False variant compiles the
-            # vote scatter out entirely; the args are unused dummies
-            vg = vp = np.zeros((1,), np.int32)
-            vv = np.zeros((1,), np.int8)
-            vvalid = np.zeros((1,), bool)
-        if self._obs is not None:
-            # accumulated: an oversized backlog runs several chunked
-            # dispatches per step and the span must account them all
-            self._obs_upload += upload_nbytes(
-                ag, ap, av, avalid, vg, vp, vv, vvalid
+        obs = self._obs
+        with (obs.phase("stage") if obs is not None else _OFF):
+            if isinstance(acks, tuple):
+                ag, ap, av, avalid = self._pad_ack_arrays(*acks)
+            else:
+                ag, ap, av, avalid = self._pad(acks, 3)
+            if votes:
+                vg, vp, vv, vvalid = self._pad(votes, 1)
+            else:
+                # vote-free round: the has_votes=False variant compiles
+                # the vote scatter out entirely; the args are unused
+                # dummies
+                vg = vp = np.zeros((1,), np.int32)
+                vv = np.zeros((1,), np.int8)
+                vvalid = np.zeros((1,), bool)
+            if obs is not None:
+                # accumulated: an oversized backlog runs several chunked
+                # dispatches per step and the span must account them all
+                self._obs_upload += upload_nbytes(
+                    ag, ap, av, avalid, vg, vp, vv, vvalid
+                )
+        # the host->device puts, in a block of their own (ROADMAP A1)
+        with (obs.phase("transfer") if obs is not None else _OFF):
+            args = (
+                jnp.asarray(ag),
+                jnp.asarray(ap),
+                jnp.asarray(av),
+                jnp.asarray(avalid),
+                jnp.asarray(vg),
+                jnp.asarray(vp),
+                jnp.asarray(vv, dtype=jnp.int8),
+                jnp.asarray(vvalid),
             )
-        out = quorum_step(
-            self.dev,
-            jnp.asarray(ag),
-            jnp.asarray(ap),
-            jnp.asarray(av),
-            jnp.asarray(avalid),
-            jnp.asarray(vg),
-            jnp.asarray(vp),
-            jnp.asarray(vv, dtype=jnp.int8),
-            jnp.asarray(vvalid),
-            do_tick=do_tick,
-            # ticking rounds must track contact even on a device_ticks=False
-            # engine (defensive: a stray do_tick=True call would otherwise
-            # consume one-shot contact acks without the reset)
-            track_contact=self.device_ticks or do_tick,
-            has_votes=bool(votes),
-            has_hier=self._hier_used,
-            has_telem=self._telem_used,
-            telem_k=self.n_telem_topk,
-            # occupancy hints for the telem fold only — this path never
-            # carries read/kv event planes
-            has_reads=self._read_plane_used,
-            has_kv=self._devsm_used,
-        )
+        with (obs.phase("launch") if obs is not None else _OFF):
+            out = quorum_step(
+                self.dev,
+                *args,
+                do_tick=do_tick,
+                # ticking rounds must track contact even on a
+                # device_ticks=False engine (defensive: a stray
+                # do_tick=True call would otherwise consume one-shot
+                # contact acks without the reset)
+                track_contact=self.device_ticks or do_tick,
+                has_votes=bool(votes),
+                has_hier=self._hier_used,
+                has_telem=self._telem_used,
+                telem_k=self.n_telem_topk,
+                # occupancy hints for the telem fold only — this path
+                # never carries read/kv event planes
+                has_reads=self._read_plane_used,
+                has_kv=self._devsm_used,
+            )
         self._dev = out.state
         dp = self._devprof
         if dp is not None:
@@ -3296,85 +3403,92 @@ class BatchedQuorumEngine:
         kernel — step() forces dense whenever they are present."""
         from .kernels import quorum_step_dense
 
-        g, p = self.n_groups, self.n_peers
-        ack_max = np.zeros((g, p), np.int32)
-        touched = np.zeros((g, p), bool)
-        if ag.size:
-            # max-aggregation == scatter-max: order-independent, exact.
-            # Flat 1-D indexing keeps ufunc.at on numpy's contiguous fast
-            # path (the 2-D tuple form is several× slower at the very
-            # occupancies that select the dense path).
-            cell = ag.astype(np.int64) * p + ap
-            np.maximum.at(ack_max.reshape(-1), cell, av)
-            touched.reshape(-1)[cell] = True
-        if votes:
-            vote_new = np.full((g, p), VOTE_NONE, np.int8)
-            cols = np.array(votes, dtype=np.int64).T
-            vote_new[cols[0], cols[1]] = cols[2].astype(np.int8)
-        else:
-            vote_new = np.zeros((1, 1), np.int8)  # unused dummy
-        has_reads = reads is not None or racks is not None
-        if has_reads:
-            s = self.n_read_slots
-            stage_idx = np.full((g, s), -1, np.int32)
-            stage_cnt = np.zeros((g, s), np.int32)
-            echo = np.zeros((g, s, p), bool)
-            if reads is not None and reads[0].size:
-                rr, sl, v, c = reads
-                stage_idx[rr, sl] = v
-                stage_cnt[rr, sl] = c
-            if racks is not None and racks[0].size:
-                rr, sl, pe = racks
-                echo[rr, sl, pe] = True
-            read_args = (
-                jnp.asarray(stage_idx), jnp.asarray(stage_cnt),
-                jnp.asarray(echo),
+        obs = self._obs
+        with (obs.phase("stage") if obs is not None else _OFF):
+            g, p = self.n_groups, self.n_peers
+            ack_max = np.zeros((g, p), np.int32)
+            touched = np.zeros((g, p), bool)
+            if ag.size:
+                # max-aggregation == scatter-max: order-independent,
+                # exact.  Flat 1-D indexing keeps ufunc.at on numpy's
+                # contiguous fast path (the 2-D tuple form is several×
+                # slower at the very occupancies that select the dense
+                # path).
+                cell = ag.astype(np.int64) * p + ap
+                np.maximum.at(ack_max.reshape(-1), cell, av)
+                touched.reshape(-1)[cell] = True
+            if votes:
+                vote_new = np.full((g, p), VOTE_NONE, np.int8)
+                cols = np.array(votes, dtype=np.int64).T
+                vote_new[cols[0], cols[1]] = cols[2].astype(np.int8)
+            else:
+                vote_new = np.zeros((1, 1), np.int8)  # unused dummy
+            has_reads = reads is not None or racks is not None
+            read_np = (None, None, None)
+            if has_reads:
+                s = self.n_read_slots
+                stage_idx = np.full((g, s), -1, np.int32)
+                stage_cnt = np.zeros((g, s), np.int32)
+                echo = np.zeros((g, s, p), bool)
+                if reads is not None and reads[0].size:
+                    rr, sl, v, c = reads
+                    stage_idx[rr, sl] = v
+                    stage_cnt[rr, sl] = c
+                if racks is not None and racks[0].size:
+                    rr, sl, pe = racks
+                    echo[rr, sl, pe] = True
+                read_np = (stage_idx, stage_cnt, echo)
+            if has_kv is None:
+                has_kv = kvents is not None or kvreads is not None
+            kv_np = (None, None, None, None)
+            if has_kv:
+                e, rk = self.n_kv_ents, self.n_kv_reads
+                kv_ei = np.full((g, e), -1, np.int32)
+                kv_ek = np.zeros((g, e), np.int32)
+                kv_ev = np.zeros((g, e), np.int32)
+                kv_rk = np.full((g, rk), -1, np.int32)
+                if kvents is not None and kvents[0].size:
+                    rr, sl, rel, key, val = kvents
+                    kv_ei[rr, sl] = rel
+                    kv_ek[rr, sl] = key
+                    kv_ev[rr, sl] = val
+                if kvreads is not None and kvreads[0].size:
+                    rr, sl, key = kvreads
+                    kv_rk[rr, sl] = key
+                kv_np = (kv_ei, kv_ek, kv_ev, kv_rk)
+            if obs is not None:
+                # the exact kernel argument tuple (upload_nbytes docstring)
+                self._obs_upload += upload_nbytes(
+                    ack_max, touched, vote_new, *read_np, *kv_np
+                )
+        # the host->device puts, in a block of their own (ROADMAP A1)
+        with (obs.phase("transfer") if obs is not None else _OFF):
+            args = (
+                jnp.asarray(ack_max),
+                jnp.asarray(touched),
+                jnp.asarray(vote_new),
             )
-        else:
-            read_args = (None, None, None)
-        if has_kv is None:
-            has_kv = kvents is not None or kvreads is not None
-        if has_kv:
-            e, rk = self.n_kv_ents, self.n_kv_reads
-            kv_ei = np.full((g, e), -1, np.int32)
-            kv_ek = np.zeros((g, e), np.int32)
-            kv_ev = np.zeros((g, e), np.int32)
-            kv_rk = np.full((g, rk), -1, np.int32)
-            if kvents is not None and kvents[0].size:
-                rr, sl, rel, key, val = kvents
-                kv_ei[rr, sl] = rel
-                kv_ek[rr, sl] = key
-                kv_ev[rr, sl] = val
-            if kvreads is not None and kvreads[0].size:
-                rr, sl, key = kvreads
-                kv_rk[rr, sl] = key
-            kv_args = (
-                jnp.asarray(kv_ei), jnp.asarray(kv_ek),
-                jnp.asarray(kv_ev), jnp.asarray(kv_rk),
+            read_args = tuple(
+                None if a is None else jnp.asarray(a) for a in read_np
             )
-        else:
-            kv_args = (None, None, None, None)
-        if self._obs is not None:
-            # the exact kernel argument tuple (upload_nbytes docstring)
-            self._obs_upload += upload_nbytes(
-                ack_max, touched, vote_new, *read_args, *kv_args
+            kv_args = tuple(
+                None if a is None else jnp.asarray(a) for a in kv_np
             )
-        out = quorum_step_dense(
-            self.dev,
-            jnp.asarray(ack_max),
-            jnp.asarray(touched),
-            jnp.asarray(vote_new),
-            *read_args,
-            *kv_args,
-            do_tick=do_tick,
-            track_contact=self.device_ticks or do_tick,
-            has_votes=bool(votes),
-            has_reads=has_reads,
-            has_kv=has_kv,
-            has_hier=self._hier_used,
-            has_telem=self._telem_used,
-            telem_k=self.n_telem_topk,
-        )
+        with (obs.phase("launch") if obs is not None else _OFF):
+            out = quorum_step_dense(
+                self.dev,
+                *args,
+                *read_args,
+                *kv_args,
+                do_tick=do_tick,
+                track_contact=self.device_ticks or do_tick,
+                has_votes=bool(votes),
+                has_reads=has_reads,
+                has_kv=has_kv,
+                has_hier=self._hier_used,
+                has_telem=self._telem_used,
+                telem_k=self.n_telem_topk,
+            )
         self._dev = out.state
         dp = self._devprof
         if dp is not None:

@@ -1012,13 +1012,16 @@ class MeshQuorumEngine:
     # observability / profiling attachment
     # ------------------------------------------------------------------
 
-    def enable_obs(self, recorder=None, registry=None):
+    def enable_obs(self, recorder=None, registry=None, host=None):
         """Attach per-shard ``EngineObs`` (one shared recorder so all
         shards' dispatch spans interleave in one ring — the overlap
         evidence) plus the facade's ``dragonboat_mesh_*`` instruments.
         Same repeat-call contract as the engine: no-args is a no-op,
-        explicit arguments rebind."""
+        explicit arguments rebind.  ``host`` tags every shard's spans
+        with the owning NodeHost."""
         if self._obs is not None and recorder is None and registry is None:
+            for s in self.shards:
+                s.enable_obs(host=host)
             return self._obs
         from ..obs.instruments import MeshObs
 
@@ -1030,7 +1033,7 @@ class MeshQuorumEngine:
 
                 recorder = _obs_mod.default_recorder()
         for i, s in enumerate(self.shards):
-            s.enable_obs(recorder, registry, shard=i)
+            s.enable_obs(recorder, registry, shard=i, host=host)
         self._obs = MeshObs(
             recorder, registry=registry, n_shards=self.n_shards
         )
@@ -1041,6 +1044,11 @@ class MeshQuorumEngine:
         self._obs = None
         for s in self.shards:
             s.disable_obs()
+
+    def set_span_parent(self, seq) -> None:
+        """Every shard's dispatch spans name this coordinator round."""
+        for s in self.shards:
+            s.set_span_parent(seq)
 
     def enable_devprof(self, devprof) -> None:
         self._devprof = devprof

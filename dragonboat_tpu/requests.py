@@ -152,7 +152,7 @@ class RequestState:
         #: moment it got around to observing the result
         self.completed_at: Optional[float] = None
         #: request-trace token (ISSUE 9): None while tracing is off (the
-        #: bit-identical default); with tracing on, a (tracer, t0)
+        #: bit-identical default); with tracing on, a (tracer, t0, kind)
         #: enqueue-timestamp token for non-sampled requests or an
         #: obs.trace.Trace for the sampled 1-in-N
         self.trace = None

@@ -34,11 +34,16 @@ from typing import Dict, Optional, TYPE_CHECKING
 
 from . import obs as _obs
 from .logger import get_logger
+from .obs.recorder import OFF as _OFF, annotate as _annotate
 
 if TYPE_CHECKING:
     from .node import Node
 
 plog = get_logger("tpuquorum")
+
+#: released/refused ReadIndex contexts remembered per group, so that a late
+#: heartbeat echo can be put down to its cause (bounded: oldest forgotten)
+_READ_GONE_KEEP = 512
 
 
 class TpuQuorumCoordinator:
@@ -163,10 +168,28 @@ class TpuQuorumCoordinator:
         # the serving authority is each group's scalar LeaderLease.
         self.lease_table = None
         # observability: ctxs confirmed BY THE DEVICE plane vs echoes that
-        # fell back to the scalar tally (overflow/stale) — the read-plane
-        # tests assert the device actually served the load
+        # fell back to the scalar tally — the read-plane tests assert the
+        # device actually served the load.  Plain integers, always on
+        # (ISSUE 26): echoes the device tallied (``read_acks``), echoes
+        # tallied scalar-side by cause — ``slot_overflow`` (the ctx never
+        # got a device slot), ``after_confirm`` (the ctx was already
+        # confirmed or prefix-released when the echo came; with three
+        # replicas the second echo of every ctx is this), ``purged`` (a
+        # transition dropped the group's FIFO, or the ctx aged out of
+        # ``_read_gone``) — and ctxs given / refused a slot.
+        # ``read_fallbacks`` is the causes' sum.
         self.read_confirms = 0
-        self.read_fallbacks = 0
+        self.read_acks = 0
+        self.read_fallback_causes = {
+            "slot_overflow": 0, "after_confirm": 0, "purged": 0,
+        }
+        self.reads_staged = 0
+        self.reads_refused = 0
+        # the four counters above as of the last recorded coord_round span
+        self._reads_spanned = (0, 0, 0, dict(self.read_fallback_causes))
+        # cid -> {(low, high): cause} of ctxs no longer (or never)
+        # device-tracked, newest last; guarded by _mu like _read_pending
+        self._read_gone: Dict[int, dict] = {}
         # device state machine plane (devsm, ISSUE 11; DevKVPlane):
         # created by the FIRST DeviceKVStateMachine registration
         # (NodeHost.start_cluster with Config.device_kv).  None keeps the
@@ -191,6 +214,11 @@ class TpuQuorumCoordinator:
         # Replicate before fsync, execengine.go:954-961)
         self._stage_mu = threading.Lock()
         self._staged: list = []
+        # perf_counter when the oldest op / tick the next round will see
+        # was staged (``wait_ms`` of its coord_round span); stamped only
+        # while _obs is attached, taken by the drain
+        self._first_at: Optional[float] = None
+        self._round_first_at: Optional[float] = None
         # per-round leader-contact dedup: one election-clock reset per
         # group per round is sufficient and idempotent; without this a
         # follower ingesting tens of thousands of Replicates per second
@@ -272,7 +300,8 @@ class TpuQuorumCoordinator:
         persistent-cache hits/misses, error)."""
         return self.eng.warmup_stats
 
-    def enable_obs(self, recorder=None, registry=None, stall_ms=None):
+    def enable_obs(self, recorder=None, registry=None, stall_ms=None,
+                   host=None):
         """Attach round-loop + engine instruments: coordinator spans and
         ``dragonboat_coord_*`` families here, ``dragonboat_device_*`` on
         the engine, node offload counters on registered nodes — all into
@@ -281,12 +310,18 @@ class TpuQuorumCoordinator:
         (the round-gate watchdog's trip point).  A repeat call with no
         recorder/registry is a no-op; explicit arguments REBIND (the
         engine's ``enable_obs`` note: a latch-attached coordinator must
-        not swallow NodeHost's later registry wiring)."""
+        not swallow NodeHost's later registry wiring).  ``host`` (the
+        NodeHost's raft address) tags every span of this coordinator and
+        its engine, so co-hosted NodeHosts can share one recorder."""
         if self._obs is None or recorder is not None or registry is not None:
             from .obs.instruments import CoordObs
 
-            eng_obs = self.eng.enable_obs(recorder, registry)
-            self._obs = CoordObs(eng_obs.recorder, registry=registry)
+            if host is None and self._obs is not None:
+                host = self._obs.host
+            eng_obs = self.eng.enable_obs(recorder, registry, host=host)
+            self._obs = CoordObs(
+                eng_obs.recorder, registry=registry, host=host
+            )
             with self._mu:
                 for node in self._nodes.values():
                     node.obs_registry = self._obs.registry
@@ -343,6 +378,21 @@ class TpuQuorumCoordinator:
         with self._mu:
             return set(self._nodes)
 
+    @property
+    def read_fallbacks(self) -> int:
+        """Heartbeat read echoes tallied scalar-side (every cause)."""
+        return sum(self.read_fallback_causes.values())
+
+    def _read_gone_note(self, cid: int, key, cause: str) -> None:
+        """Remember why ctx ``key`` of ``cid`` is not device-tracked
+        (under _mu)."""
+        gone = self._read_gone.get(cid)
+        if gone is None:
+            gone = self._read_gone[cid] = {}
+        gone[key] = cause
+        if len(gone) > _READ_GONE_KEEP:
+            del gone[next(iter(gone))]
+
     def health_snapshot(self) -> dict:
         """Round-loop health for the cluster health sampler (ISSUE 13):
         staged-op backlog, registered groups, warmup readiness and the
@@ -358,6 +408,10 @@ class TpuQuorumCoordinator:
             "fused_dispatches": self.fused_dispatches,
             "read_confirms": self.read_confirms,
             "read_fallbacks": self.read_fallbacks,
+            "read_acks": self.read_acks,
+            "read_fallback_causes": dict(self.read_fallback_causes),
+            "reads_staged": self.reads_staged,
+            "reads_refused": self.reads_refused,
         }
         lt = self.lease_table
         if lt is not None:
@@ -392,6 +446,7 @@ class TpuQuorumCoordinator:
         with self._mu:
             self._nodes.pop(cluster_id, None)
             self._read_pending.pop(cluster_id, None)
+            self._read_gone.pop(cluster_id, None)
             if self.lease_table is not None:
                 self.lease_table.remove(cluster_id)
             if cluster_id in self.eng.groups:
@@ -531,6 +586,8 @@ class TpuQuorumCoordinator:
 
     def _stage(self, op) -> None:
         with self._stage_mu:
+            if self._obs is not None and self._first_at is None:
+                self._first_at = time.perf_counter()
             self._staged.append(op)
         self._pending.set()
 
@@ -550,6 +607,8 @@ class TpuQuorumCoordinator:
             if cluster_id in self._contacted:
                 return
             self._contacted.add(cluster_id)
+            if self._obs is not None and self._first_at is None:
+                self._first_at = time.perf_counter()
             self._staged.append(("contact", cluster_id))
         self._pending.set()
 
@@ -597,6 +656,10 @@ class TpuQuorumCoordinator:
         (called from the NodeHost tick worker, once per tick for ALL
         groups — the device ticks rows in lockstep)."""
         self._tick_seq += 1
+        if self._obs is not None and self._first_at is None:
+            # single writer besides the staging lock's holders; a lost
+            # stamp costs one round's wait_ms, nothing else
+            self._first_at = time.perf_counter()
         self._pending.set()
 
     def _drain_locked(self) -> list:
@@ -611,6 +674,7 @@ class TpuQuorumCoordinator:
         with self._stage_mu:
             ops, self._staged = self._staged, []
             self._contacted.clear()
+            self._round_first_at, self._first_at = self._first_at, None
         recover = []
         lt = self.lease_table
         lease_acks: Dict[int, set] = {}
@@ -651,8 +715,12 @@ class TpuQuorumCoordinator:
                         # every pending-read slot holds an unconfirmed
                         # batch: leave this ctx to the scalar fallback
                         # (its echoes arrive as unknown-ctx racks below)
-                        pass
+                        self.reads_refused += 1
+                        self._read_gone_note(
+                            cid, (op[3], op[4]), "slot_overflow"
+                        )
                     else:
+                        self.reads_staged += 1
                         self._read_pending.setdefault(cid, []).append(
                             (slot, op[3], op[4], op[5])
                         )
@@ -664,12 +732,19 @@ class TpuQuorumCoordinator:
                             slot = sl
                             break
                     if slot is not None:
+                        self.read_acks += 1
                         self.eng.read_ack(cid, node_id, slot)
                     else:
-                        # ctx not device-tracked (slot overflow, stale or
-                        # already-confirmed echo): scalar tally under
-                        # raftMu — confirm() on an unknown ctx is a no-op
-                        self.read_fallbacks += 1
+                        # ctx not device-tracked: scalar tally under
+                        # raftMu — confirm() on an unknown ctx is a
+                        # no-op.  Counted by cause: refused a slot,
+                        # already confirmed / prefix-released, or (not
+                        # remembered either way) dropped by a transition
+                        self.read_fallback_causes[
+                            self._read_gone.get(cid, {}).get(
+                                (low, high), "purged"
+                            )
+                        ] += 1
                         node = self._nodes.get(cid)
                         if node is not None:
                             node.offload_read_echo(node_id, low, high)
@@ -765,7 +840,13 @@ class TpuQuorumCoordinator:
                 # (the bimodal slow mode would return with no clue)
                 plog.warning("engine round-thread nice failed: %r", e)
         while not self._stopped.is_set():
-            fired = self._pending.wait(timeout=self._interval)
+            if self._obs is not None:
+                # named, so a device idle gap reads "round thread had
+                # nothing to do" instead of no_event_traced
+                with _annotate("round_idle"):
+                    fired = self._pending.wait(timeout=self._interval)
+            else:
+                fired = self._pending.wait(timeout=self._interval)
             if self._stopped.is_set():
                 return
             if fired:
@@ -778,7 +859,16 @@ class TpuQuorumCoordinator:
     def _round(self) -> None:
         recover: list = []
         try:
-            self._round_inner(recover)
+            if self._obs is not None:
+                # every turn of the round thread is a dbtpu:round in a
+                # capture; one that dispatches nothing (the quiet poll
+                # every interval_s, a drain of leader contacts) holds no
+                # dbtpu:fanout and has no coord_round span: the ring is
+                # the count of dispatched rounds
+                with _annotate("round"):
+                    self._round_inner(recover)
+            else:
+                self._round_inner(recover)
         finally:
             if recover:
                 # rare-path row rebuilds, OUTSIDE _mu (lock order: raft_mu
@@ -790,7 +880,7 @@ class TpuQuorumCoordinator:
     def _round_inner(self, recover: list) -> None:
         obs = self._obs
         t0 = time.perf_counter() if obs is not None else 0.0
-        gate = None
+        span = None
         n_ops = 0
         k_rounds = 1
         fused = False
@@ -810,11 +900,12 @@ class TpuQuorumCoordinator:
             self._tick_seen = seq
             if obs is not None:
                 n_ops = len(self._staged)  # racy read, gauge-grade
-            recover.extend(self._drain_locked())
-            if self.devsm is not None:
-                # advance pending devsm binds (host apply catching the
-                # promotion watermark completes them)
-                self.devsm.poll()
+            with (obs.phase("drain") if obs is not None else _OFF):
+                recover.extend(self._drain_locked())
+                if self.devsm is not None:
+                    # advance pending devsm binds (host apply catching
+                    # the promotion watermark completes them)
+                    self.devsm.poll()
             has_acks = bool(
                 self.eng._acks or self.eng._ack_blocks or self.eng._votes
             )
@@ -836,15 +927,26 @@ class TpuQuorumCoordinator:
             if not (do_tick or has_acks or has_reads or has_kv or dirty_gate):
                 return
             if obs is not None:
-                gate = "+".join(
-                    name
-                    for name, hit in (
-                        ("tick", do_tick), ("acks", has_acks),
-                        ("reads", has_reads), ("kv", has_kv),
-                        ("dirty", dirty_gate),
-                    )
-                    if hit
+                # the round dispatches: open its span BEFORE the first
+                # dispatch, so the engine's spans name it as their parent
+                first = self._round_first_at
+                span = obs.round_open(
+                    t0=t0,
+                    gate="+".join(
+                        name
+                        for name, hit in (
+                            ("tick", do_tick), ("acks", has_acks),
+                            ("reads", has_reads), ("kv", has_kv),
+                            ("dirty", dirty_gate),
+                        )
+                        if hit
+                    ),
+                    wait_ms=(
+                        max(0.0, t0 - first) * 1e3
+                        if first is not None else 0.0
+                    ),
                 )
+                self.eng.set_span_parent(span["seq"])
             # Adaptive K-round batching (ISSUE 7 tentpole).  The fused
             # K-round program (step_rounds, the ladder's workhorse) was
             # once measured here and reverted because each first-use XLA
@@ -950,6 +1052,68 @@ class TpuQuorumCoordinator:
                         merged = set(getattr(res, field))
                         merged.update(getattr(extra, field))
                         setattr(res, field, list(merged))
+        with (obs.phase("fanout") if obs is not None else _OFF):
+            self._fan_out(
+                res, read_confirms, do_tick,
+                span["seq"] if span is not None else None,
+            )
+        if obs is not None:
+            if self.lease_table is not None:
+                # advisory lease-coverage gauge (dragonboat_lease_groups_
+                # held), refreshed from the drain-fed table — device-plane
+                # lease introspection with zero raftMu traffic
+                self.lease_table.publish(obs.registry, self._tick_seen)
+            # the recorder's stall check on wall_ms IS the round-gate
+            # watchdog: a round outlasting stall_ms (wedged dispatch,
+            # first-compile storm) auto-dumps the ring
+            # with this span as the trigger
+            # the read-plane counters since the last RECORDED round: a
+            # round that only drained (a scalar-side echo, no dispatch)
+            # has no span, so its counts ride the next one that has
+            causes = self.read_fallback_causes
+            reads0 = self._reads_spanned
+            self._reads_spanned = (
+                self.read_acks, self.reads_staged, self.reads_refused,
+                dict(causes),
+            )
+            obs.round(
+                span,
+                ops=n_ops,
+                deficit=deficit,
+                commits=len(res.commit),
+                reads_confirmed=len(read_confirms),
+                staged_depth=len(self._staged),
+                k_rounds=k_rounds,
+                fused=fused,
+                fuse_skip=fuse_skip,
+                read_acks=self.read_acks - reads0[0],
+                reads_staged=self.reads_staged - reads0[1],
+                reads_refused=self.reads_refused - reads0[2],
+                read_fallbacks={
+                    c: causes[c] - n for c, n in reads0[3].items()
+                },
+            )
+        # cost-driven placement (mesh dispatch plane): a time-gated
+        # rebalance pass on dispatched rounds only — quiet coordinators
+        # have no load to balance.  Runs under _mu like every other
+        # engine access; the pass is bounded (one migration) and bails
+        # unless the shard cost EMAs actually skew.
+        if self.mesh_devices > 1:
+            now = time.monotonic()
+            if now >= self._next_rebalance:
+                self._next_rebalance = now + self._rebalance_interval
+                try:
+                    with self._mu:
+                        self.eng.maybe_rebalance()
+                except Exception:
+                    plog.exception("mesh rebalance failed")
+
+    def _fan_out(self, res, read_confirms: list, do_tick: bool,
+                 round_seq: Optional[int]) -> None:
+        """Everything of a round after the engine returned, OUTSIDE _mu:
+        trace stamps, read-confirm and commit offloads, the tick flags,
+        election outcomes (the ``fanout_ms`` of the round's span;
+        ``round_seq`` is that span's seq, None while obs is off)."""
         # (devsm KV read captures were already delivered by the engine's
         # kv_egress_hook inside each harvest — see devsm_plane())
         # confirmed-read releases, OUTSIDE _mu like the commit callbacks:
@@ -974,7 +1138,9 @@ class TpuQuorumCoordinator:
                 cids.update(c for c, _l, _h, _t in read_confirms)
             else:
                 cids = res.commit
-            tracer.mark_clusters(cids, seq if seq >= 0 else None)
+            tracer.mark_clusters(
+                cids, seq if seq >= 0 else None, round_seq
+            )
         replattr = self.replattr
         if replattr is not None and res.commit:
             # device-plane commit attribution (ISSUE 14): link THIS
@@ -1051,43 +1217,6 @@ class TpuQuorumCoordinator:
             node = self._nodes.get(cid)
             if node is not None:
                 node.offload_election(False, term)
-        if obs is not None:
-            if self.lease_table is not None:
-                # advisory lease-coverage gauge (dragonboat_lease_groups_
-                # held), refreshed from the drain-fed table — device-plane
-                # lease introspection with zero raftMu traffic
-                self.lease_table.publish(obs.registry, self._tick_seen)
-            # the recorder's stall check on wall_ms IS the round-gate
-            # watchdog: a round outlasting stall_ms (wedged dispatch,
-            # first-compile storm) auto-dumps the ring
-            # with this span as the trigger
-            obs.round(
-                wall_ms=(time.perf_counter() - t0) * 1e3,
-                gate=gate,
-                ops=n_ops,
-                deficit=deficit,
-                commits=len(res.commit),
-                reads_confirmed=len(read_confirms),
-                read_fallbacks=self.read_fallbacks,
-                staged_depth=len(self._staged),
-                k_rounds=k_rounds,
-                fused=fused,
-                fuse_skip=fuse_skip,
-            )
-        # cost-driven placement (mesh dispatch plane): a time-gated
-        # rebalance pass on dispatched rounds only — quiet coordinators
-        # have no load to balance.  Runs under _mu like every other
-        # engine access; the pass is bounded (one migration) and bails
-        # unless the shard cost EMAs actually skew.
-        if self.mesh_devices > 1:
-            now = time.monotonic()
-            if now >= self._next_rebalance:
-                self._next_rebalance = now + self._rebalance_interval
-                try:
-                    with self._mu:
-                        self.eng.maybe_rebalance()
-                except Exception:
-                    plog.exception("mesh rebalance failed")
 
     def _collect_read_confirms(self, res, out: list) -> None:
         """Map confirmed-read egress slots back to their ctxs (under _mu).
@@ -1111,10 +1240,12 @@ class TpuQuorumCoordinator:
                 continue
             _slot, low, high, term = fifo[pos]
             for e in fifo[:pos]:  # prefix-released scalar-side
+                self._read_gone_note(cid, (e[1], e[2]), "after_confirm")
                 try:
                     self.eng.cancel_read(cid, e[0])
                 except (ValueError, KeyError):
                     pass
+            self._read_gone_note(cid, (low, high), "after_confirm")
             del fifo[: pos + 1]
             self.read_confirms += 1
             out.append((cid, low, high, term))

@@ -14,6 +14,8 @@ import os
 import pkgutil
 import time
 
+import pytest
+
 import dragonboat_tpu
 from dragonboat_tpu.events import MetricsRegistry, escape_label_value
 from dragonboat_tpu.obs import FlightRecorder
@@ -508,3 +510,401 @@ def test_hostproc_obs_live_plane_families():
             _time.sleep(0.05)
     finally:
         p.stop()
+
+
+# ---------------------------------------------------------------------------
+# spans as intervals with a cause, phases, counters at the boundaries
+# (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+DISPATCH_PARTS = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms")
+
+
+def test_recorder_default_ring_holds_16k_span_run_in_order():
+    from dragonboat_tpu.obs.recorder import DEFAULT_CAPACITY
+
+    assert DEFAULT_CAPACITY >= 16384
+    rec = FlightRecorder(stall_ms=0)
+    for i in range(16384):
+        rec.record("dispatch", n=i)
+    spans = rec.spans()
+    assert len(spans) == 16384 and spans[0]["n"] == 0
+    assert [s["seq"] for s in spans] == list(range(16384))
+    for i in range(16384, rec.capacity + 10):
+        rec.record("dispatch", n=i)  # wrap: oldest -> newest still
+    spans = rec.spans()
+    assert len(spans) == rec.capacity
+    assert [s["n"] for s in spans] == list(
+        range(10, rec.capacity + 10)
+    )
+
+
+def test_span_is_an_interval_with_host_and_parent():
+    rec = FlightRecorder(capacity=8, stall_ms=0)
+    before = time.perf_counter()
+    top = rec.record("coord_round", host="h1")
+    child = rec.record("dispatch", host="h1", parent=top["seq"],
+                       t0=before)
+    assert top["t0"] == top["t1"] >= before and top["parent"] is None
+    assert child["t0"] == before and child["parent"] == top["seq"]
+    time.sleep(0.002)
+    rec.update(child, egress_ms=1.0)
+    assert child["t1"] > child["t0"] and child["t1"] <= time.perf_counter()
+    rec.update(top, t1=child["t1"] + 1.0)  # a caller's own t1 wins
+    assert top["t1"] == child["t1"] + 1.0
+    assert top["host"] == child["host"] == "h1"
+
+
+def _leader_engine(rec, host=None, groups=8):
+    eng = BatchedQuorumEngine(groups, 3, device_ticks=False)
+    eng.enable_obs(recorder=rec, registry=MetricsRegistry(), host=host)
+    for cid in range(1, groups + 1):
+        eng.add_group(cid, node_ids=[1, 2, 3], self_id=1)
+        eng.set_leader(cid, term=1, term_start=1, last_index=1)
+    return eng
+
+
+def test_dispatch_phases_make_up_dispatch_and_egress():
+    """``dispatch_ms`` holds its four phases, which are nearly all of it
+    on a quiet engine; ``egress_ms`` likewise; ``t1 - t0`` and the whole
+    call's ``step_ms`` hold both."""
+    rec = FlightRecorder(stall_ms=0)
+    eng = _leader_engine(rec, host="solo")
+    idx = 1
+    for i in range(40):
+        idx += 1
+        for cid in range(1, 9):
+            eng.ack(cid, 1, idx)
+            eng.ack(cid, 2, idx)
+        if i % 4 == 3:  # the fused path too
+            eng.begin_round()
+            eng.step_rounds(do_tick=False)
+        else:
+            eng.step(do_tick=False)
+    spans = [s for s in rec.spans() if s["kind"] in ("dispatch", "fused")]
+    assert {s["kind"] for s in spans} == {"dispatch", "fused"}
+    import statistics
+
+    quiet = spans[8:]  # past the first-use compiles
+    for s in quiet:
+        assert s["host"] == "solo" and s["parent"] is None
+        assert sum(s[k] for k in DISPATCH_PARTS) <= s["dispatch_ms"] + 0.01
+        assert s["egress_wait_ms"] + s["decode_ms"] <= s["egress_ms"] + 0.01
+        wall = (s["t1"] - s["t0"]) * 1e3
+        assert wall + 0.01 >= s["dispatch_ms"] + s["egress_ms"], s
+        # the whole call, as its caller sees it, holds both
+        assert s["step_ms"] + 0.01 >= s["dispatch_ms"] + s["egress_ms"], s
+    # medians, so that one preempted step of a loaded test box cannot
+    # fail it: within 5% (or 0.2 ms)
+    disp = statistics.median(s["dispatch_ms"] for s in quiet)
+    named = statistics.median(
+        sum(s[k] for k in DISPATCH_PARTS) for s in quiet
+    )
+    assert disp - named <= max(0.05 * disp, 0.2), (disp, named)
+    eg = statistics.median(s["egress_ms"] for s in quiet)
+    eg_named = statistics.median(
+        s["egress_wait_ms"] + s["decode_ms"] for s in quiet
+    )
+    assert eg - eg_named <= max(0.05 * eg, 0.2), (eg, eg_named)
+    # the dirty-row upload of the registration is a row sync
+    assert spans[0]["row_sync_ms"] > 0
+
+
+class _FakeNode:
+    """The surface the coordinator's fan-out drives."""
+
+    def __init__(self, cid, raft):
+        self.cluster_id = cid
+        self.peer = type("P", (), {"raft": raft})
+        self.commits, self.confirms, self.echoes = [], [], []
+
+    def offload_commit(self, q):
+        self.commits.append(q)
+
+    def offload_read_confirm(self, low, high, term):
+        self.confirms.append((low, high, term))
+
+    def offload_read_echo(self, node_id, low, high):
+        self.echoes.append((node_id, low, high))
+
+
+def _coord_with_leaders(cids, rec=None, host="coordhost"):
+    from dragonboat_tpu.raft import InMemLogDB
+    from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
+    from tests.raft_harness import new_test_raft
+
+    coord = TpuQuorumCoordinator(capacity=8, n_peers=4, drive_ticks=False)
+    if rec is not None:
+        coord.enable_obs(recorder=rec, registry=MetricsRegistry(),
+                         host=host)
+    nodes = {}
+    for cid in cids:
+        r = new_test_raft(1, [1, 2, 3], 10, 1, InMemLogDB())
+        r.cluster_id = cid
+        r.become_candidate()
+        r.become_leader()
+        n = nodes[cid] = _FakeNode(cid, r)
+        coord._nodes[cid] = n
+        with coord._mu:
+            coord._sync_row_locked(n)
+    coord.flush()  # absorb the registration dirt
+    return coord, nodes
+
+
+def test_round_span_is_parent_of_its_dispatches_and_holds_its_phases():
+    rec = FlightRecorder(stall_ms=0)
+    coord, nodes = _coord_with_leaders([3, 4], rec)
+    try:
+        for _ in range(12):
+            for cid in nodes:
+                coord.ack(cid, 2, 1)
+            coord.flush()
+        spans = rec.spans()  # this coordinator's alone
+        rounds = {s["seq"]: s for s in spans if s["kind"] == "coord_round"}
+        kids = [s for s in spans if s["kind"] in ("dispatch", "fused")]
+        assert len(rounds) >= 8 and len(kids) >= 8  # the round thread
+        # races flush() for the staged acks: some rounds take two turns
+        by_parent = {}
+        for k in kids:
+            # every dispatch names the round that ran it, same host, and
+            # lies inside the round's interval
+            assert k["parent"] in rounds, (k, sorted(rounds))
+            r = rounds[k["parent"]]
+            assert k["host"] == r["host"] == "coordhost"
+            assert r["t0"] <= k["t0"] and k["t1"] <= r["t1"]
+            by_parent.setdefault(k["parent"], []).append(k)
+        for seq, r in rounds.items():
+            assert r["parent"] is None and r["wait_ms"] >= 0.0
+            assert abs((r["t1"] - r["t0"]) * 1e3 - r["wall_ms"]) < 0.01
+            inner = r["drain_ms"] + r["fanout_ms"] + sum(
+                k["dispatch_ms"] + k["egress_ms"]
+                for k in by_parent.get(seq, ())
+            )
+            assert inner <= r["wall_ms"] + 0.01, (r, by_parent.get(seq))
+            assert by_parent.get(seq), "a recorded round dispatched"
+        # a write staged before the round began waited for it
+        assert any(r["wait_ms"] > 0 for r in rounds.values())
+        assert any(n.commits for n in nodes.values())
+    finally:
+        coord.stop()
+
+
+def _echo_cause_cases():
+    from dragonboat_tpu.ops.state import READ_SLOTS
+
+    def overflow(coord, cid, term):
+        # every pending-read slot holds an unconfirmed batch: the next
+        # context is refused one, and its echo is tallied scalar-side
+        for low in range(1, READ_SLOTS + 2):
+            coord.read_stage(cid, 1, low=low, high=low, term=term)
+        coord.flush()
+        coord.read_ack_hint(cid, 2, low=READ_SLOTS + 1, high=READ_SLOTS + 1)
+        coord.flush()
+
+    def late(coord, cid, term):
+        # the first echo confirms the context on the device; the second
+        # replica's echo of the same context comes after
+        coord.read_stage(cid, 1, low=7, high=7, term=term)
+        coord.read_ack_hint(cid, 2, low=7, high=7)
+        coord.flush()
+        coord.read_ack_hint(cid, 3, low=7, high=7)
+        coord.flush()
+
+    def purge(coord, cid, term):
+        # a transition drops the group's FIFO under a staged context
+        coord.read_stage(cid, 1, low=9, high=9, term=term)
+        coord.flush()
+        coord.set_follower(cid, term + 1)
+        coord.read_ack_hint(cid, 2, low=9, high=9)
+        coord.flush()
+
+    return {"slot_overflow": overflow, "after_confirm": late,
+            "purged": purge}
+
+
+
+
+@pytest.mark.parametrize(
+    "cause", ["slot_overflow", "after_confirm", "purged"]
+)
+def test_read_echo_fallbacks_are_counted_by_cause(cause):
+    rec = FlightRecorder(stall_ms=0)
+    reg = MetricsRegistry()
+    coord, nodes = _coord_with_leaders([7], None)
+    coord.enable_obs(recorder=rec, registry=reg, host="h")
+    try:
+        term = nodes[7].peer.raft.term
+        _echo_cause_cases()[cause](coord, 7, term)
+        causes = coord.read_fallback_causes
+        assert causes[cause] == 1, causes
+        assert sum(causes.values()) == coord.read_fallbacks == 1
+        assert len(nodes[7].echoes) == 1  # the scalar tally got it
+        snap = coord.health_snapshot()
+        assert snap["read_fallback_causes"] == causes
+        assert snap["read_fallbacks"] == 1
+        assert snap["read_acks"] == coord.read_acks == (
+            1 if cause == "after_confirm" else 0
+        )
+        if cause == "slot_overflow":
+            assert coord.reads_refused == 1 and coord.reads_staged >= 1
+        # per round on the span (a round that only tallied an echo
+        # scalar-side dispatched nothing and has no span: its counts ride
+        # the next round that has), and as the {cause} counter (no gauge)
+        coord.ack(7, 2, 1)
+        coord.flush()
+        rounds = [s for s in rec.spans() if s["kind"] == "coord_round"]
+        assert sum(s["read_fallback_" + cause] for s in rounds) == 1
+        assert sum(s["read_acks"] for s in rounds) == coord.read_acks
+        assert reg.counter_value(
+            "dragonboat_coord_read_fallbacks_total", {"cause": cause}
+        ) == 1
+        out = io.StringIO()
+        reg.write_health_metrics(out)
+        text = out.getvalue()
+        assert 'dragonboat_coord_read_fallbacks_total{cause="%s"} 1' % (
+            cause) in text
+        assert "dragonboat_coord_read_fallbacks " not in text
+        assert "# TYPE dragonboat_coord_read_fallbacks gauge" not in text
+    finally:
+        coord.stop()
+
+
+def test_compilation_log_names_program_and_thread(tmp_path):
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    from dragonboat_tpu.ops.engine import (
+        compilation_cache_stats,
+        compilation_log,
+        enable_persistent_compilation_cache,
+    )
+
+    enable_persistent_compilation_cache(str(tmp_path))
+    before = len(compilation_log())
+    counted = compilation_cache_stats()
+
+    def compiled_on_purpose_for_issue26(x):
+        return x * 3 + 1
+
+    def work():
+        jax.jit(compiled_on_purpose_for_issue26)(jnp.arange(7))
+
+    t0 = time.perf_counter()
+    th = threading.Thread(target=work, name="compile-on-purpose")
+    th.start()
+    th.join()
+    t1 = time.perf_counter()
+    new = compilation_log()[before:]
+    mine = [e for e in new
+            if "compiled_on_purpose_for_issue26" in e[2]]
+    assert mine, new
+    c0, c1, program, thread, verdict = mine[0]
+    assert thread == "compile-on-purpose"
+    assert t0 <= c0 <= c1 <= t1
+    assert verdict in ("hit", "miss", "uncached")
+    after = compilation_cache_stats()
+    assert (after["hits"] + after["misses"]) - (
+        counted["hits"] + counted["misses"]
+    ) == sum(1 for e in new if e[4] != "uncached")
+
+
+def _host_events(trace_dir):
+    """name -> [duration ns] over every host thread of the newest
+    capture under ``trace_dir``; plus per-thread (name, start, dur)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, os.listdir(trace_dir)
+    by_name, lines = {}, []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns), int(e.duration_ns))
+                   for e in line.events if e.name.startswith("dbtpu:")]
+            if evs:
+                lines.append(evs)
+            for name, _s, d in evs:
+                by_name.setdefault(name, []).append(d)
+    return by_name, lines
+
+
+def test_profiler_capture_holds_the_same_spans_as_the_ring(tmp_path):
+    """The phases are on the profiler's clock too: a capture holds one
+    ``dbtpu:*`` event per occurrence of a phase, and their count and
+    length agree with the ring's spans of the same interval."""
+    import statistics
+
+    import jax
+
+    rec = FlightRecorder(stall_ms=0)
+    coord, nodes = _coord_with_leaders([3, 4, 5], rec)
+    try:
+        for _ in range(6):  # every program compiled before the capture
+            for cid in nodes:
+                coord.ack(cid, 2, 1)
+            coord.flush()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            t_a = time.perf_counter()
+            for _ in range(40):
+                for cid in nodes:
+                    coord.ack(cid, 2, 1)
+                coord.flush()
+            with coord._mu:  # no round in flight across the edge
+                t_b = time.perf_counter()
+        finally:
+            jax.profiler.stop_trace()
+        by_name, lines = _host_events(str(tmp_path))
+        for name in ("dbtpu:round", "dbtpu:drain", "dbtpu:stage",
+                     "dbtpu:transfer", "dbtpu:launch", "dbtpu:egress_wait",
+                     "dbtpu:decode", "dbtpu:fanout"):
+            assert by_name.get(name), (name, sorted(by_name))
+        spans = [s for s in rec.spans() if t_a <= s["t0"] < t_b]
+        rounds = [s for s in spans if s["kind"] == "coord_round"]
+        kids = [s for s in spans if s["kind"] in ("dispatch", "fused")]
+        assert len(rounds) >= 30
+
+        def agree(events_ns, field_ms, what):
+            # the same spans seen twice: as many events as spans, of the
+            # same median length (5%, or 30 us of annotation overhead)
+            assert abs(len(events_ns) - len(field_ms)) <= max(
+                1, 0.05 * len(field_ms)), (what, len(events_ns),
+                                           len(field_ms))
+            a = statistics.median(events_ns) / 1e6
+            b = statistics.median(field_ms)
+            assert abs(a - b) <= max(0.05 * b, 0.03), (what, a, b)
+
+        # a dbtpu:round that dispatched holds a dbtpu:fanout; the quiet
+        # polls of the round thread hold none and have no span
+        dispatched = []
+        for evs in lines:
+            fans = [(s, s + d) for n, s, d in evs if n == "dbtpu:fanout"]
+            for n, s, d in evs:
+                if n == "dbtpu:round" and any(
+                    s <= f0 and f1 <= s + d for f0, f1 in fans
+                ):
+                    dispatched.append(d)
+        agree(dispatched, [(s["t1"] - s["t0"]) * 1e3 for s in rounds],
+              "round")
+        agree(by_name["dbtpu:fanout"], [s["fanout_ms"] for s in rounds],
+              "fanout")
+        agree(by_name["dbtpu:launch"], [s["launch_ms"] for s in kids],
+              "launch")
+        agree(by_name["dbtpu:transfer"], [s["transfer_ms"] for s in kids],
+              "transfer")
+        agree(by_name["dbtpu:egress_wait"],
+              [s["egress_wait_ms"] for s in kids], "egress_wait")
+        # a step stages in several blocks: the phase field is their sum
+        total = sum(by_name["dbtpu:stage"]) / 1e6
+        field = sum(s["stage_ms"] for s in kids)
+        assert abs(total - field) <= max(0.05 * field, 0.5), (total, field)
+    finally:
+        coord.stop()

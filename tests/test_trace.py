@@ -21,6 +21,8 @@ Contracts under test:
 import json
 import time
 
+import pytest
+
 from dragonboat_tpu import Config, NodeHostConfig, Result
 from dragonboat_tpu import vfs
 from dragonboat_tpu.config import ExpertConfig
@@ -574,3 +576,142 @@ def test_sigusr2_handler_dumps(tmp_path):
     finally:
         nh.stop()
     assert signal.getsignal(signal.SIGUSR2) is old
+
+
+# ----------------------------------------------------------------------
+# one switch, one clock (ISSUE 26): the tracer attaches the flight
+# recorder; a device_round stamp links a span with an interval; attempt
+# outcomes are counted by result code
+# ----------------------------------------------------------------------
+
+
+from dragonboat_tpu.obs import trace as trace_mod  # noqa: E402
+from dragonboat_tpu.requests import (  # noqa: E402
+    RequestResult,
+    RequestResultCode,
+)
+
+
+def test_tracer_alone_attaches_ring_and_round_stamp_lies_in_linked_span():
+    """``trace_sample_every=8`` and ``enable_metrics=False`` (what the
+    benchmark's traced mode sets): the host has a flight recorder, and a
+    completed sampled write's ``device_round`` stamp lies inside the
+    interval of a span it links, on ``perf_counter``."""
+    nh = _mk_host(addr="tr26:1", trace=8, engine="tpu", metrics=False,
+                  warm_fused=False)
+    try:
+        _start(nh)
+        rec = nh.flight_recorder
+        assert rec is not None and nh.tracer.recorder is rec
+        qc = nh.quorum_coordinator
+        assert qc._obs is not None and qc.eng._obs is not None
+        s = nh.get_noop_session(CID)
+        states = [nh.propose(s, b"w", timeout=10.0) for _ in range(24)]
+        for rs in states:
+            assert rs.wait(10.0).completed
+        sampled = [rs.trace for rs in states if rs.trace.__class__ is Trace]
+        assert len(sampled) == 3  # 1 in 8
+        by_seq = {sp["seq"]: sp for sp in rec.spans()}
+        for t in sampled:
+            stamp = next(ts for st, ts, _th in t.events
+                         if st == "device_round")
+            linked = [by_seq[q] for q in t.spans if q in by_seq]
+            assert {sp["kind"] for sp in linked} >= {"coord_round"}, linked
+            holding = [sp for sp in linked
+                       if sp["t0"] <= stamp <= sp["t1"]]
+            assert holding, (stamp, linked)
+            assert all(sp["host"] == "tr26:1" for sp in linked)
+            # the dispatch span the stamp links is a child of that round
+            kids = [sp for sp in linked
+                    if sp["kind"] in ("dispatch", "fused")]
+            assert kids and {k["parent"] for k in kids} <= {
+                sp["seq"] for sp in linked if sp["kind"] == "coord_round"
+            }
+    finally:
+        nh.stop()
+
+
+def test_both_switches_off_leave_coordinator_and_engine_latches_none():
+    nh = _mk_host(addr="tr26off:1", trace=0, engine="tpu", metrics=False,
+                  warm_fused=False)
+    try:
+        _start(nh)
+        _assert_trace_off(nh)
+        qc = nh.quorum_coordinator
+        assert qc._obs is None and qc.eng._obs is None
+        assert qc.flight_recorder is None and nh.flight_recorder is None
+        assert qc._first_at is None  # no wait stamp is taken while off
+        assert nh.tracer not in trace_mod.live()
+    finally:
+        nh.stop()
+
+
+@pytest.mark.parametrize("code", [c.name for c in RequestResultCode])
+@pytest.mark.parametrize("kind", ["write", "read"])
+def test_outcomes_count_every_completion_by_kind_and_code(kind, code):
+    """Sampled or not, a completion is counted under ``(propose|read,
+    CODE)``; the non-COMPLETED ones keep their instant."""
+    reg = MetricsRegistry()
+    tr = Tracer(sample_every=2, registry=reg)
+    try:
+        states = [RequestState(key=i + 1, deadline=0) for i in range(4)]
+        tr.attach_all(states, CID, time.perf_counter(), kind=kind)
+        t_a = time.perf_counter()
+        for rs in states:
+            rs.notify(RequestResult(code=RequestResultCode[code]))
+        t_b = time.perf_counter()
+        name = "propose" if kind == "write" else "read"
+        out = tr.outcomes()
+        assert out["counts"] == {(name, code): 4}
+        events = out["events"]
+        if code == "COMPLETED":
+            assert events == []
+        else:
+            assert len(events) == 4
+            assert all(k == name and c == code and t_a <= t <= t_b
+                       for t, k, c in events)
+        assert sum(n for sec in out["by_second"].values()
+                   for n in sec.values()) == 4
+        tr.flush_metrics()
+        assert reg.counter_value(
+            "dragonboat_trace_requests_done_total",
+            {"kind": name, "code": code},
+        ) == 4
+    finally:
+        tr.close()
+
+
+def test_live_lists_every_open_tracer_of_the_process():
+    a = Tracer(sample_every=1, registry=MetricsRegistry())
+    b = Tracer(sample_every=1, registry=MetricsRegistry())
+    try:
+        live = trace_mod.live()
+        assert live.index(a) < live.index(b)
+        assert trace_mod.active() is b
+    finally:
+        b.close()
+        assert b not in trace_mod.live() and a in trace_mod.live()
+        a.close()
+    assert a not in trace_mod.live()
+
+
+def test_chrome_export_places_recorder_spans_by_their_own_interval():
+    """The device-plane track is drawn from ``t0``/``t1`` (the stamps'
+    clock), not from the wall time a record was written at."""
+    rec = FlightRecorder(capacity=8, stall_ms=0)
+    tr = Tracer(sample_every=1, registry=MetricsRegistry(), recorder=rec)
+    tr.host = "me:1"
+    try:
+        now = time.perf_counter()
+        span = rec.record("coord_round", host="me:1", t0=now - 0.250)
+        rec.update(span, t1=now - 0.050, wall_ms=200.0)
+        span["ts"] = 12345.0  # a lying wall clock must change nothing
+        rec.record("coord_round", host="other:1", t0=now - 0.2)
+        dev = [e for e in tr.export_chrome()["traceEvents"]
+               if e.get("cat") == "device"]
+        assert len(dev) == 1  # the co-hosted NodeHost's span stays out
+        assert abs(dev[0]["dur"] - 200000.0) < 1.0
+        assert abs(dev[0]["ts"] - tr._wall_us(now - 0.250)) < 1.0
+        assert "t0" not in dev[0]["args"] and dev[0]["args"]["seq"] == 0
+    finally:
+        tr.close()
