@@ -1723,6 +1723,25 @@ class BatchedQuorumEngine:
             slot = np.where(ok, cand, slot)
         return slot
 
+    def _free_read_slot_one(self, row: int) -> int:
+        """``_free_read_slot`` for ONE row, on scalars.  Single-op
+        staging runs on a coordinator round thread that shares the
+        interpreter with every raft worker of the process; an index-array
+        read (``arr[rows, cand]``) releases the interpreter lock whatever
+        its size, and winning it back costs the round milliseconds per
+        staged read.  ``arr[row, slot]`` on ints does not.  Same rule as
+        the block form (test_read_confirm holds the two equal)."""
+        s = self.n_read_slots
+        busy = self._read_busy
+        freed = self._read_freed_round
+        seq = self._round_seq
+        cur = int(self._read_next_slot[row])
+        for k in range(s):
+            cand = (cur + k) % s
+            if not busy[row, cand] and freed[row, cand] < seq:
+                return cand
+        return -1
+
     def _predict_read_confirm(self, rows: np.ndarray, rslots: np.ndarray) -> None:
         """Host-side confirmation prediction: the device only ever sees
         echoes THIS host staged, so once a batch's staged echoes reach
@@ -1742,6 +1761,24 @@ class BatchedQuorumEngine:
         if conf.any():
             self._read_busy[rows[conf], rslots[conf]] = False
             self._read_freed_round[rows[conf], rslots[conf]] = self._round_seq
+
+    def _predict_read_confirm_one(self, row: int, slot: int) -> None:
+        """``_predict_read_confirm`` for ONE (row, slot), on scalars (see
+        ``_free_read_slot_one``): count the voting peers that echoed or
+        are self; at quorum a still-busy slot frees for later rounds."""
+        if not self._read_busy[row, slot]:
+            return
+        a = self.mirror.arrays
+        voting = a["voting"]
+        echo = self._read_echo_host
+        self_slot = int(a["self_slot"][row])
+        cnt = 0
+        for p in range(self.n_peers):
+            if voting[row, p] and (p == self_slot or echo[row, slot, p]):
+                cnt += 1
+        if cnt >= a["quorum"][row]:
+            self._read_busy[row, slot] = False
+            self._read_freed_round[row, slot] = self._round_seq
 
     def _reset_read_rows(self, rows) -> None:
         """Drop the rows' pending-read bookkeeping (transition purge).
@@ -1781,8 +1818,7 @@ class BatchedQuorumEngine:
             raise ValueError("stage_read count must be >= 1")
         gi = self.groups[cluster_id]
         row = gi.row
-        rows1 = np.array([row], np.int64)
-        slot = int(self._free_read_slot(rows1)[0])
+        slot = self._free_read_slot_one(row)
         if slot < 0:
             raise RuntimeError(
                 f"no free pending-read slot for group {cluster_id}"
@@ -1855,9 +1891,7 @@ class BatchedQuorumEngine:
             (row, slot, peer, int(self._row_epoch[row]))
         )
         self._read_echo_host[row, slot, peer] = True
-        self._predict_read_confirm(
-            np.array([row], np.int64), np.array([slot], np.int64)
-        )
+        self._predict_read_confirm_one(row, slot)
 
     def read_ack_block(self, rows, rslots, peers) -> None:
         """Vectorized bulk echo ingest (row / pending-read-slot / peer-slot

@@ -752,7 +752,14 @@ def test_read_echo_fallbacks_are_counted_by_cause(cause):
         # the next round that has), and as the {cause} counter (no gauge)
         coord.ack(7, 2, 1)
         coord.flush()
-        rounds = [s for s in rec.spans() if s["kind"] == "coord_round"]
+        # the round thread races flush() for the staged ack: if it won,
+        # its round may still be open (a span without its counts yet)
+        deadline = time.time() + 10
+        while True:
+            rounds = [s for s in rec.spans() if s["kind"] == "coord_round"]
+            if all("wall_ms" in s for s in rounds) or time.time() > deadline:
+                break
+            time.sleep(0.005)
         assert sum(s["read_fallback_" + cause] for s in rounds) == 1
         assert sum(s["read_acks"] for s in rounds) == coord.read_acks
         assert reg.counter_value(

@@ -33,11 +33,18 @@ def _state_equal(a, b, tag=""):
         assert np.array_equal(np.asarray(va), np.asarray(vb)), (tag, name)
 
 
-def _build(n_groups=8, n_peers=3, cap=256, read_slots=None):
+def _build(n_groups=8, n_peers=3, cap=256, read_slots=None, observer=False):
+    """Groups 1..n led by node 1; with ``observer`` the highest node id
+    is a non-voting member."""
     kw = {} if read_slots is None else {"n_read_slots": read_slots}
     eng = BatchedQuorumEngine(n_groups, n_peers, event_cap=cap, **kw)
+    ids = list(range(1, n_peers + 1))
+    members = (
+        {"node_ids": ids[:-1], "observers": (ids[-1],)} if observer
+        else {"node_ids": ids}
+    )
     for cid in range(1, n_groups + 1):
-        eng.add_group(cid, node_ids=list(range(1, n_peers + 1)), self_id=1)
+        eng.add_group(cid, self_id=1, **members)
         eng.set_leader(cid, term=1, term_start=1, last_index=1)
     eng._upload_dirty()
     return eng
@@ -391,8 +398,173 @@ def test_read_rebase_shifts_pending_watermark():
 
 
 # ----------------------------------------------------------------------
+# one rule written twice: single-op scalar path == vectorized block path
+# ----------------------------------------------------------------------
+
+
+def _read_host_state(eng):
+    return {
+        name: getattr(eng, name).copy()
+        for name in ("_read_busy", "_read_freed_round", "_read_next_slot",
+                     "_read_echo_host")
+    }
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("observer", [False, True],
+                         ids=["voters", "observer"])
+@pytest.mark.parametrize("n_peers", [3, 5])
+@pytest.mark.parametrize("read_slots", [1, 4])
+def test_read_single_op_path_equals_block_path(
+    read_slots, n_peers, observer, seed
+):
+    """``stage_read`` / ``read_ack`` pick slots and predict confirmations
+    on scalars (the live drain stages one op at a time and must not hand
+    the interpreter away per op); ``stage_read_block`` /
+    ``read_ack_block`` do it vectorized.  The same random sequence of
+    stage / echo / cancel / round-advance / leader-change ops through
+    both leaves identical host bookkeeping after EVERY op, refuses the
+    same stages, and confirms the same reads at every step."""
+    g = 6
+    one = _build(g, n_peers, read_slots=read_slots, observer=observer)
+    blk = _build(g, n_peers, read_slots=read_slots, observer=observer)
+    rng = random.Random(1000 * seed + 10 * n_peers + read_slots + observer)
+    peers = list(range(2, n_peers + 1))  # the observer, if any, echoes too
+    term = {cid: 1 for cid in range(1, g + 1)}
+    rnd = 0
+    # driver view of slots a stage returned: cid -> {slot: round staged}
+    staged = {cid: {} for cid in range(1, g + 1)}
+    refusals = confirmed = cancels = 0
+
+    def same(tag):
+        a, b = _read_host_state(one), _read_host_state(blk)
+        for name in a:
+            assert np.array_equal(a[name], b[name]), (tag, name)
+
+    def stage_blk(cids):
+        rows = [blk.groups[c].row for c in cids]
+        rels = [blk._rel(blk.groups[c], blk.committed_index(c))
+                for c in cids]
+        return blk.stage_read_block(
+            np.array(rows, np.int32), np.array(rels, np.int32),
+            np.array([1 + c % 3 for c in cids], np.int32),
+        )
+
+    for step in range(160):
+        op = rng.random()
+        cid = rng.randrange(1, g + 1)
+        if op < 0.30:                                   # one stage
+            idx = one.committed_index(cid)
+            assert idx == blk.committed_index(cid)
+            try:
+                slot = one.stage_read(cid, count=1 + cid % 3, index=idx)
+            except RuntimeError:
+                slot = None
+            try:
+                got = int(stage_blk([cid])[0])
+            except RuntimeError:
+                got = None
+            assert got == slot, (step, cid)
+            if slot is None:
+                refusals += 1
+            else:
+                staged[cid][slot] = rnd
+        elif op < 0.38:                  # stages across rows, one block
+            cids = [c for c in rng.sample(range(1, g + 1), 3)
+                    if one.read_slots_free(c) > 0]
+            if cids:
+                slots = [one.stage_read(c, count=1 + c % 3,
+                                        index=one.committed_index(c))
+                         for c in cids]
+                assert [int(x) for x in stage_blk(cids)] == slots
+                for c, sl in zip(cids, slots):
+                    staged[c][sl] = rnd
+        elif op < 0.68:                  # echoes, one or several a call
+            picks = []
+            for _ in range(rng.choice((1, 1, 2, 4))):
+                c = rng.randrange(1, g + 1)
+                # any slot: echoes for free slots must be harmless too
+                picks.append((c, rng.randrange(read_slots),
+                              rng.choice(peers)))
+            for c, sl, nid in picks:
+                one.read_ack(c, nid, sl)
+            blk.read_ack_block(
+                np.array([blk.groups[c].row for c, _s, _n in picks]),
+                np.array([sl for _c, sl, _n in picks]),
+                np.array([blk.groups[c].slots[nid] for c, _s, nid in picks]),
+            )
+        elif op < 0.76:   # cancel a batch of an EARLIER round (the live
+            # coordinator cancels after a dispatch; a block stage and a
+            # single cancel of one slot in one round have no order)
+            old = [sl for sl, r in staged[cid].items() if r < rnd]
+            if old:
+                sl = rng.choice(old)
+                one.cancel_read(cid, sl)
+                blk.cancel_read(cid, sl)
+                del staged[cid][sl]
+                cancels += 1
+        elif op < 0.82:                                 # leader change
+            term[cid] += 2
+            last = one.committed_index(cid) + 1
+            for eng in (one, blk):
+                eng.set_follower(cid, term=term[cid] - 1)
+                eng.set_leader(cid, term=term[cid], term_start=last,
+                               last_index=last)
+            staged[cid].clear()
+        else:                                           # round advance
+            ra = one.step(do_tick=False)
+            rb = blk.step(do_tick=False)
+            assert ra.reads == rb.reads, step
+            confirmed += len(ra.reads)
+            for c, sl, _idx, _n in ra.reads:
+                staged[c].pop(sl, None)
+            rnd += 1
+        same((step, op))
+    assert one.step(do_tick=False).reads == blk.step(do_tick=False).reads
+    _state_equal(one.dev, blk.dev, "single-vs-block")
+    # the sequence exercised what it claims to
+    assert confirmed and refusals and cancels
+
+
+# ----------------------------------------------------------------------
 # live coordinator: reads batched per round, device-confirmed
 # ----------------------------------------------------------------------
+
+
+def _coord_leading(cid):
+    """A tick-less coordinator whose one group is led by a scalar raft
+    behind a fake node; returns ``(coord, raft, confirms, echoes)`` —
+    the lists the node's read-confirm / scalar read-echo offloads fill.
+    Registration dirt is absorbed: the next round is the caller's."""
+    from dragonboat_tpu.raft import InMemLogDB
+    from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
+    from tests.raft_harness import new_test_raft
+
+    coord = TpuQuorumCoordinator(capacity=8, n_peers=4, drive_ticks=False)
+    r = new_test_raft(1, [1, 2, 3], 10, 1, InMemLogDB())
+    r.cluster_id = cid
+    r.become_candidate()
+    r.become_leader()
+    confirms, echoes = [], []
+
+    class _Node:
+        cluster_id = cid
+
+        class peer:
+            raft = r
+
+        def offload_read_confirm(self, low, high, term):
+            confirms.append((low, high, term))
+
+        def offload_read_echo(self, node_id, low, high):
+            echoes.append((node_id, low, high))
+
+    n = _Node()
+    coord._nodes[cid] = n
+    with coord._mu:
+        coord._sync_row_locked(n)
+    coord.flush()
+    return coord, r, confirms, echoes
 
 
 def test_read_only_round_dispatches_without_ticks():
@@ -400,40 +572,70 @@ def test_read_only_round_dispatches_without_ticks():
     their own: with ticks off and no queued write/vote events the round
     gate has nothing else to fire on, and a gate that ignores the read
     plane leaves the ctx pending until the client times out."""
-    from dragonboat_tpu.raft import InMemLogDB
-    from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
-    from tests.raft_harness import new_test_raft
-
-    coord = TpuQuorumCoordinator(capacity=8, n_peers=4, drive_ticks=False)
+    cid = 7
+    coord, r, confirms, _echoes = _coord_leading(cid)
     try:
-        cid = 7
-        r = new_test_raft(1, [1, 2, 3], 10, 1, InMemLogDB())
-        r.cluster_id = cid
-        r.become_candidate()
-        r.become_leader()
-        confirms = []
-
-        class _Node:
-            cluster_id = cid
-
-            class peer:
-                raft = r
-
-            def offload_read_confirm(self, low, high, term):
-                confirms.append((low, high, term))
-
-        n = _Node()
-        coord._nodes[cid] = n
-        with coord._mu:
-            coord._sync_row_locked(n)
-        # absorb registration dirt: the next round must be driven by the
-        # read plane alone
-        coord.flush()
         coord.read_stage(cid, r.log.committed, low=1, high=1, term=r.term)
         coord.read_ack_hint(cid, 2, low=1, high=1)
         coord.flush()
         assert confirms == [(1, 1, r.term)]
         assert coord.read_confirms == 1
+    finally:
+        coord.stop()
+
+
+def test_live_drain_stays_off_vectorized_read_helpers(monkeypatch):
+    """The coordinator's drain stages ONE read op at a time, on a round
+    thread that shares the interpreter with every raft worker; the
+    vectorized helpers' index-array calls hand the interpreter away per
+    op there (tens of ms of every round, PERF.md PR 27).  With both
+    helpers raising, a live coordinator still stages, overflows, falls
+    back, confirms, prefix-releases (cancel) and restages."""
+    def _vectorized(*_a, **_k):
+        raise AssertionError("vectorized read helper on the live drain")
+
+    monkeypatch.setattr(BatchedQuorumEngine, "_free_read_slot", _vectorized)
+    monkeypatch.setattr(
+        BatchedQuorumEngine, "_predict_read_confirm", _vectorized
+    )
+    cid = 7
+    coord, r, confirms, scalar_echoes = _coord_leading(cid)
+    # every round below is a flush() of this thread: the round thread
+    # would race it for the staged ops and fan out behind its back
+    coord.stop()
+    try:
+        s = coord.eng.n_read_slots
+        # S ctxs take the S slots, the next one overflows to the scalar
+        # side; its echo is handed to the node
+        for low in range(1, s + 2):
+            coord.read_stage(cid, r.log.committed, low=low, high=0,
+                             term=r.term)
+        coord.read_ack_hint(cid, 2, low=s + 1, high=0)
+        coord.flush()
+        assert (coord.reads_staged, coord.reads_refused) == (s, 1)
+        assert scalar_echoes == [(2, s + 1, 0)]
+        assert coord.read_fallback_causes["slot_overflow"] == 1
+        assert coord.eng.read_slots_free(cid) == 0
+        # ctx S-1 confirms (self + one echo of three voters): the ctxs
+        # before it are prefix-released scalar-side, their slots cancelled
+        coord.read_ack_hint(cid, 2, low=s - 1, high=0)
+        coord.flush()
+        assert confirms == [(s - 1, 0, r.term)]
+        assert coord.read_confirms == 1 and coord.read_acks == 1
+        assert [e[1] for e in coord._read_pending[cid]] == [s]
+        # the other follower's echo for the confirmed ctx: scalar no-op
+        coord.read_ack_hint(cid, 3, low=s - 1, high=0)
+        coord.flush()  # dispatches the cancels; their slots reusable now
+        assert coord.read_fallback_causes["after_confirm"] == 1
+        assert coord.eng.read_slots_free(cid) == s - 1
+        # a freed slot is staged again and confirms, releasing ctx S too
+        coord.read_stage(cid, r.log.committed, low=s + 2, high=0,
+                         term=r.term)
+        coord.read_ack_hint(cid, 3, low=s + 2, high=0)
+        coord.flush()
+        assert confirms == [(s - 1, 0, r.term), (s + 2, 0, r.term)]
+        assert coord.reads_staged == s + 1
+        assert not coord._read_pending[cid]
     finally:
         coord.stop()
 
