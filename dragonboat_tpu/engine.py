@@ -41,7 +41,14 @@ class _WorkReady:
     def cluster_ready(self, cluster_id: int) -> None:
         idx = self.partitioner.get_partition_id(cluster_id)
         self.ready[idx].set_ready(cluster_id)
-        self.notify(idx)
+        # a worker already flagged has not taken its ready set yet (it
+        # clears the flag before it does): this group is in what it will
+        # take, and the condition's lock need not be touched.  Every
+        # message, flag and wake-up of a thousand groups comes through
+        # here; on a saturated interpreter each contended lock costs the
+        # caller a switch interval.
+        if not self.flag[idx]:
+            self.notify(idx)
 
     def all_ready(self, idx: int) -> None:
         self.notify(idx)

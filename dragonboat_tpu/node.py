@@ -135,6 +135,11 @@ class Node:
         # pipeline; the step worker skips the group until the committer
         # clears it (per-group round ordering, see engine._Committer)
         self.commit_inflight = False
+        # an Update has left ``step_node`` and is not committed yet (set
+        # and cleared under raftMu): until then the raft state belongs to
+        # it — the outbox it shares, the entries it saves — and the
+        # batched heartbeat plane's legs leave the group alone
+        self._update_out = False
         # native replication fast lane (fastlane.py / native/natraft.cpp):
         # while fast_lane is True the Python raft object is frozen and the
         # native core owns the group's steady-state data plane
@@ -372,6 +377,169 @@ class Node:
             self._off_demote = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
+
+    # ---- batched heartbeat plane (tpuquorum.py) ----
+    #
+    # The three legs of a heartbeat exchange, run by the coordinator for
+    # every group of a block in one pass on ONE thread (the round thread,
+    # the transport's delivery thread) instead of one step-worker turn a
+    # group a leg.  Each takes raftMu without waiting and returns the
+    # cause where the per-group path must be taken: the scalar handlers
+    # then see exactly today's message.  A busy group (raftMu held, or
+    # its update not committed yet) gets the leg's lock-free part only
+    # (``_hb_block_raft``) and the answer "lite".
+
+    def _hb_block_enter(self):
+        """Take raftMu without waiting and bring the scalar clock up to
+        now, as a step would have (``_catch_up_and_tick``: the guards and
+        the pending-request timeouts read it).  Returns the cause that
+        keeps this replica off the block plane, or None with raftMu HELD."""
+        if self._update_out or not self.raft_mu.acquire(blocking=False):
+            # mid-step, or its last update is not committed yet: nothing
+            # may touch the raft state under the lock until it is (the
+            # step workers skip the group for the same reason)
+            return "busy"
+        if self._update_out or self._hb_block_raft() is None:
+            # (an update that left between the test and the lock)
+            cause = "busy" if self._update_out else "state"
+            self.raft_mu.release()
+            return cause
+        delta = self._catch_up_ticks()
+        if delta:
+            if self.has_pending_requests() or not self.peer.raft.tick_quiet(
+                delta
+            ):
+                self._tick(delta, tracker_count=self._tracker_ticks(delta))
+            else:
+                # nothing pending, nothing a tick could fire: the clocks
+                # in closed form (the request trackers' clocks are
+                # relative: with nothing tracked they may stand)
+                self.current_tick += delta
+                self._update_leader_info()
+        return None
+
+    def _hb_block_leave(self) -> None:
+        """Release raftMu; a tick that left a message behind (the rate
+        limiter's) gets the step that flushes it.  (No update is in
+        flight here, so the outbox was empty when the leg began.)"""
+        pending = bool(self.peer.raft.msgs)
+        self.raft_mu.release()
+        if pending:
+            self.nh.engine.set_step_ready(self.cluster_id)
+
+    def _hb_block_raft(self):
+        """The raft of a replica that is on the block plane at all: a
+        plain device-ticked group.  None keeps it on the per-group path.
+
+        Read without raftMu for a BUSY group (mid-step, or its update not
+        committed yet): such a group cannot be touched under the lock
+        now, and handing it the per-group message instead would queue a
+        step-worker turn behind the very work that keeps it busy — on a
+        loaded host that turns the cheap path into the expensive one
+        exactly when it can least afford it.  What a heartbeat exchange
+        must do for a busy group needs no lock: plain reads of ints, and
+        stores any contact would make.  The rest (the commit index, a
+        waiting remote's resume, a lagging remote's probe) is what the
+        next tick's row does again."""
+        p = self.peer
+        if (
+            p is None or self._stopped.is_set() or self.fast_lane
+            or self.quiesce_mgr.enabled or not p.raft.device_ticks
+            or not self._initialized.is_set()
+        ):
+            return None
+        return p.raft
+
+    def hb_block_rows(self, demote: bool = False):
+        """Leader leg: ``(term, [(to, commit), ...], demoted)`` of the
+        heartbeats due now (``Raft.heartbeat_block_rows``), or ``(cause,
+        n, False)`` with the number of heartbeats the per-group path will
+        send instead.  ``demote``: the tick also closed the group's
+        check-quorum window; the scalar CHECK_QUORUM runs here, after the
+        heartbeats and under the same hold of raftMu as
+        ``_apply_offload_effects`` has them, so no response to these
+        heartbeats is counted into the window they close; ``demoted``
+        says it ran (a busy group's rows are read without the lock, and
+        its window is left to the per-group flag)."""
+        cause = self._hb_block_enter()
+        if cause is None:
+            try:
+                r = self.peer.raft
+                rows = r.heartbeat_block_rows()
+                if rows.__class__ is not str:
+                    r.heartbeat_tick = 0
+                    term = r.term
+                    if demote and r.check_quorum:
+                        r.election_tick = 0
+                        r.handle(
+                            Message(from_=self.node_id, type=MT.CHECK_QUORUM)
+                        )
+                        if not r.is_leader():  # the step flushes the rest
+                            self.nh.engine.set_step_ready(self.cluster_id)
+                    return term, rows, True
+                cause = rows
+            finally:
+                self._hb_block_leave()
+        elif cause == "busy":
+            r = self._hb_block_raft()
+            if r is not None:
+                try:
+                    term = r.term
+                    rows = r.heartbeat_block_rows()  # reads only
+                    if rows.__class__ is not str and r.term == term:
+                        return term, rows, False
+                except RuntimeError:  # the membership moved under the read
+                    pass
+        p = self.peer  # unlocked: a count for the books, nothing else
+        return cause, (len(p.raft.remotes) - 1 if p is not None else 0), False
+
+    def hb_block_contact(self, from_: int, term: int, commit: int):
+        """Follower leg (``Raft.heartbeat_block_contact``); a commit index
+        that moved wakes the group so the entries get applied.  Returns
+        None (handled), ``"lite"`` (a busy group: its clock reset, its
+        commit index left to the next row) or the cause for the per-group
+        message."""
+        cause = self._hb_block_enter()
+        if cause is not None:
+            if cause == "busy":
+                r = self._hb_block_raft()
+                if (
+                    r is not None and r.term == term and r.is_follower()
+                    and r.leader_id == from_
+                ):
+                    r.election_tick = 0
+                    return "lite"
+            return cause
+        try:
+            r = self.peer.raft
+            before = r.log.committed
+            cause = r.heartbeat_block_contact(from_, term, commit)
+            if cause is None and r.log.committed != before:
+                self.nh.engine.set_step_ready(self.cluster_id)
+            return cause
+        except RuntimeError:
+            return "state"  # a commit past the log: the scalar path's to judge
+        finally:
+            self._hb_block_leave()
+
+    def hb_block_resp(self, from_: int, term: int):
+        """Leader leg for a response row (``Raft.heartbeat_block_resp``);
+        None, ``"lite"`` (a busy group: the contact marked, a waiting or
+        lagging remote left to the next row) or the cause."""
+        cause = self._hb_block_enter()
+        if cause is not None:
+            if cause == "busy":
+                r = self._hb_block_raft()
+                if r is not None and r.term == term and r.is_leader():
+                    rp = r.remotes.get(from_)
+                    if rp is not None and r.lease is None:
+                        rp.active = True
+                        return "lite"
+            return cause
+        try:
+            return self.peer.raft.heartbeat_block_resp(from_, term)
+        finally:
+            self._hb_block_leave()
 
     def _apply_offload_effects(self) -> None:
         """Apply flagged device-engine effects (under raftMu, from a step
@@ -898,6 +1066,7 @@ class Node:
             more = self.to_apply.more_entries_to_apply()
             if self.peer.has_update(more):
                 ud = self.peer.get_update(more, self.sm.get_last_applied())
+                self._update_out = True
                 return ud
             self._maybe_enroll()
             return None
@@ -1593,6 +1762,7 @@ class Node:
         with self.raft_mu:
             if self.peer is not None:
                 self.peer.commit(ud)
+            self._update_out = False
 
     # ---- apply path (reference processApplies / handleTask) ----
 

@@ -61,6 +61,10 @@ from .wire import (
 
 plog = get_logger("nodehost")
 
+_HB_BLOCK_TYPES = (
+    MessageType.HEARTBEAT_BLOCK, MessageType.HEARTBEAT_RESP_BLOCK,
+)
+
 
 @dataclass
 class ClusterInfo:
@@ -280,6 +284,14 @@ class NodeHost:
                 ),
                 telem=health_aggregate,
             )
+            if self.fastlane is None:
+                # batched heartbeat plane: the coordinator sends one
+                # message a peer host a tick through this host's
+                # transport (with the fast lane on, every raft message of
+                # a remote rides its one ordered native stream instead)
+                self.quorum_coordinator.attach_host_link(
+                    self.node_registry.resolve, self.transport.send_to_host
+                )
             if nhconfig.enable_metrics or trace_n > 0:
                 # device-plane observability rides the same flag as the
                 # raft event metrics, and the tracer's: the flight
@@ -1458,6 +1470,13 @@ class NodeHost:
                     ctx.t_recv = time.time()
                 elif not ctx.t_ack_recv:
                     ctx.t_ack_recv = time.time()
+            if m.type in _HB_BLOCK_TYPES:
+                # batched heartbeat plane: host-addressed, every row of
+                # it handled in one pass by the coordinator (no
+                # coordinator, no plane: nothing on this host sent one)
+                if self.quorum_coordinator is not None:
+                    self.quorum_coordinator.on_heartbeat_block(m, src)
+                continue
             if m.type == MessageType.SNAPSHOT_RECEIVED:
                 # follower's ack for a sent snapshot: accelerates the
                 # parked status release; never delivered to raft

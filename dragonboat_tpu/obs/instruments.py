@@ -65,6 +65,17 @@ DISPATCH_PHASES = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms")
 EGRESS_PHASES = ("egress_wait_ms", "decode_ms")
 #: why a heartbeat read echo was tallied scalar-side (tpuquorum.py)
 READ_FALLBACK_CAUSES = ("slot_overflow", "after_confirm", "purged")
+#: why a heartbeat or its response took the per-group message instead of
+#: the block (tpuquorum.py, the batched heartbeat plane): the group was
+#: mid-step (``busy``), not a plain device-ticked leader / follower
+#: (``state``), at another term, following no leader yet, carrying a
+#: ReadIndex context as its hint, mid snapshot, of a membership with
+#: handlers of its own (lease, observer, witness), or a response from a
+#: remote that lags and must be probed
+HB_SINGLE_CAUSES = (
+    "busy", "state", "term", "unknown_leader", "read_ctx", "snapshot",
+    "membership", "lagging",
+)
 
 #: log-spaced dispatch/egress/round latency buckets (ms): the live
 #: coordinator's single-round dispatches sit near the bottom decade, a
@@ -144,6 +155,21 @@ _HELP = {
     "device slot), after_confirm (its context was already confirmed or "
     "prefix-released), purged (a transition dropped the group's FIFO)",
     _COORD + "read_acks_total": "heartbeat read echoes the device tallied",
+    _COORD + "ticks_replayed_total": "host ticks a round ran late (the "
+    "deficit beyond its own tick)",
+    _COORD + "ticks_dropped_total": "host ticks a round could not replay "
+    "(beyond the warmed K): this host's device clocks ran that much slow",
+    _COORD + "elections_held_total": "election-due flags not fanned out "
+    "because their round dropped ticks (the host stalled, not the leader)",
+    _COORD + "tick_flags_total": "device tick flags fanned out, by kind",
+    _COORD + "hb_block_rows_total": "heartbeats and responses handled by "
+    "the block",
+    _COORD + "hb_lite_rows_total": "of the block's rows, those of a busy "
+    "group (mid-step, or its update not committed yet) served without its "
+    "lock",
+    _COORD + "hb_single_total": "heartbeats and responses that took the "
+    "per-group message, by cause",
+    _COORD + "rows": "groups registered on the engine at the last round",
     _HOST + "ingress_submitted_total": "commands accepted into ingress rings",
     _HOST + "ingress_drains_total": "ingress batcher drain cycles",
     _HOST + "ingress_drained_total": "commands drained by the batcher",
@@ -1274,6 +1300,11 @@ class CoordObs:
         _COORD + "fused_dispatch_total",
         _COORD + "fused_rounds_total",
         _COORD + "read_acks_total",
+        _COORD + "ticks_replayed_total",
+        _COORD + "ticks_dropped_total",
+        _COORD + "elections_held_total",
+        _COORD + "hb_block_rows_total",
+        _COORD + "hb_lite_rows_total",
     )
 
     def __init__(
@@ -1289,7 +1320,8 @@ class CoordObs:
         r = self.registry
         _describe(r, self._COUNTERS + (
             _COORD + "staged_depth", _COORD + "read_fallbacks_total",
-            _COORD + "round_latency_ms",
+            _COORD + "round_latency_ms", _COORD + "tick_flags_total",
+            _COORD + "hb_single_total", _COORD + "rows",
         ))
         for name in self._COUNTERS:
             r.counter_add(name, 0)
@@ -1297,7 +1329,12 @@ class CoordObs:
             r.counter_add(
                 _COORD + "read_fallbacks_total", 0, {"cause": cause}
             )
+        for cause in HB_SINGLE_CAUSES:
+            r.counter_add(_COORD + "hb_single_total", 0, {"cause": cause})
+        for kind in ("heartbeat", "elect", "demote"):
+            r.counter_add(_COORD + "tick_flags_total", 0, {"kind": kind})
         r.gauge_set(_COORD + "staged_depth", 0)
+        r.gauge_set(_COORD + "rows", 0)
         r.histogram_declare(
             _COORD + "round_latency_ms", buckets=LATENCY_BUCKETS_MS
         )
@@ -1338,6 +1375,7 @@ class CoordObs:
         read_fallbacks: Optional[dict] = None,
         reads_staged: int = 0,
         reads_refused: int = 0,
+        plane: Optional[dict] = None,
     ) -> dict:
         """Close a dispatched round's span (``round_open``).  The
         recorder's stall check on ``wall_ms`` IS the round-gate watchdog:
@@ -1355,7 +1393,14 @@ class CoordObs:
         compilation.  ``read_acks`` / ``read_fallbacks`` (cause -> count)
         are this round's heartbeat read echoes, tallied on the device /
         scalar-side; ``reads_staged`` / ``reads_refused`` the ReadIndex
-        contexts given / refused a device slot."""
+        contexts given / refused a device slot.  ``plane`` is the tick
+        and heartbeat plane's account of the round: ``ticks_replayed`` /
+        ``ticks_dropped`` / ``elect_held``, the ``hb_flags`` /
+        ``elect_flags`` / ``demote_flags`` fanned out, ``hb_block_rows``
+        (``hb_lite_rows`` of them served without the group's lock)
+        against ``hb_single`` (cause -> count, since the last recorded
+        round: block messages arrive between rounds), and the ``rows``
+        registered."""
         r = self.registry
         t1 = time.perf_counter()
         wall_ms = (t1 - span["t0"]) * 1e3
@@ -1388,6 +1433,38 @@ class CoordObs:
                     _COORD + "read_fallbacks_total", n, {"cause": cause}
                 )
             extra["read_fallback_" + cause] = n
+        if plane is not None:
+            for key, name in (
+                ("ticks_replayed", "ticks_replayed_total"),
+                ("ticks_dropped", "ticks_dropped_total"),
+                ("elect_held", "elections_held_total"),
+                ("hb_block_rows", "hb_block_rows_total"),
+                ("hb_lite_rows", "hb_lite_rows_total"),
+            ):
+                if plane[key]:
+                    r.counter_add(_COORD + name, plane[key])
+                extra[key] = plane[key]
+            for key, kind in (
+                ("hb_flags", "heartbeat"), ("elect_flags", "elect"),
+                ("demote_flags", "demote"),
+            ):
+                if plane[key]:
+                    r.counter_add(
+                        _COORD + "tick_flags_total", plane[key],
+                        {"kind": kind},
+                    )
+                extra[key] = plane[key]
+            single = 0
+            for cause, n in plane["hb_single"].items():
+                if n:
+                    r.counter_add(
+                        _COORD + "hb_single_total", n, {"cause": cause}
+                    )
+                    extra["hb_single_" + cause] = n
+                    single += n
+            extra["hb_single"] = single
+            extra["rows"] = plane["rows"]
+            r.gauge_set(_COORD + "rows", plane["rows"])
         ph = self.ph
         self.recorder.update(
             span,

@@ -1026,6 +1026,16 @@ class BatchedQuorumEngine:
             plan += [
                 ("sparse_votes", dt, False, False) for dt in (True, False)
             ]
+            # a round whose acks pass ``_dense_threshold`` (a block of
+            # groups all acknowledging in one round; a campaign wave
+            # with its votes) takes the dense kernel with no read plane:
+            # first used on the round thread at a thousand rows, its
+            # compile is a stall of seconds exactly when a cluster
+            # elects or is busiest
+            plan += [
+                (kind, dt, False, False)
+                for kind in ("dense", "dense_votes") for dt in (True, False)
+            ]
             if include_reads:
                 plan += [("dense", dt, True, False) for dt in (True, False)]
         if include_kv:
@@ -1176,19 +1186,22 @@ class BatchedQuorumEngine:
                 telem_k=self.n_telem_topk,
             )
             return quorum_multiround, args, statics
-        if kind == "dense":
+        if kind in ("dense", "dense_votes"):
             do_tick = arg
+            has_votes = kind == "dense_votes"
             read_args = read_dims() if has_reads else (None, None, None)
             kv_args = kv_dims() if has_kv else (None, None, None, None)
             args = (
                 mk((g, p), jnp.int32),
                 mk((g, p), bool),
-                mk((1, 1), jnp.int8),
+                # VOTE_NONE everywhere: the warm run casts no vote
+                mk((g, p), jnp.int8, VOTE_NONE) if has_votes
+                else mk((1, 1), jnp.int8),
             ) + read_args + kv_args
             statics = dict(
                 do_tick=do_tick,
                 track_contact=self.device_ticks or do_tick,
-                has_votes=False,
+                has_votes=has_votes,
                 has_reads=has_reads,
                 has_kv=has_kv,
                 has_hier=self._hier_used,
@@ -1697,6 +1710,21 @@ class BatchedQuorumEngine:
         self._acks.append(
             (gi.row, int(self.mirror.arrays["self_slot"][gi.row]), 0,
              int(self._row_epoch[gi.row]))
+        )
+
+    def heartbeat_resp_block(self, rows, slots) -> None:
+        """``heartbeat_resp`` for many (row, peer slot) pairs: one ack
+        block at rel 0 (``ack_block``'s caller contract)."""
+        rows = np.asarray(rows, dtype=np.int32)
+        self.ack_block(rows, slots, np.zeros(rows.shape, np.int32))
+
+    def leader_contact_block(self, rows) -> None:
+        """``leader_contact`` for many follower rows: one ack block at
+        rel 0 on each row's own slot."""
+        rows = np.asarray(rows, dtype=np.int32)
+        self.ack_block(
+            rows, self.mirror.arrays["self_slot"][rows],
+            np.zeros(rows.shape, np.int32),
         )
 
     # ------------------------------------------------------------------
@@ -3542,6 +3570,13 @@ class BatchedQuorumEngine:
             return self.mirror.arrays[field_name][row]
         with self._dispatch_mu:  # the gather is a multi-device program
             return np.asarray(getattr(self.dev, field_name)[row])
+
+    def read_rows(self, field_name: str, rows) -> np.ndarray:
+        """``_read`` for many rows from at most one device gather
+        (``sync_rows`` pulls them into the mirror, which is then the truth
+        for every one of them until the next dispatch)."""
+        self.sync_rows(rows)
+        return self.mirror.arrays[field_name][np.asarray(rows, np.int64)]
 
     def committed_index(self, cluster_id: int) -> int:
         gi = self.groups[cluster_id]

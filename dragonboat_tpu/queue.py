@@ -6,6 +6,7 @@ engine's wakeup paths.
 """
 from __future__ import annotations
 
+import collections
 import threading
 from typing import Dict, List, Optional, Set
 
@@ -102,21 +103,42 @@ class ReadIndexQueue:
 
 
 class ReadyCluster:
-    """Set of clusters with pending work, swapped atomically
-    (reference ``queue.go:178`` ``readyCluster``)."""
+    """Groups with pending work, taken all at once by their one worker
+    (reference ``queue.go:178`` ``readyCluster``).
+
+    No lock: a ``deque`` append, a set ``add`` and a membership test are
+    each atomic under the interpreter lock.  Every message, device flag,
+    commit and tick sweep of a host's groups comes through ``set_ready``;
+    behind a mutex, a holder that lost the interpreter mid-hold left every
+    step worker, committer, transport thread and the host's tick worker
+    queueing for it, each paying a switch interval to get in, and a host
+    of a thousand groups stopped ticking for seconds."""
 
     def __init__(self) -> None:
-        self._mu = threading.Lock()
-        self._ready: Set[int] = set()
+        self._q: "collections.deque[int]" = collections.deque()
+        # groups flagged and not taken yet: keeps a busy group to one
+        # entry in the queue
+        self._pending: Set[int] = set()
+
+    def __len__(self) -> int:
+        return len(self._pending)
 
     def set_ready(self, cluster_id: int) -> None:
-        with self._mu:
-            self._ready.add(cluster_id)
+        # already flagged: the worker takes it, and steps it after the
+        # caller queued whatever it is flagging
+        pending = self._pending
+        if cluster_id in pending:
+            return
+        pending.add(cluster_id)
+        self._q.append(cluster_id)
 
     def get_ready(self) -> Set[int]:
-        with self._mu:
-            out, self._ready = self._ready, set()
-            return out
+        q = self._q
+        out = {q.popleft() for _ in range(len(q))}
+        # un-flag only now: a caller that found its group flagged in
+        # between is served by the step that follows this call
+        self._pending.difference_update(out)
+        return out
 
 
 class LeaderInfoQueue:

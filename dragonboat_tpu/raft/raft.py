@@ -52,7 +52,7 @@ from .hier import FarReadBatcher, HierPlane, sub_quorum_size
 from .log import CompactedError, EntryLog, ILogDB, UnavailableError
 from .rate import InMemRateLimiter
 from .readindex import ReadIndex
-from .remote import Remote
+from .remote import Remote, RemoteState
 
 plog = logger.get_logger("raft")
 
@@ -450,6 +450,35 @@ class Raft:
             self.leader_tick()
         else:
             self.non_leader_tick()
+
+    def tick_quiet(self, n: int) -> bool:
+        """``n`` calls of ``tick()`` in closed form, for a raft whose
+        ticks can do nothing but count: the device tick kernel owns every
+        fire site (``device_ticks``), the rate limiter is off and no
+        leader transfer is under way.  Returns False, with nothing done,
+        where a tick could act: the caller then ticks one by one.  (The
+        batched heartbeat plane brings a group's clock up to now on every
+        leg; tests/test_hb_block.py holds the clocks of the two paths
+        equal.)"""
+        if (
+            not self.device_ticks
+            or self.rl.enabled()
+            or self.leader_transfer_target != NO_NODE
+        ):
+            return False
+        self.quiesce = False
+        self.tick_count += n
+        if self.state == RaftState.LEADER:
+            # leader_tick: the clock wraps at the check-quorum window
+            # (a clock found at or past the window wraps on its first tick)
+            e, window = self.election_tick, self.election_timeout
+            self.election_tick = (
+                (e + n) % window if e < window else (n - 1) % window
+            )
+            self.heartbeat_tick += n
+        else:
+            self.election_tick += n
+        return True
 
     def non_leader_tick(self) -> None:
         # reference raft.go:568-592
@@ -1052,6 +1081,82 @@ class Raft:
                 hint_high=m.hint_high,
             )
         )
+
+    # ------------------------------------------------------------------
+    # the batched heartbeat plane (tpuquorum.py): the three legs of a
+    # heartbeat exchange for a group whose scalar state takes no per-group
+    # message.  Each returns a cause string where the group must take the
+    # per-group message instead (the scalar handlers above and below are
+    # the oracle: tests/test_hb_block.py drives both and holds clocks,
+    # contacts, commit indexes and every message that leaves equal).
+    # Called under raftMu, off the step path: none of them may append to
+    # ``self.msgs`` or touch anything ``get_update`` would have to flush,
+    # except the follower's commit index (its caller wakes the group).
+    # ------------------------------------------------------------------
+
+    def heartbeat_block_rows(self):
+        """Leader leg: ``[(to, commit), ...]`` of exactly the HEARTBEATs
+        ``broadcast_heartbeat_message`` would send now, or the cause that
+        keeps the group on the per-group path: a pending ReadIndex ctx
+        rides the heartbeat as its hint, a lease books the send tick, an
+        observer or witness has handlers of its own, and a remote mid
+        snapshot stays with the scalar flow control."""
+        if self.state != RaftState.LEADER:
+            return "state"
+        if self.read_index.has_pending_request():
+            return "read_ctx"
+        if self.lease is not None or self.observers or self.witnesses:
+            return "membership"
+        committed = self.log.committed
+        rows = []
+        for nid, rp in self.remotes.items():
+            if nid == self.node_id:
+                continue
+            if rp.state == RemoteState.SNAPSHOT:
+                return "snapshot"
+            rows.append((nid, rp.match if rp.match < committed else committed))
+        return rows
+
+    def heartbeat_block_contact(self, from_: int, term: int, commit: int):
+        """Follower leg, the twin of ``handle(HEARTBEAT)`` where that is
+        ``handle_follower_heartbeat`` with nothing else to do: same term,
+        a follower, that leader already known.  Resets the election clock
+        and takes the commit index (never beyond the log: the leader
+        sends ``min(match, committed)``); the caller stages the device
+        contact and the response row.  Returns None, or the cause for the
+        per-group message (any other term, a candidate, a leader not yet
+        known: the scalar term filter and ``set_leader_id`` own those)."""
+        if term != self.term:
+            return "term"
+        if self.state != RaftState.FOLLOWER:
+            return "state"
+        if self.leader_id != from_:
+            return "unknown_leader"
+        self._stepdown_etick = None
+        self.election_tick = 0
+        self.log.commit_to(commit)
+        return None
+
+    def heartbeat_block_resp(self, from_: int, term: int):
+        """Leader leg for a response row, the twin of
+        ``handle_leader_heartbeat_resp`` where that sends nothing: the
+        term it was sent in, still the leader, the remote caught up.
+        Marks the remote active (check-quorum's contact) and ends its
+        wait; the caller stages the device's activity bit.  A lagging
+        remote returns the cause: the scalar handler probes it with a
+        REPLICATE of its own."""
+        if term != self.term:
+            return "term"
+        if self.state != RaftState.LEADER:
+            return "state"
+        rp = self.remotes.get(from_)
+        if rp is None or self.lease is not None:
+            return "membership"
+        if rp.match < self.log.last_index():
+            return "lagging"
+        rp.active = True
+        rp.wait_to_retry()
+        return None
 
     def handle_install_snapshot_message(self, m: Message) -> None:
         # reference raft.go:1396-1424

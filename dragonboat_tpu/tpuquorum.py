@@ -27,6 +27,7 @@ tests in ``tests/test_tpuquorum.py`` + ``tests/test_ops_quorum.py``).
 """
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -34,7 +35,9 @@ from typing import Dict, Optional, TYPE_CHECKING
 
 from . import obs as _obs
 from .logger import get_logger
+from .obs.instruments import HB_SINGLE_CAUSES
 from .obs.recorder import OFF as _OFF, annotate as _annotate
+from .wire import Message, MessageType, pack_hb_rows, unpack_hb_rows
 
 if TYPE_CHECKING:
     from .node import Node
@@ -205,15 +208,42 @@ class TpuQuorumCoordinator:
         # writer, single reader)
         self._tick_seq = 0
         self._tick_seen = 0
+        # the tick deficit's account (plain integers, always on): host
+        # ticks a round ran late (its deficit beyond the one tick that
+        # was due), ticks it could not replay (beyond the warmed K: this
+        # host's device clocks ran that much slow), and the election-due
+        # flags such a round held back — see _round_inner
+        self.ticks_replayed = 0
+        self.ticks_dropped = 0
+        self.elections_held = 0
+        # the shortest election timeout (ticks) of any row ever synced
+        self._min_election_timeout = 1 << 30
+        # batched heartbeat plane: the host's link to its peers
+        # (``attach_host_link``, set by NodeHost; None leaves every
+        # heartbeat on the per-group message) and what went by the block
+        # against what took the per-group message, by cause
+        self._hb_link = None
+        self.hb_block_rows = 0
+        # of those, a busy group's rows, served in reduced form without
+        # the group's lock (Node._hb_block_raft)
+        self.hb_lite_rows = 0
+        self.hb_single_causes = dict.fromkeys(HB_SINGLE_CAUSES, 0)
+        # the counters above as of the last recorded coord_round span
+        self._plane_spanned = (0, 0, dict(self.hb_single_causes))
         self._nodes: Dict[int, "Node"] = {}
         self._mu = threading.RLock()
         # staging is decoupled from the engine lock: raft step workers only
-        # append under this micro-lock and NEVER wait on an in-flight
-        # device dispatch — a blocked step worker delays heartbeats and
-        # provokes spurious elections (the same reason the reference sends
-        # Replicate before fsync, execengine.go:954-961)
-        self._stage_mu = threading.Lock()
-        self._staged: list = []
+        # append and NEVER wait on an in-flight device dispatch — a
+        # blocked step worker delays heartbeats and provokes spurious
+        # elections (the same reason the reference sends Replicate before
+        # fsync, execengine.go:954-961).  A deque and no lock at all: an
+        # append is atomic under the interpreter lock, a group's ops are
+        # staged under its raftMu (so in order), and the one consumer pops
+        # what it found.  Under a micro-lock every staged op of every
+        # step worker of the host queued for it, and on a saturated
+        # interpreter a contended lock costs each waiter a switch
+        # interval: one host's workers crawled while its peers' ran.
+        self._staged: collections.deque = collections.deque()
         # perf_counter when the oldest op / tick the next round will see
         # was staged (``wait_ms`` of its coord_round span); stamped only
         # while _obs is attached, taken by the drain
@@ -398,8 +428,7 @@ class TpuQuorumCoordinator:
         staged-op backlog, registered groups, warmup readiness and the
         read-plane tallies — all lock-free or micro-locked reads, never
         the engine lock (a sampler must not queue behind a dispatch)."""
-        with self._stage_mu:
-            staged = len(self._staged)
+        staged = len(self._staged)
         d = {
             "groups": len(self._nodes),
             "staged": staged,
@@ -510,6 +539,8 @@ class TpuQuorumCoordinator:
             # log); register a self-only row until membership_changed
             # resyncs it
             voters = sorted(set(voters) | {r.node_id})
+        if r.election_timeout < self._min_election_timeout:
+            self._min_election_timeout = r.election_timeout
         self.eng.add_group(
             cid,
             node_ids=voters,
@@ -584,12 +615,23 @@ class TpuQuorumCoordinator:
     # staging hooks (called from raft under the node's raftMu)
     # ------------------------------------------------------------------
 
+    def _wake_round(self) -> None:
+        """Tell the round thread there is work.  ``Event.set`` takes the
+        event's lock whether or not it is set already; called once a
+        staged op by every step worker of the host, that lock becomes a
+        convoy on a saturated interpreter (each contended acquire costs
+        its caller a switch interval) and one host's workers stop while
+        its peers' run.  The flag is read without the lock: whoever finds
+        it set staged before the round thread cleared it, and the drain
+        that follows the clear takes the op."""
+        if not self._pending.is_set():
+            self._pending.set()
+
     def _stage(self, op) -> None:
-        with self._stage_mu:
-            if self._obs is not None and self._first_at is None:
-                self._first_at = time.perf_counter()
-            self._staged.append(op)
-        self._pending.set()
+        if self._obs is not None and self._first_at is None:
+            self._first_at = time.perf_counter()
+        self._staged.append(op)
+        self._wake_round()
 
     def ack(self, cluster_id: int, node_id: int, index: int) -> None:
         self._stage(("ack", cluster_id, node_id, index))
@@ -601,19 +643,194 @@ class TpuQuorumCoordinator:
         self._stage(("hbresp", cluster_id, node_id))
 
     def leader_contact(self, cluster_id: int) -> None:
+        # (a racing duplicate is one more idempotent clock reset)
         if cluster_id in self._contacted:
             return
-        with self._stage_mu:
-            if cluster_id in self._contacted:
-                return
-            self._contacted.add(cluster_id)
-            if self._obs is not None and self._first_at is None:
-                self._first_at = time.perf_counter()
-            self._staged.append(("contact", cluster_id))
-        self._pending.set()
+        self._contacted.add(cluster_id)
+        self._stage(("contact", cluster_id))
 
     def set_randomized_timeout(self, cluster_id: int, timeout: int) -> None:
         self._stage(("randto", cluster_id, timeout))
+
+    # ------------------------------------------------------------------
+    # batched heartbeat plane
+    #
+    # The device ticks every row in lockstep and hands back all of a
+    # host's heartbeat-due flags in one egress.  Spent one group at a
+    # time they cost the process, per group and tick, a step-worker turn
+    # on the leader, two messages out, a turn on each follower, two
+    # responses back and two more turns on the leader — at 1,024 groups
+    # and a 50 ms tick some 160,000 message events a second against one
+    # interpreter.  Spent by the block they cost a HOST, per tick, one
+    # message to each peer host and one back, whatever the group count:
+    #
+    #   leader's round thread   _fan_out -> _heartbeat_block: for every
+    #       due row whose scalar state takes no per-group message
+    #       (Node.hb_block_rows), one (cluster, to, from, term, commit)
+    #       row into the HEARTBEAT_BLOCK of the peer's host;
+    #   follower's host         on_heartbeat_block: every row whose group
+    #       agrees (same term, that leader, a follower) resets its
+    #       election clock scalar-side and, as ONE staged block, on the
+    #       device rows, takes the commit index, and answers with a row
+    #       of the HEARTBEAT_RESP_BLOCK going back;
+    #   leader's host           on_heartbeat_block: every response row of
+    #       the term it was sent in marks the remote active and stages
+    #       its device activity bit, again as one block.
+    #
+    # A row whose group does not agree at any leg is handed to the group
+    # as the per-group HEARTBEAT / HEARTBEAT_RESP it stands for, and the
+    # scalar handlers see exactly today's message (tests/test_hb_block.py
+    # holds the two paths equal).  Block or single is chosen by what the
+    # round sees: one due row takes the per-group path, more take the
+    # block (the scalars-for-one, one-pass-for-many rule of stage_read).
+    # ------------------------------------------------------------------
+
+    def attach_host_link(self, resolve, send_to_host) -> None:
+        """NodeHost's wiring: ``resolve(cluster_id, node_id) -> address``
+        and ``send_to_host(address, Message) -> bool``."""
+        self._hb_link = (resolve, send_to_host)
+
+    def _hb_single(self, cause: str, n: int = 1) -> None:
+        self.hb_single_causes[cause] += n
+
+    def _heartbeat_block(self, cids, demote: set) -> list:
+        """Leader leg (round thread, outside _mu): send the block rows of
+        the due groups; returns the groups that take the per-group
+        ``offload_tick_heartbeat`` instead.  A group of the block whose
+        check-quorum window closed this tick (``demote``) has had its
+        scalar CHECK_QUORUM run with the heartbeat and is taken out of
+        the set."""
+        resolve, send = self._hb_link
+        nodes = self._nodes
+        singles = []
+        by_host: Dict[str, list] = {}
+        for cid in cids:
+            node = nodes.get(cid)
+            if node is None:
+                continue
+            term, rows, demoted = node.hb_block_rows(cid in demote)
+            if term.__class__ is str:
+                singles.append(cid)
+                # ``rows`` heartbeats and as many responses go per group
+                self._hb_single(term, 2 * max(rows, 0))
+                continue
+            if demoted:
+                demote.discard(cid)
+            me = node.node_id
+            for to, commit in rows:
+                addr = resolve(cid, to)
+                if addr is None:
+                    continue
+                block = by_host.get(addr)
+                if block is None:
+                    block = by_host[addr] = []
+                block.append((cid, to, me, term, commit))
+        for addr, rows in by_host.items():
+            send(addr, Message(
+                type=MessageType.HEARTBEAT_BLOCK, entries=pack_hb_rows(rows)
+            ))
+        return singles
+
+    def on_heartbeat_block(self, m, src: str) -> None:
+        """A block message arrived (the transport's delivery thread):
+        handle every row whose group agrees, hand the rest to their groups
+        as per-group messages."""
+        rows = unpack_hb_rows(m)
+        if not rows:
+            return
+        nodes = self._nodes
+        ok_cids = []
+        ok_peers = []
+        if m.type == MessageType.HEARTBEAT_BLOCK:
+            resp = []
+            for cid, to, from_, term, commit in rows:
+                node = nodes.get(cid)
+                if node is None or node.node_id != to:
+                    continue
+                cause = node.hb_block_contact(from_, term, commit)
+                if cause is None or cause == "lite":
+                    ok_cids.append(cid)
+                    resp.append((cid, from_, to, term, 0))
+                    if cause is not None:
+                        self.hb_lite_rows += 1
+                else:
+                    # the heartbeat and its response go per group
+                    self._hb_single(cause, 2)
+                    node.handle_message_batch(Message(
+                        type=MessageType.HEARTBEAT, cluster_id=cid, to=to,
+                        from_=from_, term=term, commit=commit,
+                    ))
+            if ok_cids:
+                self.hb_block_rows += len(ok_cids)
+                self._stage_block(("contact_block", ok_cids))
+            if resp and self._hb_link is not None and src:
+                self._hb_link[1](src, Message(
+                    type=MessageType.HEARTBEAT_RESP_BLOCK,
+                    entries=pack_hb_rows(resp),
+                ))
+            return
+        for cid, to, from_, term, _commit in rows:
+            node = nodes.get(cid)
+            if node is None or node.node_id != to:
+                continue
+            cause = node.hb_block_resp(from_, term)
+            if cause is None or cause == "lite":
+                ok_cids.append(cid)
+                ok_peers.append(from_)
+                if cause is not None:
+                    self.hb_lite_rows += 1
+            else:
+                self._hb_single(cause)
+                node.handle_message_batch(Message(
+                    type=MessageType.HEARTBEAT_RESP, cluster_id=cid, to=to,
+                    from_=from_, term=term,
+                ))
+        if ok_cids:
+            self.hb_block_rows += len(ok_cids)
+            self._stage_block(("hbresp_block", ok_cids, ok_peers))
+
+    def _stage_block(self, op) -> None:
+        if op[0] == "contact_block":
+            # the per-round contact dedup covers these groups too
+            self._contacted.update(op[1])
+        self._stage(op)
+
+    def _drain_block(self, op, recover: list) -> None:
+        """Apply a staged contact / heartbeat-response block (under _mu):
+        the rows are resolved here, where a resync cannot move them."""
+        groups = self.eng.groups
+        contact = op[0] == "contact_block"
+        if self.mesh_devices > 1:
+            # a mesh engine's rows are shard-local: op by op
+            for i, cid in enumerate(op[1]):
+                if cid in groups:
+                    try:
+                        if contact:
+                            self.eng.leader_contact(cid)
+                        else:
+                            self.eng.heartbeat_resp(cid, op[2][i])
+                    except (ValueError, KeyError):
+                        recover.append(cid)
+            return
+        rows = []
+        slots = []
+        for i, cid in enumerate(op[1]):
+            gi = groups.get(cid)
+            if gi is None:
+                continue
+            if not contact:
+                slot = gi.slots.get(op[2][i])
+                if slot is None:  # unknown peer: rebuild the row (rare)
+                    recover.append(cid)
+                    continue
+                slots.append(slot)
+            rows.append(gi.row)
+        if not rows:
+            return
+        if contact:
+            self.eng.leader_contact_block(rows)
+        else:
+            self.eng.heartbeat_resp_block(rows, slots)
 
     def read_stage(
         self, cluster_id: int, committed: int, low: int, high: int, term: int
@@ -660,7 +877,7 @@ class TpuQuorumCoordinator:
             # single writer besides the staging lock's holders; a lost
             # stamp costs one round's wait_ms, nothing else
             self._first_at = time.perf_counter()
-        self._pending.set()
+        self._wake_round()
 
     def _drain_locked(self) -> list:
         """Apply staged ops to the engine in staging order (so a
@@ -671,10 +888,12 @@ class TpuQuorumCoordinator:
         HERE (under _mu) deadlocks against fast_eject -> register (seen
         live in the tpu+fastlane chaos run); the caller recovers after
         releasing _mu."""
-        with self._stage_mu:
-            ops, self._staged = self._staged, []
-            self._contacted.clear()
-            self._round_first_at, self._first_at = self._first_at, None
+        # the dedup set first: a contact that finds the new, empty set is
+        # staged (again, at worst), never skipped for an op already taken
+        self._contacted = set()
+        self._round_first_at, self._first_at = self._first_at, None
+        staged = self._staged
+        ops = [staged.popleft() for _ in range(len(staged))]
         recover = []
         lt = self.lease_table
         lease_acks: Dict[int, set] = {}
@@ -692,6 +911,9 @@ class TpuQuorumCoordinator:
             self.eng.sync_rows(sync_rows)
         for op in ops:
             kind, cid = op[0], op[1]
+            if kind in ("contact_block", "hbresp_block"):
+                self._drain_block(op, recover)
+                continue
             if cid not in self.eng.groups:
                 continue
             try:
@@ -895,7 +1117,8 @@ class TpuQuorumCoordinator:
             # fallback can't turn a stall into a dispatch storm.
             fused_ok = self.drive_ticks and self.eng.fused_ready
             cap = self.fused_k_max if fused_ok else 4
-            deficit = min(seq - self._tick_seen, cap) if self.drive_ticks else 0
+            missed = seq - self._tick_seen if self.drive_ticks else 0
+            deficit = min(missed, cap)
             do_tick = deficit > 0
             self._tick_seen = seq
             if obs is not None:
@@ -1052,6 +1275,33 @@ class TpuQuorumCoordinator:
                         merged = set(getattr(res, field))
                         merged.update(getattr(extra, field))
                         setattr(res, field, list(merged))
+            n_rows = len(self.eng.groups)
+        # The tick deficit's rule.  A round runs every host tick since the
+        # last one, up to the largest warmed K (4 until the fused programs
+        # are warm, and whenever votes or churn keep a backlog off them);
+        # ticks beyond that are DROPPED: this host's device clocks run that
+        # much slow, which delays its own followers' elections and widens
+        # its check-quorum windows, and deposes nobody.  Both are counted.
+        # What a stalled host must not do is read its own stall as its
+        # leaders' silence: the contacts that arrived meanwhile were all
+        # applied before the replayed ticks, and its scalar clocks catch
+        # up by the whole stall at their next step.  A host that was away
+        # for an election timeout or more therefore HOLDS its elections:
+        # the round's election-due flags are not fanned out and every row
+        # of the host gets its election clock reset (a staged contact
+        # block, a no-op on leader rows) — one more timeout, by which a
+        # leader that really is gone is found.
+        dropped = missed - deficit
+        held = 0
+        if deficit > 1:
+            self.ticks_replayed += deficit - 1
+        if dropped > 0:
+            self.ticks_dropped += dropped
+        if missed >= self._min_election_timeout:
+            held = len(res.elect)
+            self.elections_held += held
+            res.elect = []
+            self._stage_block(("contact_block", list(self._nodes)))
         with (obs.phase("fanout") if obs is not None else _OFF):
             self._fan_out(
                 res, read_confirms, do_tick,
@@ -1092,6 +1342,9 @@ class TpuQuorumCoordinator:
                 read_fallbacks={
                     c: causes[c] - n for c, n in reads0[3].items()
                 },
+                plane=self._plane_account(
+                    res, do_tick, deficit, dropped, held, n_rows
+                ),
             )
         # cost-driven placement (mesh dispatch plane): a time-gated
         # rebalance pass on dispatched rounds only — quiet coordinators
@@ -1179,13 +1432,19 @@ class TpuQuorumCoordinator:
                     node.offload_tick_elect(**wake_kw)
                     if hp is not None:
                         touched[cid] = node
-            for cid in res.heartbeat:
+            due, demote = res.heartbeat, res.demote
+            if len(due) > 1 and self._hb_link is not None:
+                # more than one row due: by the block; what comes back
+                # are the groups whose state takes the per-group message
+                demote = set(demote)
+                due = self._heartbeat_block(due, demote)
+            for cid in due:
                 node = self._nodes.get(cid)
                 if node is not None:
                     node.offload_tick_heartbeat(**wake_kw)
                     if hp is not None:
                         touched[cid] = node
-            for cid in res.demote:
+            for cid in demote:
                 node = self._nodes.get(cid)
                 if node is not None:
                     node.offload_tick_demote(**wake_kw)
@@ -1200,15 +1459,29 @@ class TpuQuorumCoordinator:
         # lacks a quorum at that term
         won_terms = {}
         lost_terms = {}
-        with self._mu:
-            for cid in res.won:
-                gi = self.eng.groups.get(cid)
-                if gi is not None:
-                    won_terms[cid] = int(self.eng._read("term", gi.row))
-            for cid in res.lost:
-                gi = self.eng.groups.get(cid)
-                if gi is not None:
-                    lost_terms[cid] = int(self.eng._read("term", gi.row))
+        if res.won or res.lost:
+            with self._mu:
+                groups = self.eng.groups
+                decided = [
+                    (cid, groups[cid].row, cid_list is res.won)
+                    for cid_list in (res.won, res.lost)
+                    for cid in cid_list if cid in groups
+                ]
+                if len(decided) > 1 and self.mesh_devices <= 1:
+                    # a campaign wave decides hundreds of elections in
+                    # one round: ONE gather for their terms, not a device
+                    # read a group under _mu (seconds of a round, in
+                    # which no heartbeat of this host left)
+                    terms = self.eng.read_rows(
+                        "term", [row for _c, row, _w in decided]
+                    ).tolist()
+                else:
+                    terms = [
+                        int(self.eng._read("term", row))
+                        for _c, row, _w in decided
+                    ]
+                for (cid, _row, won), term in zip(decided, terms):
+                    (won_terms if won else lost_terms)[cid] = int(term)
         for cid, term in won_terms.items():
             node = self._nodes.get(cid)
             if node is not None:
@@ -1217,6 +1490,29 @@ class TpuQuorumCoordinator:
             node = self._nodes.get(cid)
             if node is not None:
                 node.offload_election(False, term)
+
+    def _plane_account(self, res, do_tick: bool, deficit: int, dropped: int,
+                       held: int, n_rows: int) -> dict:
+        """The tick and heartbeat plane's fields of a round's span (obs
+        on).  Block messages arrive between rounds, so the block / single
+        counts are those since the last recorded round."""
+        blocks0, busy0, single0 = self._plane_spanned
+        causes = self.hb_single_causes
+        self._plane_spanned = (
+            self.hb_block_rows, self.hb_lite_rows, dict(causes)
+        )
+        return {
+            "ticks_replayed": max(deficit - 1, 0),
+            "ticks_dropped": dropped,
+            "elect_held": held,
+            "hb_flags": len(res.heartbeat) if do_tick else 0,
+            "elect_flags": len(res.elect) if do_tick else 0,
+            "demote_flags": len(res.demote) if do_tick else 0,
+            "hb_block_rows": self.hb_block_rows - blocks0,
+            "hb_lite_rows": self.hb_lite_rows - busy0,
+            "hb_single": {c: causes[c] - n for c, n in single0.items()},
+            "rows": n_rows,
+        }
 
     def _collect_read_confirms(self, res, out: list) -> None:
         """Map confirmed-read egress slots back to their ctxs (under _mu).

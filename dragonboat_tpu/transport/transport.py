@@ -7,6 +7,7 @@ receive, and the chunked snapshot send plane (``snapshot.go``/``job.go``).
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -51,8 +52,45 @@ class CircuitBreaker:
 
 
 class SendQueue:
+    """The messages waiting for one remote: many producers (step workers,
+    committers, coordinator round threads), one consumer (the remote's
+    sender thread).  A ``deque`` and an ``Event`` instead of a
+    ``queue.Queue``: appends and pops are atomic under the interpreter
+    lock, and a producer touches a lock only to wake a consumer that found
+    the queue empty.  With a ``queue.Queue`` every ``put`` and ``get`` of
+    every thread went through one mutex, and on a saturated interpreter a
+    contended mutex costs each waiter a switch interval: a thousand groups'
+    senders then spent their time queueing for the queue."""
+
     def __init__(self, size: int):
-        self.q: "queue.Queue[Optional[Message]]" = queue.Queue(maxsize=size)
+        self.size = size
+        self._q: "collections.deque[Optional[Message]]" = collections.deque()
+        self._wake = threading.Event()
+
+    def put_nowait(self, m: Optional[Message]) -> None:
+        if m is not None and len(self._q) >= self.size:
+            raise queue.Full
+        self._q.append(m)
+        if not self._wake.is_set():
+            self._wake.set()
+
+    def get_nowait(self) -> Optional[Message]:
+        try:
+            return self._q.popleft()
+        except IndexError:
+            raise queue.Empty from None
+
+    def get(self, timeout: float) -> Optional[Message]:
+        while True:
+            try:
+                return self._q.popleft()
+            except IndexError:
+                pass
+            self._wake.clear()
+            if self._q:  # appended between the pop and the clear
+                continue
+            if not self._wake.wait(timeout):
+                raise queue.Empty
 
 
 class Transport:
@@ -162,18 +200,24 @@ class Transport:
     # ---- send path ----
 
     def breaker(self, addr: str) -> CircuitBreaker:
-        with self._mu:
-            b = self._breakers.get(addr)
-            if b is None:
-                b = CircuitBreaker()
-                self._breakers[addr] = b
-            return b
+        # lock-free on the send path (a dict get is atomic): the lock is
+        # for the first message to a remote only
+        b = self._breakers.get(addr)
+        if b is None:
+            with self._mu:
+                b = self._breakers.setdefault(addr, CircuitBreaker())
+        return b
 
     def send(self, m: Message) -> bool:
-        if self._stopped.is_set():
-            return False
         addr = self.registry.resolve(m.cluster_id, m.to)
         if addr is None:
+            return False
+        return self.send_to_host(addr, m)
+
+    def send_to_host(self, addr: str, m: Message) -> bool:
+        """Queue ``m`` for the host at ``addr`` (the tail of ``send``; a
+        host-addressed block message has no (cluster, node) to resolve)."""
+        if self._stopped.is_set():
             return False
         pf = self.partition_filter
         if pf is not None and pf(addr):
@@ -181,12 +225,18 @@ class Transport:
         b = self.breaker(addr)
         if not b.ready():
             return False
-        with self._mu:
-            sq = self._queues.get(addr)
-            spawn = sq is None
-            if spawn:
-                sq = SendQueue(self._queue_len)
-                self._queues[addr] = sq
+        # lock-free where the remote's queue exists (a send that races a
+        # dying sender's removal of the queue loses its message, as one
+        # queued a moment earlier would have been lost with it)
+        sq = self._queues.get(addr)
+        spawn = False
+        if sq is None:
+            with self._mu:
+                sq = self._queues.get(addr)
+                spawn = sq is None
+                if spawn:
+                    sq = SendQueue(self._queue_len)
+                    self._queues[addr] = sq
         if spawn:
             t = threading.Thread(
                 target=self._process_queue,
@@ -196,7 +246,7 @@ class Transport:
             )
             t.start()
         try:
-            sq.q.put_nowait(m)
+            sq.put_nowait(m)
             return True
         except queue.Full:
             self.metrics.message_dropped()
@@ -211,7 +261,7 @@ class Transport:
             self._publish_conn_event(addr, failed=False)
             while not self._stopped.is_set():
                 try:
-                    m = sq.q.get(timeout=1.0)
+                    m = sq.get(timeout=1.0)
                 except queue.Empty:
                     continue
                 if m is None:
@@ -232,7 +282,7 @@ class Transport:
                 # batch everything already queued, up to the cap
                 while size < Soft.max_message_batch_size:
                     try:
-                        nxt = sq.q.get_nowait()
+                        nxt = sq.get_nowait()
                     except queue.Empty:
                         break
                     if nxt is None:
@@ -423,7 +473,7 @@ class Transport:
             queues = list(self._queues.values())
         for sq in queues:
             try:
-                sq.q.put_nowait(None)
+                sq.put_nowait(None)
             except queue.Full:
                 pass
         self.rpc.stop()

@@ -14,6 +14,8 @@ import enum
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 NO_NODE = 0
 NO_LEADER = 0
 
@@ -47,9 +49,16 @@ class MessageType(enum.IntEnum):
     LEADER_TRANSFER = 23
     TIMEOUT_NOW = 24
     RATE_LIMIT = 25
+    # the batched heartbeat plane (tpuquorum.py): one message a peer HOST a
+    # tick, carrying the heartbeats (or the responses) of every group of
+    # the sending host whose scalar state takes no per-group message.
+    # Host-addressed (``cluster_id`` 0): the rows ride ``entries[0].cmd``
+    # (``pack_hb_rows``), so both wires carry them with no codec change.
+    HEARTBEAT_BLOCK = 26
+    HEARTBEAT_RESP_BLOCK = 27
 
 
-NUM_MESSAGE_TYPES = 26
+NUM_MESSAGE_TYPES = 28
 
 
 class EntryType(enum.IntEnum):
@@ -304,6 +313,27 @@ class Message:
     # message — the wire codec emits NOTHING for None (no flag bit, no
     # payload), so the trace-off encoding stays bit-identical
     trace: Optional[ReplTrace] = None
+
+
+#: int64 columns of one heartbeat-block row: cluster, to, from, term, and
+#: the commit index a HEARTBEAT carries (0 on a response row)
+HB_ROW_FIELDS = 5
+
+
+def pack_hb_rows(rows) -> list:
+    """The ``entries`` payload of a HEARTBEAT_BLOCK / HEARTBEAT_RESP_BLOCK:
+    ``rows`` is a list of ``(cluster_id, to, from_, term, commit)``."""
+    return [Entry(cmd=np.asarray(rows, dtype="<i8").tobytes())]
+
+
+def unpack_hb_rows(m: "Message") -> list:
+    """Rows of a block message as lists of ints; ``[]`` for a malformed
+    payload (a block is advisory traffic: dropping it costs one tick)."""
+    if len(m.entries) != 1 or len(m.entries[0].cmd) % (8 * HB_ROW_FIELDS):
+        return []
+    return np.frombuffer(m.entries[0].cmd, dtype="<i8").reshape(
+        -1, HB_ROW_FIELDS
+    ).tolist()
 
 
 @dataclass(slots=True)

@@ -63,8 +63,8 @@ def test_second_enable_is_cache_hot(tmp_path):
     assert stats["error"] is None
     assert eng.fused_ready
     # 2 fused (reads on/off) + 2 sparse + 2 sparse-votes (tick on/off)
-    # + 2 dense read
-    assert stats["programs"] == 8
+    # + 2 dense + 2 dense-votes + 2 dense read
+    assert stats["programs"] == 12
     s1 = compilation_cache_stats()
     assert s1["misses"] > s0["misses"], "cold warmup must populate the cache"
 
