@@ -115,10 +115,11 @@ def predict_bytes(
     ``make_state`` (``ops.state.state_layout``), so it is exact by
     construction and every field scales linearly with the group axis:
     ``bytes_per_group = state_bytes / n_groups``.  The dispatch half
-    mirrors the fused ``quorum_multiround`` argument tuple the engine
-    ships (``upload_nbytes`` semantics, dummies included) — the read/kv
-    stage tensors only count when those planes are live, exactly like
-    the engine's ``has_reads``/``has_kv`` statics.
+    is the ONE ingress block a fused ``quorum_multiround`` dispatch
+    ships, by the layout rule the engine stages through
+    (``ops.packed.ingress_sections``) — the read/kv sections only count
+    when those planes are live, exactly like the engine's
+    ``has_reads``/``has_kv`` statics.
     """
     layout = state_layout(
         n_groups, n_peers,
@@ -139,25 +140,26 @@ def predict_bytes(
         "dispatch_bytes": 0,
     }
     if k_bucket > 0:
-        from ..ops.state import KV_ENT_SLOTS, KV_READ_SLOTS, READ_SLOTS
+        from ..ops.packed import ingress_sections, ingress_size
+        from ..ops.state import (
+            KV_ENT_SLOTS, KV_READ_SLOTS, KV_SLOTS, READ_SLOTS,
+        )
 
         # the value-slot width (V) does not ride the dispatch — only
-        # the entry/read stage tensors do
-        g, p, k = n_groups, n_peers, k_bucket
-        s = READ_SLOTS if n_read_slots is None else n_read_slots
-        e = KV_ENT_SLOTS if n_kv_ents is None else n_kv_ents
-        rk = KV_READ_SLOTS if n_kv_reads is None else n_kv_reads
-        # the fused argument tuple: ack_max (K,G,P) i32, vote dummy
-        # (1,1,1) i8, four churn dummies (1,1) i32, tick_mask (K,) bool
-        d = k * g * p * 4 + 1 + 4 * 4 + k
-        if include_reads:
-            # stage_idx/stage_cnt (K,G,S) i32 + echo (K,G,S,P) bool
-            d += k * g * s * 8 + k * g * s * p
-        if include_kv:
-            # kv_ei/kv_ek/kv_ev (K,G,E) i32 + kv_rk (K,G,R) i32
-            d += k * g * e * 12 + k * g * rk * 4
-        out["dispatch_bytes"] = d
-        out["k_bucket"] = k
+        # the entry/read stage sections do
+        dims = tuple(
+            d if v is None else v for v, d in (
+                (n_read_slots, READ_SLOTS), (n_kv_slots, KV_SLOTS),
+                (n_kv_ents, KV_ENT_SLOTS), (n_kv_reads, KV_READ_SLOTS),
+            )
+        )
+        # the live coordinator's fused block: ack maxima (K,G,P), the
+        # tick mask (K,), no votes, no churn; all int32 lanes
+        out["dispatch_bytes"] = 4 * ingress_size(ingress_sections(
+            "fused", n_groups, n_peers, dims, k=k_bucket, do_tick=True,
+            has_reads=include_reads, has_kv=include_kv,
+        ))
+        out["k_bucket"] = k_bucket
     out["total_bytes"] = state_bytes + out["dispatch_bytes"]
     return out
 
@@ -386,30 +388,16 @@ class DevProf:
         tensors plus the in-flight pipelined dispatch's egress
         accumulators (live ``nbytes`` — pure metadata, no transfer)."""
         artifacts: Dict[Tuple[str, str], int] = {}
-        for name, arr in eng._dev._asdict().items():
-            artifacts[(field_plane(name), name)] = int(arr.nbytes)
+        for name, nbytes in eng.state_nbytes().items():
+            artifacts[(field_plane(name), name)] = nbytes
         inflight = eng._inflight
         if inflight is not None:
-            import jax
-
-            out = inflight[0]
-            extra = sum(
-                int(leaf.nbytes)
-                for leaf in jax.tree_util.tree_leaves((
-                    getattr(out, "committed", None),
-                    getattr(out, "won", None),
-                    getattr(out, "lost", None),
-                    getattr(out, "flags", None),
-                    getattr(out, "read_done_count", None),
-                    getattr(out, "read_done_index", None),
-                    getattr(out, "kv_read_val", None),
-                    getattr(out, "kv_read_index", None),
-                    getattr(out, "kv_applied", None),
-                ))
+            # the double buffer: the in-flight program's state blocks
+            # already ARE the engine's (donated chain), so only its
+            # egress block is extra residency
+            artifacts[("dispatch", "inflight_egress")] = int(
+                inflight[0].nbytes
             )
-            # the double buffer: out.state already IS eng._dev (donated
-            # chain) so only the egress accumulators are extra residency
-            artifacts[("dispatch", "inflight_egress")] = extra
         return artifacts
 
     def hbm_ledger(self) -> dict:
@@ -551,10 +539,10 @@ class DevProf:
             # tensors a fused dispatch actually ships (predict_bytes's
             # closed form is the engine-less twin; the test suite
             # asserts the two agree on every plane combination)
-            _, args, _ = geng._variant_args(
+            _, ing, _ = geng._variant_args(
                 "fused", k, key[0], key[1], abstract=True
             )
-            base["dispatch_bytes"] = _spec_nbytes(args)
+            base["dispatch_bytes"] = _spec_nbytes((ing,))
             base["total_bytes"] = base["state_bytes"] + base["dispatch_bytes"]
             self._predict_cache[key] = base
         # shallow copy: the measured/budget fields below are per-call,
@@ -563,9 +551,7 @@ class DevProf:
         if ledger_state_bytes is None:
             engines = shards if shards else [eng]
             ledger_state_bytes = sum(
-                int(arr.nbytes)
-                for e in engines
-                for arr in e._dev._asdict().values()
+                sum(e.state_nbytes().values()) for e in engines
             )
         measured = ledger_state_bytes
         predicted_state = pred["state_bytes"] * n_shards
@@ -613,7 +599,7 @@ class DevProf:
         budgets: list = []
         for e in engines:
             try:
-                dev = next(iter(e._dev.committed.devices()))
+                dev = next(iter(e._blk.gi.devices()))
                 stats = dev.memory_stats()
             except Exception:
                 stats = None

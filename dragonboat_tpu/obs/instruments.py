@@ -61,7 +61,8 @@ from ..events import DEFAULT_BUCKETS, DEFAULT_REGISTRY, MetricsRegistry
 from .recorder import OFF, FlightRecorder, Phase, annotate
 
 #: the phases of one engine step, by the span field they sum into
-DISPATCH_PHASES = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms")
+DISPATCH_PHASES = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms",
+                   "retire_ms")
 EGRESS_PHASES = ("egress_wait_ms", "decode_ms")
 #: why a heartbeat read echo was tallied scalar-side (tpuquorum.py)
 READ_FALLBACK_CAUSES = ("slot_overflow", "after_confirm", "purged")
@@ -533,6 +534,8 @@ class EngineObs:
         pending_rounds: int = 0,
         read_slots_in_use: Optional[int] = None,
         n_dispatches: int = 1,
+        arrays_made: int = 0,
+        arrays_retired: int = 0,
     ) -> dict:
         """One logical step's device work launched: publish counters +
         latency, and open its span (egress fields land via
@@ -541,7 +544,11 @@ class EngineObs:
         step — so ``dispatch_total`` tracks programs, not steps.
         ``k_rounds`` is the LIVE round count of the block (real staged
         rounds, or ticked rounds when a deficit replay ticks into the
-        padding) vs ``rounds``, the padded program K."""
+        padding) vs ``rounds``, the padded program K.  ``arrays_made``
+        (device arrays the step made by put) and ``arrays_retired``
+        (state blocks and ingress blocks it dropped; :meth:`egress` adds
+        the egress block) count what costs the round thread a hand-off
+        of the interpreter each."""
         r = self.registry
         r.counter_add(_DEV + "dispatch_total", n_dispatches)
         r.counter_add(_DEV + "rounds_total", rounds)
@@ -589,6 +596,8 @@ class EngineObs:
             dispatch_ms=round(dispatch_ms, 4),
             **self._take(DISPATCH_PHASES),
             mu_wait_ms=round(mu_wait_ms, 4),
+            arrays_made=arrays_made,
+            arrays_retired=arrays_retired,
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")
@@ -597,10 +606,11 @@ class EngineObs:
 
     def egress(
         self, span: dict, *, egress_ms: float, egress_rows: int,
-        reads_released: int,
+        reads_released: int, arrays_retired: int = 0,
     ) -> None:
         """Close a dispatch span at harvest: blocking egress wall time
-        plus what the block released."""
+        plus what the block released; ``arrays_retired`` (the egress
+        block, dropped once fetched) adds to the span's count."""
         r = self.registry
         r.histogram_observe(
             _DEV + "egress_latency_ms", egress_ms, buckets=LATENCY_BUCKETS_MS
@@ -616,6 +626,7 @@ class EngineObs:
             **self._take(EGRESS_PHASES),
             egress_rows=egress_rows,
             reads_released=reads_released,
+            arrays_retired=span.get("arrays_retired", 0) + arrays_retired,
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")

@@ -63,6 +63,10 @@ ANNOTATIONS = {
     "stage": "dbtpu:stage",              # dispatch/fused stage_ms
     "transfer": "dbtpu:transfer",        # dispatch/fused transfer_ms
     "launch": "dbtpu:launch",            # dispatch/fused launch_ms
+    "retire": "dbtpu:retire",            # dispatch/fused retire_ms: the
+                                         # explicit drops of the arrays a
+                                         # step retires (state blocks,
+                                         # ingress block)
     "egress_wait": "dbtpu:egress_wait",  # dispatch/fused egress_wait_ms
     "decode": "dbtpu:decode",            # dispatch/fused decode_ms
     "compile": "dbtpu:compile",          # ops.engine.compilation_log()

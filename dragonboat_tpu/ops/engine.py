@@ -8,6 +8,13 @@ batches) → ONE ``quorum_step`` device dispatch per round → host egress
 rebase) mutate a numpy mirror row and are scattered onto the device arrays
 before the next dispatch.
 
+What crosses the host/device boundary of a dispatch is a handful of
+arrays, because each one a step makes or retires costs the round thread a
+hand-off of the interpreter (PERF.md §5): the state travels as four
+blocks, everything staged as ONE ingress block, everything read back as
+ONE egress block (``packed.py``); ``eng.dev`` unpacks a ``QuorumState``
+on demand.
+
 The group axis is shardable over a ``jax.sharding.Mesh`` (see
 ``sharding.py``): every kernel op is row-wise over groups, so XLA partitions
 the whole step with zero collectives — groups are embarrassingly parallel,
@@ -27,12 +34,13 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from .. import obs as _obs
 from ..logger import get_logger
 from ..obs.recorder import OFF as _OFF, annotate as _annotate
-from .kernels import TELEM_TOPK, quorum_step
+from . import packed as _pk
+from .kernels import TELEM_TOPK
+from .sharding import block_sharding
 from .state import (
     CANDIDATE,
     FOLLOWER,
@@ -48,6 +56,8 @@ from .state import (
     WITNESS,
     HostMirror,
     QuorumState,
+    StateBlocks,
+    pack_state,
 )
 
 elog = get_logger("ops.engine")
@@ -84,9 +94,8 @@ def upload_nbytes(*arrays) -> int:
     capacity model's per-dispatch term all read this, so the sum can
     never drift from the tensors actually passed to the kernel (ISSUE 15
     satellite — three hand-maintained per-site sums preceded it).
-    Callers pass EXACTLY the argument tuple the kernel receives; the
-    few-byte dummies of compiled-out planes are counted (they are
-    genuinely uploaded)."""
+    Callers pass EXACTLY what the program receives: since ISSUE 30 the
+    one ingress block of the dispatch."""
     return int(sum(a.nbytes for a in arrays if a is not None))
 
 
@@ -117,13 +126,14 @@ _CC_COMPILE_MU = threading.RLock()
 
 
 def kernel_source_hash() -> str:
-    """SHA-256 over the kernel-defining sources (kernels.py + state.py):
-    the version key of the persistent compilation cache directory."""
+    """SHA-256 over the program-defining sources (kernels.py, packed.py,
+    state.py): the version key of the persistent compilation cache
+    directory."""
     import hashlib
 
     h = hashlib.sha256()
     base = os.path.dirname(os.path.abspath(__file__))
-    for fname in ("kernels.py", "state.py"):
+    for fname in ("kernels.py", "packed.py", "state.py"):
         with open(os.path.join(base, fname), "rb") as f:
             h.update(f.read())
     return h.hexdigest()
@@ -255,25 +265,6 @@ def compilation_log() -> List[Tuple[float, float, str, str, str]]:
     :func:`compilation_cache_stats`, and the other side of a ``warmup``
     span's ``variant``."""
     return list(_CC_LOG)
-
-
-@jax.jit
-def _gather_rows(fields: Dict[str, jax.Array], idx) -> Dict[str, jax.Array]:
-    """Rows ``idx`` of every field in ONE program.  The eager per-field
-    form compiled a gather per (field, index-shape) pair — ~25 fields x
-    log2(G) shapes of tiny programs, each a first-use compile on the
-    round thread (on the v5e a cold 48-group cluster spent its first
-    minute there and timed proposals out)."""
-    return {k: a[idx] for k, a in fields.items()}
-
-
-@jax.jit
-def _scatter_rows(
-    fields: Dict[str, jax.Array], idx, vals: Dict[str, np.ndarray]
-) -> Dict[str, jax.Array]:
-    """``fields[k][idx] = vals[k]`` for every field in ONE program (the
-    upload twin of :func:`_gather_rows`)."""
-    return {k: a.at[idx].set(vals[k]) for k, a in fields.items()}
 
 
 @dataclass
@@ -525,7 +516,22 @@ class BatchedQuorumEngine:
         # all guard themselves.
         self._n_devices = n_dev
         self._dispatch_mu = threading.RLock() if n_dev > 1 else nullcontext()
-        self._dev: QuorumState = self.mirror.to_device(sharding)
+        #: the slot counts the layout rule needs besides G and P
+        self._dims = (n_read_slots, n_kv_slots, n_kv_ents, n_kv_reads)
+        # --- the packed carry (ISSUE 30) --------------------------------
+        # Between steps the 31 leaves live as four blocks
+        # (state.StateBlocks): a step makes and retires the blocks, one
+        # ingress block and one egress block, not ~90 arrays, each of
+        # which cost the round thread a hand-off of the interpreter.
+        # ``dev`` unpacks on demand; steady-state steps never do.
+        self._blk: StateBlocks = self._put_state(self.mirror)
+        # the host buffers dispatches stage into (_pk.Ingress), restaged
+        # in place: a buffer is reused only once the program that read it
+        # has handed back its egress.  Bounded: one per shape of the warm
+        # plan (``_ingress_keep``: what a live coordinator dispatches),
+        # and for every other shape the last-used one of its kind
+        self._ingress: Dict[object, Tuple[tuple, _pk.Ingress]] = {}
+        self._ingress_keep: set = set()
         self._cache_stale = False
         self.groups: Dict[int, GroupInfo] = {}
         self.rows: Dict[int, GroupInfo] = {}
@@ -572,8 +578,9 @@ class BatchedQuorumEngine:
         # the pre-recycle device row; a rare-path mutation on such a row
         # collapses the recycle to pre-block ordering (_sync_row)
         self._churn_pending: set = set()
-        # in-flight pipelined dispatch: (StepOutputs, prev_committed,
-        # row_cid snapshot, row_base snapshot, n_rounds) — the ingest of
+        # in-flight pipelined dispatch: (egress block, telem aggregate,
+        # (has_reads, has_kv), prev_committed, row_cid snapshot, row_base
+        # snapshot, n_rounds) — the ingest of
         # block i+1 overlaps the device execution of block i, and every
         # host read of device state harvests first (_harvest_inflight)
         self._inflight = None
@@ -696,6 +703,8 @@ class BatchedQuorumEngine:
         self._obs_kv_span = None   # apply_kernel span of the same dispatch
         self._obs_mu_wait = 0.0    # _dispatch_mu wait of the next dispatch
         self._obs_upload = 0       # upload bytes of the current dispatch
+        self._n_made = 0           # arrays put since the last span
+        self._n_retired = 0        # arrays dropped since the last span
         # --- device capacity & profiling plane (ISSUE 15) ---------------
         # LATCH, same contract as _obs: None by default, every hot-path
         # site gates on `is not None`, so a profile-off engine keeps a
@@ -972,10 +981,7 @@ class BatchedQuorumEngine:
                     )
                 except (OSError, AttributeError):
                     pass
-            scratch = HostMirror(
-                self.n_groups, self.n_peers, self.n_read_slots,
-                self.n_kv_slots, self.n_kv_ents,
-            ).to_device(self.sharding)
+            scratch = self._scratch_blocks()
             for kind, a, hr, kv in self._kv_plan(k_buckets):
                 if self._warmup_cancel.is_set():
                     return
@@ -1037,7 +1043,14 @@ class BatchedQuorumEngine:
                 for kind in ("dense", "dense_votes") for dt in (True, False)
             ]
             if include_reads:
-                plan += [("dense", dt, True, False) for dt in (True, False)]
+                # ... and with the read plane riding along; the vote
+                # twin too: a host that campaigns for one group while it
+                # serves another's reads dispatches both in one round
+                plan += [
+                    (kind, dt, True, False)
+                    for kind in ("dense", "dense_votes")
+                    for dt in (True, False)
+                ]
         if include_kv:
             plan += self._kv_plan(k_buckets)
         return plan
@@ -1077,10 +1090,7 @@ class BatchedQuorumEngine:
                 except (OSError, AttributeError):
                     pass
             hits0, miss0 = _CC["hits"], _CC["misses"]
-            scratch = HostMirror(
-                self.n_groups, self.n_peers, self.n_read_slots,
-                self.n_kv_slots, self.n_kv_ents,
-            ).to_device(self.sharding)
+            scratch = self._scratch_blocks()
             scratch = self._warm_row_syncs(scratch, include_reads)
             plan = self.warm_plan(
                 k_buckets, include_reads, include_single, include_kv
@@ -1121,129 +1131,98 @@ class BatchedQuorumEngine:
         self, kind: str, arg, has_reads: bool, has_kv: bool = False,
         abstract: bool = False,
     ):
-        """Kernel entry point, argument tensors (state excluded) and
-        static kwargs for one warm-plan variant.  ``abstract=False``
-        builds the concrete zero/fill tensors the warm dispatch runs
-        (``_warm_one``); ``abstract=True`` builds
-        :class:`jax.ShapeDtypeStruct` stand-ins for the devprof program
-        registry's AOT ``lower().compile()`` (``lower_variant``) — ONE
-        builder, so the registry analyzes byte-for-byte the programs the
-        warmup compiled.  Shapes/statics must mirror the live call sites
-        EXACTLY — a near-miss warms a program the live path never uses."""
-        from .kernels import quorum_multiround, quorum_step_dense
-
-        g, p, s = self.n_groups, self.n_peers, self.n_read_slots
-        e, rk = self.n_kv_ents, self.n_kv_reads
-        if abstract:
-            def mk(shape, dtype, fill=0):
-                del fill  # shape/dtype is all a lowering needs
-                return jax.ShapeDtypeStruct(shape, dtype)
-        else:
-            def mk(shape, dtype, fill=0):
-                # host numpy, exactly what the live call sites pass — a
-                # jnp.full/zeros here compiled one tiny broadcast program
-                # per argument shape
-                return np.full(shape, fill, dtype)
-
-        def read_dims(*lead):
-            return (
-                mk(lead + (g, s), jnp.int32, -1),
-                mk(lead + (g, s), jnp.int32),
-                mk(lead + (g, s, p), bool),
-            )
-
-        def kv_dims(*lead):
-            return (
-                mk(lead + (g, e), jnp.int32, -1),
-                mk(lead + (g, e), jnp.int32),
-                mk(lead + (g, e), jnp.int32),
-                mk(lead + (g, rk), jnp.int32, -1),
-            )
-
+        """Program, ingress block (state excluded) and static kwargs for
+        one warm-plan variant.  ``abstract=False`` builds the concrete
+        nothing-staged ingress the warm dispatch runs (``_warm_one``);
+        ``abstract=True`` a :class:`jax.ShapeDtypeStruct` stand-in for
+        the devprof program registry's AOT ``lower().compile()``
+        (``lower_variant``) — ONE builder, so the registry analyzes
+        byte-for-byte the programs the warmup compiled.  The statics
+        must mirror the live call sites EXACTLY — a near-miss warms a
+        program the live path never uses; the ingress shape cannot miss,
+        both sides take it from ``_pk.ingress_sections``.  A concrete
+        variant's shape joins ``_ingress_keep``: the live path keeps the
+        staging buffer of a warmed shape (``_ingress_for``)."""
         if kind == "fused":
-            k = arg
-            read_args = read_dims(k) if has_reads else (None, None, None)
-            kv_args = kv_dims(k) if has_kv else (None, None, None, None)
-            z11 = mk((1, 1), jnp.int32)
-            args = (
-                mk((k, g, p), jnp.int32, -1),
-                mk((1, 1, 1), jnp.int8),
-                z11, z11, z11, z11,
-                mk((k,), bool),
-            ) + read_args + kv_args
-            statics = dict(
-                do_tick=True,
-                track_contact=True,
-                has_votes=False,
-                has_churn=False,
-                has_reads=has_reads,
-                purge_reads=False,
-                has_kv=has_kv,
-                purge_kv=False,
-                has_hier=self._hier_used,
-                has_telem=self._telem_used,
-                purge_telem=False,
-                telem_k=self.n_telem_topk,
+            fn, layout = _pk.quorum_multiround, dict(
+                k=arg, c=0, do_tick=True, has_votes=False, has_churn=False,
+                has_reads=has_reads, has_kv=has_kv,
             )
-            return quorum_multiround, args, statics
-        if kind in ("dense", "dense_votes"):
-            do_tick = arg
-            has_votes = kind == "dense_votes"
-            read_args = read_dims() if has_reads else (None, None, None)
-            kv_args = kv_dims() if has_kv else (None, None, None, None)
-            args = (
-                mk((g, p), jnp.int32),
-                mk((g, p), bool),
-                # VOTE_NONE everywhere: the warm run casts no vote
-                mk((g, p), jnp.int8, VOTE_NONE) if has_votes
-                else mk((1, 1), jnp.int8),
-            ) + read_args + kv_args
             statics = dict(
+                layout,
+                track_contact=True,
+                purge_reads=False,
+                purge_kv=False,
+                purge_telem=False,
+            )
+        elif kind in ("dense", "dense_votes"):
+            do_tick = arg
+            fn, layout = _pk.quorum_step_dense, dict(
+                has_votes=kind == "dense_votes", has_reads=has_reads,
+                has_kv=has_kv,
+            )
+            kind = "dense"
+            statics = dict(
+                layout,
                 do_tick=do_tick,
                 track_contact=self.device_ticks or do_tick,
-                has_votes=has_votes,
-                has_reads=has_reads,
-                has_kv=has_kv,
-                has_hier=self._hier_used,
-                has_telem=self._telem_used,
-                telem_k=self.n_telem_topk,
             )
-            return quorum_step_dense, args, statics
-        # sparse single-round (the quiet-path workhorse)
-        do_tick = arg
-        cap = self.event_cap
-        z32 = mk((cap,), jnp.int32)
-        has_votes = kind == "sparse_votes"
-        if has_votes:  # vote events pad to the full event cap
-            vg = vp = z32
-            vv = mk((cap,), jnp.int8)
-            vvalid = mk((cap,), bool)
-        else:
-            vg = vp = mk((1,), jnp.int32)
-            vv = mk((1,), jnp.int8)
-            vvalid = mk((1,), bool)
-        args = (z32, z32, z32, mk((cap,), bool), vg, vp, vv, vvalid)
-        statics = dict(
-            do_tick=do_tick,
-            track_contact=self.device_ticks or do_tick,
-            has_votes=has_votes,
+        else:  # sparse single-round (the quiet-path workhorse)
+            do_tick = arg
+            fn, layout = _pk.quorum_step, dict(
+                cap=self.event_cap, has_votes=kind == "sparse_votes",
+            )
+            kind = "sparse"
+            statics = dict(
+                layout,
+                do_tick=do_tick,
+                track_contact=self.device_ticks or do_tick,
+                **self._fold_hints(),
+            )
+        sections = _pk.ingress_sections(
+            kind, self.n_groups, self.n_peers, self._dims, **layout
+        )
+        if not abstract:  # warmed: a live shape, its buffer is kept
+            self._ingress_keep.add(self._ingress_key(kind, layout))
+        ing = (
+            jax.ShapeDtypeStruct((_pk.ingress_size(sections),), np.int32)
+            if abstract else _pk.Ingress(sections).buf
+        )
+        return fn, ing, dict(statics, **self._engine_statics())
+
+    def _engine_statics(self) -> dict:
+        """The statics every program of this engine takes, whatever the
+        dispatch carries (``_launch`` adds them; the warm plan too)."""
+        return dict(
+            dims=self._dims,
             has_hier=self._hier_used,
             has_telem=self._telem_used,
             telem_k=self.n_telem_topk,
         )
-        return quorum_step, args, statics
+
+    def _fold_hints(self) -> dict:
+        """The sparse program's occupancy hints for the telem fold (it
+        never carries read/kv event planes).  Statics key the jit cache
+        whether the program reads them or not — and an omitted one and
+        an explicit False key it apart — so they are held False without
+        the fold: the first read must not flip them and recompile the
+        warmed sparse programs on the round thread."""
+        return dict(
+            has_reads=self._telem_used and self._read_plane_used,
+            has_kv=self._telem_used and self._devsm_used,
+        )
 
     def _warm_one(
-        self, scratch: QuorumState, kind: str, arg, has_reads: bool,
+        self, scratch: StateBlocks, kind: str, arg, has_reads: bool,
         has_kv: bool = False,
     ):
-        """Compile-and-run one variant against the scratch state (donated;
-        the successor state is returned)."""
-        fn, args, statics = self._variant_args(kind, arg, has_reads, has_kv)
+        """Compile-and-run one variant against the scratch blocks
+        (donated; their successors are returned)."""
+        fn, ing, statics = self._variant_args(kind, arg, has_reads, has_kv)
         with self._dispatch_mu:  # multi-device programs take the lock
-            out = fn(scratch, *args, **statics)
-            jax.block_until_ready(out.committed)
-        return out.state
+            out = fn(scratch, ing, **statics)
+            jax.block_until_ready(out.egress)
+        return out.blocks
 
     def lower_variant(
         self, kind: str, arg, has_reads: bool, has_kv: bool = False
@@ -1255,31 +1234,21 @@ class BatchedQuorumEngine:
         figures (ISSUE 15).  With the persistent compilation cache
         enabled the compile step deserializes the warmed executable
         instead of recompiling."""
-        fn, args, statics = self._variant_args(
+        fn, ing, statics = self._variant_args(
             kind, arg, has_reads, has_kv, abstract=True
         )
-        from .state import make_state
-
-        st = jax.eval_shape(
-            lambda: make_state(
-                self.n_groups, self.n_peers, self.n_read_slots,
-                self.n_kv_slots, self.n_kv_ents,
-            )
+        # a mesh-sharded engine's live/warmed programs are GSPMD
+        # partitions of the state — lowering unsharded here would
+        # analyze an executable the cluster never runs (and miss the
+        # persistent cache).  The ingress stays unsharded, matching the
+        # live call sites (host numpy → replication decided by GSPMD,
+        # exactly as _warm_one dispatches it).
+        sh = block_sharding(self.sharding)
+        st = jax.tree_util.tree_map(
+            lambda b: jax.ShapeDtypeStruct(b.shape, b.dtype, sharding=sh),
+            self._blk,
         )
-        if self.sharding is not None:
-            # a mesh-sharded engine's live/warmed programs are GSPMD
-            # partitions of the state — lowering unsharded here would
-            # analyze an executable the cluster never runs (and miss
-            # the persistent cache).  The event args stay unsharded,
-            # matching the live call sites (host numpy → replication
-            # decided by GSPMD, exactly as _warm_one dispatches them).
-            st = jax.tree_util.tree_map(
-                lambda s: jax.ShapeDtypeStruct(
-                    s.shape, s.dtype, sharding=self.sharding
-                ),
-                st,
-            )
-        return fn.lower(st, *args, **statics)
+        return fn.lower(st, ing, **statics)
 
     @staticmethod
     def _obs_gate(do_tick, acks, votes, recycles, reads, echoes) -> str:
@@ -1295,9 +1264,41 @@ class BatchedQuorumEngine:
             parts.append("reads")
         return "+".join(parts) or "drain"
 
+    def _put_state(self, mirror: HostMirror) -> StateBlocks:
+        """``mirror``'s arrays on the device, as blocks."""
+        sh = block_sharding(self.sharding)
+        return StateBlocks(*(
+            jax.device_put(b, sh)
+            for b in pack_state(QuorumState(**mirror.arrays), np)
+        ))
+
+    def _scratch_blocks(self) -> StateBlocks:
+        """An all-dead state of this engine's shapes and shardings, for
+        the warm-up passes to run (and donate) programs against."""
+        return self._put_state(HostMirror(
+            self.n_groups, self.n_peers, self.n_read_slots,
+            self.n_kv_slots, self.n_kv_ents,
+        ))
+
+    def state_nbytes(self) -> Dict[str, int]:
+        """Resident bytes of every state leaf, by field name, from the
+        shapes alone (the HBM ledger's walk: a leaf costs in its block
+        what it cost as an array of its own)."""
+        return {
+            name: int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+            for name, leaf in jax.eval_shape(
+                lambda b: _pk.unpack_state(b, self._dims[:3]), self._blk
+            )._asdict().items()
+        }
+
     @property
     def dev(self) -> QuorumState:
-        return self._dev
+        """The state as the kernels see it, unpacked on demand (tests,
+        benches, the HBM ledger; the steady-state step never asks).
+        Point-in-time: the next dispatch donates the blocks it was read
+        from, not these leaves."""
+        with self._dispatch_mu:
+            return _pk.unpack(self._blk, dims=self._dims[:3])
 
     @dev.setter
     def dev(self, st: QuorumState) -> None:
@@ -1306,7 +1307,11 @@ class BatchedQuorumEngine:
         longer be trusted, so the next step() re-reads it from the device
         once instead of mis-reporting commit deltas."""
         self._harvest_inflight()
-        self._dev = st
+        with self._dispatch_mu:
+            blocks = _pk.pack(st)
+            if self.sharding is not None:
+                blocks = jax.device_put(blocks, block_sharding(self.sharding))
+        self._blk = blocks
         self._cache_stale = True
         self._synced.clear()
 
@@ -2526,7 +2531,7 @@ class BatchedQuorumEngine:
             obs.begin_step()
         self._upload_dirty()
         self._refresh_committed_cache()
-        out = self._dispatch_multiround(
+        out, planes = self._dispatch_multiround(
             blocks, do_tick, tick_mask,
             k_rounds=max(n_real, tick_rounds if do_tick else 0),
         )
@@ -2534,7 +2539,9 @@ class BatchedQuorumEngine:
         # every staged recycle is now inside the dispatched program
         self._churn_pending.clear()
         self._inflight = (
-            out,
+            out.egress,
+            out.telem,
+            planes,
             # snapshot, not alias: stage_recycle zeroes cache rows in
             # place while this dispatch is in flight, which must not
             # corrupt ITS commit-delta baseline
@@ -2560,43 +2567,31 @@ class BatchedQuorumEngine:
     def _harvest_inflight_locked(self) -> Optional[MultiRoundResult]:
         if self._inflight is None:
             return None
-        out, prev_committed, row_cid, row_base, n_rounds = self._inflight
+        (
+            egress, telem, planes, prev_committed, row_cid, row_base,
+            n_rounds,
+        ) = self._inflight
         self._inflight = None
         obs = self._obs
         span, self._obs_span = self._obs_span, None
         kv_span, self._obs_kv_span = self._obs_kv_span, None
         t_eg = time.perf_counter() if obs is not None else 0.0
-        with (obs.phase("egress_wait") if obs is not None else _OFF):
-            (
-                committed, won, lost, elect, hb, demote, rdc, rdi,
-                kvv, kvi, kva,
-            ) = jax.device_get(
-                (
-                    out.committed,
-                    out.won,
-                    out.lost,
-                    out.flags.elect_due,
-                    out.flags.hb_due,
-                    out.flags.checkq_demote,
-                    out.read_done_count,
-                    out.read_done_index,
-                    out.kv_read_val,
-                    out.kv_read_index,
-                    out.kv_applied,
-                )
-            )
+        eg = self._fetch_egress(egress)
+        del egress
         with (obs.phase("decode") if obs is not None else _OFF):
-            if out.telem is not None:
+            if telem is not None:
                 # dispatch-time row_cid snapshot: a re-registration while
                 # the block was in flight must not mislabel a drill-down
                 # row.  The device arrays stay resident until
                 # telem_snapshot pulls them — the fold must not add a
                 # per-dispatch readback
-                self._stage_telem(out.telem, row_cid, rounds=n_rounds)
+                self._stage_telem(telem, row_cid, rounds=n_rounds)
             res = MultiRoundResult(n_rounds)
+            committed, bits, rdc, rdi, kvv, kvi, kva = _pk.split_egress(
+                eg, self._dims, *planes
+            )
             if rdc is not None:
                 self._translate_reads(res, rdc, rdi, row_cid, row_base)
-            committed = np.asarray(committed)
             res.committed_rel = committed
             self._committed_cache = np.array(committed, dtype=np.int32)
             if self._churn_pending:
@@ -2615,13 +2610,12 @@ class BatchedQuorumEngine:
             if self._devsm_used:
                 self._kv_free_applied()
             res.commit_rows = self._translate_egress(
-                res, committed, prev_committed, row_cid, row_base,
-                (("won", won), ("lost", lost), ("elect", elect),
-                 ("heartbeat", hb), ("demote", demote)),
+                res, committed, prev_committed, row_cid, row_base, bits
             )
         if obs is not None and span is not None:
             obs.egress(
                 span,
+                arrays_retired=1,
                 egress_ms=(time.perf_counter() - t_eg) * 1e3,
                 egress_rows=int(res.commit_rows.size),
                 reads_released=(
@@ -2639,26 +2633,35 @@ class BatchedQuorumEngine:
             )
         return res
 
+    def _fetch_egress(self, egress) -> np.ndarray:
+        """The blocking ``device_get`` of a dispatch's ONE egress block;
+        the caller drops the device array right after (it is counted as
+        retired on the span's egress half)."""
+        obs = self._obs
+        with (obs.phase("egress_wait") if obs is not None else _OFF):
+            return jax.device_get(egress)
+
     @staticmethod
     def _translate_egress(
-        res, committed, prev_committed, row_cid, row_base, flags
+        res, committed, prev_committed, row_cid, row_base, bits
     ) -> np.ndarray:
         """Vectorized row→cluster egress translation, shared by step()'s
         single-round path and the fused harvest: watermark deltas become
         (cid, abs) arrays (dead rows — cid -1 — dropped; the commit dict
-        materializes lazily), flag vectors become cid lists.  Returns the
-        changed-row index vector."""
+        materializes lazily), the flag bit field (``_pk.FLAG_BITS``)
+        becomes cid lists.  Returns the changed-row index vector."""
         changed = np.nonzero(committed != prev_committed)[0]
         if changed.size:
             cids = row_cid[changed]
             live = cids >= 0
             res._commit_cids = cids[live]
             res._commit_abs = (row_base[changed] + committed[changed])[live]
-        for name, arr in flags:
-            idx = np.nonzero(np.asarray(arr))[0]
-            if idx.size:
-                cids = row_cid[idx]
-                getattr(res, name).extend(cids[cids >= 0].tolist())
+        if bits.any():
+            for i, name in enumerate(_pk.FLAG_BITS):
+                idx = np.nonzero(bits & (1 << i))[0]
+                if idx.size:
+                    cids = row_cid[idx]
+                    getattr(res, name).extend(cids[cids >= 0].tolist())
         return changed
 
     def _translate_kv(self, res, kvv, kvi, kva, row_cid, row_base) -> None:
@@ -2706,20 +2709,41 @@ class BatchedQuorumEngine:
         self, blocks: List[_RoundBuf], do_tick: bool, tick_mask: np.ndarray,
         k_rounds: Optional[int] = None,
     ):
-        """Stack K closed rounds into (K,G,P) tensors + (K,C) churn blocks
-        and run ``kernels.quorum_multiround`` — one scan, one upload, one
-        egress for the whole block."""
-        from .kernels import quorum_multiround
-
+        """Stage K closed rounds into ONE ingress block — (K,G,P) ack
+        maxima, (K,C) churn records, the planes in use — and run the
+        fused program: one scan, one upload, one egress for the whole
+        block.  Returns ``(out, (has_reads, has_kv))``."""
         obs = self._obs
         with (obs.phase("stage") if obs is not None else _OFF):
             k = len(blocks)
             g, p = self.n_groups, self.n_peers
-            # -1 = untouched sentinel: one tensor instead of (max,
+            has_votes = any(b.votes for b in blocks)
+            has_churn = any(b.churn for b in blocks)
+            cap = 0
+            if has_churn:
+                # pad the per-round churn width to a power of two so the
+                # jit cache stays bounded at ~log2(G) entries per K (the
+                # same shape-bucketing rationale as _pad_rows)
+                cmax = max(len(b.churn) for b in blocks)
+                cap = max(1 << max(0, cmax - 1).bit_length(), 1)
+            has_reads = any(
+                b.reads is not None or b.racks is not None for b in blocks
+            )
+            # the fold runs while ops sit buffered
+            has_kv = any(
+                b.kvents is not None or b.kvreads is not None
+                for b in blocks
+            ) or self._kv_ents_buffered()
+            ing = self._ingress_for(
+                "fused", k=k, c=cap, has_votes=has_votes,
+                has_churn=has_churn, do_tick=do_tick, has_reads=has_reads,
+                has_kv=has_kv,
+            )
+            v = ing.views
+            # -1 = untouched sentinel: one plane instead of (max,
             # touched) — halves both the host staging stores and the
             # upload bytes
-            ack_max = np.full((k, g, p), -1, np.int32)
-            flat = ack_max.reshape(-1)
+            flat = v["ack"].reshape(-1)
             stride = g * p
             for r, b in enumerate(blocks):
                 if b.rows.size:
@@ -2730,128 +2754,62 @@ class BatchedQuorumEngine:
                             r * g + b.rows.astype(np.int64)
                         ) * p + b.slots
                     np.maximum.at(flat, cell, b.rels)
-            has_votes = any(b.votes for b in blocks)
-            if has_votes:
-                vote_new = np.full((k, g, p), VOTE_NONE, np.int8)
-                for r, b in enumerate(blocks):
-                    if b.votes:
-                        cols = np.array(b.votes, dtype=np.int64).T
-                        vote_new[r, cols[0], cols[1]] = cols[2].astype(
-                            np.int8
-                        )
-            else:
-                vote_new = np.zeros((1, 1, 1), np.int8)  # unused dummy
-            has_churn = any(b.churn for b in blocks)
-            if has_churn:
-                # pad the per-round churn width to a power of two so the
-                # jit cache stays bounded at ~log2(G) entries per K (the
-                # same shape-bucketing rationale as _pad_rows)
-                cmax = max(len(b.churn) for b in blocks)
-                cap = 1 << max(0, cmax - 1).bit_length()
-                cap = max(cap, 1)
-                # g = padding (drops)
-                churn_row = np.full((k, cap), g, np.int32)
-                churn_term = np.zeros((k, cap), np.int32)
-                churn_start = np.zeros((k, cap), np.int32)
-                churn_last = np.zeros((k, cap), np.int32)
-                for r, b in enumerate(blocks):
-                    if b.churn:
-                        cols = np.array(b.churn, dtype=np.int64).T
-                        n = cols.shape[1]
-                        churn_row[r, :n] = cols[0]
-                        churn_term[r, :n] = cols[1]
-                        churn_start[r, :n] = cols[2]
-                        churn_last[r, :n] = cols[3]
-            else:
-                z = np.zeros((1, 1), np.int32)
-                churn_row = churn_term = churn_start = churn_last = z
-            has_reads = any(
-                b.reads is not None or b.racks is not None for b in blocks
-            )
-            read_np = (None, None, None)
-            if has_reads:
-                s = self.n_read_slots
-                stage_idx = np.full((k, g, s), -1, np.int32)
-                stage_cnt = np.zeros((k, g, s), np.int32)
-                echo = np.zeros((k, g, s, p), bool)
-                for r, b in enumerate(blocks):
-                    if b.reads is not None and b.reads[0].size:
-                        rr, sl, v, c = b.reads
-                        stage_idx[r, rr, sl] = v
-                        stage_cnt[r, rr, sl] = c
-                    if b.racks is not None and b.racks[0].size:
-                        rr, sl, pe = b.racks
-                        echo[r, rr, sl, pe] = True
-                read_np = (stage_idx, stage_cnt, echo)
-            # the fold runs while ops sit buffered
-            has_kv = any(
-                b.kvents is not None or b.kvreads is not None
-                for b in blocks
-            ) or self._kv_ents_buffered()
-            kv_np = (None, None, None, None)
-            if has_kv:
-                e, rk = self.n_kv_ents, self.n_kv_reads
-                kv_ei = np.full((k, g, e), -1, np.int32)
-                kv_ek = np.zeros((k, g, e), np.int32)
-                kv_ev = np.zeros((k, g, e), np.int32)
-                kv_rk = np.full((k, g, rk), -1, np.int32)
-                for r, b in enumerate(blocks):
-                    if b.kvents is not None and b.kvents[0].size:
-                        rr, sl, rel, key, val = b.kvents
-                        kv_ei[r, rr, sl] = rel
-                        kv_ek[r, rr, sl] = key
-                        kv_ev[r, rr, sl] = val
-                    if b.kvreads is not None and b.kvreads[0].size:
-                        rr, sl, key = b.kvreads
-                        kv_rk[r, rr, sl] = key
-                kv_np = (kv_ei, kv_ek, kv_ev, kv_rk)
-        # the host->device puts, in a block of their own: one transfer
-        # per staged array today (ROADMAP A1)
-        with (obs.phase("transfer") if obs is not None else _OFF):
-            args = tuple(
-                jnp.asarray(a) for a in (
-                    ack_max, vote_new, churn_row, churn_term, churn_start,
-                    churn_last, tick_mask,
-                )
-            )
-            read_args = tuple(
-                None if a is None else jnp.asarray(a) for a in read_np
-            )
-            kv_args = tuple(
-                None if a is None else jnp.asarray(a) for a in kv_np
-            )
-        with (obs.phase("launch") if obs is not None else _OFF):
-            out = quorum_multiround(
-                self._dev,
-                *args,
-                *read_args,
-                *kv_args,
-                do_tick=do_tick,
-                track_contact=self.device_ticks or do_tick,
-                has_votes=has_votes,
-                has_churn=has_churn,
-                has_reads=has_reads,
-                # a never-used read plane is all-zero: compile its recycle
-                # purges out (measured ~40% of rung-5 churn throughput).
-                # Normalized to False when the block carries no churn —
-                # the flag is only consumed inside _apply_recycle, but as
-                # a static it keys the jit cache, and letting it flip with
-                # _read_plane_used would recompile the live coordinator's
-                # fused program the moment the first read stages (exactly
-                # the first-use stall the warmup pass exists to kill)
-                purge_reads=self._read_plane_used and has_churn,
-                has_kv=has_kv,
-                # the devsm twin of purge_reads, same normalization
-                # rationale
-                purge_kv=self._devsm_used and has_churn,
-                has_hier=self._hier_used,
-                has_telem=self._telem_used,
-                # the telem twin of purge_reads, same normalization
-                # rationale
-                purge_telem=self._telem_used and has_churn,
-                telem_k=self.n_telem_topk,
-            )
-        self._dev = out.state
+                if b.votes:
+                    cols = np.array(b.votes, dtype=np.int64).T
+                    v["votes"][r, cols[0], cols[1]] = cols[2]
+                if b.churn:
+                    cols = np.array(b.churn, dtype=np.int64).T
+                    n = cols.shape[1]
+                    v["churn_row"][r, :n] = cols[0]
+                    v["churn_term"][r, :n] = cols[1]
+                    v["churn_start"][r, :n] = cols[2]
+                    v["churn_last"][r, :n] = cols[3]
+                if b.reads is not None and b.reads[0].size:
+                    rr, sl, val, c = b.reads
+                    v["read_idx"][r, rr, sl] = val
+                    v["read_cnt"][r, rr, sl] = c
+                if b.racks is not None and b.racks[0].size:
+                    rr, sl, pe = b.racks
+                    np.bitwise_or.at(
+                        v["read_echo"][r], (rr, sl), np.left_shift(1, pe)
+                    )
+                if b.kvents is not None and b.kvents[0].size:
+                    rr, sl, rel, key, val = b.kvents
+                    v["kv_idx"][r, rr, sl] = rel
+                    v["kv_key"][r, rr, sl] = key
+                    v["kv_val"][r, rr, sl] = val
+                if b.kvreads is not None and b.kvreads[0].size:
+                    rr, sl, key = b.kvreads
+                    v["kv_rkey"][r, rr, sl] = key
+            if do_tick:
+                v["tick"][:] = tick_mask
+        out = self._launch(
+            _pk.quorum_multiround,
+            ing,
+            k=k,
+            c=cap,
+            do_tick=do_tick,
+            track_contact=self.device_ticks or do_tick,
+            has_votes=has_votes,
+            has_churn=has_churn,
+            has_reads=has_reads,
+            # a never-used read plane is all-zero: compile its recycle
+            # purges out (measured ~40% of rung-5 churn throughput).
+            # Normalized to False when the block carries no churn —
+            # the flag is only consumed inside _apply_recycle, but as
+            # a static it keys the jit cache, and letting it flip with
+            # _read_plane_used would recompile the live coordinator's
+            # fused program the moment the first read stages (exactly
+            # the first-use stall the warmup pass exists to kill)
+            purge_reads=self._read_plane_used and has_churn,
+            has_kv=has_kv,
+            # the devsm twin of purge_reads, same normalization
+            # rationale
+            purge_kv=self._devsm_used and has_churn,
+            # the telem twin of purge_reads, same normalization
+            # rationale
+            purge_telem=self._telem_used and has_churn,
+        )
         if obs is not None:
             n_acks = int(sum(b.rows.size for b in blocks))
             n_votes = sum(len(b.votes) for b in blocks)
@@ -2862,14 +2820,10 @@ class BatchedQuorumEngine:
             n_echo = int(sum(
                 b.racks[0].size for b in blocks if b.racks is not None
             ))
-            # EXACTLY the argument tuple the kernel received (dummies of
-            # compiled-out planes included — they are genuinely shipped):
-            # the one accounting point shared with the devprof capacity
-            # model (upload_nbytes docstring)
-            up = upload_nbytes(
-                ack_max, vote_new, churn_row, churn_term, churn_start,
-                churn_last, tick_mask, *read_args, *kv_args,
-            )
+            # EXACTLY what the program received: the one ingress block
+            # (the one accounting point shared with the devprof capacity
+            # model: upload_nbytes docstring)
+            up = upload_nbytes(ing.buf)
             if has_kv:
                 n_kvops = int(sum(
                     b.kvents[0].size for b in blocks if b.kvents is not None
@@ -2905,6 +2859,7 @@ class BatchedQuorumEngine:
                     int(self._read_busy.sum())
                     if self._read_plane_used else None
                 ),
+                **self._take_array_counts(),
             )
             self.last_span_seq = self._obs_span["seq"]
         dp = self._devprof
@@ -2916,7 +2871,7 @@ class BatchedQuorumEngine:
             # estimate; the sampled delta is stamped onto this
             # dispatch's flight-recorder span as `device_ms`
             dp.note_dispatch(
-                "fused", out.committed, rounds=k,
+                "fused", out.egress, rounds=k,
                 live_rounds=(
                     min(k, k_rounds) if k_rounds is not None else k
                 ),
@@ -2925,7 +2880,7 @@ class BatchedQuorumEngine:
                 # record, and stamping device_ms there would corrupt it
                 span=self._obs_span if obs is not None else None,
             )
-        return out
+        return out, (has_reads, has_kv)
 
     def _refresh_committed_cache(self) -> None:
         """Re-read the host committed twin from the device when it was
@@ -2937,7 +2892,7 @@ class BatchedQuorumEngine:
         obs = self._obs
         with (obs.phase("row_sync") if obs is not None else _OFF):
             self._committed_cache = np.array(
-                np.asarray(self._dev.committed), dtype=np.int32
+                np.asarray(self.dev.committed), dtype=np.int32
             )
             if self._churn_pending:
                 rows = np.fromiter(self._churn_pending, dtype=np.int64)
@@ -2992,26 +2947,24 @@ class BatchedQuorumEngine:
         self._pull_rows(self._pad_rows(np.array([row], np.int32)))
         self._synced.add(row)
 
-    def _gather_sync_rows(self, st: QuorumState, keys, idx) -> dict:
-        """Rows ``idx`` of the ``keys`` fields of ``st`` as host arrays:
-        one gather program, one transfer."""
+    def _gather_sync_rows(self, blocks: StateBlocks, keys, idx) -> dict:
+        """Rows ``idx`` of the ``keys`` fields of ``blocks`` as host
+        arrays: one gather program, one transfer."""
         with self._dispatch_mu:  # the gather is a multi-device program
-            return jax.device_get(
-                _gather_rows({k: getattr(st, k) for k in keys}, idx)
-            )
+            return jax.device_get(_pk.gather_rows(
+                blocks, idx, dims=self._dims[:3], keys=tuple(keys)
+            ))
 
-    def _scatter_sync_rows(self, st: QuorumState, idx, vals: dict):
-        """``st`` with rows ``idx`` of the ``vals`` fields overwritten
-        (one scatter program)."""
-        return st._replace(**_scatter_rows(
-            {k: getattr(st, k) for k in vals}, idx, vals
-        ))
+    def _scatter_sync_rows(self, blocks: StateBlocks, idx, vals: dict):
+        """``blocks`` (donated) with rows ``idx`` of the ``vals`` fields
+        overwritten (one scatter program)."""
+        return _pk.scatter_rows(blocks, idx, vals, dims=self._dims[:3])
 
     def _pull_rows(self, idx: np.ndarray) -> None:
         """Device rows ``idx`` -> mirror, every sync field."""
         obs = self._obs
         with (obs.phase("row_sync") if obs is not None else _OFF):
-            got = self._gather_sync_rows(self.dev, self._sync_keys(), idx)
+            got = self._gather_sync_rows(self._blk, self._sync_keys(), idx)
             for k, v in got.items():
                 self.mirror.arrays[k][idx] = v
 
@@ -3058,7 +3011,7 @@ class BatchedQuorumEngine:
             [idx, np.full(cap - idx.size, idx[0], idx.dtype)]
         )
 
-    def _warm_row_syncs(self, scratch: QuorumState, include_reads: bool):
+    def _warm_row_syncs(self, scratch: StateBlocks, include_reads: bool):
         """Compile the row gather/scatter programs for every bucket, for
         the current sync field set and (``include_reads``) the one the
         first staged read switches to — against the scratch state, which
@@ -3107,31 +3060,18 @@ class BatchedQuorumEngine:
         obs = self._obs
         with (obs.phase("row_sync") if obs is not None else _OFF):
             rows = self._pad_rows(np.fromiter(self._dirty, dtype=np.int32))
-            self._dev = self._scatter_sync_rows(
-                self.dev, rows,
-                {k: self.mirror.arrays[k][rows] for k in self._sync_keys()},
-            )
+            with self._dispatch_mu:
+                self._blk = self._scatter_sync_rows(
+                    self._blk, rows,
+                    {k: self.mirror.arrays[k][rows]
+                     for k in self._sync_keys()},
+                )
             # keep the host committed twin coherent with the rows just
             # written
             self._committed_cache[rows] = (
                 self.mirror.arrays["committed"][rows]
             )
             self._dirty.clear()
-
-    def _pad(self, events, width):
-        cap = self.event_cap
-        n = len(events)
-        g = np.zeros((cap,), np.int32)
-        p = np.zeros((cap,), np.int32)
-        v = np.zeros((cap,), np.int32 if width == 3 else np.int8)
-        valid = np.zeros((cap,), bool)
-        if n:
-            cols = np.array(events, dtype=np.int64).T
-            g[:n] = cols[0]
-            p[:n] = cols[1]
-            v[:n] = cols[2]
-            valid[:n] = True
-        return g, p, v, valid
 
     def step(self, do_tick: bool = True) -> StepResult:
         """Run one fused device dispatch over all pending events.
@@ -3216,17 +3156,25 @@ class BatchedQuorumEngine:
                 ack_g, ack_p, ack_v, self._votes, do_tick, reads, racks,
                 kvents, kvreads, has_kv=has_kv,
             )
+            planes = (has_reads, has_kv)
         else:
+            planes = (False, False)  # the sparse program carries neither
             pos = 0
             n_chunks = 0
             while (ack_g.size - pos) > self.event_cap or len(self._votes) > self.event_cap:
                 take = min(self.event_cap, ack_g.size - pos)
-                self._dispatch(
+                chunk = self._dispatch(
                     (ack_g[pos : pos + take], ack_p[pos : pos + take],
                      ack_v[pos : pos + take]),
                     self._votes[: self.event_cap],
                     False,
                 )
+                # the next chunk restages the ingress buffer this one
+                # was put from: not before the program has read it
+                jax.block_until_ready(chunk.egress)
+                if obs is not None:
+                    self._n_retired += 1  # this chunk's egress, unread
+                del chunk
                 pos += take
                 n_chunks += 1
                 del self._votes[: self.event_cap]
@@ -3273,39 +3221,26 @@ class BatchedQuorumEngine:
                     int(self._read_busy.sum())
                     if self._read_plane_used else None
                 ),
+                **self._take_array_counts(),
             )
             self.last_span_seq = span["seq"]
             t_eg = time.perf_counter()
 
         res = StepResult()
-        # one batched device→host transfer for the whole egress set (a
+        # ONE device→host transfer for the whole egress set (a
         # network-attached chip pays the full round trip per readback)
-        with (obs.phase("egress_wait") if obs is not None else _OFF):
-            (
-                committed, won, lost, elect, hb, demote, rdc, rdi,
-                kvv, kvi, kva,
-            ) = jax.device_get(
-                (
-                    out.committed,
-                    out.won,
-                    out.lost,
-                    out.flags.elect_due,
-                    out.flags.hb_due,
-                    out.flags.checkq_demote,
-                    out.read_done_count,
-                    out.read_done_index,
-                    out.kv_read_val,
-                    out.kv_read_index,
-                    out.kv_applied,
-                )
-            )
+        egress, telem = out.egress, out.telem
+        del out
+        eg = self._fetch_egress(egress)
+        del egress
         with (obs.phase("decode") if obs is not None else _OFF):
-            if out.telem is not None:
+            if telem is not None:
                 # deferred readback: stage the device aggregate, pull it
                 # at snapshot (sampler) cadence, not dispatch cadence
-                self._stage_telem(
-                    out.telem, self._row_cid.copy(), rounds=1
-                )
+                self._stage_telem(telem, self._row_cid.copy(), rounds=1)
+            committed, bits, rdc, rdi, kvv, kvi, kva = _pk.split_egress(
+                eg, self._dims, *planes
+            )
             if rdc is not None:
                 self._translate_reads(
                     res, rdc, rdi, self._row_cid, self._row_base
@@ -3323,13 +3258,12 @@ class BatchedQuorumEngine:
                 self._kv_free_applied()
             changed = self._translate_egress(
                 res, committed, prev_committed, self._row_cid,
-                self._row_base,
-                (("won", won), ("lost", lost), ("elect", elect),
-                 ("heartbeat", hb), ("demote", demote)),
+                self._row_base, bits,
             )
         if obs is not None:
             obs.egress(
                 span,
+                arrays_retired=1,
                 egress_ms=(time.perf_counter() - t_eg) * 1e3,
                 egress_rows=int(changed.size),
                 reads_released=(
@@ -3380,181 +3314,178 @@ class BatchedQuorumEngine:
             np.concatenate([p[2] for p in parts]),
         )
 
-    def _pad_ack_arrays(self, g, p, v):
-        cap = self.event_cap
-        n = g.size
-        og = np.zeros((cap,), np.int32)
-        op = np.zeros((cap,), np.int32)
-        ov = np.zeros((cap,), np.int32)
-        valid = np.zeros((cap,), bool)
-        if n:
-            og[:n] = g
-            op[:n] = p
-            ov[:n] = v
-            valid[:n] = True
-        return og, op, ov, valid
+    def _ingress_for(self, kind: str, **layout) -> _pk.Ingress:
+        """The host buffer a dispatch of this shape stages into, every
+        section back at its nothing-staged fill.  Made on first use and
+        restaged in place from then on: the caller has fetched the egress
+        of the last program that read it (the sparse chunk loop blocks
+        on it instead)."""
+        key = self._ingress_key(kind, layout)
+        # a shape outside the warm plan (a churn width, a K of a bench's
+        # own) shares one slot with the others of its kind: the buffers
+        # held never outgrow the plan's plus one a kind
+        slot = key if key in self._ingress_keep else kind
+        held = self._ingress.get(slot)
+        if held is not None and held[0] == key:
+            held[1].reset()
+            return held[1]
+        ing = _pk.Ingress(_pk.ingress_sections(
+            kind, self.n_groups, self.n_peers, self._dims, **layout
+        ))
+        self._ingress[slot] = (key, ing)
+        return ing
+
+    @staticmethod
+    def _ingress_key(kind: str, layout: dict) -> tuple:
+        return (kind,) + tuple(sorted(layout.items()))
+
+    def _launch(self, fn, ing: _pk.Ingress, **statics) -> _pk.PackedOut:
+        """Put the staged ingress block (ONE array made), run ``fn`` on
+        the donated state blocks and keep their successors.  What the
+        launch retires — the old blocks, the ingress block — is dropped
+        here, in a phase of its own, so the time has a name."""
+        obs = self._obs
+        # the host->device put, in a block of its own (ROADMAP A1)
+        with (obs.phase("transfer") if obs is not None else _OFF):
+            dev_in = jax.device_put(ing.buf)
+        with (obs.phase("launch") if obs is not None else _OFF):
+            out = fn(
+                self._blk, dev_in, **self._engine_statics(), **statics
+            )
+        with (obs.phase("retire") if obs is not None else _OFF):
+            self._blk = out.blocks
+            del dev_in
+        if obs is not None:
+            self._n_made += 1
+            self._n_retired += len(StateBlocks._fields) + 1
+        return out
+
+    def _take_array_counts(self) -> dict:
+        """``arrays_made`` (by put) and ``arrays_retired`` (state blocks,
+        ingress blocks, unread egress) since the last span, zeroed."""
+        made, self._n_made = self._n_made, 0
+        retired, self._n_retired = self._n_retired, 0
+        return {"arrays_made": made, "arrays_retired": retired}
+
+    def _stage_sparse(self, acks, votes, has_votes: bool) -> _pk.Ingress:
+        """The event lists of one sparse dispatch in its ingress block:
+        ``acks`` as ``_gather_acks`` hands them, ``votes`` the staged
+        (row, slot, grant) tuples."""
+        ing = self._ingress_for(
+            "sparse", cap=self.event_cap, has_votes=has_votes
+        )
+        v = ing.views
+        ag, ap, av = acks
+        n = ag.size
+        v["ack_g"][:n] = ag
+        v["ack_p"][:n] = ap
+        v["ack_val"][:n] = av
+        v["n"][0] = n
+        if votes:
+            cols = np.array(votes, dtype=np.int64).T
+            n = cols.shape[1]
+            v["vote_g"][:n] = cols[0]
+            v["vote_p"][:n] = cols[1]
+            v["vote_grant"][:n] = cols[2]
+            v["n"][1] = n
+        return ing
 
     def _dispatch(self, acks, votes, do_tick: bool):
         obs = self._obs
+        # vote-free round: the has_votes=False variant compiles the vote
+        # scatter out entirely and its ingress has no vote lists
+        has_votes = bool(votes)
         with (obs.phase("stage") if obs is not None else _OFF):
-            if isinstance(acks, tuple):
-                ag, ap, av, avalid = self._pad_ack_arrays(*acks)
-            else:
-                ag, ap, av, avalid = self._pad(acks, 3)
-            if votes:
-                vg, vp, vv, vvalid = self._pad(votes, 1)
-            else:
-                # vote-free round: the has_votes=False variant compiles
-                # the vote scatter out entirely; the args are unused
-                # dummies
-                vg = vp = np.zeros((1,), np.int32)
-                vv = np.zeros((1,), np.int8)
-                vvalid = np.zeros((1,), bool)
+            ing = self._stage_sparse(acks, votes, has_votes)
             if obs is not None:
                 # accumulated: an oversized backlog runs several chunked
                 # dispatches per step and the span must account them all
-                self._obs_upload += upload_nbytes(
-                    ag, ap, av, avalid, vg, vp, vv, vvalid
-                )
-        # the host->device puts, in a block of their own (ROADMAP A1)
-        with (obs.phase("transfer") if obs is not None else _OFF):
-            args = (
-                jnp.asarray(ag),
-                jnp.asarray(ap),
-                jnp.asarray(av),
-                jnp.asarray(avalid),
-                jnp.asarray(vg),
-                jnp.asarray(vp),
-                jnp.asarray(vv, dtype=jnp.int8),
-                jnp.asarray(vvalid),
-            )
-        with (obs.phase("launch") if obs is not None else _OFF):
-            out = quorum_step(
-                self.dev,
-                *args,
-                do_tick=do_tick,
-                # ticking rounds must track contact even on a
-                # device_ticks=False engine (defensive: a stray
-                # do_tick=True call would otherwise consume one-shot
-                # contact acks without the reset)
-                track_contact=self.device_ticks or do_tick,
-                has_votes=bool(votes),
-                has_hier=self._hier_used,
-                has_telem=self._telem_used,
-                telem_k=self.n_telem_topk,
-                # occupancy hints for the telem fold only — this path
-                # never carries read/kv event planes
-                has_reads=self._read_plane_used,
-                has_kv=self._devsm_used,
-            )
-        self._dev = out.state
+                self._obs_upload += upload_nbytes(ing.buf)
+        out = self._launch(
+            _pk.quorum_step,
+            ing,
+            cap=self.event_cap,
+            do_tick=do_tick,
+            # ticking rounds must track contact even on a
+            # device_ticks=False engine (defensive: a stray
+            # do_tick=True call would otherwise consume one-shot
+            # contact acks without the reset)
+            track_contact=self.device_ticks or do_tick,
+            has_votes=has_votes,
+            **self._fold_hints(),
+        )
         dp = self._devprof
         if dp is not None:
-            dp.note_dispatch("sparse", out.committed, rounds=1, live_rounds=1)
+            dp.note_dispatch("sparse", out.egress, rounds=1, live_rounds=1)
         return out
 
     def _dispatch_dense(
         self, ag, ap, av, votes, do_tick: bool, reads=None, racks=None,
         kvents=None, kvreads=None, has_kv=None,
     ):
-        """Aggregate a round's events into (G,P) matrices and run the
-        scatter-free dense kernel (kernels.quorum_step_dense_impl).
-        ``reads``/``racks`` are the round's gathered read-plane buffers
-        (``_gather_reads`` shape) and ``kvents``/``kvreads`` the devsm
-        buffers (``_gather_kv`` shape); both planes live only on this
-        kernel — step() forces dense whenever they are present."""
-        from .kernels import quorum_step_dense
-
+        """Aggregate a round's events into the (G,P) planes of ONE
+        ingress block and run the scatter-free dense kernel
+        (kernels.quorum_step_dense_impl).  ``reads``/``racks`` are the
+        round's gathered read-plane buffers (``_gather_reads`` shape) and
+        ``kvents``/``kvreads`` the devsm buffers (``_gather_kv`` shape);
+        both planes live only on this kernel — step() forces dense
+        whenever they are present."""
         obs = self._obs
         with (obs.phase("stage") if obs is not None else _OFF):
-            g, p = self.n_groups, self.n_peers
-            ack_max = np.zeros((g, p), np.int32)
-            touched = np.zeros((g, p), bool)
-            if ag.size:
-                # max-aggregation == scatter-max: order-independent,
-                # exact.  Flat 1-D indexing keeps ufunc.at on numpy's
-                # contiguous fast path (the 2-D tuple form is several×
-                # slower at the very occupancies that select the dense
-                # path).
-                cell = ag.astype(np.int64) * p + ap
-                np.maximum.at(ack_max.reshape(-1), cell, av)
-                touched.reshape(-1)[cell] = True
-            if votes:
-                vote_new = np.full((g, p), VOTE_NONE, np.int8)
-                cols = np.array(votes, dtype=np.int64).T
-                vote_new[cols[0], cols[1]] = cols[2].astype(np.int8)
-            else:
-                vote_new = np.zeros((1, 1), np.int8)  # unused dummy
+            p = self.n_peers
+            has_votes = bool(votes)
             has_reads = reads is not None or racks is not None
-            read_np = (None, None, None)
-            if has_reads:
-                s = self.n_read_slots
-                stage_idx = np.full((g, s), -1, np.int32)
-                stage_cnt = np.zeros((g, s), np.int32)
-                echo = np.zeros((g, s, p), bool)
-                if reads is not None and reads[0].size:
-                    rr, sl, v, c = reads
-                    stage_idx[rr, sl] = v
-                    stage_cnt[rr, sl] = c
-                if racks is not None and racks[0].size:
-                    rr, sl, pe = racks
-                    echo[rr, sl, pe] = True
-                read_np = (stage_idx, stage_cnt, echo)
             if has_kv is None:
                 has_kv = kvents is not None or kvreads is not None
-            kv_np = (None, None, None, None)
-            if has_kv:
-                e, rk = self.n_kv_ents, self.n_kv_reads
-                kv_ei = np.full((g, e), -1, np.int32)
-                kv_ek = np.zeros((g, e), np.int32)
-                kv_ev = np.zeros((g, e), np.int32)
-                kv_rk = np.full((g, rk), -1, np.int32)
-                if kvents is not None and kvents[0].size:
-                    rr, sl, rel, key, val = kvents
-                    kv_ei[rr, sl] = rel
-                    kv_ek[rr, sl] = key
-                    kv_ev[rr, sl] = val
-                if kvreads is not None and kvreads[0].size:
-                    rr, sl, key = kvreads
-                    kv_rk[rr, sl] = key
-                kv_np = (kv_ei, kv_ek, kv_ev, kv_rk)
-            if obs is not None:
-                # the exact kernel argument tuple (upload_nbytes docstring)
-                self._obs_upload += upload_nbytes(
-                    ack_max, touched, vote_new, *read_np, *kv_np
-                )
-        # the host->device puts, in a block of their own (ROADMAP A1)
-        with (obs.phase("transfer") if obs is not None else _OFF):
-            args = (
-                jnp.asarray(ack_max),
-                jnp.asarray(touched),
-                jnp.asarray(vote_new),
-            )
-            read_args = tuple(
-                None if a is None else jnp.asarray(a) for a in read_np
-            )
-            kv_args = tuple(
-                None if a is None else jnp.asarray(a) for a in kv_np
-            )
-        with (obs.phase("launch") if obs is not None else _OFF):
-            out = quorum_step_dense(
-                self.dev,
-                *args,
-                *read_args,
-                *kv_args,
-                do_tick=do_tick,
-                track_contact=self.device_ticks or do_tick,
-                has_votes=bool(votes),
-                has_reads=has_reads,
+            ing = self._ingress_for(
+                "dense", has_votes=has_votes, has_reads=has_reads,
                 has_kv=has_kv,
-                has_hier=self._hier_used,
-                has_telem=self._telem_used,
-                telem_k=self.n_telem_topk,
             )
-        self._dev = out.state
+            v = ing.views
+            if ag.size:
+                # max-aggregation == scatter-max: order-independent,
+                # exact; -1 = untouched (touched cells hold rel >= 0).
+                # Flat 1-D indexing keeps ufunc.at on numpy's contiguous
+                # fast path (the 2-D tuple form is several× slower at the
+                # very occupancies that select the dense path).
+                np.maximum.at(
+                    v["ack"].reshape(-1), ag.astype(np.int64) * p + ap, av
+                )
+            if has_votes:
+                cols = np.array(votes, dtype=np.int64).T
+                v["votes"][cols[0], cols[1]] = cols[2]
+            if reads is not None and reads[0].size:
+                rr, sl, val, c = reads
+                v["read_idx"][rr, sl] = val
+                v["read_cnt"][rr, sl] = c
+            if racks is not None and racks[0].size:
+                rr, sl, pe = racks
+                np.bitwise_or.at(
+                    v["read_echo"], (rr, sl), np.left_shift(1, pe)
+                )
+            if kvents is not None and kvents[0].size:
+                rr, sl, rel, key, val = kvents
+                v["kv_idx"][rr, sl] = rel
+                v["kv_key"][rr, sl] = key
+                v["kv_val"][rr, sl] = val
+            if kvreads is not None and kvreads[0].size:
+                rr, sl, key = kvreads
+                v["kv_rkey"][rr, sl] = key
+            if obs is not None:
+                # exactly what the program receives (upload_nbytes docstring)
+                self._obs_upload += upload_nbytes(ing.buf)
+        out = self._launch(
+            _pk.quorum_step_dense,
+            ing,
+            do_tick=do_tick,
+            track_contact=self.device_ticks or do_tick,
+            has_votes=has_votes,
+            has_reads=has_reads,
+            has_kv=has_kv,
+        )
         dp = self._devprof
         if dp is not None:
-            dp.note_dispatch("dense", out.committed, rounds=1, live_rounds=1)
+            dp.note_dispatch("dense", out.egress, rounds=1, live_rounds=1)
         return out
 
     # ------------------------------------------------------------------
@@ -3565,11 +3496,7 @@ class BatchedQuorumEngine:
         """Field value at a row: pending mirror edits win over device —
         including a staged in-program recycle, whose mirror row is the
         post-recycle truth while the device still holds the old tenant."""
-        self._harvest_inflight()
-        if row in self._dirty or row in self._churn_pending:
-            return self.mirror.arrays[field_name][row]
-        with self._dispatch_mu:  # the gather is a multi-device program
-            return np.asarray(getattr(self.dev, field_name)[row])
+        return self.read_rows(field_name, [row])[0]
 
     def read_rows(self, field_name: str, rows) -> np.ndarray:
         """``_read`` for many rows from at most one device gather

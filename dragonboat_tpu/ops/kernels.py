@@ -38,6 +38,7 @@ breaks.
 """
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import NamedTuple
 
@@ -673,8 +674,22 @@ def _finish_step(
     return StepOutputs(st, committed, won, lost, flags)
 
 
+def _entry(impl, name: str):
+    """``impl`` as it is, under a program name of its own: the entries
+    below take a ``QuorumState`` and separate event arrays (tests, the
+    benches, the ladder harnesses); the engine's programs
+    (``ops/packed.py``) carry the kernels' names in a device trace."""
+
+    @functools.wraps(impl)
+    def entry(*args, **kwargs):
+        return impl(*args, **kwargs)
+
+    entry.__name__ = entry.__qualname__ = name
+    return entry
+
+
 quorum_step = jax.jit(
-    quorum_step_impl,
+    _entry(quorum_step_impl, "quorum_step_unpacked"),
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_hier", "has_telem",
         "telem_k", "has_reads", "has_kv",
@@ -790,7 +805,7 @@ def quorum_step_dense_impl(
 
 
 quorum_step_dense = jax.jit(
-    quorum_step_dense_impl,
+    _entry(quorum_step_dense_impl, "quorum_step_dense_unpacked"),
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_reads", "has_kv",
         "has_hier", "has_telem", "telem_k",
@@ -1254,7 +1269,7 @@ def quorum_multiround_impl(
 
 
 quorum_multiround = jax.jit(
-    quorum_multiround_impl,
+    _entry(quorum_multiround_impl, "quorum_multiround_unpacked"),
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_churn", "has_reads",
         "purge_reads", "has_kv", "purge_kv", "has_hier", "has_telem",

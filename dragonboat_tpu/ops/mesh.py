@@ -632,9 +632,10 @@ class MeshQuorumEngine:
         from .sharding import state_sharding
 
         shardings = state_sharding(self.mesh)
+        views = [s.dev for s in self.shards]  # each shard's blocks, unpacked
         fields = {}
         for name in QuorumState._fields:
-            pieces = [getattr(s._dev, name) for s in self.shards]
+            pieces = [getattr(v, name) for v in views]
             global_shape = (self.n_groups,) + tuple(pieces[0].shape[1:])
             fields[name] = jax.make_array_from_single_device_arrays(
                 global_shape, getattr(shardings, name), pieces

@@ -7,9 +7,9 @@ point of ``ops/kernels.py`` is compiled at the BASELINE ladder's size
 (1,024 x 8), with the static flags the live path turns on
 (``has_reads`` / ``has_kv`` / ``has_telem`` / ``has_hier``); one mesh case
 partitions the state over the four described devices.  The three live
-entry points take their arguments from the engine's own
-``_variant_args`` builder, so what compiles here is what the coordinator
-dispatches.
+programs (``ops/packed.py``: the state as blocks, one ingress block) take
+their ingress and statics from the engine's own ``_variant_args``
+builder, so what compiles here is what the coordinator dispatches.
 
 The topology is described inside a module-scoped fixture: only one
 process may load the TPU library, so it must never happen at import (every
@@ -35,8 +35,8 @@ from jax.sharding import (  # noqa: E402
 
 from dragonboat_tpu.ops import kernels  # noqa: E402
 from dragonboat_tpu.ops.engine import BatchedQuorumEngine  # noqa: E402
-from dragonboat_tpu.ops.sharding import GROUP_AXIS, state_sharding  # noqa: E402
-from dragonboat_tpu.ops.state import make_state  # noqa: E402
+from dragonboat_tpu.ops.sharding import GROUP_AXIS, block_sharding  # noqa: E402
+from dragonboat_tpu.ops.state import make_state, pack_state  # noqa: E402
 
 #: (groups, peers): BASELINE.json's ladder size, and tpuquorum.py's default
 SIZES = {"ladder": (65536, 5), "live": (1024, 8)}
@@ -93,6 +93,13 @@ def _state(groups, peers, sharding):
     return _on(sharding, jax.eval_shape(lambda: make_state(groups, peers)))
 
 
+def _blocks(groups, peers, sharding):
+    """The packed carry the engine's programs take, as shapes."""
+    return _on(sharding, jax.eval_shape(
+        lambda: pack_state(make_state(groups, peers))
+    ))
+
+
 def _engine(groups, peers):
     """A live-shaped engine (the coordinator's event_cap rule) with the
     telemetry and hier latches up, as a fully featured NodeHost runs it."""
@@ -119,11 +126,13 @@ def _compile(fn, st, args, statics):
 def test_live_program_compiles_for_v5e(topo, no_compile_cache, size, variant):
     groups, peers = SIZES[size]
     one_chip = SingleDeviceSharding(topo.devices[0])
-    fn, args, statics = _engine(groups, peers)._variant_args(
+    fn, ing, statics = _engine(groups, peers)._variant_args(
         *variant, abstract=True
     )
     assert statics["has_telem"] and statics["has_hier"]
-    _compile(fn, _state(groups, peers, one_chip), _on(one_chip, args), statics)
+    _compile(
+        fn, _blocks(groups, peers, one_chip), (_on(one_chip, ing),), statics
+    )
 
 
 def _multistep_args(groups, peers, rounds, dense):
@@ -160,28 +169,24 @@ def test_group_sharded_program_compiles_for_v5e_2x2(topo, no_compile_cache):
     groups, peers = 4 * SIZES["ladder"][0], SIZES["ladder"][1]
     mesh = Mesh(np.array(topo.devices), (GROUP_AXIS,))
     assert mesh.devices.size == 4
-    st = jax.tree_util.tree_map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        jax.eval_shape(lambda: make_state(groups, peers)),
-        state_sharding(mesh),
+    # the blocks stack leaves on a leading axis: the leaves' P(groups)
+    # moves one axis to the right
+    st = _blocks(
+        groups, peers, block_sharding(NamedSharding(mesh, P(GROUP_AXIS)))
     )
-    fn, args, statics = _engine(groups, peers)._variant_args(
+    fn, ing, statics = _engine(groups, peers)._variant_args(
         "fused", 16, True, False, abstract=True
     )
-    replicated = NamedSharding(mesh, P())
-    by_group = NamedSharding(mesh, P(None, GROUP_AXIS))
-    args = tuple(
-        None if a is None else jax.ShapeDtypeStruct(
-            a.shape, a.dtype,
-            sharding=by_group
-            if a.ndim >= 2 and a.shape[1] == groups else replicated,
-        )
-        for a in args
+    # the ingress block is one flat host buffer: replicated
+    compiled = _compile(
+        fn, st, (_on(NamedSharding(mesh, P()), ing),), statics
     )
-    compiled = _compile(fn, st, args, statics)
-    per_device = compiled.memory_analysis().argument_size_in_bytes
+    # what a device holds of the arguments, less the replicated ingress
+    per_device = compiled.memory_analysis().argument_size_in_bytes - (
+        int(np.prod(ing.shape)) * np.dtype(ing.dtype).itemsize
+    )
     whole = sum(
         int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
         for s in jax.tree_util.tree_leaves(st)
     )
-    assert per_device < whole, "state was not partitioned across the mesh"
+    assert per_device <= whole // 3, "state was not partitioned across the mesh"

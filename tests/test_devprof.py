@@ -121,7 +121,7 @@ def _lead(eng, cid=1, n=3):
 
 def _live_plane_bytes(eng):
     planes = {}
-    for name, arr in eng._dev._asdict().items():
+    for name, arr in eng.dev._asdict().items():
         p = field_plane(name)
         planes[p] = planes.get(p, 0) + int(arr.nbytes)
     return planes
@@ -296,9 +296,10 @@ def test_predict_dispatch_term_matches_variant_spec_all_planes():
     k = max(WARM_K_BUCKETS)
     for ir in (False, True):
         for ik in (False, True):
-            _, args, _ = eng._variant_args(
+            _, ing, _ = eng._variant_args(
                 "fused", k, ir, ik, abstract=True
             )
+            args = (ing,)
             pred = predict_bytes(
                 g, p, k_bucket=k, include_reads=ir, include_kv=ik
             )

@@ -610,6 +610,46 @@ def test_dispatch_phases_make_up_dispatch_and_egress():
     assert spans[0]["row_sync_ms"] > 0
 
 
+@pytest.mark.parametrize("reads", [False, True], ids=["writes", "reads"])
+def test_steady_state_step_makes_one_array_and_retires_few(reads):
+    """A steady-state step of a single-device engine puts ONE array (its
+    ingress block; the read plane rides in it) and retires the four state
+    blocks, the ingress block and the egress block — under 12, where the
+    31-leaf state, eight puts and six flag vectors made it ~53 — and the
+    explicit drops have a phase, ``retire_ms``.  The six older phase
+    fields all stay on the span: ``engine_other_ms`` reads ``step_ms``
+    less those six, and so still contains ``retire_ms``."""
+    rec = FlightRecorder(stall_ms=0)
+    eng = _leader_engine(rec)
+    idx = 1
+    for i in range(12):
+        idx += 1
+        for cid in range(1, 9):
+            eng.ack(cid, 1, idx)
+            eng.ack(cid, 2, idx)
+        if reads:
+            slot = eng.stage_read(1 + i % 8, count=2)
+            eng.read_ack(1 + i % 8, 2, slot)
+        if i % 3 == 2:  # the fused path too
+            eng.begin_round()
+            res = eng.step_rounds(do_tick=True, pad_rounds_to=4)
+        else:
+            res = eng.step(do_tick=i % 2 == 0)
+        assert res.commit and (not reads or res.reads)
+    spans = [s for s in rec.spans() if s["kind"] in ("dispatch", "fused")]
+    assert {s["kind"] for s in spans} == {"dispatch", "fused"}
+    older = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms",
+             "egress_wait_ms", "decode_ms")
+    for s in spans[1:]:  # the first also uploads the registrations
+        assert s["arrays_made"] == 1, s
+        assert s["arrays_retired"] == 6, s
+        assert s["arrays_made"] + s["arrays_retired"] < 12
+        assert s["retire_ms"] >= 0.0
+        assert all(s[k] is not None and s[k] >= 0.0 for k in older), s
+        named = sum(s[k] for k in older) + s["retire_ms"]
+        assert named <= s["step_ms"] + 0.01, s
+
+
 class _FakeNode:
     """The surface the coordinator's fan-out drives."""
 
@@ -628,12 +668,13 @@ class _FakeNode:
         self.echoes.append((node_id, low, high))
 
 
-def _coord_with_leaders(cids, rec=None, host="coordhost"):
+def _coord_with_leaders(cids, rec=None, host="coordhost", capacity=8):
     from dragonboat_tpu.raft import InMemLogDB
     from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
     from tests.raft_harness import new_test_raft
 
-    coord = TpuQuorumCoordinator(capacity=8, n_peers=4, drive_ticks=False)
+    coord = TpuQuorumCoordinator(capacity=capacity, n_peers=4,
+                                 drive_ticks=False)
     if rec is not None:
         coord.enable_obs(recorder=rec, registry=MetricsRegistry(),
                          host=host)
@@ -850,7 +891,9 @@ def test_profiler_capture_holds_the_same_spans_as_the_ring(tmp_path):
     import jax
 
     rec = FlightRecorder(stall_ms=0)
-    coord, nodes = _coord_with_leaders([3, 4, 5], rec)
+    # 16,384 rows make a round ~2 ms here, so that 5% governs the round's
+    # comparison: its annotation holds eleven nested ones (~30-50 us)
+    coord, nodes = _coord_with_leaders([3, 4, 5], rec, capacity=16384)
     try:
         for _ in range(6):  # every program compiled before the capture
             for cid in nodes:

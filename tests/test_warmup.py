@@ -63,8 +63,8 @@ def test_second_enable_is_cache_hot(tmp_path):
     assert stats["error"] is None
     assert eng.fused_ready
     # 2 fused (reads on/off) + 2 sparse + 2 sparse-votes (tick on/off)
-    # + 2 dense + 2 dense-votes + 2 dense read
-    assert stats["programs"] == 12
+    # + 2 dense + 2 dense-votes + 2 dense read + 2 dense-votes read
+    assert stats["programs"] == 14
     s1 = compilation_cache_stats()
     assert s1["misses"] > s0["misses"], "cold warmup must populate the cache"
 
@@ -107,6 +107,7 @@ class FakeNode:
         self.peer = _P()
         self.peer.raft = raft
         self.commits = []
+        self.confirms = []
 
     def offload_commit(self, q):
         r = self.peer.raft
@@ -115,6 +116,12 @@ class FakeNode:
                 self.commits.append(q)
 
     def offload_election(self, won, term):
+        pass
+
+    def offload_read_confirm(self, low, high, term):
+        self.confirms.append((low, high, term))
+
+    def offload_read_echo(self, node_id, low, high):
         pass
 
     def offload_tick_elect(self):
@@ -128,14 +135,19 @@ class FakeNode:
 
 
 def _mk_coord_cluster(n_groups=4, warm=False):
-    from dragonboat_tpu.raft import InMemLogDB
     from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
-    from tests.raft_harness import new_test_raft
 
     coord = TpuQuorumCoordinator(
         capacity=64, n_peers=4, drive_ticks=True, interval_s=60.0,
         warm_fused=warm,
     )
+    return coord, _register_leaders(coord, n_groups)
+
+
+def _register_leaders(coord, n_groups):
+    from dragonboat_tpu.raft import InMemLogDB
+    from tests.raft_harness import new_test_raft
+
     nodes = {}
     for g in range(n_groups):
         cid = 1 + g
@@ -150,7 +162,7 @@ def _mk_coord_cluster(n_groups=4, warm=False):
         with coord._mu:
             coord._sync_row_locked(n)
     coord.flush()
-    return coord, nodes
+    return nodes
 
 
 def _drive_round(coord, nodes, ticks=4):
@@ -255,3 +267,147 @@ def test_warmup_metrics_published():
     spans = [s for s in eng._obs.recorder.spans() if s["kind"] == "warmup"]
     assert len(spans) == 2
     assert all("compile_ms" in s and not s.get("stalled") for s in spans)
+
+
+def test_warm_plan_is_every_program_a_live_coordinator_dispatches():
+    """The plan, spelled out: the fused block per K bucket and the three
+    single-round kinds with and without votes, each with and without the
+    read plane where the kernel carries it — every one a packed program
+    (``ops/packed.py``: blocks in, one ingress block, one egress block)."""
+    from dragonboat_tpu.ops import packed
+
+    eng = BatchedQuorumEngine(16, 4, event_cap=64)
+    plan = eng.warm_plan()
+    assert len(plan) == len(set(plan))
+    assert set(plan) == (
+        {("fused", k, hr, False) for k in WARM_K_BUCKETS
+         for hr in (False, True)}
+        | {(kind, dt, False, False) for kind in ("sparse", "sparse_votes")
+           for dt in (False, True)}
+        | {(kind, dt, hr, False) for kind in ("dense", "dense_votes")
+           for dt in (False, True) for hr in (False, True)}
+    )
+    programs = {
+        "fused": packed.quorum_multiround, "sparse": packed.quorum_step,
+        "dense": packed.quorum_step_dense,
+    }
+    for kind, arg, hr, kv in plan + eng.warm_plan(include_kv=True)[-8:]:
+        fn, ing, statics = eng._variant_args(kind, arg, hr, kv)
+        assert fn is programs[kind.split("_")[0]], kind
+        # ONE ingress block, laid out by the rule the live path stages by
+        assert ing.ndim == 1 and ing.dtype == "int32"
+        assert statics["dims"] == eng._dims
+
+
+def test_warm_coordinator_compiles_nothing_on_first_use():
+    """A cold coordinator that has finished warming compiles nothing on
+    the round thread: not at registration, its first write, its first
+    tick backlog, its first read, its first election, nor at an election
+    while reads are pending (``compilation_log`` names what would)."""
+    from dragonboat_tpu.ops.engine import compilation_log
+    from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
+    from dragonboat_tpu.wire import Entry
+
+    jax.clear_caches()  # the in-process twin of a cold start
+    coord = TpuQuorumCoordinator(
+        capacity=64, n_peers=4, drive_ticks=True, interval_s=60.0,
+    )
+    try:
+        t = coord.start_warmup()
+        t.join(timeout=600)
+        assert coord.eng.fused_ready, coord.warmup_stats
+        warmed = len(compilation_log())
+        assert warmed > 0
+
+        def fresh():
+            return [(e[2], e[3]) for e in compilation_log()[warmed:]]
+
+        nodes = _register_leaders(coord, 4)
+        assert fresh() == [], "registration"
+        _drive_round(coord, nodes, ticks=0)     # sparse, no tick
+        _drive_round(coord, nodes, ticks=1)     # sparse, tick
+        assert fresh() == [], "first write"
+        before = coord.fused_dispatches
+        _drive_round(coord, nodes, ticks=3)     # fused K=4
+        _drive_round(coord, nodes, ticks=9)     # fused K=16
+        assert coord.fused_dispatches == before + 2
+        assert fresh() == [], "first tick backlog"
+
+        def read(cid, ctx, ticks):
+            r = nodes[cid].peer.raft
+            coord.read_stage(cid, r.log.committed, ctx, ctx, r.term)
+            coord.read_ack_hint(cid, 2, ctx, ctx)
+            coord.read_ack_hint(cid, 3, ctx, ctx)
+            for _ in range(ticks):
+                coord.request_tick()
+            coord.flush()
+
+        read(1, 101, ticks=0)                   # dense reads, no tick
+        read(1, 102, ticks=1)                   # dense reads, tick
+        read(2, 103, ticks=5)                   # fused reads
+        assert len(nodes[1].confirms) == 2 and nodes[2].confirms
+        assert fresh() == [], "first read"
+
+        def campaign(cid, ticks):
+            r = nodes[cid].peer.raft
+            coord.set_candidate(cid, r.term + 1)
+            coord.vote(cid, 2, True)
+            coord.vote(cid, 3, True)
+            for _ in range(ticks):
+                coord.request_tick()
+            coord.flush()
+            coord.set_leader(cid, r.term + 1, r.log.last_index(),
+                             r.log.last_index())
+            coord.flush()
+
+        campaign(3, ticks=0)                    # sparse votes, no tick
+        campaign(4, ticks=1)                    # sparse votes, tick
+        assert fresh() == [], "first election"
+        # a campaign while another group's read is pending: dense + votes
+        r = nodes[1].peer.raft
+        coord.read_stage(1, r.log.committed, 104, 104, r.term)
+        coord.read_ack_hint(1, 2, 104, 104)
+        campaign(3, ticks=0)
+        coord.read_stage(1, r.log.committed, 105, 105, r.term)
+        coord.read_ack_hint(1, 2, 105, 105)
+        campaign(4, ticks=1)
+        assert fresh() == [], "election with reads pending"
+        for n in nodes.values():
+            with n.raft_mu:
+                n.peer.raft.append_entries([Entry(cmd=b"w")])
+        _drive_round(coord, nodes, ticks=2)
+        assert fresh() == [], "after it all"
+        # ... and staged every one of them into a buffer the plan keeps
+        assert set(coord.eng._ingress) <= coord.eng._ingress_keep
+        assert len(coord.eng._ingress) >= 6
+    finally:
+        coord.stop()
+
+
+def test_ingress_buffers_held_are_the_warm_plan_and_one_a_kind():
+    """The host buffers an engine keeps for staging are bounded: one per
+    shape the warm plan holds (what a live coordinator dispatches), and
+    for every other shape — a bench's churn widths, a K of its own — the
+    last-used one of its kind."""
+    eng = BatchedQuorumEngine(64, 4, event_cap=64)
+    fused = dict(has_votes=False, do_tick=True, has_reads=False,
+                 has_kv=False)
+    for c in (1, 2, 4, 8):
+        for k in (4, 16):
+            eng._ingress_for("fused", k=k, c=c, has_churn=True, **fused)
+    assert set(eng._ingress) == {"fused"}
+    a = eng._ingress_for("sparse", cap=64, has_votes=False)
+    a.views["n"][0] = 5
+    b = eng._ingress_for("sparse", cap=64, has_votes=False)
+    assert b is a and b.views["n"][0] == 0  # restaged in place
+    assert eng._ingress_for("sparse", cap=64, has_votes=True) is not a
+    assert set(eng._ingress) == {"fused", "sparse"}
+
+    eng.warmup_fused(k_buckets=(4,), include_reads=False,
+                     include_single=False, background=False)
+    assert len(eng._ingress_keep) == 1
+    x = eng._ingress_for("fused", k=4, c=0, has_churn=False, **fused)
+    eng._ingress_for("fused", k=4, c=8, has_churn=True, **fused)
+    assert eng._ingress_for("fused", k=4, c=0, has_churn=False,
+                            **fused) is x  # the plan's own is kept
+    assert len(eng._ingress) == 3
