@@ -6,8 +6,7 @@ PY ?= python
 .PHONY: test test-all test-kernels test-obs test-trace test-warmup \
 	test-hostplane test-hostproc test-lease test-devsm test-health \
 	test-repltrace test-devprof test-mesh test-recovery test-hiercommit \
-	native soak soak-smoke soak-churn soak-churn-smoke \
-	bench dryrun perf-ledger perf-ledger-check
+	native soak soak-smoke soak-churn soak-churn-smoke dryrun
 
 test: native
 	$(PY) -m pytest tests/ -x -q -m "not slow"
@@ -166,16 +165,12 @@ test-hiercommit:
 test-telem:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_telem.py -q
 
-# parallel run: heavy multi-NodeHost modules carry
-# xdist_group("heavy-multiprocess") and serialize on one worker while
-# the light majority fans out (4 workers x multiprocess clusters
-# starve each other on the 8-vCPU box otherwise).  Known residual race:
-# tests allocate ephemeral ports via bind(0)+close before NodeHost
-# rebinds them, so a concurrent worker can steal a just-released port —
-# rare (not observed across repeated runs) and absent from the serial
-# CI gate the driver uses.
+# parallel run, as the driver's tier-1 does it: tests marked
+# xdist_group("heavy-multiprocess") hold one file lock each
+# (tests/conftest.py), so live multi-NodeHost clusters never run side
+# by side while the light majority fans out
 test-par: native
-	$(PY) -m pytest tests/ -q -n auto --dist loadgroup
+	$(PY) -m pytest tests/ -q -m "not slow" -n 6 --dist loadfile
 
 test-all: native
 	$(PY) -m pytest tests/ -x -q
@@ -232,22 +227,6 @@ soak-churn: native
 soak-churn-smoke: native
 	$(PY) soak.py --churn --minutes 0.1 --groups 20 --seed 7
 	$(PY) soak.py --churn --minutes 0.1 --groups 20 --seed 7 --recover
-
-bench: native
-	$(PY) bench.py
-
-# per-subsystem micro-benchmarks (reference `make benchmark`,
-# benchmark_test.go families)
-bench-micro: native
-	$(PY) bench_micro.py
-
-# regenerate the PERF.md A/B ledger tables from the bench artifact
-# (every table traceable to BENCH_DETAIL.json — run after a bench capture)
-perf-ledger:
-	$(PY) tools/perf_ledger.py
-
-perf-ledger-check:
-	$(PY) tools/perf_ledger.py --check
 
 dryrun:
 	$(PY) __graft_entry__.py

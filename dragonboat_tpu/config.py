@@ -155,9 +155,8 @@ class ExpertConfig:
         run lost a third of its throughput for its lifetime.  A
         decisive ``tpu`` e2e win still wants spare host cores for the
         dispatch thread, a co-located device, or group
-        counts far past the per-group-Python crossover — measure with
-        bench.py's scale rung on the target topology before switching
-        (PERF.md round-5 §3).
+        counts far past the per-group-Python crossover — measure on the
+        target topology before switching.
     """
 
     quorum_engine: str = "scalar"

@@ -1,7 +1,7 @@
 """Compartmentalized host plane: ingress batcher, group-commit WAL,
 decoupled apply/egress executors.
 
-The e2e leaf profile (PROFILE_e2e.txt) is lock waits plus the
+The propose path's leaf profile is lock waits plus the
 ``commit_write_batch`` durability hop: every client proposal takes the
 per-group ``entry_q`` lock and a step-ready condition-variable notify,
 every persisting step-worker cycle rides its own fsync, and the apply

@@ -375,13 +375,16 @@ class HostProcPlane:
         # doubles as the spawn handshake.
         rec.hb = self._ctx.Value("d", 0.0, lock=False)
         specs = [(c.req.name, c.resp.name) for c in rec.pairs]
-        rec.proc = self._ctx.Process(
+        proc = self._ctx.Process(
             target=wp.worker_main,
             args=(rec.wid, specs, rec.hb),
             name=f"hostproc-worker-{rec.wid}",
             daemon=True,
         )
-        rec.proc.start()
+        proc.start()
+        # published only once started: stop() must never join a Process
+        # the monitor's respawn has made and not yet started
+        rec.proc = proc
 
     def _monitor_main(self) -> None:
         warned_stale = set()
@@ -444,6 +447,7 @@ class HostProcPlane:
                     hs = time.monotonic() + 30.0
                     while (rec.hb.value == 0.0
                            and rec.proc.exitcode is None
+                           and not self._stopping
                            and time.monotonic() < hs):
                         time.sleep(0.01)
                     if rec.hb.value:
@@ -458,7 +462,7 @@ class HostProcPlane:
                             # with the lane, not a monitor period later
                             obs.workers_alive(self.alive_count())
                             obs.ring_depth(self.ring_depth())
-                    else:
+                    elif not self._stopping:
                         plog.error(
                             "hostproc worker %d respawn handshake failed",
                             rec.wid,
@@ -603,6 +607,11 @@ class HostProcPlane:
         if self._stopping:
             return
         self._stopping = True
+        # the monitor first: a respawn it has in flight ends (its
+        # handshake wait sees _stopping) before the workers are stopped,
+        # so the one stopped below is the one that exists
+        if self._monitor is not None and self._monitor.is_alive():
+            self._monitor.join(timeout=5.0)
         for rec in self._workers:
             p = rec.proc
             if p is None:
@@ -623,8 +632,6 @@ class HostProcPlane:
                 p.join(1.0)
             for c in rec.pairs:
                 c.alive = False
-        if self._monitor is not None and self._monitor.is_alive():
-            self._monitor.join(timeout=2.0)
         for rec in self._workers:
             for c in rec.pairs:
                 c.req.close()

@@ -14,7 +14,9 @@ discipline is seqlock-style single-writer-per-cursor:
 The cursors live on separate cache lines and never wrap (u64 of total
 bytes; ``cursor % capacity`` is the byte offset), so each side publishes
 exactly one aligned 8-byte store and reads the other side's with one
-aligned 8-byte load.  On x86-64 (TSO) that ordering is sufficient
+aligned 8-byte load (an item of a ``memoryview.cast("Q")``; a
+``Struct.pack_into`` is a zero-fill and then a write, two stores the
+other process can read between).  On x86-64 (TSO) that ordering is sufficient
 without explicit fences: the producer's record stores cannot sink below
 its tail store, and the consumer's loads cannot hoist above its tail
 load; the CPython eval loop adds further (incidental) fencing around
@@ -35,7 +37,6 @@ import struct
 from multiprocessing import shared_memory
 from typing import Optional
 
-_U64 = struct.Struct("<Q")
 _U32 = struct.Struct("<I")
 
 #: header bytes ahead of the data region: tail @0, head @64 — one cache
@@ -56,7 +57,7 @@ class ShmRing:
     the requested size), so producer and consumer always agree.
     """
 
-    __slots__ = ("shm", "cap", "_owner", "closed")
+    __slots__ = ("shm", "cap", "_owner", "closed", "_cur")
 
     def __init__(self, capacity: int = 1 << 20, name: Optional[str] = None,
                  create: bool = True):
@@ -78,14 +79,20 @@ class ShmRing:
         self.cap = self.shm.size - HEADER
         self._owner = create
         self.closed = False
+        # the cursors as native 64-bit items: an item of a cast view is
+        # read and written in ONE aligned 8-byte move.  Struct.pack_into
+        # zero-fills its target before it writes, so the other process
+        # could read a cursor as 0 between the two (a consumer then
+        # takes a record that is not there, a producer sees a full ring)
+        self._cur = self.shm.buf[:HEADER].cast("Q")
 
     # ---- cursors ----
 
     def _load(self, off: int) -> int:
-        return _U64.unpack_from(self.shm.buf, off)[0]
+        return self._cur[off >> 3]
 
     def _store(self, off: int, v: int) -> None:
-        _U64.pack_into(self.shm.buf, off, v)
+        self._cur[off >> 3] = v
 
     @property
     def tail(self) -> int:
@@ -174,6 +181,7 @@ class ShmRing:
             return
         self.closed = True
         try:
+            self._cur.release()
             self.shm.close()
         except Exception:
             pass

@@ -71,7 +71,7 @@ MT = MessageType
 # length-prefix header packer for native batch appends, cached per length:
 # a pipelined burst is almost always one payload size repeated, and the
 # per-entry ``struct.pack`` (plus the in-function ``import struct``) was a
-# measured leaf in PROFILE_e2e.txt's propose path
+# measured leaf of the propose path
 _pack_len = lru_cache(maxsize=1024)(_struct.Struct("<I").pack)
 # wire types the native fast lane serves (natraft.cpp handle_fast)
 _FAST_WIRE_TYPES = frozenset(
@@ -783,7 +783,6 @@ class Node:
             return ing.submit(self, session, cmds, timeout_s)
         # encode in one pass — empty commands are never re-encoded, and
         # the separate any(enc) scan collapsed into the same loop
-        # (PROFILE_e2e.txt propose-path leaves)
         tr = self.tracer
         t0 = time.perf_counter() if tr is not None else 0.0
         ct = self._entry_ct

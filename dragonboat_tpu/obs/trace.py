@@ -63,9 +63,8 @@ Overhead contract (the PR-5 ``is not None`` latch precedent): tracing
 is OFF by default — ``NodeHost.tracer`` / ``Node.tracer`` /
 ``Engine.tracer`` / coordinator ``tracer`` stay ``None``,
 ``RequestState.trace`` stays ``None``, and every hot-path hook gates on
-a plain attribute check, so the trace-off host path is bit-identical.
-Trace-ON overhead is measured by the bench trace axis
-(``bench_e2e.run_trace_axis``, <5% asserted on the fused host loop).
+a plain attribute check, so the trace-off host path is bit-identical
+(``tests/test_trace.py``).
 """
 from __future__ import annotations
 

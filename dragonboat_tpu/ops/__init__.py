@@ -25,7 +25,5 @@ from .kernels import (  # noqa: F401
     tick_step,
     quorum_step,
     quorum_step_dense,
-    quorum_multistep,
-    quorum_multistep_dense,
 )
 from .engine import BatchedQuorumEngine  # noqa: F401

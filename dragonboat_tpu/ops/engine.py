@@ -1302,8 +1302,8 @@ class BatchedQuorumEngine:
 
     @dev.setter
     def dev(self, st: QuorumState) -> None:
-        """External state assignment (hybrid direct-dispatch callers, e.g.
-        the bench's staged multistep) — the host committed twin can no
+        """External state assignment (hybrid direct-dispatch callers) —
+        the host committed twin can no
         longer be trusted, so the next step() re-reads it from the device
         once instead of mis-reporting commit deltas."""
         self._harvest_inflight()

@@ -814,135 +814,6 @@ quorum_step_dense = jax.jit(
 )
 
 
-def quorum_multistep_impl(
-    st: QuorumState,
-    ack_g: jax.Array,      # (R,K) — R staged rounds of event batches
-    ack_p: jax.Array,
-    ack_val: jax.Array,
-    ack_valid: jax.Array,
-    vote_g: jax.Array,
-    vote_p: jax.Array,
-    vote_grant: jax.Array,
-    vote_valid: jax.Array,
-    do_tick: bool = True,
-    track_contact: bool = True,
-    has_votes: bool = True,
-    has_hier: bool = False,
-) -> StepOutputs:
-    """R engine rounds in ONE dispatch via ``lax.scan``.
-
-    Host↔device round trips are the latency floor (SURVEY.md §7 hard-part
-    3) — especially over a network-attached TPU.  The host therefore stages
-    R rounds of ingested events and scans them on device, mirroring the
-    reference's pipelining (proposals accepted while prior ones are in
-    flight, ``execengine.go:954-966``).  Outputs carry the final state plus
-    OR-accumulated flags and the final commit watermark; commit
-    notifications are monotone, so the final watermark is sufficient for
-    host egress.
-    """
-
-    def body(carry, ev):
-        if has_votes:
-            args = ev
-        else:
-            # vote args are NOT scanned when has_votes=False; the step
-            # accepts dummies of any shape there
-            z32 = jnp.zeros((1,), I32)
-            args = ev + (z32, z32, jnp.zeros((1,), jnp.int8),
-                         jnp.zeros((1,), jnp.bool_))
-        out = quorum_step_impl(
-            carry,
-            *args,
-            do_tick=do_tick,
-            track_contact=track_contact,
-            has_votes=has_votes,
-            has_hier=has_hier,
-        )
-        acc = (out.won, out.lost, out.flags)
-        return out.state, acc
-
-    xs = (
-        (ack_g, ack_p, ack_val, ack_valid, vote_g, vote_p, vote_grant, vote_valid)
-        if has_votes
-        else (ack_g, ack_p, ack_val, ack_valid)
-    )
-    st, (won, lost, flags) = jax.lax.scan(body, st, xs)
-    any_ = lambda x: jnp.any(x, axis=0)  # noqa: E731
-    return StepOutputs(
-        st,
-        st.committed,
-        any_(won),
-        any_(lost),
-        TickFlags(*(any_(f) for f in flags)),
-    )
-
-
-quorum_multistep = jax.jit(
-    quorum_multistep_impl,
-    static_argnames=("do_tick", "track_contact", "has_votes", "has_hier"),
-    donate_argnums=(0,),
-)
-
-
-def quorum_multistep_dense_impl(
-    st: QuorumState,
-    ack_max: jax.Array,      # (R,G,P)
-    ack_touched: jax.Array,  # (R,G,P)
-    vote_new: jax.Array,     # (R,G,P) i8
-    do_tick: bool = True,
-    track_contact: bool = True,
-    has_votes: bool = True,
-    has_hier: bool = False,
-) -> StepOutputs:
-    """R dense rounds in ONE dispatch (see :func:`quorum_multistep_impl`).
-
-    Stacked ``(R, G, P)`` inputs are only practical when R·G·P stays small
-    or the rounds are derived on device (the headline bench synthesizes
-    them inside its own jit and calls :func:`quorum_step_dense_impl` in a
-    scan directly); this wrapper serves host-staged short pipelines and
-    the differential tests.
-    """
-
-    def body(carry, ev):
-        if has_votes:
-            am, at_, vn = ev
-        else:
-            # vote_new is NOT scanned when has_votes=False (the caller may
-            # pass a dummy of any shape, per the step contract)
-            am, at_ = ev
-            vn = jnp.zeros((1, 1), jnp.int8)
-        out = quorum_step_dense_impl(
-            carry,
-            am,
-            at_,
-            vn,
-            do_tick=do_tick,
-            track_contact=track_contact,
-            has_votes=has_votes,
-            has_hier=has_hier,
-        )
-        acc = (out.won, out.lost, out.flags)
-        return out.state, acc
-
-    xs = (ack_max, ack_touched, vote_new) if has_votes else (ack_max, ack_touched)
-    st, (won, lost, flags) = jax.lax.scan(body, st, xs)
-    any_ = lambda x: jnp.any(x, axis=0)  # noqa: E731
-    return StepOutputs(
-        st,
-        st.committed,
-        any_(won),
-        any_(lost),
-        TickFlags(*(any_(f) for f in flags)),
-    )
-
-
-quorum_multistep_dense = jax.jit(
-    quorum_multistep_dense_impl,
-    static_argnames=("do_tick", "track_contact", "has_votes", "has_hier"),
-    donate_argnums=(0,),
-)
-
-
 def _apply_recycle(
     st: QuorumState,
     row: jax.Array,    # (C,) i32 — target rows; G (out of range) = padding

@@ -36,8 +36,8 @@ host in BOTH arms; ``--recover`` additionally turns on the closed-loop
 recovery plane (``NodeHostConfig.auto_recover``).  The run is scored by
 automated MTTR — per-detector open→close durations merged fleet-wide —
 while keeping the base soak's gates: linearizable histories, no
-same-applied divergence, zero dropped fast-lane spans.  ``bench_e2e.py
---churn-soak`` runs both arms on the same seed and compares::
+same-applied divergence, zero dropped fast-lane spans.  Run both arms on
+the same seed and compare (``make soak-churn``)::
 
     python soak.py --churn --minutes 2 --groups 100 --seed 7            # OFF arm
     python soak.py --churn --minutes 2 --groups 100 --seed 7 --recover  # ON arm

@@ -27,9 +27,8 @@ from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
 from dragonboat_tpu.linearizability import HistoryRecorder, check_linearizable
 from dragonboat_tpu.monkey import get_applied_index, get_state_hash
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
+# heavy multi-NodeHost tests never overlap each other (the lock in
+# tests/conftest.py): side by side they starve each other on an 8-vCPU box
 pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
 
 

@@ -1,8 +1,8 @@
 """The live path's kernels compile for a v5e — checked without one.
 
 The TPU compiler is installed in CI; it compiles for a *described*
-``v5e:2x2`` chip (nothing is attached, nothing runs).  Every jitted entry
-point of ``ops/kernels.py`` is compiled at the BASELINE ladder's size
+``v5e:2x2`` chip (nothing is attached, nothing runs).  Every program the
+live path dispatches is compiled at the BASELINE ladder's size
 (65,536 groups x 5 peers) and at the live coordinator's default
 (1,024 x 8), with the static flags the live path turns on
 (``has_reads`` / ``has_kv`` / ``has_telem`` / ``has_hier``); one mesh case
@@ -33,7 +33,6 @@ from jax.sharding import (  # noqa: E402
     SingleDeviceSharding,
 )
 
-from dragonboat_tpu.ops import kernels  # noqa: E402
 from dragonboat_tpu.ops.engine import BatchedQuorumEngine  # noqa: E402
 from dragonboat_tpu.ops.sharding import GROUP_AXIS, block_sharding  # noqa: E402
 from dragonboat_tpu.ops.state import make_state, pack_state  # noqa: E402
@@ -89,10 +88,6 @@ def _on(sharding, tree):
     )
 
 
-def _state(groups, peers, sharding):
-    return _on(sharding, jax.eval_shape(lambda: make_state(groups, peers)))
-
-
 def _blocks(groups, peers, sharding):
     """The packed carry the engine's programs take, as shapes."""
     return _on(sharding, jax.eval_shape(
@@ -132,34 +127,6 @@ def test_live_program_compiles_for_v5e(topo, no_compile_cache, size, variant):
     assert statics["has_telem"] and statics["has_hier"]
     _compile(
         fn, _blocks(groups, peers, one_chip), (_on(one_chip, ing),), statics
-    )
-
-
-def _multistep_args(groups, peers, rounds, dense):
-    if dense:
-        return (
-            jax.ShapeDtypeStruct((rounds, groups, peers), jnp.int32),
-            jax.ShapeDtypeStruct((rounds, groups, peers), bool),
-            jax.ShapeDtypeStruct((rounds, groups, peers), jnp.int8),
-        )
-    cap = max(4 * groups, 4096)
-    i32 = jax.ShapeDtypeStruct((rounds, cap), jnp.int32)
-    valid = jax.ShapeDtypeStruct((rounds, cap), bool)
-    grant = jax.ShapeDtypeStruct((rounds, cap), jnp.int8)
-    return (i32, i32, i32, valid, i32, i32, grant, valid)
-
-
-@pytest.mark.parametrize("size", sorted(SIZES))
-@pytest.mark.parametrize("dense", [False, True], ids=["sparse", "dense"])
-def test_multistep_compiles_for_v5e(topo, no_compile_cache, size, dense):
-    groups, peers = SIZES[size]
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    fn = kernels.quorum_multistep_dense if dense else kernels.quorum_multistep
-    _compile(
-        fn,
-        _state(groups, peers, one_chip),
-        _on(one_chip, _multistep_args(groups, peers, 4, dense)),
-        dict(do_tick=True, track_contact=True, has_votes=True, has_hier=True),
     )
 
 

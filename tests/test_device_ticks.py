@@ -12,14 +12,10 @@ from __future__ import annotations
 import threading
 import time
 
-import pytest
 
 from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
 from dragonboat_tpu.config import ExpertConfig
 from dragonboat_tpu.transport import ChanRouter, ChanTransport
-
-# serialized with the other heavy system tests under xdist
-pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
 
 
 GROUPS = 64

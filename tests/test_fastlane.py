@@ -21,9 +21,8 @@ from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
 from dragonboat_tpu.config import ExpertConfig
 from dragonboat_tpu.native import natraft
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
+# heavy multi-NodeHost tests never overlap each other (the lock in
+# tests/conftest.py): side by side they starve each other on an 8-vCPU box
 pytestmark = [pytest.mark.skipif(
     not natraft.available(), reason="libnatraft unavailable"
 ), pytest.mark.xdist_group("heavy-multiprocess")]
@@ -134,6 +133,28 @@ def _propose_all(nh, payloads, deadline_s=180.0):
         r = rs.wait(max(0.1, deadline - time.time()))
         assert r.completed, r
     return len(pending)
+
+
+def _propose_through_lane(leader, tag, n):
+    """Batches of ``n`` payloads until one rode the native lane; how many
+    payloads were proposed in all.  The lane can EJECT under full-suite
+    load between an enroll check and the proposals (liveness timeouts on
+    a starved box — the r07 contention-flake class): retry through
+    re-enrollment instead of asserting on a single window.  A genuinely
+    broken lane never carries a batch and still fails here."""
+    sent = 0
+    for attempt in range(4):
+        st0 = leader.fastlane.stats()
+        sent += _propose_all(
+            leader, [b"%s%d-%d" % (tag, attempt, i) for i in range(n)]
+        )
+        if leader.fastlane.stats()["proposed"] > st0["proposed"]:
+            return sent
+        assert _wait_enrolled(leader), "lane never re-enrolled"
+    raise AssertionError(
+        f"fast lane carried no proposals in 4 batches: "
+        f"{leader.fastlane.stats()}"
+    )
 
 
 def _wait_converged(sms, count, timeout=90.0):
@@ -393,19 +414,14 @@ def test_observer_group_enrolls_and_replicates(tmp_path):
         # observer present (the old eligibility refused observer-bearing
         # groups outright)
         assert _wait_enrolled(leader), "observer-bearing group never enrolled"
-        st0 = leader.fastlane.stats()
-        _propose_all(leader, [b"c%d" % i for i in range(30)])
-        st1 = leader.fastlane.stats()
-        assert st1["proposed"] > st0["proposed"], (
-            "proposals bypassed the native lane"
-        )
+        want = 2 + _propose_through_lane(leader, b"c", 30)
         # the observer (never part of quorum) still receives everything
         deadline = time.time() + 30
         while time.time() < deadline:
-            if sms.get(4) is not None and len(sms[4].applied) == 32:
+            if sms.get(4) is not None and len(sms[4].applied) == want:
                 break
             time.sleep(0.05)
-        assert sms.get(4) is not None and len(sms[4].applied) == 32, (
+        assert sms.get(4) is not None and len(sms[4].applied) == want, (
             "observer did not catch up through the native lane"
         )
         # quorum stays voter-only: stop BOTH non-leader voters; with only
@@ -445,24 +461,7 @@ def test_witness_group_enrolls_and_witness_ack_commits(tmp_path):
             time.sleep(0.1)
         assert 3 in m.witnesses
         assert _wait_enrolled(leader), "witness-bearing group never enrolled"
-        # the lane can EJECT under full-suite load between the enroll
-        # check and the proposals (liveness timeouts on a starved box —
-        # the r07 contention-flake class): retry through re-enrollment
-        # instead of asserting on a single window.  A genuinely broken
-        # lane never carries a batch and still fails here.
-        for attempt in range(4):
-            st0 = leader.fastlane.stats()
-            _propose_all(
-                leader, [b"w%d-%d" % (attempt, i) for i in range(20)]
-            )
-            if leader.fastlane.stats()["proposed"] > st0["proposed"]:
-                break
-            assert _wait_enrolled(leader), "lane never re-enrolled"
-        else:
-            raise AssertionError(
-                f"fast lane carried no proposals in 4 batches: "
-                f"{leader.fastlane.stats()}"
-            )
+        _propose_through_lane(leader, b"w", 20)
         # the witness's scalar log holds only metadata twins
         r3 = nhs[3].get_node(CID).peer.raft
         deadline = time.time() + 20
@@ -470,9 +469,12 @@ def test_witness_group_enrolls_and_witness_ack_commits(tmp_path):
             time.sleep(0.05)
         from dragonboat_tpu.wire import EntryType
 
-        ents = r3.log.get_entries(
-            r3.log.first_index(), r3.log.last_index() + 1, 1 << 62
-        )
+        # under the group's lock: its step worker appends and persists
+        # meanwhile, and a read between the two finds a hole
+        with nhs[3].get_node(CID).raft_mu:
+            ents = r3.log.get_entries(
+                r3.log.first_index(), r3.log.last_index() + 1, 1 << 62
+            )
         assert ents and all(
             e.type in (EntryType.METADATA, EntryType.CONFIG_CHANGE)
             for e in ents

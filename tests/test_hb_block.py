@@ -34,8 +34,6 @@ from dragonboat_tpu.wire import (
     Message, MessageType, pack_hb_rows, unpack_hb_rows,
 )
 
-pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
-
 MT = MessageType
 GROUPS = 128
 #: what ``World.state`` holds for a replica, in order

@@ -112,6 +112,41 @@ def test_ring_wraparound_integrity():
         r.close()
 
 
+def _flip_tail(name, a, b, secs):
+    ring = ShmRing(name=name, create=False)
+    deadline = time.time() + secs
+    while time.time() < deadline:
+        for _ in range(500):
+            ring._store(0, a)
+            ring._store(0, b)
+    ring.close()
+
+
+def test_ring_cursor_is_never_read_torn():
+    """A cursor is published in one store: while another process writes
+    ``tail`` over and over, this one reads only values that were written
+    (a store made of a zero-fill and a write reads as 0 in between, and
+    a consumer then pops a record that was never pushed)."""
+    import multiprocessing as mp
+
+    a, b = 0x00FFFFFF, 0x01000000
+    ring = ShmRing(capacity=4096)
+    try:
+        ring._store(0, a)
+        w = mp.get_context("spawn").Process(
+            target=_flip_tail, args=(ring.name, a, b, 2.0), daemon=True
+        )
+        w.start()
+        seen = set()
+        while w.is_alive():
+            seen.update(ring._load(0) for _ in range(2000))
+        w.join()
+        assert w.exitcode == 0
+        assert seen == {a, b}, [hex(v) for v in sorted(seen)][:6]
+    finally:
+        ring.close()
+
+
 def test_ring_rejects_oversized_record():
     r = ShmRing(capacity=4096)
     try:

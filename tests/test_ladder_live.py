@@ -18,8 +18,6 @@ import pytest
 from benchmark import run as harness
 from benchmark.cluster import LiveCluster
 
-pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
-
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 
 

@@ -583,6 +583,28 @@ class KVSM:
 CID = 770
 
 
+class _HeldLinks(LatencyInjector):
+    """Every link into ``dst`` can be held shut: a batch for it waits in
+    its sender thread until :meth:`release`.  No delay otherwise."""
+
+    def __init__(self, dst):
+        super().__init__()
+        self._dst = dst
+        self._open = threading.Event()
+        self._open.set()
+
+    def hold(self):
+        self._open.clear()
+
+    def release(self):
+        self._open.set()
+
+    def delay(self, src, dst):
+        if dst == self._dst:
+            self._open.wait()
+        return 0.0
+
+
 def _mk_hosts(n=3, rtt_ms=5, engine="scalar", metrics=False, prefix="ls"):
     router = ChanRouter()
     nhs = []
@@ -809,8 +831,16 @@ def test_live_transfer_soak_linearizable_and_stale_lease_caught():
 
     # ---- (b) the injected fault: suppressed cede + delayed handoff ----
     nhs, _router = _mk_hosts(rtt_ms=10, prefix="lf")
+    # everything INBOUND to host 1 goes through links the test can hold
+    # shut: the handoff window in which a non-ceding leader would serve
+    # stale reads is as long as the test keeps it
+    inj = _HeldLinks("lf1:1")
     try:
-        _start(nhs, prefix="lf", election_rtt=60)
+        # a lease outlives its last quorum of acks by election_rtt less
+        # the drift margin and no longer, held links or not: 4.8 s here,
+        # so the stale read below is asked inside it on a loaded box too
+        # (the handoff itself is TIMEOUT_NOW: it waits for no timeout)
+        _start(nhs, prefix="lf", election_rtt=600)
         nh1 = nhs[0]
         _propose_retry(nh1, nh1.get_noop_session(CID), b"k=v1")
         wait_until(
@@ -819,11 +849,6 @@ def test_live_transfer_soak_linearizable_and_stale_lease_caught():
         )
         rec = HistoryRecorder()
         rec.invoke(1, "put", "k", "v1")(True)
-        # delay everything INBOUND to host 1: the handoff window in which
-        # a non-ceding leader would serve stale reads becomes real
-        inj = LatencyInjector()
-        inj.set_pair("lf2:1", "lf1:1", 0.4)
-        inj.set_pair("lf3:1", "lf1:1", 0.4)
         from dragonboat_tpu.monkey import set_latency
 
         set_latency(nhs, inj)
@@ -842,6 +867,17 @@ def test_live_transfer_soak_linearizable_and_stale_lease_caught():
                 return True
             if not node1.is_leader():
                 return False
+            # the leader sends TIMEOUT_NOW at once only to a target that
+            # has acknowledged its whole log: let the acks in, and shut
+            # the links before the transfer, not after it (a handoff is
+            # over in milliseconds): host 1 then cannot hear of the new
+            # term until the test lets it
+            inj.release()
+            with node1.raft_mu:
+                r = node1.peer.raft
+                if r.remotes[2].match != r.log.last_index():
+                    return False
+            inj.hold()
             try:
                 nh1.request_leader_transfer(CID, 2)
             except Exception:
@@ -862,7 +898,7 @@ def test_live_transfer_soak_linearizable_and_stale_lease_caught():
             what="transfer target leading",
         )
         # the target now leads and commits v2 with host 3 (near link)
-        # while host 1 has not yet heard of the new term
+        # while host 1 cannot hear of the new term
         done_v2 = rec.invoke(1, "put", "k", "v2")
         _propose_retry(nhs[1], nhs[1].get_noop_session(CID), b"k=v2",
                        timeout=10.0)
@@ -879,6 +915,7 @@ def test_live_transfer_soak_linearizable_and_stale_lease_caught():
             "the checker must catch the stale lease read"
         )
     finally:
+        inj.release()
         _stop(nhs)
 
 

@@ -22,9 +22,8 @@ from dragonboat_tpu.config import ExpertConfig
 from dragonboat_tpu.native import natraft, natsm
 from dragonboat_tpu.native.natsm import NativeKVStateMachine
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
+# heavy multi-NodeHost tests never overlap each other (the lock in
+# tests/conftest.py): side by side they starve each other on an 8-vCPU box
 pytestmark = [pytest.mark.skipif(
     not (natraft.available() and natsm.available()),
     reason="native libraries unavailable",

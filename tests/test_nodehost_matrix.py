@@ -550,17 +550,31 @@ def test_leader_transfer_to_unknown_target_noops(trio):
 
 
 def test_leader_transfer_to_real_target(trio):
+    from tests.loadwait import wait_until
+
     nhs, addrs, lid, router = trio
     target = (lid % 3) + 1
-    nhs[lid - 1].request_leader_transfer(9, target)
-    deadline = time.time() + 15
-    while time.time() < deadline:
-        new_lid, ok = nhs[0].get_leader_id(9)
-        if ok and new_lid == target:
-            break
-        time.sleep(0.05)
-    new_lid, ok = nhs[0].get_leader_id(9)
-    assert ok and new_lid == target
+    asked = 0.0
+
+    def led_by_target():
+        nonlocal asked
+        if nhs[target - 1].get_node(9).is_leader():
+            return True
+        # a transfer the leader dropped (target behind, a round lost) is
+        # asked for again, of whoever leads now, as a client would
+        if time.time() - asked >= 1.0:
+            asked = time.time()
+            for nh in nhs:
+                if nh.get_node(9).is_leader():
+                    nh.request_leader_transfer(9, target)
+        return False
+
+    wait_until(led_by_target, 15.0, what=f"node {target} leads group 9")
+    # and the others follow it
+    wait_until(
+        lambda: all(nh.get_leader_id(9) == (target, True) for nh in nhs),
+        15.0, what=f"every host names node {target} leader",
+    )
 
 
 def test_concurrent_config_change_rejected(trio):

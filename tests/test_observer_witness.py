@@ -15,11 +15,6 @@ import pytest
 from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
 from dragonboat_tpu.transport import ChanRouter, ChanTransport
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
-pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
-
 
 RTT = 10
 CID = 5

@@ -665,68 +665,6 @@ def test_has_votes_false_matches_empty_vote_batch():
     assert np.array_equal(np.asarray(out_a.committed), np.asarray(out_b.committed))
 
 
-def test_multistep_has_votes_false_accepts_dummies():
-    """Both multisteps must accept arbitrary-shape vote dummies when
-    has_votes=False and match the has_votes=True/empty-votes result."""
-    from dragonboat_tpu.ops.kernels import (
-        quorum_multistep,
-        quorum_multistep_dense,
-    )
-
-    g, p, cap, r = 8, 3, 16, 4
-    eng_a = _random_engine(random.Random(3), g, p, cap)
-    eng_b = _random_engine(random.Random(3), g, p, cap)
-
-    rows = np.arange(g, dtype=np.int32)
-    ag = np.broadcast_to(np.concatenate([rows, rows]), (r, cap)).copy()
-    ap = np.broadcast_to(
-        np.concatenate([np.zeros(g, np.int32), np.ones(g, np.int32)]), (r, cap)
-    ).copy()
-    av = np.broadcast_to(
-        4 + np.arange(r, dtype=np.int32)[:, None], (r, cap)
-    ).copy()
-    avalid = np.ones((r, cap), bool)
-    zi = np.zeros((r, cap), np.int32)
-    z8 = np.zeros((r, cap), np.int8)
-    zb = np.zeros((r, cap), bool)
-
-    out_t = quorum_multistep(
-        eng_a.dev, *(jnp.asarray(x) for x in (ag, ap, av, avalid, zi, zi, z8, zb)),
-        do_tick=True, has_votes=True,
-    )
-    out_f = quorum_multistep(
-        eng_b.dev, jnp.asarray(ag), jnp.asarray(ap), jnp.asarray(av),
-        jnp.asarray(avalid),
-        # dummies of unrelated shape — must not be scanned
-        jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1,), jnp.int8), jnp.zeros((1,), bool),
-        do_tick=True, has_votes=False,
-    )
-    _state_equal(out_t.state, out_f.state)
-
-    # dense multistep: same contract
-    eng_c = _random_engine(random.Random(3), g, p, cap)
-    eng_d = _random_engine(random.Random(3), g, p, cap)
-    ack_max = np.zeros((r, g, p), np.int32)
-    touched = np.zeros((r, g, p), bool)
-    for rr in range(r):
-        ack_max[rr, :, 0] = 4 + rr
-        ack_max[rr, :, 1] = 4 + rr
-        touched[rr, :, :2] = True
-    vt = np.full((r, g, p), -1, np.int8)
-    out_dt = quorum_multistep_dense(
-        eng_c.dev, jnp.asarray(ack_max), jnp.asarray(touched), jnp.asarray(vt),
-        do_tick=True, has_votes=True,
-    )
-    out_df = quorum_multistep_dense(
-        eng_d.dev, jnp.asarray(ack_max), jnp.asarray(touched),
-        jnp.zeros((1, 1), jnp.int8),  # dummy, not scanned
-        do_tick=True, has_votes=False,
-    )
-    _state_equal(out_dt.state, out_df.state)
-    _state_equal(out_t.state, out_dt.state)  # sparse ≡ dense end state
-
-
 def test_engine_dense_ingest_validation():
     with pytest.raises(ValueError):
         BatchedQuorumEngine(4, 3, dense_ingest=1)

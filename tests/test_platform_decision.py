@@ -21,12 +21,14 @@ from dragonboat_tpu import hostplatform  # noqa: E402
 from dragonboat_tpu.ops import engine as ops_engine  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    _BENCHMARK = json.load(_f)
 
 
 def _run(args, timeout=600, **env):
     """Run a repo script the way a user would, on the CPU backend."""
     full = {k: v for k, v in os.environ.items()
-            if k not in ("BENCH_PLATFORM", "JAX_COMPILATION_CACHE_DIR")}
+            if k != "JAX_COMPILATION_CACHE_DIR"}
     full.update(JAX_PLATFORMS="cpu", **env)
     return subprocess.run(
         [sys.executable, *args], cwd=REPO, env=full, capture_output=True,
@@ -44,24 +46,15 @@ def test_require_tpu_raises_on_the_cpu_backend():
 @pytest.mark.parametrize("script", [
     ["chip_smoke.py"],
     ["chip_smoke.py", "--chips", "4"],
-    ["bench.py"],
-    ["bench_e2e.py", "--devsm"],
-    ["bench_e2e.py"],
-], ids=lambda a: " ".join(a))
+    *(["benchmark/run.py", "--workload", w["name"], "--seed", "1",
+       "--seconds", "4", "--trace", "0"] for w in _BENCHMARK["workloads"]),
+], ids=lambda a: " ".join(a[:3]))
 def test_scripts_exit_nonzero_without_a_tpu(script):
-    env = {"BENCH_SKIP_E2E": "1", "E2E_PROCS": "1"}
-    r = _run(script, timeout=300, **env)
+    r = _run(script, timeout=300)
     assert r.returncode != 0, r.stdout[-500:]
     # no result line, no device metric under any name
-    assert '"ok"' not in r.stdout and "writes_per_sec" not in r.stdout
+    assert '"ok"' not in r.stdout and "ops_per_s" not in r.stdout
     assert "need 1 TPU" in r.stderr or "need 4 TPU" in r.stderr, r.stderr[-800:]
-
-
-def test_bench_parent_stays_off_jax_until_it_takes_the_device():
-    """One process per chip: importing bench (and reaching main's e2e
-    section) must not initialize a backend the rank-0 child needs."""
-    r = _run(["-c", "import sys, bench; sys.exit('jax' in sys.modules)"])
-    assert r.returncode == 0, r.stderr[-500:]
 
 
 @pytest.mark.parametrize("chips", [1, 4])

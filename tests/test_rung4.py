@@ -33,9 +33,8 @@ from dragonboat_tpu.wire import Entry, Message, MessageType as MT
 
 from tests.raft_harness import new_test_raft
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
+# heavy multi-NodeHost tests never overlap each other (the lock in
+# tests/conftest.py): side by side they starve each other on an 8-vCPU box
 pytestmark = [pytest.mark.slow, pytest.mark.xdist_group("heavy-multiprocess")]
 
 N = 65_536

@@ -22,9 +22,8 @@ from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
 from dragonboat_tpu.config import ExpertConfig
 from dragonboat_tpu.native import natraft
 
-# heavy multi-NodeHost tests serialize on one xdist worker
-# (--dist loadgroup): 4-way-parallel multiprocess clusters
-# starve each other on an 8-vCPU box
+# heavy multi-NodeHost tests never overlap each other (the lock in
+# tests/conftest.py): side by side they starve each other on an 8-vCPU box
 pytestmark = [pytest.mark.skipif(
     not natraft.available(), reason="libnatraft unavailable"
 ), pytest.mark.xdist_group("heavy-multiprocess")]
@@ -117,11 +116,12 @@ def test_far_behind_follower_streams_snapshot_under_load(tmp_path):
 
         def put(j, deadline):
             # retry timed-out proposes until the deadline: on a starved CI
-            # box a single 10s-budget write can time out without implying
-            # anything about snapshot catch-up (the thing under test)
+            # box a single write can time out without implying anything
+            # about snapshot catch-up (the thing under test); 3 s an
+            # attempt, so that one the lane loses costs the test 3 s
             while True:
-                rs = leader.propose(s, f"w{j}=a{j}".encode(), timeout=10.0)
-                if rs.wait(30.0).completed:
+                rs = leader.propose(s, f"w{j}=a{j}".encode(), timeout=3.0)
+                if rs.wait(10.0).completed:
                     return
                 assert time.time() < deadline, f"write w{j} never completed"
 

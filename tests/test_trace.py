@@ -565,13 +565,16 @@ def test_sigusr2_handler_dumps(tmp_path):
         # the handler only flags; the tick worker performs the dump
         # (dumping inline in signal context would re-acquire
         # non-reentrant locks the interrupted frame may hold)
-        wait_until(
-            lambda: glob.glob(str(tmp_path / "nh" / "dbtpu-dump-*.json")),
-            timeout=5.0, what="SIGUSR2 dump file",
-        )
-        files = glob.glob(str(tmp_path / "nh" / "dbtpu-dump-*.json"))
-        with open(files[0]) as f:
-            d = json.load(f)
+        def _dump():
+            for path in glob.glob(str(tmp_path / "nh" / "dbtpu-dump-*.json")):
+                with open(path) as f:
+                    try:
+                        return json.load(f)
+                    except ValueError:
+                        pass  # the tick worker is still writing it
+            return None
+
+        d = wait_until(_dump, timeout=5.0, what="SIGUSR2 dump file")
         assert d["traces"]["sampled"] >= 1
     finally:
         nh.stop()
