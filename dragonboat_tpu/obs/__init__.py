@@ -20,8 +20,8 @@ handling lives on cheap continuous telemetry"):
   dispatch names its ``coord_round``), and its phases as ``*_ms``
   fields that are also ``jax.profiler`` annotations
   (``recorder.ANNOTATIONS``: ``dbtpu:round``, ``dbtpu:drain``,
-  ``dbtpu:step``, ``dbtpu:stage``, ``dbtpu:transfer``,
-  ``dbtpu:launch``, ``dbtpu:egress_wait``, ``dbtpu:decode``,
+  ``dbtpu:step``, ``dbtpu:stage``, ``dbtpu:launch``,
+  ``dbtpu:retire``, ``dbtpu:egress_wait``, ``dbtpu:decode``,
   ``dbtpu:fanout``, …); ``ops.engine.compilation_log()`` names every
   compile beside it;
 - :mod:`instruments` — ``EngineObs`` / ``CoordObs``: counters, gauges

@@ -599,7 +599,7 @@ class DevProf:
         budgets: list = []
         for e in engines:
             try:
-                dev = next(iter(e._blk.gi.devices()))
+                dev = next(iter(e._blk.i32.devices()))
                 stats = dev.memory_stats()
             except Exception:
                 stats = None

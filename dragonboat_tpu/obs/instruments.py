@@ -355,7 +355,7 @@ class EngineObs:
     """
 
     __slots__ = ("recorder", "registry", "shard", "host", "parent", "t0",
-                 "ph", "_in_step", "_scope_span")
+                 "ph", "_in_step", "_scope_span", "_last_span")
 
     _COUNTERS = (
         _DEV + "dispatch_total",
@@ -409,6 +409,7 @@ class EngineObs:
         self.ph: dict = {}
         self._in_step = False  # inside a dbtpu:step scope
         self._scope_span = None  # the span that scope's call opened
+        self._last_span = None  # the newest dispatch span (``retire``)
         r = self.registry
         _describe(r, self._COUNTERS + (
             _DEV + "staged_rounds", _DEV + "read_slots_in_use",
@@ -536,6 +537,7 @@ class EngineObs:
         n_dispatches: int = 1,
         arrays_made: int = 0,
         arrays_retired: int = 0,
+        ack_blocks_stale: int = 0,
     ) -> dict:
         """One logical step's device work launched: publish counters +
         latency, and open its span (egress fields land via
@@ -545,10 +547,12 @@ class EngineObs:
         ``k_rounds`` is the LIVE round count of the block (real staged
         rounds, or ticked rounds when a deficit replay ticks into the
         padding) vs ``rounds``, the padded program K.  ``arrays_made``
-        (device arrays the step made by put) and ``arrays_retired``
-        (state blocks and ingress blocks it dropped; :meth:`egress` adds
-        the egress block) count what costs the round thread a hand-off
-        of the interpreter each."""
+        (device arrays the step made by put: none, the ingress block
+        rides the launch) and ``arrays_retired`` (state blocks its launch
+        replaced; :meth:`egress` adds the egress block) count what costs
+        the round thread a hand-off of the interpreter each;
+        ``ack_blocks_stale`` the staged ack blocks that took the per-row
+        epoch comparison (staged before a row transition)."""
         r = self.registry
         r.counter_add(_DEV + "dispatch_total", n_dispatches)
         r.counter_add(_DEV + "rounds_total", rounds)
@@ -598,11 +602,23 @@ class EngineObs:
             mu_wait_ms=round(mu_wait_ms, 4),
             arrays_made=arrays_made,
             arrays_retired=arrays_retired,
+            ack_blocks_stale=ack_blocks_stale,
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")
-        self._scope_span = span
+        self._scope_span = self._last_span = span
         return span
+
+    def retire(self) -> None:
+        """The late half of ``retire_ms``: the blocks the newest step's
+        launch replaced were dropped after that step returned (the
+        engine's ``drop_retired``, a coordinator's call once its round's
+        commits are offloaded); the time joins that step's span, as
+        :meth:`egress` writes its half late."""
+        span = self._last_span
+        late = self._take(("retire_ms",))["retire_ms"]
+        if span is not None:  # in place: the span's t1 stays its egress
+            span["retire_ms"] = round(span.get("retire_ms", 0.0) + late, 4)
 
     def egress(
         self, span: dict, *, egress_ms: float, egress_rows: int,

@@ -61,12 +61,14 @@ ANNOTATIONS = {
                                          # the phases
     "row_sync": "dbtpu:row_sync",        # dispatch/fused row_sync_ms
     "stage": "dbtpu:stage",              # dispatch/fused stage_ms
-    "transfer": "dbtpu:transfer",        # dispatch/fused transfer_ms
+    "transfer": "dbtpu:transfer",        # dispatch/fused transfer_ms:
+                                         # 0.0, the put rides the launch
     "launch": "dbtpu:launch",            # dispatch/fused launch_ms
     "retire": "dbtpu:retire",            # dispatch/fused retire_ms: the
-                                         # explicit drops of the arrays a
-                                         # step retires (state blocks,
-                                         # ingress block)
+                                         # drops of the state blocks a
+                                         # launch replaced and of its
+                                         # fetched egress block, after
+                                         # the round's fan-out
     "egress_wait": "dbtpu:egress_wait",  # dispatch/fused egress_wait_ms
     "decode": "dbtpu:decode",            # dispatch/fused decode_ms
     "compile": "dbtpu:compile",          # ops.engine.compilation_log()
@@ -183,9 +185,10 @@ class FlightRecorder:
                             committed-cache refresh: every
                             gather/scatter-rows program), ``stage_ms``
                             (event gathering, padding, the K-round block
-                            build), ``transfer_ms`` (the ``jnp.asarray``
-                            puts), ``launch_ms`` (the jitted call
-                            until it returns), and what is left over:
+                            build), ``transfer_ms`` (0.0: the ingress
+                            block rides the launch), ``launch_ms`` (the
+                            jitted call until it returns), and what is
+                            left over:
                             time inside no phase, the round thread
                             waiting for the interpreter between two
     ``egress_ms``           blocking device→host egress wall time (set at

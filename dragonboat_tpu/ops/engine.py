@@ -10,10 +10,10 @@ before the next dispatch.
 
 What crosses the host/device boundary of a dispatch is a handful of
 arrays, because each one a step makes or retires costs the round thread a
-hand-off of the interpreter (PERF.md §5): the state travels as four
-blocks, everything staged as ONE ingress block, everything read back as
-ONE egress block (``packed.py``); ``eng.dev`` unpacks a ``QuorumState``
-on demand.
+hand-off of the interpreter (PERF.md §5): the state travels as two
+blocks, everything staged rides the launch as ONE host buffer, everything
+read back is ONE egress block (``packed.py``); ``eng.dev`` unpacks a
+``QuorumState`` on demand.
 
 The group axis is shardable over a ``jax.sharding.Mesh`` (see
 ``sharding.py``): every kernel op is row-wise over groups, so XLA partitions
@@ -94,8 +94,8 @@ def upload_nbytes(*arrays) -> int:
     capacity model's per-dispatch term all read this, so the sum can
     never drift from the tensors actually passed to the kernel (ISSUE 15
     satellite — three hand-maintained per-site sums preceded it).
-    Callers pass EXACTLY what the program receives: since ISSUE 30 the
-    one ingress block of the dispatch."""
+    Callers pass EXACTLY what the program receives: the one ingress
+    block of the dispatch."""
     return int(sum(a.nbytes for a in arrays if a is not None))
 
 
@@ -274,6 +274,7 @@ class GroupInfo:
     slots: Dict[int, int]            # node_id -> peer slot
     base: int = 0                    # uint64 absolute index of rel 0
     node_ids: List[int] = field(default_factory=list)
+    self_slot: int = 0               # peer slot of this replica
 
 
 class StepResult:
@@ -518,13 +519,20 @@ class BatchedQuorumEngine:
         self._dispatch_mu = threading.RLock() if n_dev > 1 else nullcontext()
         #: the slot counts the layout rule needs besides G and P
         self._dims = (n_read_slots, n_kv_slots, n_kv_ents, n_kv_reads)
-        # --- the packed carry (ISSUE 30) --------------------------------
-        # Between steps the 31 leaves live as four blocks
-        # (state.StateBlocks): a step makes and retires the blocks, one
-        # ingress block and one egress block, not ~90 arrays, each of
-        # which cost the round thread a hand-off of the interpreter.
-        # ``dev`` unpacks on demand; steady-state steps never do.
+        # --- the packed carry -------------------------------------------
+        # Between steps the 31 leaves live as two blocks
+        # (state.StateBlocks): a step retires the blocks and one egress
+        # block, not ~90 arrays, each of which cost the round thread a
+        # hand-off of the interpreter.  ``dev`` unpacks on demand;
+        # steady-state steps never do.
         self._blk: StateBlocks = self._put_state(self.mirror)
+        # the blocks the last launch replaced (donated: they hold no
+        # device memory) and its egress block once fetched, kept so that
+        # their deaths do not stand between a launch and the fan-out of
+        # what it committed: dropped by ``drop_retired`` (a coordinator,
+        # once its round's commits are offloaded) or by the next launch,
+        # so at most one step's are ever held
+        self._retired: tuple = ()
         # the host buffers dispatches stage into (_pk.Ingress), restaged
         # in place: a buffer is reused only once the program that read it
         # has handed back its egress.  Bounded: one per shape of the warm
@@ -557,12 +565,24 @@ class BatchedQuorumEngine:
         # every transition (measured 0.66ms per transition at 4k groups —
         # an election burst of 1,024 transitions cost a 680ms round).
         self._row_epoch = np.zeros((n_groups,), np.int32)
+        # the epochs' generation: one counter, bumped with every row's
+        # epoch.  A staged ack BLOCK carries it instead of a per-row copy
+        # of ``_row_epoch`` (an index-array read when it is staged and
+        # another when it is gathered, each a hand-off of the interpreter
+        # on the round thread): a block of the current generation is
+        # whole, an older one is filtered against the newest entries of
+        # ``_epoch_bumped`` (the rows bumped while a block was staged,
+        # kept until the next gather), as many as it is generations old
+        self._epoch_gen = 0
+        self._epoch_bumped: List[int] = []
+        self._n_stale_blocks = 0   # ack blocks filtered since the last span
         # pending event buffers (grow unbounded host-side; chunked at
         # dispatch); entries carry the staging epoch as a 4th column
         self._acks: List[Tuple[int, int, int, int]] = []  # row, slot, rel, ep
         self._votes: List[Tuple[int, int, int, int]] = []  # row, slot, g, ep
         self._voted_cells: dict = {}  # (row, slot) -> staging epoch
-        # vectorized bulk-ingest blocks (ack_block): (rows, slots, rels, eps)
+        # vectorized bulk-ingest blocks (ack_block): (rows, slots, rels,
+        # epoch generation)
         self._ack_blocks: List[Tuple[np.ndarray, ...]] = []
         # --- multi-round fused staging (ISSUE 1 tentpole) ---------------
         # closed ingest rounds awaiting ONE fused dispatch (begin_round /
@@ -703,8 +723,7 @@ class BatchedQuorumEngine:
         self._obs_kv_span = None   # apply_kernel span of the same dispatch
         self._obs_mu_wait = 0.0    # _dispatch_mu wait of the next dispatch
         self._obs_upload = 0       # upload bytes of the current dispatch
-        self._n_made = 0           # arrays put since the last span
-        self._n_retired = 0        # arrays dropped since the last span
+        self._n_retired = 0        # arrays retired since the last span
         # --- device capacity & profiling plane (ISSUE 15) ---------------
         # LATCH, same contract as _obs: None by default, every hot-path
         # site gates on `is not None`, so a profile-off engine keeps a
@@ -1340,7 +1359,10 @@ class BatchedQuorumEngine:
         if len(all_ids) > self.n_peers:
             raise ValueError("too many peers for tensor width")
         slots = {nid: i for i, nid in enumerate(all_ids)}
-        gi = GroupInfo(cluster_id, row, slots, node_ids=all_ids)
+        gi = GroupInfo(
+            cluster_id, row, slots, node_ids=all_ids,
+            self_slot=slots[self_id],
+        )
         self.groups[cluster_id] = gi
         self.rows[row] = gi
         self._row_cid[row] = cluster_id
@@ -1355,7 +1377,7 @@ class BatchedQuorumEngine:
         a["term_start"][row] = 0
         n_voting = len(set(node_ids) | set(witnesses))
         a["quorum"][row] = n_voting // 2 + 1
-        a["self_slot"][row] = slots[self_id]
+        a["self_slot"][row] = gi.self_slot
         a["election_tick"][row] = 0
         a["heartbeat_tick"][row] = 0
         a["election_timeout"][row] = election_timeout
@@ -1393,8 +1415,8 @@ class BatchedQuorumEngine:
         belong to the old term and must never reach the new term's tally
         (the scalar twin drops mismatched-term responses in
         ``handle_vote_resp`` / ``handle_replicate_resp``).  O(1): the row's
-        staging epoch is bumped and stale-epoch events are filtered in one
-        vectorized pass at dispatch.
+        staging epoch is bumped and stale-epoch events are filtered at
+        dispatch (``_gather_acks``).
 
         Pending READS die with the transition too (scalar twin: every
         ``become_*`` builds a fresh ``ReadIndex``) — slot bookkeeping and
@@ -1407,6 +1429,9 @@ class BatchedQuorumEngine:
         like a scalar SM across terms.  Queued ops, staged slots and
         pending read captures drop with the host bookkeeping reset."""
         self._row_epoch[row] += 1
+        self._epoch_gen += 1
+        if self._ack_blocks:  # only a staged block can be older than this
+            self._epoch_bumped.append(row)
         self._reset_read_rows([row])
         if self._read_plane_used:  # else provably already clear
             self.mirror.clear_reads(row)
@@ -1677,10 +1702,9 @@ class BatchedQuorumEngine:
         # below-base acks are legal raft traffic (delayed retransmits) and
         # clamp to rel 0, matching ack()'s scalar semantics
         rels = np.maximum(rels, 0)
-        rows32 = rows.astype(np.int32)
         self._ack_blocks.append(
-            (rows32, slots.astype(np.int32), rels.astype(np.int32),
-             self._row_epoch[rows32].copy())
+            (rows.astype(np.int32), slots.astype(np.int32),
+             rels.astype(np.int32), self._epoch_gen)
         )
 
     def vote(self, cluster_id: int, node_id: int, granted: bool) -> None:
@@ -1713,24 +1737,16 @@ class BatchedQuorumEngine:
         any event touching a non-leader row)."""
         gi = self.groups[cluster_id]
         self._acks.append(
-            (gi.row, int(self.mirror.arrays["self_slot"][gi.row]), 0,
-             int(self._row_epoch[gi.row]))
+            (gi.row, gi.self_slot, 0, int(self._row_epoch[gi.row]))
         )
 
     def heartbeat_resp_block(self, rows, slots) -> None:
-        """``heartbeat_resp`` for many (row, peer slot) pairs: one ack
-        block at rel 0 (``ack_block``'s caller contract)."""
+        """``heartbeat_resp`` for many (row, peer slot) pairs, and
+        ``leader_contact`` for many follower rows, each with its own slot
+        (``GroupInfo.self_slot``): one ack block at rel 0 (``ack_block``'s
+        caller contract)."""
         rows = np.asarray(rows, dtype=np.int32)
         self.ack_block(rows, slots, np.zeros(rows.shape, np.int32))
-
-    def leader_contact_block(self, rows) -> None:
-        """``leader_contact`` for many follower rows: one ack block at
-        rel 0 on each row's own slot."""
-        rows = np.asarray(rows, dtype=np.int32)
-        self.ack_block(
-            rows, self.mirror.arrays["self_slot"][rows],
-            np.zeros(rows.shape, np.int32),
-        )
 
     # ------------------------------------------------------------------
     # device read plane: ReadIndex staging (ISSUE 3 tentpole)
@@ -2406,7 +2422,8 @@ class BatchedQuorumEngine:
         # host bookkeeping: the new tenant takes the SAME row at base 0
         del self.groups[old_cluster_id]
         ngi = GroupInfo(
-            new_cluster_id, row, gi.slots, base=0, node_ids=gi.node_ids
+            new_cluster_id, row, gi.slots, base=0, node_ids=gi.node_ids,
+            self_slot=gi.self_slot,
         )
         self.groups[new_cluster_id] = ngi
         self.rows[row] = ngi
@@ -2577,7 +2594,6 @@ class BatchedQuorumEngine:
         kv_span, self._obs_kv_span = self._obs_kv_span, None
         t_eg = time.perf_counter() if obs is not None else 0.0
         eg = self._fetch_egress(egress)
-        del egress
         with (obs.phase("decode") if obs is not None else _OFF):
             if telem is not None:
                 # dispatch-time row_cid snapshot: a re-registration while
@@ -2634,12 +2650,15 @@ class BatchedQuorumEngine:
         return res
 
     def _fetch_egress(self, egress) -> np.ndarray:
-        """The blocking ``device_get`` of a dispatch's ONE egress block;
-        the caller drops the device array right after (it is counted as
-        retired on the span's egress half)."""
+        """The blocking ``device_get`` of a dispatch's ONE egress block.
+        The device array joins ``_retired`` (it is counted as retired on
+        the span's egress half): its death, like the replaced state
+        blocks', is not on the way from the fetch to the fan-out."""
         obs = self._obs
         with (obs.phase("egress_wait") if obs is not None else _OFF):
-            return jax.device_get(egress)
+            eg = jax.device_get(egress)
+        self._retired += (egress,)
+        return eg
 
     @staticmethod
     def _translate_egress(
@@ -3170,7 +3189,7 @@ class BatchedQuorumEngine:
                     False,
                 )
                 # the next chunk restages the ingress buffer this one
-                # was put from: not before the program has read it
+                # was launched on: not before the program has read it
                 jax.block_until_ready(chunk.egress)
                 if obs is not None:
                     self._n_retired += 1  # this chunk's egress, unread
@@ -3229,10 +3248,9 @@ class BatchedQuorumEngine:
         res = StepResult()
         # ONE device→host transfer for the whole egress set (a
         # network-attached chip pays the full round trip per readback)
-        egress, telem = out.egress, out.telem
+        telem = out.telem
+        eg = self._fetch_egress(out.egress)
         del out
-        eg = self._fetch_egress(egress)
-        del egress
         with (obs.phase("decode") if obs is not None else _OFF):
             if telem is not None:
                 # deferred readback: stage the device aggregate, pull it
@@ -3285,33 +3303,42 @@ class BatchedQuorumEngine:
 
     def _gather_acks(self):
         """Tuple-staged + block-staged acks as three flat arrays, with
-        stale-epoch events (staged before a row transition) filtered out
-        in one vectorized pass; clears both buffers."""
+        stale-epoch events (staged before a row transition) filtered out;
+        clears both buffers.  No array is read through an index array on
+        the way (each such read hands the interpreter away, whatever its
+        size): tuples are filtered as tuples and become the three columns
+        once; a block is whole unless a row's epoch was bumped after it
+        was staged, and only then compared, against those rows."""
         parts = []
         if self._acks:
-            cols = np.array(self._acks, dtype=np.int64)
-            rows = cols[:, 0].astype(np.int32)
-            keep = cols[:, 3].astype(np.int32) == self._row_epoch[rows]
-            parts.append(
-                (rows[keep], cols[keep, 1].astype(np.int32),
-                 cols[keep, 2].astype(np.int32))
-            )
+            epoch = self._row_epoch
+            live = [
+                (r, s, v) for r, s, v, ep in self._acks if ep == epoch[r]
+            ]
             self._acks = []
+            if live:
+                parts.append(tuple(np.array(live, dtype=np.int32).T))
         if self._ack_blocks:
-            for r, s, v, ep in self._ack_blocks:
-                keep = ep == self._row_epoch[r]
-                if keep.all():
+            for r, s, v, gen in self._ack_blocks:
+                if gen == self._epoch_gen:
                     parts.append((r, s, v))
-                elif keep.any():
+                    continue
+                self._n_stale_blocks += 1
+                keep = np.isin(
+                    r, self._epoch_bumped[gen - self._epoch_gen:],
+                    invert=True,
+                )
+                if keep.any():
                     parts.append((r[keep], s[keep], v[keep]))
             self._ack_blocks = []
+        self._epoch_bumped.clear()  # no staged block is left to be older
         if not parts:
             z = np.zeros((0,), np.int32)
             return z, z, z
-        return (
-            np.concatenate([p[0] for p in parts]),
-            np.concatenate([p[1] for p in parts]),
-            np.concatenate([p[2] for p in parts]),
+        if len(parts) == 1:
+            return parts[0]
+        return tuple(
+            np.concatenate([p[i] for p in parts]) for i in range(3)
         )
 
     def _ingress_for(self, kind: str, **layout) -> _pk.Ingress:
@@ -3340,32 +3367,50 @@ class BatchedQuorumEngine:
         return (kind,) + tuple(sorted(layout.items()))
 
     def _launch(self, fn, ing: _pk.Ingress, **statics) -> _pk.PackedOut:
-        """Put the staged ingress block (ONE array made), run ``fn`` on
-        the donated state blocks and keep their successors.  What the
-        launch retires — the old blocks, the ingress block — is dropped
-        here, in a phase of its own, so the time has a name."""
+        """Run ``fn`` on the donated state blocks and the staged ingress
+        block, handed over as the host buffer it is (the put rides the
+        launch: no array of the step's own is made, none has to die), and
+        keep the blocks' successors.  The blocks the launch replaced stay
+        in ``_retired`` until ``drop_retired``: their deaths are not on
+        the way from the launch to the egress and the fan-out."""
         obs = self._obs
-        # the host->device put, in a block of its own (ROADMAP A1)
-        with (obs.phase("transfer") if obs is not None else _OFF):
-            dev_in = jax.device_put(ing.buf)
+        if self._retired:  # a bare caller's last step's, still held
+            self._drop_retired()
         with (obs.phase("launch") if obs is not None else _OFF):
             out = fn(
-                self._blk, dev_in, **self._engine_statics(), **statics
+                self._blk, ing.buf, **self._engine_statics(), **statics
             )
-        with (obs.phase("retire") if obs is not None else _OFF):
-            self._blk = out.blocks
-            del dev_in
+        self._retired, self._blk = tuple(self._blk), out.blocks
         if obs is not None:
-            self._n_made += 1
-            self._n_retired += len(StateBlocks._fields) + 1
+            self._n_retired += len(StateBlocks._fields)
         return out
 
+    def _drop_retired(self) -> None:
+        obs = self._obs
+        with (obs.phase("retire") if obs is not None else _OFF):
+            self._retired = ()
+
+    def drop_retired(self) -> None:
+        """Drop the state blocks the last step's launch replaced and its
+        fetched egress block.  A coordinator calls this once its round's
+        commits are offloaded (each death hands the interpreter away, and
+        there it delays no acknowledgement); the time lands late on that
+        step's span, as ``retire_ms``.  A caller that never does loses
+        nothing: the next launch drops them."""
+        if self._retired:
+            self._drop_retired()
+            if self._obs is not None:
+                self._obs.retire()
+
     def _take_array_counts(self) -> dict:
-        """``arrays_made`` (by put) and ``arrays_retired`` (state blocks,
-        ingress blocks, unread egress) since the last span, zeroed."""
-        made, self._n_made = self._n_made, 0
+        """``arrays_made`` (none: the ingress block rides the launch),
+        ``arrays_retired`` (state blocks replaced, unread egress) and
+        ``ack_blocks_stale`` (ack blocks that took the per-row epoch
+        comparison) since the last span, zeroed."""
         retired, self._n_retired = self._n_retired, 0
-        return {"arrays_made": made, "arrays_retired": retired}
+        stale, self._n_stale_blocks = self._n_stale_blocks, 0
+        return {"arrays_made": 0, "arrays_retired": retired,
+                "ack_blocks_stale": stale}
 
     def _stage_sparse(self, acks, votes, has_votes: bool) -> _pk.Ingress:
         """The event lists of one sparse dispatch in its ingress block:

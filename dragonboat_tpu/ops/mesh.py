@@ -770,6 +770,12 @@ class MeshQuorumEngine:
         merged = self._merge(results)
         return merged if merged is not None else StepResult()
 
+    def drop_retired(self) -> None:
+        """Every shard's ``drop_retired`` (the coordinator's call after
+        its fan-out; no shard is launching by then)."""
+        for s in self.shards:
+            s.drop_retired()
+
     def harvest(self) -> Optional[MultiRoundResult]:
         live = [
             i for i, s in enumerate(self.shards) if s._inflight is not None

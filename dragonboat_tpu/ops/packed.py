@@ -4,7 +4,7 @@ A step on the round thread pays a hand-off of the interpreter for every
 ``jax.Array`` it makes and retires, whatever its size (PERF.md §5).  So
 what crosses the host/device boundary of one dispatch is held to:
 
-* the state, carried as four blocks (``state.StateBlocks``), donated;
+* the state, carried as two blocks (``state.StateBlocks``), donated;
 * ONE ingress block: everything the dispatch stages, in one flat ``int32``
   host buffer (:class:`Ingress`) whose sections the program slices;
 * ONE egress block: ``(rows, G)`` ``int32`` — the commit watermark, the
@@ -30,7 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from . import kernels as _k
-from .state import I8, I32, VOTE_NONE, StateBlocks, pack_state, unpack_state
+from .state import (
+    I8, I32, VOTE_NONE, StateBlocks, block_dims, pack_state, unpack_state,
+)
 
 #: the egress bit field, low bit first (``StepResult`` field of each)
 FLAG_BITS = ("won", "lost", "elect", "heartbeat", "demote")
@@ -211,7 +213,7 @@ def _step(
     telem_k: int = _k.TELEM_TOPK, has_reads: bool = False,
     has_kv: bool = False,
 ) -> PackedOut:
-    g, p = blocks.pi.shape[1:]
+    g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
         "sparse", g, p, dims, cap=cap, has_votes=has_votes))
     return _finish(_k.quorum_step_impl(
@@ -230,7 +232,7 @@ def _step_dense(
     has_reads: bool = False, has_kv: bool = False, has_hier: bool = False,
     has_telem: bool = False, telem_k: int = _k.TELEM_TOPK,
 ) -> PackedOut:
-    g, p = blocks.pi.shape[1:]
+    g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
         "dense", g, p, dims, has_votes=has_votes, has_reads=has_reads,
         has_kv=has_kv))
@@ -256,7 +258,7 @@ def _multiround(
     purge_kv: bool = True, has_hier: bool = False, has_telem: bool = False,
     purge_telem: bool = True, telem_k: int = _k.TELEM_TOPK,
 ) -> PackedOut:
-    g, p = blocks.pi.shape[1:]
+    g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
         "fused", g, p, dims, k=k, c=c, has_votes=has_votes,
         has_churn=has_churn, do_tick=do_tick, has_reads=has_reads,

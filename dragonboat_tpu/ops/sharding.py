@@ -53,10 +53,10 @@ def state_sharding(mesh: Mesh, axis: str = GROUP_AXIS) -> QuorumState:
 
 
 def block_sharding(sharding):
-    """The sharding of a packed state block (``state.StateBlocks``) of an
-    engine whose leaves shard as ``sharding``: a block stacks leaves on a
-    new LEADING axis, so the leaves' spec moves one axis to the right and
-    the group axis stays split exactly as before."""
+    """The sharding of a packed state block (``state.StateBlocks``,
+    ``(rows, G)``) of an engine whose leaves shard as ``sharding``: the
+    leaves' group axis is the blocks' LAST, so the leaves' spec moves one
+    axis to the right and the groups stay split exactly as before."""
     if isinstance(sharding, NamedSharding):
         return NamedSharding(sharding.mesh, P(None, *sharding.spec))
     return sharding

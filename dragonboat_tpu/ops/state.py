@@ -28,6 +28,7 @@ TPU-first design decisions (deltas from the reference):
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -253,62 +254,71 @@ def make_state(
 
 
 # ----------------------------------------------------------------------
-# packed carry (ISSUE 30): the state between two dispatches
+# packed carry: the state between two dispatches
 # ----------------------------------------------------------------------
 # A ``QuorumState`` is 31 device arrays, and a step that takes it donated
 # and hands back the next one makes 31 and retires 31: each birth and each
 # death is a hand-off of the interpreter on the round thread.  Between
-# steps the engine therefore holds the same leaves as four blocks, grouped
-# by storage dtype and by whether a peer axis follows the group axis, and
-# stacked on a NEW LEADING axis so that G and P stay where the leaves have
-# them: a group-sharded engine keeps sharding G
-# (``sharding.block_sharding``).  ``bool`` leaves are stored as ``int8``
-# beside the two ``int8`` leaves (one byte a cell either way); a slot axis
-# between G and P, or in P's place (read slots, kv slots), becomes rows of
-# the block.  The kernels never see the blocks: a jitted program is
+# steps the engine therefore holds the same leaves as TWO blocks, one per
+# storage dtype, each ``(rows, G)``: a leaf's axes behind the group axis
+# (peers, read / kv slots) become rows, G stays last and is the axis a
+# group-sharded engine shards (``sharding.block_sharding``).  ``bool``
+# leaves are stored as ``int8`` beside the two ``int8`` leaves (one byte a
+# cell either way).  The kernels never see the blocks: a jitted program is
 # ``unpack -> the kernel as it is -> pack`` (``ops/packed.py``).
 
 
 class StateBlocks(NamedTuple):
-    """The packed carry: every :class:`QuorumState` leaf, in four arrays."""
+    """The packed carry: every :class:`QuorumState` leaf, in two arrays."""
 
-    gi: jax.Array  # (rows, G) i32: per-group scalars; (G,X) i32 leaves as X rows
-    gb: jax.Array  # (rows, G) i8: node_state and the per-group bools
-    pi: jax.Array  # (rows, G, P) i32: match, next
-    pb: jax.Array  # (rows, G, P) i8: votes, per-peer bools; read_acks as S rows
+    i32: jax.Array  # (rows, G) i32: a (G,) leaf one row, (G,X) X, (G,P) P rows
+    i8: jax.Array   # (rows, G) i8: node_state, votes, every bool leaf
 
 
 @functools.lru_cache(maxsize=None)
 def _leaf_table() -> tuple:
-    """``(name, block, is_bool, slot_axis)`` of every leaf, in field
-    order; ``slot_axis`` names the axis that becomes rows (``""`` for a
-    leaf that is one row, else ``"s"`` / ``"v"`` / ``"e"``).  Read off
+    """``(name, block, is_bool, axes)`` of every leaf, in field order;
+    ``axes`` names what follows the group axis (``""``, ``"p"``, ``"s"``,
+    ``"sp"``, ``"v"``, ``"e"``) and becomes rows of the block.  Read off
     :func:`make_state` at five distinct sizes, so a new field packs by
     its dtype and shape without an entry here."""
     sds = jax.eval_shape(lambda: make_state(2, 3, 5, 7, 11))
     axis = {3: "p", 5: "s", 7: "v", 11: "e"}
-    table = []
-    for name, leaf in sds._asdict().items():
-        axes = "".join(axis[d] for d in leaf.shape[1:])
-        per_peer = axes.endswith("p")
-        wide = np.dtype(leaf.dtype) == np.int32
-        block = ("p" if per_peer else "g") + ("i" if wide else "b")
-        table.append((
-            name, block, np.dtype(leaf.dtype) == np.bool_,
-            axes[:-1] if per_peer else axes,
-        ))
-    return tuple(table)
+    return tuple(
+        (name, "i32" if np.dtype(leaf.dtype) == np.int32 else "i8",
+         np.dtype(leaf.dtype) == np.bool_,
+         "".join(axis[d] for d in leaf.shape[1:]))
+        for name, leaf in sds._asdict().items()
+    )
+
+
+def block_dims(blocks: StateBlocks, dims: tuple) -> tuple:
+    """``(G, P)`` of ``blocks``.  G is the blocks' last axis; P is not an
+    axis of its own any more, so it is read off the ``int32`` block's
+    row count, which is ``fixed + per_peer * P`` over the leaf table at
+    the engine's slot counts ``dims``."""
+    size = dict(zip("sve", dims))
+    fixed = per_peer = 0
+    for _, block, _, axes in _leaf_table():
+        if block == "i32":
+            n = math.prod(size[a] for a in axes if a != "p")
+            if "p" in axes:
+                per_peer += n
+            else:
+                fixed += n
+    rows, g = blocks.i32.shape
+    return g, (rows - fixed) // per_peer
 
 
 def pack_state(st: QuorumState, xp=jnp) -> StateBlocks:
     """``st`` as blocks (``xp``: ``jnp`` inside a program, ``numpy`` for
     the host mirror)."""
     parts = {b: [] for b in StateBlocks._fields}
-    for leaf, (_, block, _, slot_axis) in zip(st, _leaf_table()):
-        if block[1] == "b":
+    for leaf, (_, block, _, _) in zip(st, _leaf_table()):
+        if block == "i8":
             leaf = leaf.astype(xp.int8)
         parts[block].append(
-            xp.moveaxis(leaf, 1, 0) if slot_axis else leaf[None]
+            xp.moveaxis(leaf, 0, -1).reshape(-1, leaf.shape[0])
         )
     return StateBlocks(*(xp.concatenate(parts[b], axis=0)
                          for b in StateBlocks._fields))
@@ -319,17 +329,21 @@ def unpack_state(
     dims: tuple = (READ_SLOTS, KV_SLOTS, KV_ENT_SLOTS),
     xp=jnp,
 ) -> QuorumState:
-    """The kernels' view of ``blocks``: slices, and ``!= 0`` where a
-    ``bool`` leaf is stored as ``int8``.  ``dims`` is the engine's
-    ``(n_read_slots, n_kv_slots, n_kv_ents)``."""
-    rows_of = dict(zip(("", "s", "v", "e"), (1,) + tuple(dims)))
+    """The kernels' view of ``blocks``: slices, the group axis moved back
+    to the front, and ``!= 0`` where a ``bool`` leaf is stored as
+    ``int8``.  ``dims`` is the engine's ``(n_read_slots, n_kv_slots,
+    n_kv_ents)``."""
+    g, p = block_dims(blocks, dims)
+    size = dict(zip("psve", (p,) + tuple(dims)))
     at = dict.fromkeys(StateBlocks._fields, 0)
     leaves = []
-    for _, block, is_bool, slot_axis in _leaf_table():
+    for _, block, is_bool, axes in _leaf_table():
+        tail = tuple(size[a] for a in axes)
         lo = at[block]
-        at[block] = hi = lo + rows_of[slot_axis]
-        blk = getattr(blocks, block)
-        leaf = xp.moveaxis(blk[lo:hi], 0, 1) if slot_axis else blk[lo]
+        at[block] = hi = lo + math.prod(tail)
+        leaf = xp.moveaxis(
+            getattr(blocks, block)[lo:hi].reshape(tail + (g,)), -1, 0
+        )
         leaves.append(leaf != 0 if is_bool else leaf)
     return QuorumState(*leaves)
 

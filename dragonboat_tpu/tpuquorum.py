@@ -818,18 +818,16 @@ class TpuQuorumCoordinator:
             gi = groups.get(cid)
             if gi is None:
                 continue
-            if not contact:
+            if contact:  # a contact is an ack at rel 0 on the row's own slot
+                slot = gi.self_slot
+            else:
                 slot = gi.slots.get(op[2][i])
                 if slot is None:  # unknown peer: rebuild the row (rare)
                     recover.append(cid)
                     continue
-                slots.append(slot)
+            slots.append(slot)
             rows.append(gi.row)
-        if not rows:
-            return
-        if contact:
-            self.eng.leader_contact_block(rows)
-        else:
+        if rows:
             self.eng.heartbeat_resp_block(rows, slots)
 
     def read_stage(
@@ -1307,6 +1305,9 @@ class TpuQuorumCoordinator:
                 res, read_confirms, do_tick,
                 span["seq"] if span is not None else None,
             )
+        # the state blocks the step replaced die here, once the round's
+        # commits are offloaded: each death hands the interpreter away
+        self.eng.drop_retired()
         if obs is not None:
             if self.lease_table is not None:
                 # advisory lease-coverage gauge (dragonboat_lease_groups_

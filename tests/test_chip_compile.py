@@ -136,8 +136,8 @@ def test_group_sharded_program_compiles_for_v5e_2x2(topo, no_compile_cache):
     groups, peers = 4 * SIZES["ladder"][0], SIZES["ladder"][1]
     mesh = Mesh(np.array(topo.devices), (GROUP_AXIS,))
     assert mesh.devices.size == 4
-    # the blocks stack leaves on a leading axis: the leaves' P(groups)
-    # moves one axis to the right
+    # the blocks are (rows, G): the leaves' P(groups) moves one axis to
+    # the right
     st = _blocks(
         groups, peers, block_sharding(NamedSharding(mesh, P(GROUP_AXIS)))
     )

@@ -612,13 +612,15 @@ def test_dispatch_phases_make_up_dispatch_and_egress():
 
 @pytest.mark.parametrize("reads", [False, True], ids=["writes", "reads"])
 def test_steady_state_step_makes_one_array_and_retires_few(reads):
-    """A steady-state step of a single-device engine puts ONE array (its
-    ingress block; the read plane rides in it) and retires the four state
-    blocks, the ingress block and the egress block — under 12, where the
-    31-leaf state, eight puts and six flag vectors made it ~53 — and the
-    explicit drops have a phase, ``retire_ms``.  The six older phase
-    fields all stay on the span: ``engine_other_ms`` reads ``step_ms``
-    less those six, and so still contains ``retire_ms``."""
+    """A steady-state step of a single-device engine makes NO array of
+    its own (the ingress block rides the launch as the host buffer it is;
+    the read plane rides in it) and retires the two state blocks and the
+    egress block: three, where the 31-leaf state, eight puts and six flag
+    vectors made it ~53 and four blocks with a put ingress block 7.  The
+    drops of the blocks have a phase, ``retire_ms`` (in a bare loop the
+    next launch's, so inside that step).  The six older phase fields all
+    stay on the span, ``transfer_ms`` as 0.0: ``engine_other_ms`` reads
+    ``step_ms`` less those six."""
     rec = FlightRecorder(stall_ms=0)
     eng = _leader_engine(rec)
     idx = 1
@@ -641,13 +643,16 @@ def test_steady_state_step_makes_one_array_and_retires_few(reads):
     older = ("row_sync_ms", "stage_ms", "transfer_ms", "launch_ms",
              "egress_wait_ms", "decode_ms")
     for s in spans[1:]:  # the first also uploads the registrations
-        assert s["arrays_made"] == 1, s
-        assert s["arrays_retired"] == 6, s
-        assert s["arrays_made"] + s["arrays_retired"] < 12
+        assert s["arrays_made"] == 0, s
+        assert s["arrays_retired"] <= 3, s
+        assert s["transfer_ms"] == 0.0, s
         assert s["retire_ms"] >= 0.0
+        assert s["ack_blocks_stale"] == 0
         assert all(s[k] is not None and s[k] >= 0.0 for k in older), s
         named = sum(s[k] for k in older) + s["retire_ms"]
         assert named <= s["step_ms"] + 0.01, s
+    # each launch dropped what the one before it had replaced
+    assert any(s["retire_ms"] > 0.0 for s in spans[1:])
 
 
 class _FakeNode:
@@ -690,6 +695,85 @@ def _coord_with_leaders(cids, rec=None, host="coordhost", capacity=8):
             coord._sync_row_locked(n)
     coord.flush()  # absorb the registration dirt
     return coord, nodes
+
+
+def test_replaced_blocks_die_after_the_commit_fan_out():
+    """A coordinator round offloads its commits BEFORE the state blocks
+    its step replaced and the egress block it fetched are dropped (their
+    deaths hand the interpreter away and must not stand between a launch
+    and an acknowledgement), the time lands late on that step's span; a
+    bare ``eng.step()`` loop, which never calls ``drop_retired``, holds
+    one step's arrays at most."""
+    rec = FlightRecorder(stall_ms=0)
+    coord, nodes = _coord_with_leaders([3, 4], rec)
+    try:
+        # the rounds below are this thread's alone (flush): the round
+        # thread would race them for the staged acks
+        coord._stopped.set()
+        coord._pending.set()
+        coord._thread.join(timeout=5)
+        assert not coord._thread.is_alive()
+        eng = coord.eng
+        order = []
+        held_at_commit = []
+        drop = eng.drop_retired
+
+        def dropping():
+            order.append(("drop", len(eng._retired)))
+            drop()
+
+        eng.drop_retired = dropping
+        for n in nodes.values():
+            def offload(q, n=n):
+                order.append(("commit", n.cluster_id))
+                held_at_commit.append(len(eng._retired))
+                n.commits.append(q)
+            n.offload_commit = offload
+        for idx in range(1, 6):
+            for cid in nodes:
+                coord.ack(cid, 2, idx)
+            coord.flush()
+        kinds = [k for k, _ in order]
+        assert kinds.count("commit") >= 2
+        # every commit of a round comes before that round's drop ...
+        for i, k in enumerate(kinds):
+            if k == "commit":
+                assert "drop" in kinds[i:], order
+        # ... the two replaced blocks and the egress block were still
+        # held while they went out,
+        assert held_at_commit and all(n == 3 for n in held_at_commit)
+        # and dropped right after: nothing is held between rounds
+        assert [n for k, n in order if k == "drop"][-1] == 3
+        assert eng._retired == ()
+        steps = [s for s in rec.spans() if s["kind"] == "dispatch"]
+        assert steps and all(s["retire_ms"] >= 0.0 for s in steps)
+        assert any(s["retire_ms"] > 0.0 for s in steps)
+        assert all(s["transfer_ms"] == 0.0 and s["arrays_made"] == 0
+                   for s in steps)
+    finally:
+        coord.stop()
+
+    eng = _leader_engine(FlightRecorder(stall_ms=0))
+    idx = 1
+    seen = ()
+    for i in range(6):
+        idx += 1
+        for cid in range(1, 9):
+            eng.ack(cid, 1, idx)
+            eng.ack(cid, 2, idx)
+        if i % 3 == 2:
+            eng.begin_round()
+            eng.step_rounds(do_tick=False)
+        else:
+            eng.step(do_tick=False)
+        # one step's: the blocks the newest launch replaced (donated:
+        # no device memory) and the egress block it fetched
+        assert len(eng._retired) == 3
+        assert [b.is_deleted() for b in eng._retired] == [True, True, False]
+        assert not any(b is old for b in eng._retired for old in seen)
+        seen = eng._retired
+    eng.drop_retired()
+    assert eng._retired == ()
 
 
 def test_round_span_is_parent_of_its_dispatches_and_holds_its_phases():
@@ -914,9 +998,11 @@ def test_profiler_capture_holds_the_same_spans_as_the_ring(tmp_path):
             jax.profiler.stop_trace()
         by_name, lines = _host_events(str(tmp_path))
         for name in ("dbtpu:round", "dbtpu:drain", "dbtpu:stage",
-                     "dbtpu:transfer", "dbtpu:launch", "dbtpu:egress_wait",
+                     "dbtpu:launch", "dbtpu:retire", "dbtpu:egress_wait",
                      "dbtpu:decode", "dbtpu:fanout"):
             assert by_name.get(name), (name, sorted(by_name))
+        # the put rides the launch: no phase of its own any more
+        assert "dbtpu:transfer" not in by_name
         spans = [s for s in rec.spans() if t_a <= s["t0"] < t_b]
         rounds = [s for s in spans if s["kind"] == "coord_round"]
         kids = [s for s in spans if s["kind"] in ("dispatch", "fused")]
@@ -948,8 +1034,10 @@ def test_profiler_capture_holds_the_same_spans_as_the_ring(tmp_path):
               "fanout")
         agree(by_name["dbtpu:launch"], [s["launch_ms"] for s in kids],
               "launch")
-        agree(by_name["dbtpu:transfer"], [s["transfer_ms"] for s in kids],
-              "transfer")
+        assert all(s["transfer_ms"] == 0.0 for s in kids)
+        # the replaced blocks die after the fan-out, late on the span
+        agree(by_name["dbtpu:retire"], [s["retire_ms"] for s in kids],
+              "retire")
         agree(by_name["dbtpu:egress_wait"],
               [s["egress_wait_ms"] for s in kids], "egress_wait")
         # a step stages in several blocks: the phase field is their sum

@@ -1,7 +1,8 @@
-"""The packed step against the unpacked entry points (ISSUE 30).
+"""The packed step against the unpacked entry points (ISSUES 30, 32).
 
-The engine carries its state as four blocks, stages every dispatch into
-ONE ingress block and reads ONE egress block back (``ops/packed.py``).
+The engine carries its state as two ``(rows, G)`` blocks, stages every
+dispatch into ONE ingress block and reads ONE egress block back
+(``ops/packed.py``).
 The oracle here is the engine as it was before: ``_Unpacked`` stages a
 dispatch the old way — separate padded arrays, a ``(max, touched)`` pair,
 a ``bool`` echo cube, dummies for compiled-out planes — and calls the
@@ -27,6 +28,7 @@ from dragonboat_tpu.ops.state import (  # noqa: E402
     VOTE_NONE,
     HostMirror,
     QuorumState,
+    block_dims,
     pack_state,
     unpack_state,
 )
@@ -450,7 +452,10 @@ def test_pack_unpack_round_trip_every_leaf(g, p, dims):
     st = QuorumState(**m.arrays)
     host = pack_state(st, np)
     dev = packed.pack(m.to_device())
-    assert [b.shape[1] for b in host] == [g] * 4
+    # two blocks, one a storage dtype, the group axis last in both
+    assert [(b.ndim, b.shape[1], b.dtype) for b in host] == [
+        (2, g, np.int32), (2, g, np.int8)]
+    assert block_dims(host, dims) == (g, p)
     for hb, db in zip(host, dev):  # the host's and the program's agree
         assert hb.dtype == db.dtype and np.array_equal(hb, np.asarray(db))
     for name, x, y, z in zip(
@@ -463,3 +468,44 @@ def test_pack_unpack_round_trip_every_leaf(g, p, dims):
     again = pack_state(unpack_state(host, dims, np), np)
     for hb, ab in zip(host, again):  # pack(unpack(x)) == x
         assert np.array_equal(hb, ab)
+
+
+@pytest.mark.parametrize("mode", ["sparse", "dense", "fused4"])
+def test_packed_step_on_a_group_sharded_engine(mode):
+    """The same script on an engine whose blocks shard their LAST axis
+    (the groups) over the virtual CPU devices: equal to the unsharded
+    unpacked oracle step for step, and still sharded after every one."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dragonboat_tpu.ops.sharding import GROUP_AXIS, make_mesh
+
+    devices = jax.local_devices(backend="cpu")
+    n_dev = min(4, len(devices))
+    if n_dev < 2:
+        pytest.skip("one CPU device: nothing to shard over")
+    g, p = 64, 3
+    kw = dict(
+        event_cap=256,
+        dense_ingest={"sparse": False, "dense": True}.get(mode, "auto"),
+    )
+    a = BatchedQuorumEngine(g, p, sharding=NamedSharding(
+        make_mesh(np.array(devices[:n_dev])), P(GROUP_AXIS)), **kw)
+    b = _Unpacked(g, p, **kw)
+    script = _Script([a, b], seed=32, g=g, p=p, reads=mode != "sparse",
+                     kv=False)
+    for step in range(5):
+        where = f"sharded {mode} step={step}"
+        script.events()
+        if mode == "fused4":
+            script.each_block("begin_round")
+            script.events()
+            ra, rb = (e.step_rounds(do_tick=step % 2 == 0, pad_rounds_to=4)
+                      for e in (a, b))
+        else:
+            ra, rb = (e.step(do_tick=step % 2 == 0) for e in (a, b))
+        _assert_result_equal(ra, rb, where)
+        assert np.array_equal(a.committed_view(), b.committed_view()), where
+        _assert_state_equal(a, b, where)
+        for blk in a._blk:
+            assert blk.sharding.spec == P(None, GROUP_AXIS), where
+        script.transitions(step)

@@ -615,6 +615,15 @@ def test_tracer_alone_attaches_ring_and_round_stamp_lies_in_linked_span():
         sampled = [rs.trace for rs in states if rs.trace.__class__ is Trace]
         assert len(sampled) == 3  # 1 in 8
         by_seq = {sp["seq"]: sp for sp in rec.spans()}
+        # a write completes out of its round's fan-out; the round's span
+        # closes a little later (the arrays the step retired die first)
+        wait_until(
+            lambda: all(
+                "wall_ms" in by_seq[q] for t in sampled for q in t.spans
+                if q in by_seq and by_seq[q]["kind"] == "coord_round"),
+            timeout=5.0, interval=0.01,
+            what="the sampled writes' rounds closed",
+        )
         for t in sampled:
             stamp = next(ts for st, ts, _th in t.events
                          if st == "device_round")
