@@ -616,7 +616,7 @@ class Node:
                 if r.term != term:
                     continue
                 ctx = SystemCtx(low=low, high=high)
-                r.apply_read_releases(r.read_index.release(ctx), ctx)
+                r.apply_read_releases(r.read_index.release(ctx))
         if echoes and r.is_leader():
             for from_, low, high in echoes:
                 r.handle_read_index_leader_confirmation(

@@ -171,6 +171,9 @@ _HELP = {
     _COORD + "hb_single_total": "heartbeats and responses that took the "
     "per-group message, by cause",
     _COORD + "rows": "groups registered on the engine at the last round",
+    _COORD + "reads_total": "ReadIndex contexts a leader's coordinator "
+    "staged, by origin: the host's own clients' (local) or forwarded by a "
+    "follower (remote)",
     _HOST + "ingress_submitted_total": "commands accepted into ingress rings",
     _HOST + "ingress_drains_total": "ingress batcher drain cycles",
     _HOST + "ingress_drained_total": "commands drained by the batcher",
@@ -1349,6 +1352,7 @@ class CoordObs:
             _COORD + "staged_depth", _COORD + "read_fallbacks_total",
             _COORD + "round_latency_ms", _COORD + "tick_flags_total",
             _COORD + "hb_single_total", _COORD + "rows",
+            _COORD + "reads_total",
         ))
         for name in self._COUNTERS:
             r.counter_add(name, 0)
@@ -1360,6 +1364,8 @@ class CoordObs:
             r.counter_add(_COORD + "hb_single_total", 0, {"cause": cause})
         for kind in ("heartbeat", "elect", "demote"):
             r.counter_add(_COORD + "tick_flags_total", 0, {"kind": kind})
+        for origin in ("local", "remote"):
+            r.counter_add(_COORD + "reads_total", 0, {"origin": origin})
         r.gauge_set(_COORD + "staged_depth", 0)
         r.gauge_set(_COORD + "rows", 0)
         r.histogram_declare(
@@ -1403,6 +1409,7 @@ class CoordObs:
         reads_staged: int = 0,
         reads_refused: int = 0,
         plane: Optional[dict] = None,
+        fan_in: Optional[dict] = None,
     ) -> dict:
         """Close a dispatched round's span (``round_open``).  The
         recorder's stall check on ``wall_ms`` IS the round-gate watchdog:
@@ -1427,7 +1434,11 @@ class CoordObs:
         (``hb_lite_rows`` of them served without the group's lock)
         against ``hb_single`` (cause -> count, since the last recorded
         round: block messages arrive between rounds), and the ``rows``
-        registered."""
+        registered.  ``fan_in`` is what the drains since the last recorded
+        round handed on: ``acks_drained`` (follower acknowledgements),
+        ``reads_local`` / ``reads_remote`` (ReadIndex contexts a leader
+        staged for the host's own clients / that a follower forwarded),
+        and ``voters``, the largest voter count of the host's rows."""
         r = self.registry
         t1 = time.perf_counter()
         wall_ms = (t1 - span["t0"]) * 1e3
@@ -1492,6 +1503,14 @@ class CoordObs:
             extra["hb_single"] = single
             extra["rows"] = plane["rows"]
             r.gauge_set(_COORD + "rows", plane["rows"])
+        if fan_in is not None:
+            for origin in ("local", "remote"):
+                if fan_in["reads_" + origin]:
+                    r.counter_add(
+                        _COORD + "reads_total", fan_in["reads_" + origin],
+                        {"origin": origin},
+                    )
+            extra.update(fan_in)
         ph = self.ph
         self.recorder.update(
             span,

@@ -139,6 +139,10 @@ class _MeshGroupInfo:
         return self._gi.slots
 
     @property
+    def self_slot(self) -> int:
+        return self._gi.self_slot
+
+    @property
     def node_ids(self):
         return self._gi.node_ids
 

@@ -1460,6 +1460,7 @@ class Raft:
                 self.offload.read_stage(
                     self.cluster_id, self.log.committed, ctx.low, ctx.high,
                     self.term,
+                    remote=m.from_ not in (NO_NODE, self.node_id),
                 )
             self.broadcast_heartbeat_message_with_hint(ctx)
         else:
@@ -1575,16 +1576,25 @@ class Raft:
         # reference raft.go:1740-1760
         ctx = SystemCtx(low=m.hint, high=m.hint_high)
         ris = self.read_index.confirm(ctx, m.from_, self.quorum())
-        self.apply_read_releases(ris, ctx)
+        self.apply_read_releases(ris)
 
-    def apply_read_releases(self, ris, ctx: SystemCtx) -> None:
+    def apply_read_releases(self, ris) -> None:
         """Route released ReadStatuses: local requesters land in
-        ``ready_to_read``, remote ones get a READ_INDEX_RESP carrying the
-        CONFIRMED ctx (reference raft.go:1740-1760 echoes ``m.Hint``, not
-        the released request's own ctx).  Shared by the scalar confirm
-        above and the device read plane's confirmed egress
-        (``node._apply_offload_effects``) — both release through
-        ``read_index``, so routing and indices are identical."""
+        ``ready_to_read``, remote ones get a READ_INDEX_RESP carrying
+        their OWN ctx.  Shared by the scalar confirm above and the device
+        read plane's confirmed egress (``node._apply_offload_effects``) —
+        both release through ``read_index``, so routing and indices are
+        identical.
+
+        A departure from the reference (raft.go:1740-1760 echoes
+        ``m.Hint``, the CONFIRMING ctx, to every released requester): a
+        confirmation releases its ctx and every ctx queued before it, and
+        a follower completes a forwarded read by the ctx in the response
+        (``handle_follower_read_index_resp``), so a requester released by
+        a later ctx — another follower's, or a later one of its own —
+        was told of a ctx it never asked for and waited out its timeout.
+        With four forwarders a group that is a tenth of the reads
+        (ISSUE 34); the index is the confirming ctx's either way."""
         for s in ris:
             if s.from_ == NO_NODE or s.from_ == self.node_id:
                 self.add_ready_to_read(s.index, s.ctx)
@@ -1594,8 +1604,8 @@ class Raft:
                         to=s.from_,
                         type=MT.READ_INDEX_RESP,
                         log_index=s.index,
-                        hint=ctx.low,
-                        hint_high=ctx.high,
+                        hint=s.ctx.low,
+                        hint_high=s.ctx.high,
                     )
                 )
 

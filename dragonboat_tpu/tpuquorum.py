@@ -190,6 +190,14 @@ class TpuQuorumCoordinator:
         self.reads_refused = 0
         # the four counters above as of the last recorded coord_round span
         self._reads_spanned = (0, 0, 0, dict(self.read_fallback_causes))
+        # what the drains handed on, counted only while _obs is attached:
+        # follower acknowledgements, and the ReadIndex contexts a leader
+        # staged by origin — the host's own clients' against those a
+        # follower forwarded; then the three as of the last recorded span
+        self.acks_drained = 0
+        self.reads_local = 0
+        self.reads_remote = 0
+        self._drained_spanned = (0, 0, 0)
         # cid -> {(low, high): cause} of ctxs no longer (or never)
         # device-tracked, newest last; guarded by _mu like _read_pending
         self._read_gone: Dict[int, dict] = {}
@@ -831,12 +839,16 @@ class TpuQuorumCoordinator:
             self.eng.heartbeat_resp_block(rows, slots)
 
     def read_stage(
-        self, cluster_id: int, committed: int, low: int, high: int, term: int
+        self, cluster_id: int, committed: int, low: int, high: int, term: int,
+        remote: bool = False,
     ) -> None:
         """A leader accepted a ReadIndex ctx (``handle_leader_read_index``
         under raftMu): stage it into the group's pending-read slot,
-        captured at scalar raft's own committed watermark."""
-        self._stage(("rstage", cluster_id, committed, low, high, term))
+        captured at scalar raft's own committed watermark.  ``remote``
+        says a follower forwarded it (counted by origin, obs on)."""
+        self._stage(
+            ("rstage", cluster_id, committed, low, high, term, remote)
+        )
 
     def read_ack_hint(
         self, cluster_id: int, node_id: int, low: int, high: int
@@ -895,6 +907,7 @@ class TpuQuorumCoordinator:
         recover = []
         lt = self.lease_table
         lease_acks: Dict[int, set] = {}
+        obs_on = self._obs is not None
         # bulk-pull every row a transition below will mutate: one device
         # gather per field for the whole set, instead of ~20 single-row
         # reads inside each set_* call (the dominant cost of election
@@ -917,6 +930,12 @@ class TpuQuorumCoordinator:
             try:
                 if kind == "ack":
                     self.eng.ack(cid, op[2], op[3])
+                    if obs_on:
+                        # a leader's own append is staged as an ack too,
+                        # and is no follower's acknowledgement
+                        gi = self.eng.groups[cid]
+                        if gi.slots.get(op[2]) != gi.self_slot:
+                            self.acks_drained += 1
                 elif kind == "vote":
                     self.eng.vote(cid, op[2], op[3])
                 elif kind == "hbresp":
@@ -929,6 +948,11 @@ class TpuQuorumCoordinator:
                 elif kind == "randto":
                     self.eng.set_randomized_timeout(cid, op[2])
                 elif kind == "rstage":
+                    if obs_on:  # by origin, given a device slot or refused
+                        if op[6]:
+                            self.reads_remote += 1
+                        else:
+                            self.reads_local += 1
                     try:
                         slot = self.eng.stage_read(cid, count=1, index=op[2])
                     except RuntimeError:
@@ -1346,6 +1370,7 @@ class TpuQuorumCoordinator:
                 plane=self._plane_account(
                     res, do_tick, deficit, dropped, held, n_rows
                 ),
+                fan_in=self._fan_in_account(),
             )
         # cost-driven placement (mesh dispatch plane): a time-gated
         # rebalance pass on dispatched rounds only — quiet coordinators
@@ -1513,6 +1538,29 @@ class TpuQuorumCoordinator:
             "hb_lite_rows": self.hb_lite_rows - busy0,
             "hb_single": {c: causes[c] - n for c, n in single0.items()},
             "rows": n_rows,
+        }
+
+    def _fan_in_account(self) -> dict:
+        """What the drains since the last recorded round handed on (obs
+        on; a round that only drained has no span, so its counts ride
+        the next one that has)."""
+        acks0, local0, remote0 = self._drained_spanned
+        self._drained_spanned = (
+            self.acks_drained, self.reads_local, self.reads_remote
+        )
+        # a descriptor: the widest majority the kernel computes here, read
+        # off the masks it computes with (a mesh engine has one a shard)
+        voters = 0
+        for shard in getattr(self.eng, "shards", (self.eng,)):
+            a = shard.mirror.arrays
+            voters = max(
+                voters, int((a["voting"].sum(axis=1) * a["live"]).max())
+            )
+        return {
+            "acks_drained": self.acks_drained - acks0,
+            "reads_local": self.reads_local - local0,
+            "reads_remote": self.reads_remote - remote0,
+            "voters": voters,
         }
 
     def _collect_read_confirms(self, res, out: list) -> None:

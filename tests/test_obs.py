@@ -814,6 +814,17 @@ def test_round_span_is_parent_of_its_dispatches_and_holds_its_phases():
         coord.stop()
 
 
+def _closed_rounds(rec, timeout_s=10.0):
+    """The recorder's ``coord_round`` spans once none is still open (the
+    round thread races ``flush()`` for what was staged)."""
+    deadline = time.time() + timeout_s
+    while True:
+        rounds = [s for s in rec.spans() if s["kind"] == "coord_round"]
+        if all("wall_ms" in s for s in rounds) or time.time() > deadline:
+            return rounds
+        time.sleep(0.005)
+
+
 def _echo_cause_cases():
     from dragonboat_tpu.ops.state import READ_SLOTS
 
@@ -879,12 +890,7 @@ def test_read_echo_fallbacks_are_counted_by_cause(cause):
         coord.flush()
         # the round thread races flush() for the staged ack: if it won,
         # its round may still be open (a span without its counts yet)
-        deadline = time.time() + 10
-        while True:
-            rounds = [s for s in rec.spans() if s["kind"] == "coord_round"]
-            if all("wall_ms" in s for s in rounds) or time.time() > deadline:
-                break
-            time.sleep(0.005)
+        rounds = _closed_rounds(rec)
         assert sum(s["read_fallback_" + cause] for s in rounds) == 1
         assert sum(s["read_acks"] for s in rounds) == coord.read_acks
         assert reg.counter_value(
@@ -897,6 +903,65 @@ def test_read_echo_fallbacks_are_counted_by_cause(cause):
             cause) in text
         assert "dragonboat_coord_read_fallbacks " not in text
         assert "# TYPE dragonboat_coord_read_fallbacks gauge" not in text
+    finally:
+        coord.stop()
+
+
+@pytest.mark.parametrize("obs_on", [True, False])
+def test_round_span_counts_the_fan_in_by_origin(obs_on):
+    """ISSUE 34: with the instruments attached a round's span carries the
+    follower acknowledgements its drains handed on, the ReadIndex contexts
+    a leader staged by origin (the host's own clients' against those a
+    follower forwarded; a context refused a device slot counts too) and
+    the largest voter count of the host's rows, and the contexts feed
+    ``dragonboat_coord_reads_total{origin}``.  Detached, nothing of it is
+    counted."""
+    from dragonboat_tpu.ops.state import READ_SLOTS
+
+    rec = FlightRecorder(stall_ms=0)
+    reg = MetricsRegistry()
+    coord, nodes = _coord_with_leaders([7, 8], None)
+    if obs_on:
+        coord.enable_obs(recorder=rec, registry=reg, host="h")
+    try:
+        term = nodes[7].peer.raft.term
+        coord.read_stage(7, 1, low=1, high=1, term=term)
+        coord.read_stage(8, 1, low=2, high=2, term=term, remote=True)
+        coord.ack(7, 1, 1)  # the leader's own append: no follower's
+        coord.ack(7, 2, 1)
+        coord.ack(7, 3, 1)
+        coord.flush()
+        # more forwarded contexts than the group has slots: refused ones
+        # are still contexts a follower forwarded
+        for low in range(3, READ_SLOTS + 5):
+            coord.read_stage(8, 1, low=low, high=low, term=term, remote=True)
+        coord.ack(8, 2, 1)
+        coord.flush()
+        n_remote = 1 + READ_SLOTS + 2
+        assert coord.reads_refused >= 1
+        if not obs_on:
+            assert coord._obs is None
+            assert (coord.acks_drained, coord.reads_local,
+                    coord.reads_remote) == (0, 0, 0)
+            assert rec.spans() == []
+            return
+        assert (coord.acks_drained, coord.reads_local,
+                coord.reads_remote) == (3, 1, n_remote)
+        rounds = _closed_rounds(rec)
+        assert sum(s["acks_drained"] for s in rounds) == 3
+        assert sum(s["reads_local"] for s in rounds) == 1
+        assert sum(s["reads_remote"] for s in rounds) == n_remote
+        assert {s["voters"] for s in rounds} == {3}
+        assert sum(s["commits"] for s in rounds) == 2  # both groups
+        for origin, n in (("local", 1), ("remote", n_remote)):
+            assert reg.counter_value(
+                "dragonboat_coord_reads_total", {"origin": origin}) == n
+        out = io.StringIO()
+        reg.write_health_metrics(out)
+        text = out.getvalue()
+        assert 'dragonboat_coord_reads_total{origin="remote"} %d' % (
+            n_remote) in text
+        assert "# HELP dragonboat_coord_reads_total" in text
     finally:
         coord.stop()
 
