@@ -541,6 +541,9 @@ class EngineObs:
         arrays_made: int = 0,
         arrays_retired: int = 0,
         ack_blocks_stale: int = 0,
+        read_blocks_stale: int = 0,
+        reads_scalar: int = 0,
+        echoes_scalar: int = 0,
     ) -> dict:
         """One logical step's device work launched: publish counters +
         latency, and open its span (egress fields land via
@@ -555,7 +558,11 @@ class EngineObs:
         replaced; :meth:`egress` adds the egress block) count what costs
         the round thread a hand-off of the interpreter each;
         ``ack_blocks_stale`` the staged ack blocks that took the per-row
-        epoch comparison (staged before a row transition)."""
+        epoch comparison (staged before a row transition),
+        ``read_blocks_stale`` the read-stage and echo blocks that did;
+        ``reads_scalar`` / ``echoes_scalar`` the tuple-staged read stages
+        (cancels too) and echoes that were filtered as tuples, on scalars
+        (of ``reads`` / ``echoes``; the rest rode blocks)."""
         r = self.registry
         r.counter_add(_DEV + "dispatch_total", n_dispatches)
         r.counter_add(_DEV + "rounds_total", rounds)
@@ -606,6 +613,9 @@ class EngineObs:
             arrays_made=arrays_made,
             arrays_retired=arrays_retired,
             ack_blocks_stale=ack_blocks_stale,
+            read_blocks_stale=read_blocks_stale,
+            reads_scalar=reads_scalar,
+            echoes_scalar=echoes_scalar,
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")
@@ -625,11 +635,13 @@ class EngineObs:
 
     def egress(
         self, span: dict, *, egress_ms: float, egress_rows: int,
-        reads_released: int, arrays_retired: int = 0,
+        reads_released: int, arrays_retired: int = 0, decode_pairs: int = 0,
     ) -> None:
         """Close a dispatch span at harvest: blocking egress wall time
         plus what the block released; ``arrays_retired`` (the egress
-        block, dropped once fetched) adds to the span's count."""
+        block, dropped once fetched) adds to the span's count;
+        ``decode_pairs`` the (row, slot) pairs the read decode visited on
+        scalars (0: no read plane, or the whole plane was scanned)."""
         r = self.registry
         r.histogram_observe(
             _DEV + "egress_latency_ms", egress_ms, buckets=LATENCY_BUCKETS_MS
@@ -646,6 +658,7 @@ class EngineObs:
             egress_rows=egress_rows,
             reads_released=reads_released,
             arrays_retired=span.get("arrays_retired", 0) + arrays_retired,
+            decode_pairs=decode_pairs,
         )
         if self.recorder.stalls != stalls:
             r.counter_add(_DEV + "stalls_total")

@@ -267,6 +267,28 @@ def compilation_log() -> List[Tuple[float, float, str, str, str]]:
     return list(_CC_LOG)
 
 
+def _ack_columns(live, blocks) -> tuple:
+    """Filtered acks (``_filter_acks``) as three int32 columns."""
+    cols = _concat_columns(live, blocks, 3)
+    if cols is None:
+        z = np.zeros((0,), np.int32)
+        return z, z, z
+    return cols
+
+
+def _concat_columns(tuples, blocks, n: int):
+    """``n`` columns of staged events, the tuple-staged ones (as int32)
+    first, then each block's; None where there is none."""
+    parts = list(blocks)
+    if tuples:
+        parts.insert(0, tuple(np.array(tuples, dtype=np.int32).T))
+    if not parts:
+        return None
+    if len(parts) == 1:
+        return parts[0]
+    return tuple(np.concatenate([p[i] for p in parts]) for i in range(n))
+
+
 @dataclass
 class GroupInfo:
     cluster_id: int
@@ -566,16 +588,23 @@ class BatchedQuorumEngine:
         # an election burst of 1,024 transitions cost a 680ms round).
         self._row_epoch = np.zeros((n_groups,), np.int32)
         # the epochs' generation: one counter, bumped with every row's
-        # epoch.  A staged ack BLOCK carries it instead of a per-row copy
-        # of ``_row_epoch`` (an index-array read when it is staged and
-        # another when it is gathered, each a hand-off of the interpreter
-        # on the round thread): a block of the current generation is
-        # whole, an older one is filtered against the newest entries of
-        # ``_epoch_bumped`` (the rows bumped while a block was staged,
-        # kept until the next gather), as many as it is generations old
+        # epoch.  A staged BLOCK (acks, read stages, read echoes) carries
+        # it instead of a per-row copy of ``_row_epoch`` (an index-array
+        # read when it is staged and another when it is gathered, each a
+        # hand-off of the interpreter on the round thread): a block of
+        # the current generation is whole, an older one is filtered
+        # against the newest entries of ``_epoch_bumped`` (the rows
+        # bumped while a block was staged, kept until the next gather),
+        # as many as it is generations old
         self._epoch_gen = 0
         self._epoch_bumped: List[int] = []
-        self._n_stale_blocks = 0   # ack blocks filtered since the last span
+        # since the last span: staged blocks that took the per-row
+        # comparison (acks; read stages and echoes), tuple-staged read
+        # events filtered as tuples, (row, slot) pairs the decode visited
+        self._n_stale_blocks = 0
+        self._n_stale_read_blocks = 0
+        self._n_reads_scalar = 0
+        self._n_echoes_scalar = 0
         # pending event buffers (grow unbounded host-side; chunked at
         # dispatch); entries carry the staging epoch as a 4th column
         self._acks: List[Tuple[int, int, int, int]] = []  # row, slot, rel, ep
@@ -606,12 +635,22 @@ class BatchedQuorumEngine:
         self._inflight = None
         # --- device read plane staging (ISSUE 3 tentpole) ---------------
         # ReadIndex batches and heartbeat echoes of the CURRENT open
-        # round; epoch columns filter events staged before a transition,
-        # exactly like the ack/vote buffers
+        # round; an epoch column (tuples) or the epoch generation (blocks)
+        # filters events staged before a transition, exactly like the
+        # ack buffers: (row, slot, rel, count, epoch) / (rows, slots,
+        # rels, counts, generation); (row, slot, peer, epoch) / (rows,
+        # rslots, peers, generation)
         self._read_stages: List[Tuple[int, int, int, int, int]] = []
-        self._read_stage_blocks: List[Tuple[np.ndarray, ...]] = []
+        self._read_stage_blocks: List[tuple] = []
         self._read_echoes: List[Tuple[int, int, int, int]] = []
-        self._read_echo_blocks: List[Tuple[np.ndarray, ...]] = []
+        self._read_echo_blocks: List[tuple] = []
+        # (row, slot) pairs the next read-plane decode must visit besides
+        # those its own dispatch stages or echoes: the slots pending on a
+        # row whose mirror was uploaded since the last such decode (an
+        # upload rewrites ``voting`` / ``quorum`` / ``node_state`` /
+        # ``live``, which ``kernels.read_confirm`` reads too).  None:
+        # the device state was assigned from outside, scan the plane.
+        self._read_recheck: Optional[set] = set()
         # host slot bookkeeping.  A slot is BUSY from stage until its
         # batch deterministically confirms: the device only ever sees
         # echoes this host staged, so once the staged echoes of a batch
@@ -1332,6 +1371,7 @@ class BatchedQuorumEngine:
                 blocks = jax.device_put(blocks, block_sharding(self.sharding))
         self._blk = blocks
         self._cache_stale = True
+        self._read_recheck = None
         self._synced.clear()
 
     # ------------------------------------------------------------------
@@ -1430,7 +1470,10 @@ class BatchedQuorumEngine:
         pending read captures drop with the host bookkeeping reset."""
         self._row_epoch[row] += 1
         self._epoch_gen += 1
-        if self._ack_blocks:  # only a staged block can be older than this
+        if (
+            self._ack_blocks or self._read_stage_blocks
+            or self._read_echo_blocks
+        ):  # only a staged block can be older than this
             self._epoch_bumped.append(row)
         self._reset_read_rows([row])
         if self._read_plane_used:  # else provably already clear
@@ -1922,7 +1965,7 @@ class BatchedQuorumEngine:
         self._read_stage_blocks.append(
             (rows.astype(np.int32), slot.astype(np.int32),
              rels.astype(np.int32), counts.astype(np.int32),
-             self._row_epoch[rows.astype(np.int32)].copy())
+             self._epoch_gen)
         )
         return slot
 
@@ -1958,11 +2001,10 @@ class BatchedQuorumEngine:
             raise ValueError("read_ack_block read slot out of range")
         if peers.min() < 0 or peers.max() >= self.n_peers:
             raise ValueError("read_ack_block peer slot out of range")
-        rows32 = rows.astype(np.int32)
         self._read_plane_used = True
         self._read_echo_blocks.append(
-            (rows32, rslots.astype(np.int32), peers.astype(np.int32),
-             self._row_epoch[rows32].copy())
+            (rows.astype(np.int32), rslots.astype(np.int32),
+             peers.astype(np.int32), self._epoch_gen)
         )
         rows64 = rows.astype(np.int64)
         rslots64 = rslots.astype(np.int64)
@@ -1995,58 +2037,73 @@ class BatchedQuorumEngine:
         )
         return int(free.sum())
 
-    def _gather_reads(self):
-        """Open-round read-plane buffers as flat arrays with stale-epoch
-        events filtered; clears the buffers and advances the slot-reuse
-        round seq (one call per round close).  Returns ``(reads, racks)``
-        — each a tuple of int32 arrays or None."""
+    def _filter_reads(self):
+        """The open round's read-plane buffers with stale-epoch events
+        (staged before a row transition) filtered out: ``(stages,
+        stage_blocks, echoes, echo_blocks)``, the tuples ``(row, slot,
+        rel, count)`` / ``(row, slot, peer)`` as they were staged, the
+        blocks as tuples of int32 arrays; clears the buffers and advances
+        the slot-reuse round seq (one call per round close, after
+        ``_filter_acks``: no staged block is left to be older than a
+        bump).  Each event keeps the arity it was staged in, as in
+        ``_filter_acks``: tuples are filtered as tuples against
+        ``_row_epoch[r]`` read as a scalar and no array is made of them;
+        a block is whole unless a row's epoch was bumped after it was
+        staged, and only then compared, against those rows."""
         self._round_seq += 1
-        reads = racks = None
-        parts = []
+        epoch = self._row_epoch
+        stages = echoes = ()
         if self._read_stages:
-            cols = np.array(self._read_stages, dtype=np.int64)
-            rows = cols[:, 0].astype(np.int32)
-            keep = cols[:, 4].astype(np.int32) == self._row_epoch[rows]
-            if keep.any():
-                parts.append(tuple(
-                    cols[keep, i].astype(np.int32) for i in range(4)
-                ))
+            stages = [
+                e[:4] for e in self._read_stages if e[4] == epoch[e[0]]
+            ]
             self._read_stages = []
-        if self._read_stage_blocks:
-            for r, sl, v, c, ep in self._read_stage_blocks:
-                keep = ep == self._row_epoch[r]
-                if keep.all():
-                    parts.append((r, sl, v, c))
-                elif keep.any():
-                    parts.append((r[keep], sl[keep], v[keep], c[keep]))
-            self._read_stage_blocks = []
-        if parts:
-            reads = tuple(
-                np.concatenate([p[i] for p in parts]) for i in range(4)
-            )
-        parts = []
+            self._n_reads_scalar += len(stages)
         if self._read_echoes:
-            cols = np.array(self._read_echoes, dtype=np.int64)
-            rows = cols[:, 0].astype(np.int32)
-            keep = cols[:, 3].astype(np.int32) == self._row_epoch[rows]
-            if keep.any():
-                parts.append(tuple(
-                    cols[keep, i].astype(np.int32) for i in range(3)
-                ))
+            echoes = [
+                e[:3] for e in self._read_echoes if e[3] == epoch[e[0]]
+            ]
             self._read_echoes = []
+            self._n_echoes_scalar += len(echoes)
+        stage_blocks = echo_blocks = ()
+        if self._read_stage_blocks:
+            stage_blocks, stale = self._live_blocks(self._read_stage_blocks)
+            self._n_stale_read_blocks += stale
+            self._read_stage_blocks = []
         if self._read_echo_blocks:
-            for r, sl, p, ep in self._read_echo_blocks:
-                keep = ep == self._row_epoch[r]
-                if keep.all():
-                    parts.append((r, sl, p))
-                elif keep.any():
-                    parts.append((r[keep], sl[keep], p[keep]))
+            echo_blocks, stale = self._live_blocks(self._read_echo_blocks)
+            self._n_stale_read_blocks += stale
             self._read_echo_blocks = []
-        if parts:
-            racks = tuple(
-                np.concatenate([p[i] for p in parts]) for i in range(3)
+        self._epoch_bumped.clear()
+        return stages, stage_blocks, echoes, echo_blocks
+
+    def _live_blocks(self, blocks) -> tuple:
+        """Staged blocks ``(rows, ..., generation)`` less the rows bumped
+        after each was staged, and how many of them had to be compared
+        row by row: a block of the current generation is whole."""
+        out, stale = [], 0
+        for *cols, gen in blocks:
+            if gen == self._epoch_gen:
+                out.append(tuple(cols))
+                continue
+            stale += 1
+            keep = np.isin(
+                cols[0], self._epoch_bumped[gen - self._epoch_gen:],
+                invert=True,
             )
-        return reads, racks
+            if keep.any():
+                out.append(tuple(c[keep] for c in cols))
+        return out, stale
+
+    def _gather_reads(self):
+        """``_filter_reads`` as flat arrays, for a sealed round of the
+        fused path: ``(reads, racks)``, each a tuple of int32 arrays
+        (tuple-staged events first) or None."""
+        stages, stage_blocks, echoes, echo_blocks = self._filter_reads()
+        return (
+            _concat_columns(stages, stage_blocks, 4),
+            _concat_columns(echoes, echo_blocks, 3),
+        )
 
     def _reads_pending(self) -> bool:
         return bool(
@@ -2608,6 +2665,7 @@ class BatchedQuorumEngine:
             )
             if rdc is not None:
                 self._translate_reads(res, rdc, rdi, row_cid, row_base)
+                self._read_recheck = set()  # the scan saw every slot
             res.committed_rel = committed
             self._committed_cache = np.array(committed, dtype=np.int32)
             if self._churn_pending:
@@ -2723,6 +2781,44 @@ class BatchedQuorumEngine:
             row_base[rows] + np.asarray(done_idx)[rows, slots]
         )
         res.read_counts = done_cnt[rows, slots].astype(np.int64)
+
+    def _decode_reads(self, res, done_cnt, done_idx, reads) -> int:
+        """Confirmed-read egress of ONE single-round dispatch, off the
+        fetched block: visits only the (row, slot) pairs that can have
+        confirmed in it, on scalars, and returns how many it visited (0:
+        it scanned the plane).  A slot's confirmation changes only in a
+        dispatch that staged or echoed it (a quorum of one confirms at
+        its stage), or after its row's mirror was uploaded
+        (``_read_recheck``); everything already confirmed was released by
+        the dispatch that confirmed it.  The device stays the authority:
+        what is released is what the block says at those pairs, in
+        ``_translate_reads``' order and types.  A dispatch that carried
+        block-staged events keeps the vectorized scan."""
+        stages, stage_blocks, echoes, echo_blocks = reads
+        recheck, self._read_recheck = self._read_recheck, set()
+        if stage_blocks or echo_blocks or recheck is None:
+            self._translate_reads(
+                res, done_cnt, done_idx, self._row_cid, self._row_base
+            )
+            return 0
+        for r, sl, _rel, _c in stages:
+            recheck.add((r, sl))
+        for r, sl, _pe in echoes:
+            recheck.add((r, sl))
+        row_cid, row_base = self._row_cid, self._row_base
+        out = []
+        for r, sl in sorted(recheck):
+            c = done_cnt[r, sl]
+            if c and row_cid[r] >= 0:
+                out.append((
+                    int(row_cid[r]), sl,
+                    int(row_base[r]) + int(done_idx[r, sl]), int(c),
+                ))
+        if out:
+            (res.read_cids, res.read_slots, res.read_index_abs,
+             res.read_counts) = np.array(out, dtype=np.int64).T
+            res._reads_list = out
+        return len(recheck)
 
     def _dispatch_multiround(
         self, blocks: List[_RoundBuf], do_tick: bool, tick_mask: np.ndarray,
@@ -3079,11 +3175,20 @@ class BatchedQuorumEngine:
         obs = self._obs
         with (obs.phase("row_sync") if obs is not None else _OFF):
             rows = self._pad_rows(np.fromiter(self._dirty, dtype=np.int32))
+            vals = {k: self.mirror.arrays[k][rows] for k in self._sync_keys()}
             with self._dispatch_mu:
-                self._blk = self._scatter_sync_rows(
-                    self._blk, rows,
-                    {k: self.mirror.arrays[k][rows]
-                     for k in self._sync_keys()},
+                self._blk = self._scatter_sync_rows(self._blk, rows, vals)
+            pending = vals.get("read_count")
+            if (
+                pending is not None and self._read_recheck is not None
+                and pending.any()
+            ):
+                # an upload rewrites what ``read_confirm`` reads of the
+                # row: its pending slots may confirm in the next
+                # read-plane dispatch with no event of their own
+                at, slots = np.nonzero(pending)
+                self._read_recheck.update(
+                    zip(rows[at].tolist(), slots.tolist())
                 )
             # keep the host committed twin coherent with the rows just
             # written
@@ -3142,11 +3247,12 @@ class BatchedQuorumEngine:
 
         n_dispatches = 1
         with (obs.phase("stage") if obs is not None else _OFF):
-            ack_g, ack_p, ack_v = self._gather_acks()
-            reads, racks = self._gather_reads()
+            acks = self._filter_acks()
+            n_acks = len(acks[0]) + sum(b[0].size for b in acks[1])
+            reads = self._filter_reads()
             kvents, kvreads = self._gather_kv()
             n_votes = len(self._votes) if obs is not None else 0
-            has_reads = reads is not None or racks is not None
+            has_reads = any(reads)
             # the apply fold must ALSO run while any entry sits buffered
             # on device: its commit may land in this (otherwise kv-free)
             # dispatch, and a fold-free program would leave it unapplied
@@ -3166,18 +3272,19 @@ class BatchedQuorumEngine:
         if has_reads or has_kv or self.dense_ingest is True or (
             self.dense_ingest == "auto"
             and (
-                ack_g.size >= self._dense_threshold
-                or ack_g.size > self.event_cap
+                n_acks >= self._dense_threshold
+                or n_acks > self.event_cap
                 or len(self._votes) > self.event_cap
             )
         ):
             out = self._dispatch_dense(
-                ack_g, ack_p, ack_v, self._votes, do_tick, reads, racks,
+                acks, self._votes, do_tick, reads if has_reads else None,
                 kvents, kvreads, has_kv=has_kv,
             )
             planes = (has_reads, has_kv)
         else:
             planes = (False, False)  # the sparse program carries neither
+            ack_g, ack_p, ack_v = _ack_columns(*acks)
             pos = 0
             n_chunks = 0
             while (ack_g.size - pos) > self.event_cap or len(self._votes) > self.event_cap:
@@ -3208,8 +3315,9 @@ class BatchedQuorumEngine:
         self._synced.clear()
 
         if obs is not None:
-            n_reads = int(reads[0].size) if reads is not None else 0
-            n_echo = int(racks[0].size) if racks is not None else 0
+            stages, stage_blocks, echoes, echo_blocks = reads
+            n_reads = len(stages) + sum(b[0].size for b in stage_blocks)
+            n_echo = len(echoes) + sum(b[0].size for b in echo_blocks)
             if has_kv:
                 self._obs_kv_span = obs.apply_kernel(
                     ops=int(kvents[0].size) if kvents is not None else 0,
@@ -3223,7 +3331,7 @@ class BatchedQuorumEngine:
                 "dispatch",
                 rounds=1,
                 k_rounds=1,
-                acks=int(ack_g.size),
+                acks=n_acks,
                 votes=n_votes,
                 recycles=0,
                 reads=n_reads,
@@ -3232,7 +3340,7 @@ class BatchedQuorumEngine:
                 n_dispatches=n_dispatches,
                 dispatch_ms=(time.perf_counter() - obs.t0) * 1e3,
                 gate=self._obs_gate(
-                    do_tick, ack_g.size, n_votes, 0, n_reads, n_echo
+                    do_tick, n_acks, n_votes, 0, n_reads, n_echo
                 ),
                 mu_wait_ms=mu_wait,
                 pending_rounds=0,
@@ -3259,10 +3367,10 @@ class BatchedQuorumEngine:
             committed, bits, rdc, rdi, kvv, kvi, kva = _pk.split_egress(
                 eg, self._dims, *planes
             )
-            if rdc is not None:
-                self._translate_reads(
-                    res, rdc, rdi, self._row_cid, self._row_base
-                )
+            n_pairs = (
+                self._decode_reads(res, rdc, rdi, reads)
+                if rdc is not None else 0
+            )
             # device_get arrays are read-only; the cache must stay
             # writable for _upload_dirty's row sync
             self._committed_cache = np.array(committed, dtype=np.int32)
@@ -3288,6 +3396,7 @@ class BatchedQuorumEngine:
                     int(res.read_counts.sum())
                     if res.read_counts is not None else 0
                 ),
+                decode_pairs=n_pairs,
             )
             kv_span, self._obs_kv_span = self._obs_kv_span, None
             if kv_span is not None:
@@ -3301,45 +3410,34 @@ class BatchedQuorumEngine:
                 )
         return res
 
-    def _gather_acks(self):
-        """Tuple-staged + block-staged acks as three flat arrays, with
-        stale-epoch events (staged before a row transition) filtered out;
-        clears both buffers.  No array is read through an index array on
-        the way (each such read hands the interpreter away, whatever its
-        size): tuples are filtered as tuples and become the three columns
-        once; a block is whole unless a row's epoch was bumped after it
-        was staged, and only then compared, against those rows."""
-        parts = []
+    def _filter_acks(self):
+        """Tuple-staged and block-staged acks with stale-epoch events
+        (staged before a row transition) filtered out: ``(live, blocks)``,
+        the ``(row, slot, rel)`` tuples and the blocks as triples of
+        int32 arrays; clears both buffers.  No array is read through an
+        index array on the way (each such read hands the interpreter
+        away, whatever its size): tuples are filtered as tuples; a block
+        is whole unless a row's epoch was bumped after it was staged, and
+        only then compared, against those rows (``_epoch_bumped``, which
+        ``_filter_reads`` clears once the round's read blocks have been
+        held against it too)."""
+        live, blocks = (), ()
         if self._acks:
             epoch = self._row_epoch
             live = [
                 (r, s, v) for r, s, v, ep in self._acks if ep == epoch[r]
             ]
             self._acks = []
-            if live:
-                parts.append(tuple(np.array(live, dtype=np.int32).T))
         if self._ack_blocks:
-            for r, s, v, gen in self._ack_blocks:
-                if gen == self._epoch_gen:
-                    parts.append((r, s, v))
-                    continue
-                self._n_stale_blocks += 1
-                keep = np.isin(
-                    r, self._epoch_bumped[gen - self._epoch_gen:],
-                    invert=True,
-                )
-                if keep.any():
-                    parts.append((r[keep], s[keep], v[keep]))
+            blocks, stale = self._live_blocks(self._ack_blocks)
+            self._n_stale_blocks += stale
             self._ack_blocks = []
-        self._epoch_bumped.clear()  # no staged block is left to be older
-        if not parts:
-            z = np.zeros((0,), np.int32)
-            return z, z, z
-        if len(parts) == 1:
-            return parts[0]
-        return tuple(
-            np.concatenate([p[i] for p in parts]) for i in range(3)
-        )
+        return live, blocks
+
+    def _gather_acks(self):
+        """``_filter_acks`` as three flat arrays (the sparse program's
+        event lists, a sealed round of the fused path)."""
+        return _ack_columns(*self._filter_acks())
 
     def _ingress_for(self, kind: str, **layout) -> _pk.Ingress:
         """The host buffer a dispatch of this shape stages into, every
@@ -3404,13 +3502,23 @@ class BatchedQuorumEngine:
 
     def _take_array_counts(self) -> dict:
         """``arrays_made`` (none: the ingress block rides the launch),
-        ``arrays_retired`` (state blocks replaced, unread egress) and
-        ``ack_blocks_stale`` (ack blocks that took the per-row epoch
-        comparison) since the last span, zeroed."""
-        retired, self._n_retired = self._n_retired, 0
-        stale, self._n_stale_blocks = self._n_stale_blocks, 0
-        return {"arrays_made": 0, "arrays_retired": retired,
-                "ack_blocks_stale": stale}
+        ``arrays_retired`` (state blocks replaced, unread egress),
+        ``ack_blocks_stale`` / ``read_blocks_stale`` (staged blocks that
+        took the per-row epoch comparison) and ``reads_scalar`` /
+        ``echoes_scalar`` (tuple-staged read events filtered as tuples)
+        since the last span, zeroed."""
+        out = {
+            "arrays_made": 0,
+            "arrays_retired": self._n_retired,
+            "ack_blocks_stale": self._n_stale_blocks,
+            "read_blocks_stale": self._n_stale_read_blocks,
+            "reads_scalar": self._n_reads_scalar,
+            "echoes_scalar": self._n_echoes_scalar,
+        }
+        self._n_retired = self._n_stale_blocks = 0
+        self._n_stale_read_blocks = 0
+        self._n_reads_scalar = self._n_echoes_scalar = 0
+        return out
 
     def _stage_sparse(self, acks, votes, has_votes: bool) -> _pk.Ingress:
         """The event lists of one sparse dispatch in its ingress block:
@@ -3465,21 +3573,28 @@ class BatchedQuorumEngine:
         return out
 
     def _dispatch_dense(
-        self, ag, ap, av, votes, do_tick: bool, reads=None, racks=None,
+        self, acks, votes, do_tick: bool, reads=None,
         kvents=None, kvreads=None, has_kv=None,
     ):
         """Aggregate a round's events into the (G,P) planes of ONE
         ingress block and run the scatter-free dense kernel
-        (kernels.quorum_step_dense_impl).  ``reads``/``racks`` are the
-        round's gathered read-plane buffers (``_gather_reads`` shape) and
-        ``kvents``/``kvreads`` the devsm buffers (``_gather_kv`` shape);
-        both planes live only on this kernel — step() forces dense
-        whenever they are present."""
+        (kernels.quorum_step_dense_impl).  ``acks`` is ``_filter_acks``'
+        pair, ``reads`` the round's ``_filter_reads`` (None: none
+        survived) and ``kvents``/``kvreads`` the devsm buffers
+        (``_gather_kv`` shape); both planes live only on this kernel —
+        step() forces dense whenever they are present.
+
+        An event is stored in the arity it was staged in: a tuple by a
+        scalar store (``read_idx[r, s] = v``: nothing on the way hands
+        the interpreter away), a block through its index arrays.  The
+        sections that held only scalar stores name their cells to the
+        ingress buffer, so its next reset puts back those and refills
+        nothing whole."""
         obs = self._obs
         with (obs.phase("stage") if obs is not None else _OFF):
             p = self.n_peers
             has_votes = bool(votes)
-            has_reads = reads is not None or racks is not None
+            has_reads = reads is not None
             if has_kv is None:
                 has_kv = kvents is not None or kvreads is not None
             ing = self._ingress_for(
@@ -3487,27 +3602,52 @@ class BatchedQuorumEngine:
                 has_kv=has_kv,
             )
             v = ing.views
-            if ag.size:
-                # max-aggregation == scatter-max: order-independent,
-                # exact; -1 = untouched (touched cells hold rel >= 0).
+            cells = ing.cells
+            live, blocks = acks
+            ack = v["ack"]
+            # max-aggregation == scatter-max: order-independent, exact;
+            # -1 = untouched (touched cells hold rel >= 0)
+            for r, sl, rel in live:
+                if rel > ack[r, sl]:
+                    ack[r, sl] = rel
+            if blocks:
                 # Flat 1-D indexing keeps ufunc.at on numpy's contiguous
                 # fast path (the 2-D tuple form is several× slower at the
-                # very occupancies that select the dense path).
-                np.maximum.at(
-                    v["ack"].reshape(-1), ag.astype(np.int64) * p + ap, av
-                )
+                # very occupancies that select the dense path).  The flat
+                # index is made block by block: a coordinator's blocks
+                # are a host's heartbeats each, short of the length past
+                # which a numpy pass hands the interpreter away, which
+                # their concatenation is not
+                cell, rels = _concat_columns((), [
+                    (r.astype(np.int64) * p + sl, rel)
+                    for r, sl, rel in blocks
+                ], 2)
+                np.maximum.at(ack.reshape(-1), cell, rels)
+            else:
+                cells["ack"] = [(r, sl) for r, sl, _rel in live]
             if has_votes:
                 cols = np.array(votes, dtype=np.int64).T
                 v["votes"][cols[0], cols[1]] = cols[2]
-            if reads is not None and reads[0].size:
-                rr, sl, val, c = reads
-                v["read_idx"][rr, sl] = val
-                v["read_cnt"][rr, sl] = c
-            if racks is not None and racks[0].size:
-                rr, sl, pe = racks
-                np.bitwise_or.at(
-                    v["read_echo"], (rr, sl), np.left_shift(1, pe)
+            if has_reads:
+                stages, stage_blocks, echoes, echo_blocks = reads
+                ridx, rcnt, recho = (
+                    v["read_idx"], v["read_cnt"], v["read_echo"]
                 )
+                # a slot staged twice in a round keeps its last stage
+                # (a cancel after its stage): tuples first, in order
+                for r, sl, rel, c in stages:
+                    ridx[r, sl] = rel
+                    rcnt[r, sl] = c
+                for r, sl, pe in echoes:
+                    recho[r, sl] |= 1 << pe
+                if stage_blocks or echo_blocks:
+                    self._stage_read_blocks(v, stage_blocks, echo_blocks)
+                if not stage_blocks:
+                    cells["read_idx"] = cells["read_cnt"] = [
+                        (r, sl) for r, sl, _rel, _c in stages
+                    ]
+                if not echo_blocks:
+                    cells["read_echo"] = [(r, sl) for r, sl, _pe in echoes]
             if kvents is not None and kvents[0].size:
                 rr, sl, rel, key, val = kvents
                 v["kv_idx"][rr, sl] = rel
@@ -3532,6 +3672,18 @@ class BatchedQuorumEngine:
         if dp is not None:
             dp.note_dispatch("dense", out.egress, rounds=1, live_rounds=1)
         return out
+
+    @staticmethod
+    def _stage_read_blocks(v, stage_blocks, echo_blocks) -> None:
+        """Block-staged read stages and echoes into the dense read
+        sections, through their index arrays."""
+        for rr, sl, val, c in stage_blocks:
+            v["read_idx"][rr, sl] = val
+            v["read_cnt"][rr, sl] = c
+        for rr, sl, pe in echo_blocks:
+            np.bitwise_or.at(
+                v["read_echo"], (rr, sl), np.left_shift(1, pe)
+            )
 
     # ------------------------------------------------------------------
     # introspection (tests / debugging)

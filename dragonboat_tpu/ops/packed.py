@@ -115,13 +115,19 @@ def split_egress(eg, dims: tuple, has_reads: bool, has_kv: bool) -> tuple:
 
 class Ingress:
     """One preallocated host buffer for everything a dispatch of one
-    shape stages, and the views its sections are written through."""
+    shape stages, and the views its sections are written through.
 
-    __slots__ = ("buf", "views", "_fills")
+    ``cells`` is what the last staging says it wrote by scalar stores:
+    section name -> the indexes it stored at.  :meth:`reset` puts back
+    just those; a section the staging does not name (one it wrote through
+    index arrays, or does not know) is refilled whole."""
+
+    __slots__ = ("buf", "views", "cells", "_fills")
 
     def __init__(self, sections: tuple):
         self.buf = np.zeros((ingress_size(sections),), np.int32)
         self.views = {}
+        self.cells = {}
         self._fills = []
         at = 0
         for name, shape, fill in sections:
@@ -130,11 +136,17 @@ class Ingress:
             self.views[name] = view
             if fill is not None:
                 view.fill(fill)
-                self._fills.append((view, fill))
+                self._fills.append((name, view, fill))
 
     def reset(self) -> None:
-        for view, fill in self._fills:
-            view.fill(fill)
+        cells, self.cells = self.cells, {}
+        for name, view, fill in self._fills:
+            stored = cells.get(name)
+            if stored is None:
+                view.fill(fill)
+            else:
+                for at in stored:
+                    view[at] = fill
 
 
 def _split(ingress: jax.Array, sections: tuple) -> dict:

@@ -27,7 +27,8 @@ G, P = 16, 3
 
 class _OldStaging(BatchedQuorumEngine):
     """``ack_block``, ``leader_contact_block`` and ``_gather_acks`` as
-    they were before ISSUE 32."""
+    they were before ISSUE 32 (``_filter_acks`` hands the old gather to
+    the step)."""
 
     def ack_block(self, rows, slots, rels) -> None:
         rows = np.asarray(rows)
@@ -74,6 +75,13 @@ class _OldStaging(BatchedQuorumEngine):
             np.concatenate([p[1] for p in parts]),
             np.concatenate([p[2] for p in parts]),
         )
+
+    def _filter_acks(self):
+        # the step's seam since ISSUE 35: the old gather's arrays, handed
+        # on as one block, reach the sparse lists and the dense plane
+        # through index arrays as they did
+        cols = self._gather_acks()
+        return (), ([cols] if cols[0].size else [])
 
 
 def _capture_launches(eng):

@@ -784,9 +784,10 @@ def test_round_span_is_parent_of_its_dispatches_and_holds_its_phases():
             for cid in nodes:
                 coord.ack(cid, 2, 1)
             coord.flush()
-        spans = rec.spans()  # this coordinator's alone
-        rounds = {s["seq"]: s for s in spans if s["kind"] == "coord_round"}
-        kids = [s for s in spans if s["kind"] in ("dispatch", "fused")]
+        # this coordinator's alone; the round thread may still hold a
+        # round open when the last flush() returns
+        rounds = {s["seq"]: s for s in _closed_rounds(rec)}
+        kids = [s for s in rec.spans() if s["kind"] in ("dispatch", "fused")]
         assert len(rounds) >= 8 and len(kids) >= 8  # the round thread
         # races flush() for the staged acks: some rounds take two turns
         by_parent = {}

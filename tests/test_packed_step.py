@@ -23,6 +23,8 @@ from dragonboat_tpu.ops import kernels, packed  # noqa: E402
 from dragonboat_tpu.ops.engine import (  # noqa: E402
     BatchedQuorumEngine,
     MultiRoundResult,
+    _ack_columns,
+    _concat_columns,
 )
 from dragonboat_tpu.ops.state import (  # noqa: E402
     VOTE_NONE,
@@ -131,8 +133,17 @@ class _Unpacked(BatchedQuorumEngine):
                 rkey[at + (rr, sl)] = key
         return tuple(jnp.asarray(a) for a in (ei, ek, ev, rkey))
 
-    def _dispatch_dense(self, ag, ap, av, votes, do_tick, reads=None,
-                        racks=None, kvents=None, kvreads=None, has_kv=None):
+    def _dispatch_dense(self, acks, votes, do_tick, reads=None,
+                        kvents=None, kvreads=None, has_kv=None):
+        # the step hands over what it filtered, each event in the arity
+        # it was staged in (ISSUE 35): flat columns for the kernel's
+        # unpacked entry point
+        ag, ap, av = _ack_columns(*acks)
+        racks = None
+        if reads is not None:
+            stages, stage_blocks, echoes, echo_blocks = reads
+            reads = _concat_columns(stages, stage_blocks, 4)
+            racks = _concat_columns(echoes, echo_blocks, 3)
         g, p = self.n_groups, self.n_peers
         ack_max = np.zeros((g, p), np.int32)
         touched = np.zeros((g, p), bool)

@@ -584,20 +584,13 @@ def test_read_only_round_dispatches_without_ticks():
         coord.stop()
 
 
-def test_live_drain_stays_off_vectorized_read_helpers(monkeypatch):
-    """The coordinator's drain stages ONE read op at a time, on a round
-    thread that shares the interpreter with every raft worker; the
-    vectorized helpers' index-array calls hand the interpreter away per
-    op there (tens of ms of every round, PERF.md PR 27).  With both
-    helpers raising, a live coordinator still stages, overflows, falls
-    back, confirms, prefix-releases (cancel) and restages."""
-    def _vectorized(*_a, **_k):
-        raise AssertionError("vectorized read helper on the live drain")
+def _vectorized(*_a, **_k):
+    raise AssertionError("vectorized read helper on the live round")
 
-    monkeypatch.setattr(BatchedQuorumEngine, "_free_read_slot", _vectorized)
-    monkeypatch.setattr(
-        BatchedQuorumEngine, "_predict_read_confirm", _vectorized
-    )
+
+def _singly_staged_rounds():
+    """A live coordinator stages, overflows, falls back, confirms,
+    prefix-releases (cancel) and restages reads, one op at a time."""
     cid = 7
     coord, r, confirms, scalar_echoes = _coord_leading(cid)
     # every round below is a flush() of this thread: the round thread
@@ -638,6 +631,48 @@ def test_live_drain_stays_off_vectorized_read_helpers(monkeypatch):
         assert not coord._read_pending[cid]
     finally:
         coord.stop()
+    return coord
+
+
+def test_live_drain_stays_off_vectorized_read_helpers(monkeypatch):
+    """The coordinator's drain stages ONE read op at a time, on a round
+    thread that shares the interpreter with every raft worker; the
+    vectorized helpers' index-array calls hand the interpreter away per
+    op there (tens of ms of every round, PERF.md PR 27).  With both
+    helpers raising, a live coordinator still stages, overflows, falls
+    back, confirms, prefix-releases (cancel) and restages."""
+    monkeypatch.setattr(BatchedQuorumEngine, "_free_read_slot", _vectorized)
+    monkeypatch.setattr(
+        BatchedQuorumEngine, "_predict_read_confirm", _vectorized
+    )
+    _singly_staged_rounds()
+
+
+def test_live_step_stays_off_vectorized_read_helpers(monkeypatch):
+    """The same rounds between the drain and the fan-out (PERF.md PR 35):
+    singly staged reads are gathered, stored into the ingress block and
+    decoded off the egress block on scalars, for the (row, slot) pairs
+    the round's events name.  With the block-arity gather, the
+    index-array stores and the whole-plane decode raising too, the
+    rounds run as before, and the ingress reset refills no read section
+    whole."""
+    from dragonboat_tpu.ops import packed
+
+    for name in ("_free_read_slot", "_predict_read_confirm", "_live_blocks",
+                 "_gather_reads", "_stage_read_blocks", "_translate_reads"):
+        monkeypatch.setattr(BatchedQuorumEngine, name, _vectorized)
+    by_cells, whole = [], []
+    reset = packed.Ingress.reset
+
+    def noting(self):
+        for name, _view, _fill in self._fills:
+            (by_cells if name in self.cells else whole).append(name)
+        reset(self)
+
+    monkeypatch.setattr(packed.Ingress, "reset", noting)
+    _singly_staged_rounds()
+    assert "read_echo" in by_cells and "ack" in by_cells
+    assert not [n for n in whole if n.startswith("read_")]
 
 
 def test_live_coordinator_batches_read_confirmations():
