@@ -1,10 +1,11 @@
-"""The system under test: three in-process NodeHosts on one chip.
+"""The system under test: a deployment's in-process NodeHosts (three or
+five) on one chip.
 
 Bring-up follows ``chip_smoke.live_phase`` (copied, not imported: the
 benchmark may name no file outside its own directory): ``quorum_engine=
 "tpu"``, ``fast_lane=False``, ``ChanTransport``, durable ``node_host_dir``
 with fsync honoured, all hosts sharing the one chip in the one process.
-Leaders are placed by explicit campaigns (``bench_e2e._campaign_and_wait``).
+Leaders are placed by explicit campaigns.
 
 ``LiveCluster`` is the narrow surface the generator drives; the plain
 reference (``reference/kv.py``) and the test fakes implement the same four
@@ -12,6 +13,7 @@ methods, so any of them can stand in the program's place.
 """
 from __future__ import annotations
 
+import dataclasses
 import shutil
 import tempfile
 import threading
@@ -88,8 +90,44 @@ def wait_until(pred, timeout_s, what, poll_s=0.02):
     return time.perf_counter() - t0
 
 
+#: the ``Config`` fields the harness sets itself: the two identities, and
+#: the two clocks that a configuration states under ``assumed``
+OWNED = ("cluster_id", "node_id", "election_rtt", "heartbeat_rtt")
+
+
+def group_config(config: dict) -> dict:
+    """Every keyword but the two identities of the ``Config`` each replica
+    is started with: the clocks under ``assumed`` and the configuration's
+    optional ``group_config`` block, ``Config`` field names to JSON scalars
+    (``check_quorum``, ``snapshot_entries``, ``compaction_overhead``,
+    ``read_lease``, ...).  Absent or empty, the ``Config`` built is the one
+    built before the key existed.  A key that is no field of ``Config``, one
+    the harness owns, or a value that is no ``bool`` or ``int`` raises
+    ``ValueError`` naming it; what the fields mean together is
+    ``Config.validate``'s, which ``start_cluster`` runs."""
+    from dragonboat_tpu import Config
+
+    fields = {f.name for f in dataclasses.fields(Config)}
+    block = config.get("group_config", {})
+    if not isinstance(block, dict):
+        raise ValueError(f"group_config is not an object: {block!r}")
+    for key, value in block.items():
+        if key not in fields:
+            raise ValueError(f"group_config: {key!r} is not a field of Config")
+        if key in OWNED:
+            raise ValueError(f"group_config: {key!r} is the harness's "
+                             "(the clocks are stated under 'assumed')")
+        if not isinstance(value, (bool, int)):
+            raise ValueError(f"group_config: {key!r} is not a bool or an int: "
+                             f"{value!r}")
+    assumed = config["assumed"]
+    return dict(block, election_rtt=assumed["election_rtt"],
+                heartbeat_rtt=assumed["heartbeat_rtt"])
+
+
 class LiveCluster:
-    """Three NodeHosts of one deployment (a ``configs/*.json``)."""
+    """The NodeHosts of one deployment (a ``configs/*.json``): three, or as
+    many as it has ``replicas``."""
 
     def __init__(self, config: dict, cache_dir: str, trace_sample_every=0,
                  sm_class=KV):
@@ -99,6 +137,7 @@ class LiveCluster:
         from dragonboat_tpu.requests import RequestError
         from dragonboat_tpu.transport import ChanRouter, ChanTransport
 
+        settings = group_config(config)  # raises before a NodeHost exists
         self.busy_errors = (RequestError,)
         self.groups = int(config["groups"])
         self.replicas = int(config["replicas"])
@@ -149,9 +188,7 @@ class LiveCluster:
                 for i, nh in enumerate(self.nhs, start=1):
                     nh.start_cluster(
                         addrs, False, make_sm,
-                        Config(cluster_id=cid, node_id=i,
-                               election_rtt=assumed["election_rtt"],
-                               heartbeat_rtt=assumed["heartbeat_rtt"]),
+                        Config(cluster_id=cid, node_id=i, **settings),
                     )
             self.phases["start_cluster_s"] = time.perf_counter() - t1
             self.coords = [nh.quorum_coordinator for nh in self.nhs]

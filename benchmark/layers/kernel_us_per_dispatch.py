@@ -1,6 +1,9 @@
-"""Mean device time of one quorum program, from the device trace."""
+"""Mean device time of one quorum program, from the device trace: the sparse
+step, the dense step (what a round that carries reads runs) and the fused
+rounds, whichever the traced stretch dispatched."""
 
-KERNELS = ("quorum_step_impl", "quorum_multiround_impl")
+KERNELS = ("quorum_step_impl", "quorum_step_dense_impl",
+           "quorum_multiround_impl")
 
 
 def read(ctx):

@@ -2,7 +2,8 @@
 chip could take for their dispatches (state read once and written once,
 ``roofline.py``) over the device time they took.  Bound: memory."""
 
-KERNELS = ("quorum_step_impl", "quorum_multiround_impl")
+KERNELS = ("quorum_step_impl", "quorum_step_dense_impl",
+           "quorum_multiround_impl")
 
 
 def read(ctx):

@@ -1,6 +1,7 @@
 """What the quorum kernels need of the chip, from shapes alone.
 
-One dispatch of ``quorum_step_impl`` (or of the fused K-round
+One dispatch of ``quorum_step_impl`` (or of its dense twin
+``quorum_step_dense_impl``, or of the fused K-round
 ``quorum_multiround_impl``) is handed the whole struct-of-arrays state of a
 host's engine, donated, and hands back the next one: at the least every
 leaf is read once and written once.  The staged events and the egress are

@@ -63,3 +63,30 @@ def test_new_config_cell_and_metric_are_only_additions(tmp_path):
     assert all(m["name"].endswith(".tput") for m in old.metrics("per_layer"))
     assert [m["name"] for m in old.metrics("end_to_end")] == [
         "ops_per_s", "setup_s"]
+
+
+def test_a_configuration_states_its_groups_raft_settings_as_data(
+        add_configuration):
+    """The documented-settings deployment: one configuration file with a
+    ``group_config`` block, its reference beside it, two entries.  The
+    harness hands the block to every ``Config``; nothing that exists is
+    edited, and the cells without the block build what they built."""
+    from benchmark.cluster import group_config
+
+    block = {"check_quorum": True, "snapshot_entries": 10,
+             "compaction_overhead": 5}
+    root, name = add_configuration(
+        "documented8x3", "upstream48x3.write_closed", groups=8,
+        group_config=block)
+    cell = harness.Cell(name, root=root)
+    assert group_config(cell.config) == dict(
+        block, election_rtt=10, heartbeat_rtt=1)
+    old = harness.Cell("upstream48x3.write_closed", root=root)
+    assert cell.config["guarantees"] == old.config["guarantees"]
+    sound = harness.run(cell, control.build("reference", cell, 1), 3, 0.5,
+                        False, DEVICE, True, setup_clock=lambda: 0.0)
+    assert sound["correct"] and set(sound["metrics"]) == {
+        "ops_per_s", "setup_s"}
+    for other in ("upstream48x3.write_closed", "ladder512x5.mixed91"):
+        assert group_config(harness.Cell(other, root=root).config) == {
+            "election_rtt": 10, "heartbeat_rtt": 1}
