@@ -10,6 +10,7 @@ device dispatch per tick (SURVEY.md §7).
 from __future__ import annotations
 
 import threading
+import time as _time
 from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 
 from .logger import get_logger
@@ -123,8 +124,6 @@ class _Committer:
                         self.engine.set_step_ready(n.cluster_id)
 
     def _commit(self, batch) -> None:
-        import time as _time
-
         t0 = _time.perf_counter()
         merged = [ud for _, updates in batch for ud in updates]
         tr = self.engine.tracer
@@ -211,8 +210,12 @@ class Engine:
         import queue as _queue
 
         self._ss_q: "_queue.Queue" = _queue.Queue()
-        snapshot_workers = max(2, min(8, step_workers * 2))
-        for i in range(snapshot_workers):
+        self.snapshot_workers = max(2, min(8, step_workers * 2))
+        # replica-plane instruments (obs/instruments.py ReplicaObs; set by
+        # NodeHost): the pool's busy seconds and queue depth.  None keeps
+        # the workers' loop untouched.
+        self.replica_obs = None
+        for i in range(self.snapshot_workers):
             t = threading.Thread(
                 target=self._snapshot_worker_main,
                 name=f"snapshot-worker-{i}", daemon=True,
@@ -313,8 +316,6 @@ class Engine:
             active = [nodes[cid] for cid in ready if cid in nodes]
             if active:
                 try:
-                    import time as _time
-
                     st = self._step_stats[idx]
                     t0 = _time.perf_counter()
                     # the step batch, named for the profiler while the
@@ -455,10 +456,14 @@ class Engine:
             fn = self._ss_q.get()
             if fn is None or self._stopped.is_set():
                 return
+            obs = self.replica_obs
+            t0 = _time.perf_counter() if obs is not None else 0.0
             try:
                 fn()
             except Exception:
                 plog.exception("snapshot worker task failed")
+            if obs is not None:
+                obs.pool_task(t0, _time.perf_counter(), self._ss_q.qsize())
 
     def stop(self) -> None:
         import os

@@ -19,6 +19,7 @@ from .config import Config
 from .events import SystemEvent, SystemEventType
 from .logdb import LogReader
 from .logger import get_logger
+from .obs.recorder import OFF as _OFF
 from .queue import EntryQueue
 from .quiesce import QuiesceManager
 from .requests import (
@@ -186,6 +187,14 @@ class Node:
         # effects under dragonboat_node_offload_applied_total{kind=...}.
         # None (the default) keeps the apply path untouched.
         self.obs_registry = None
+        # replica-plane instruments (obs/instruments.py ReplicaObs, ISSUE
+        # 37; set by NodeHost when its tracer or enable_metrics is on):
+        # snapshot saves, compactions, InstallSnapshot, check-quorum
+        # windows.  None keeps every site below untouched.
+        self.replica_obs = None
+        # applied index at which the next skipped periodic snapshot is
+        # counted (kept only while replica_obs is attached)
+        self._ss_refuse_from = 0
         # device state machine (devsm, ISSUE 11): set by NodeHost when a
         # DeviceKVStateMachine group registers (Config.device_kv on the
         # tpu engine).  None keeps every path below bit-identical.  The
@@ -474,6 +483,9 @@ class Node:
                         r.handle(
                             Message(from_=self.node_id, type=MT.CHECK_QUORUM)
                         )
+                        obs = self.replica_obs
+                        if obs is not None:
+                            obs.checkq_window(not r.is_leader())
                         if not r.is_leader():  # the step flushes the rest
                             self.nh.engine.set_step_ready(self.cluster_id)
                     return term, rows, True
@@ -663,6 +675,9 @@ class Node:
         if demote and r.device_ticks and r.is_leader() and r.check_quorum:
             r.election_tick = 0
             r.handle(Message(from_=self.node_id, type=MT.CHECK_QUORUM))
+            obs = self.replica_obs
+            if obs is not None:
+                obs.checkq_window(not r.is_leader())
 
     def _publish_event(
         self, type: SystemEventType, index: int = 0, from_: int = 0
@@ -1541,6 +1556,9 @@ class Node:
 
     def _handle_install_snapshot(self, m: Message) -> None:
         # record arrival; raft decides whether to accept (restore path)
+        obs = self.replica_obs
+        if obs is not None:
+            obs.install("received")
         self.peer.handle(m)
 
     def _broadcast_quiesce(self) -> None:
@@ -1623,6 +1641,7 @@ class Node:
                     node_id=self.node_id,
                     save=True,
                     ss_request=req,
+                    queued_at=self._obs_now(),
                 )
             )
             self.nh.engine.set_apply_ready(self.cluster_id)
@@ -1679,6 +1698,9 @@ class Node:
             if m.type == MT.REPLICATE:
                 continue
             if m.type == MT.INSTALL_SNAPSHOT:
+                obs = self.replica_obs
+                if obs is not None:
+                    obs.install("sent")
                 self.nh.send_snapshot_message(m)
             else:
                 ctx = m.trace
@@ -1745,17 +1767,37 @@ class Node:
             return
         # held until the queued PERIODIC save completes (_save_snapshot
         # releases it), so duplicate save tasks never pile up
+        obs = self.replica_obs
         if not self._snapshotting.acquire(blocking=False):
+            if obs is not None:
+                # one more snapshot_entries applied behind a save still
+                # queued or running: that snapshot is skipped
+                applied = self.sm.get_last_applied()
+                if applied >= self._ss_refuse_from:
+                    self._ss_refuse_from = (
+                        applied + self.config.snapshot_entries
+                    )
+                    obs.save_refused()
             return
+        if obs is not None:
+            self._ss_refuse_from = (
+                self.sm.get_last_applied() + self.config.snapshot_entries
+            )
         self.to_apply.enqueue(
             Task(
                 cluster_id=self.cluster_id,
                 node_id=self.node_id,
                 save=True,
                 ss_request=SSRequest(type=SSReqType.PERIODIC),
+                queued_at=self._obs_now(),
             )
         )
         self.nh.engine.set_apply_ready(self.cluster_id)
+
+    def _obs_now(self) -> float:
+        """``perf_counter`` while the replica instruments are attached
+        (a snapshot task's ``queued_at``), else 0.0."""
+        return time.perf_counter() if self.replica_obs is not None else 0.0
 
     def commit_raft_update(self, ud: Update) -> None:
         with self.raft_mu:
@@ -1858,7 +1900,32 @@ class Node:
             req, index, term, kv_image, sess_image, membership=pre_members
         )
 
+    def _snapshot_scope(self, t: Task, kind: str):
+        """The ``snapshot_save`` span and annotation around one snapshot
+        task (``ReplicaObs.save``), or None while the instruments are
+        off."""
+        obs = self.replica_obs
+        if obs is None:
+            return None
+        return obs.save(
+            kind=kind, cluster_id=self.cluster_id, node_id=self.node_id,
+            queued_at=t.queued_at,
+            entries_since=(
+                self.sm.get_last_applied() - self.sm.get_snapshot_index()
+            ),
+            snapshot_entries=self.config.snapshot_entries,
+        )
+
     def _save_snapshot(self, t: Task) -> None:
+        scope = self._snapshot_scope(
+            t,
+            "periodic" if t.ss_request.type == SSReqType.PERIODIC
+            else "requested",
+        )
+        with (scope if scope is not None else _OFF):
+            self._save_snapshot_task(t, scope)
+
+    def _save_snapshot_task(self, t: Task, scope) -> None:
         req = t.ss_request
         # only user-initiated requests may resolve the pending-snapshot slot;
         # PERIODIC failures must not complete an unrelated user request
@@ -1867,6 +1934,10 @@ class Node:
             try:
                 cap = self._try_capture_save(req)
                 ss, env = cap if cap is not None else self.sm.save(req)
+                if scope is not None:
+                    # a regular state machine's save holds the group's
+                    # applies out (rsm.StateMachine._update_mu)
+                    scope.lap("sm_save")
             except SnapshotIgnored:
                 if user_req:
                     self.pending_snapshot.notify(
@@ -1900,6 +1971,8 @@ class Node:
                 return
             try:
                 self.snapshotter.commit(ss, env)
+                if scope is not None:
+                    scope.lap("commit")
                 self._publish_event(SystemEventType.SNAPSHOT_CREATED, index=ss.index)
             except FileExistsError:
                 env.remove_tmp_dir()
@@ -1917,8 +1990,9 @@ class Node:
                         RequestResult(code=RequestResultCode.ABORTED)
                     )
                 return
-            self._compact_log(ss, req)
-            self.snapshotter.compact()
+            with (scope.saved(ss) if scope is not None else _OFF):
+                self._compact_log(ss, req)
+                self.snapshotter.compact()
             self._publish_event(SystemEventType.SNAPSHOT_COMPACTED, index=ss.index)
             if req.type == SSReqType.USER_REQUESTED:
                 self.pending_snapshot.notify(
@@ -1942,11 +2016,17 @@ class Node:
                 stream=True,
                 stream_to=to,
                 ss_request=SSRequest(type=SSReqType.STREAMING),
+                queued_at=self._obs_now(),
             )
         )
         self.nh.engine.set_apply_ready(self.cluster_id)
 
     def _stream_snapshot(self, t: Task) -> None:
+        scope = self._snapshot_scope(t, "stream")
+        with (scope if scope is not None else _OFF):
+            self._stream_snapshot_task(t)
+
+    def _stream_snapshot_task(self, t: Task) -> None:
         to = t.stream_to
         sink = self.nh.transport.get_stream_sink(self.cluster_id, to)
         if sink is None:
@@ -1981,6 +2061,9 @@ class Node:
         self.logdb.remove_entries_to(self.cluster_id, self.node_id, compact_to)
         with self._compacted_to_mu:
             self._compacted_to = compact_to
+        obs = self.replica_obs
+        if obs is not None:
+            obs.compaction()
         self._publish_event(SystemEventType.LOG_COMPACTED, index=compact_to)
 
     def _recover_from_snapshot(self, t: Task) -> None:

@@ -572,6 +572,22 @@ class NodeHost:
         )
         if self.tracer is not None:
             self.engine.tracer = self.tracer
+        # replica-plane instruments (obs/instruments.py ReplicaObs, ISSUE
+        # 37): snapshot saves, compactions, InstallSnapshot, the snapshot
+        # pool and check-quorum windows, on the tracer's and
+        # enable_metrics' one switch.  With both off nothing is built and
+        # every site keeps its ``is None`` latch.
+        self.replica_obs = None
+        if nhconfig.enable_metrics or self.tracer is not None:
+            from .obs.instruments import ReplicaObs
+
+            self.replica_obs = ReplicaObs(
+                recorder=self.flight_recorder,
+                registry=self.raft_events.registry,
+                host=nhconfig.raft_address,
+                workers=self.engine.snapshot_workers,
+            )
+            self.engine.replica_obs = self.replica_obs
         # opt-in SIGUSR2 live-debug dump (ISSUE 9 satellite): the
         # handler sets the flag; the tick worker performs the dump
         self._dump_sig_old = None
@@ -991,6 +1007,7 @@ class NodeHost:
             node.tracer = self.tracer
             node.pending_reads._tracer = self.tracer
             node.replattr = self.replattr
+        node.replica_obs = self.replica_obs
         node.start(addresses, initial=not join and new_node, new_node=new_node)
         with self._mu:
             self._clusters[cluster_id] = node
@@ -1077,6 +1094,8 @@ class NodeHost:
             self.server_ctx.stop()
         if self.tracer is not None:
             self.tracer.close()
+        if self.replica_obs is not None:
+            self.replica_obs.close()
         if self._dump_sig_old is not None:
             import signal as _signal
 

@@ -46,6 +46,14 @@ echoes tallied scalar-side, by cause (``slot_overflow`` /
 device tallied.  Node offload application counts under
 ``dragonboat_node_offload_applied_total{kind=…}`` (node.py).
 
+Replica plane (``ReplicaObs``, ISSUE 37; what a group's own settings turn
+on: ``snapshot_entries``, ``compaction_overhead``, ``check_quorum``):
+``dragonboat_snapshot_saves_total{kind}``, ``…saves_refused_total``,
+``…save_latency_ms`` (histogram), ``…compactions_total``,
+``…installs_total{direction}``, ``…pool_busy_seconds_total``, gauge
+``…pool_queue_depth``; ``dragonboat_checkq_windows_total`` /
+``…stepdowns_total``; one ``snapshot_save`` span a save.
+
 Spans (ISSUE 26): every dispatch / round span is an interval
 (``t0``/``t1`` on ``perf_counter``) with ``host`` and ``parent``, and
 carries its phases as ``*_ms`` fields — ``EngineObs.phase`` /
@@ -54,6 +62,7 @@ profiler (``recorder.ANNOTATIONS``) and add it to the span being built.
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Optional
 
@@ -97,6 +106,8 @@ _DEVPROF = "dragonboat_devprof_"
 _MESH = "dragonboat_mesh_"
 _RECOV = "dragonboat_recovery_"
 _TELEM = "dragonboat_telem_"
+_SNAP = "dragonboat_snapshot_"
+_CHECKQ = "dragonboat_checkq_"
 
 #: recovery-duration buckets (seconds): a worker respawn lands near the
 #: bottom, a failover around election timeouts, a wedged rebind loop or
@@ -109,6 +120,22 @@ RECOVERY_BUCKETS_S = (
 #: ``# TYPE``-only).  Families not listed fall back to the registry's
 #: deterministic placeholder.
 _HELP = {
+    _SNAP + "saves_total": "snapshot tasks a pool worker finished with "
+    "an image committed (or streamed), by kind",
+    _SNAP + "saves_refused_total": "periodic snapshots that fell due "
+    "while the one before was still queued or running (skipped)",
+    _SNAP + "save_latency_ms": "pool-worker wall time of one snapshot "
+    "save: state machine save, commit, log and snapshot compaction",
+    _SNAP + "compactions_total": "log compactions behind a snapshot",
+    _SNAP + "installs_total": "InstallSnapshot messages sent to a "
+    "follower behind the compacted log / received whole, by direction",
+    _SNAP + "pool_busy_seconds_total": "seconds the snapshot pool's "
+    "workers spent on tasks",
+    _SNAP + "pool_queue_depth": "tasks waiting for a snapshot-pool "
+    "worker when the last task finished",
+    _CHECKQ + "windows_total": "check-quorum windows a leader closed",
+    _CHECKQ + "stepdowns_total": "leaders that stepped down because a "
+    "closed check-quorum window had not heard from a quorum",
     _HPROC + "workers_alive": "host-plane worker processes currently "
     "alive (spawned minus crashed/stopped)",
     _HPROC + "worker_restarts_total": "worker processes respawned after "
@@ -1542,3 +1569,247 @@ class CoordObs:
             **extra,
         )
         return span
+
+
+#: what a ``snapshot_save`` span's ``save_kind`` says: the periodic save that
+#: ``snapshot_entries`` makes fall due, a user's ``request_snapshot``
+#: (exported ones too), an on-disk state machine streamed to a follower
+SNAPSHOT_KINDS = ("periodic", "requested", "stream")
+#: whole seconds of ``ReplicaObs.window`` kept (oldest dropped)
+REPLICA_SECONDS_KEEP = 900
+
+_LIVE_REPLICA_OBS: list = []
+
+
+def replica_obs_live() -> list:
+    """Every ``ReplicaObs`` of this process whose NodeHost has not stopped
+    (co-hosted NodeHosts have one each)."""
+    return list(_LIVE_REPLICA_OBS)
+
+
+class _SaveScope:
+    """One snapshot task on a pool worker: a ``dbtpu:snapshot_save``
+    annotation around it and, at its end, one ``snapshot_save`` span."""
+
+    __slots__ = ("obs", "ann", "t0", "lap_t", "fields")
+
+    def __init__(self, obs, fields: dict):
+        self.obs = obs
+        self.ann = annotate("snapshot_save")
+        self.fields = fields
+
+    def lap(self, name: str) -> None:
+        """``<name>_ms``: the time since the task began or the lap before
+        (``sm_save``, ``commit``: self time of ``dbtpu:snapshot_save``)."""
+        now = time.perf_counter()
+        self.fields[name + "_ms"] = round((now - self.lap_t) * 1e3, 4)
+        self.lap_t = now
+
+    def saved(self, ss) -> Phase:
+        """The image is committed; what is left is the compaction behind
+        it: ``with scope.saved(ss):`` is ``compact_ms`` and an annotation
+        of its own (``dbtpu:compact``)."""
+        fields = self.fields
+        fields["saved"] = True
+        fields["index"] = ss.index
+        fields["image_bytes"] = ss.file_size
+        return Phase(fields, "compact")
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = self.lap_t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        self.obs._save_done(self, t1)
+        return False
+
+
+class ReplicaObs:
+    """What a group's own settings turn on, for the replicas of one
+    NodeHost: snapshot saves and the log compaction behind them
+    (``snapshot_entries``, ``compaction_overhead``), InstallSnapshot
+    traffic, the snapshot pool, and the check-quorum windows a leader
+    closes (``check_quorum``).
+
+    A save is one ``snapshot_save`` span (``t0``..``t1`` on
+    ``perf_counter``, the pool worker's time on it): ``queue_ms`` from the
+    task's enqueue to the worker taking it, ``sm_save_ms`` the state
+    machine's ``save`` (a regular state machine holds the group's applies
+    out for as long; the wait for that lock is in it), ``commit_ms``
+    ``snapshotter.commit`` (rename, fsync, the LogDB record),
+    ``compact_ms`` the log and snapshot compaction behind it, ``save_ms``
+    all of it, ``image_bytes``, ``entries_since`` (applied less the
+    previous snapshot's index when the worker took the task) beside the
+    group's ``snapshot_entries``, ``save_kind`` (``SNAPSHOT_KINDS``; a
+    span's ``kind`` is ``snapshot_save``) and ``saved`` once the image was
+    committed.  ``save_ms`` is no stall
+    field: a slow user ``save_snapshot`` is the user's.
+
+    Counts are kept twice: in the registry (``dragonboat_snapshot_*``,
+    ``dragonboat_checkq_*``) and by the whole ``perf_counter`` second they
+    fell in, so a reader takes a window's from ``window(lo, hi)`` whatever
+    the span ring still holds.  Same ``is not None`` latch as every other
+    plane: with tracer and ``enable_metrics`` off a NodeHost builds none,
+    and ``Node.replica_obs`` / ``Engine.replica_obs`` stay ``None``."""
+
+    __slots__ = ("recorder", "registry", "host", "workers", "_mu", "_secs")
+
+    _COUNTERS = (
+        _SNAP + "saves_refused_total",
+        _SNAP + "compactions_total",
+        _SNAP + "pool_busy_seconds_total",
+        _CHECKQ + "windows_total",
+        _CHECKQ + "stepdowns_total",
+    )
+
+    def __init__(
+        self, recorder: Optional[FlightRecorder] = None,
+        registry: Optional[MetricsRegistry] = None,
+        host: Optional[str] = None, workers: int = 0,
+    ):
+        from . import default_recorder
+
+        self.recorder = recorder or default_recorder()
+        self.registry = registry or DEFAULT_REGISTRY
+        self.host = host
+        #: snapshot-pool workers of the host's execution engine
+        self.workers = workers
+        self._mu = threading.Lock()
+        self._secs: dict = {}
+        r = self.registry
+        _describe(r, self._COUNTERS + (
+            _SNAP + "saves_total", _SNAP + "save_latency_ms",
+            _SNAP + "installs_total", _SNAP + "pool_queue_depth",
+        ))
+        for name in self._COUNTERS:
+            r.counter_add(name, 0)
+        for kind in SNAPSHOT_KINDS:
+            r.counter_add(_SNAP + "saves_total", 0, {"kind": kind})
+        for direction in ("sent", "received"):
+            r.counter_add(
+                _SNAP + "installs_total", 0, {"direction": direction}
+            )
+        r.gauge_set(_SNAP + "pool_queue_depth", 0)
+        r.histogram_declare(
+            _SNAP + "save_latency_ms", buckets=LATENCY_BUCKETS_MS
+        )
+        _LIVE_REPLICA_OBS.append(self)
+
+    def close(self) -> None:
+        try:
+            _LIVE_REPLICA_OBS.remove(self)
+        except ValueError:
+            pass
+
+    def _add_locked(self, sec: int, name: str, n) -> None:
+        secs = self._secs
+        bucket = secs.get(sec)
+        if bucket is None:
+            bucket = secs[sec] = {}
+            if len(secs) > REPLICA_SECONDS_KEEP:
+                del secs[min(secs)]
+        bucket[name] = bucket.get(name, 0) + n
+
+    def _count(self, name: str) -> None:
+        sec = int(time.perf_counter())
+        with self._mu:
+            self._add_locked(sec, name, 1)
+
+    def window(self, lo: float, hi: float) -> dict:
+        """name -> count over the whole seconds whose middle lies in
+        ``[lo, hi)`` on ``perf_counter``: ``saves``, ``saves_refused``,
+        ``compactions``, ``installs_sent``, ``installs_received``,
+        ``pool_busy_s``, ``checkq_windows``, ``checkq_stepdowns``."""
+        out: dict = {}
+        with self._mu:
+            for sec, bucket in self._secs.items():
+                if lo <= sec + 0.5 < hi:
+                    for name, n in bucket.items():
+                        out[name] = out.get(name, 0) + n
+        return out
+
+    # ---- snapshot saves (node.py, on a snapshot-pool worker) ----------
+
+    def save(self, *, kind: str, cluster_id: int, node_id: int,
+             queued_at: float, entries_since: int,
+             snapshot_entries: int) -> _SaveScope:
+        """``with obs.save(...) as scope:`` around one snapshot task."""
+        fields = {
+            "save_kind": kind, "cluster_id": cluster_id, "node_id": node_id,
+            "entries_since": entries_since,
+            "snapshot_entries": snapshot_entries, "saved": False,
+        }
+        if queued_at:
+            fields["queue_ms"] = round(
+                (time.perf_counter() - queued_at) * 1e3, 4
+            )
+        return _SaveScope(self, fields)
+
+    def _save_done(self, scope: _SaveScope, t1: float) -> None:
+        fields = scope.fields
+        save_ms = (t1 - scope.t0) * 1e3
+        if "compact_ms" in fields:
+            fields["compact_ms"] = round(fields["compact_ms"], 4)
+        self.recorder.record(
+            "snapshot_save", t0=scope.t0, t1=t1, host=self.host,
+            save_ms=round(save_ms, 4), **fields
+        )
+        if fields["saved"] or fields["save_kind"] == "stream":
+            r = self.registry
+            r.counter_add(
+                _SNAP + "saves_total", 1, {"kind": fields["save_kind"]}
+            )
+            r.histogram_observe(
+                _SNAP + "save_latency_ms", save_ms,
+                buckets=LATENCY_BUCKETS_MS,
+            )
+            self._count("saves")
+
+    def save_refused(self) -> None:
+        """A periodic save fell due while the one before it was still
+        queued or running: it is skipped, not queued behind it."""
+        self.registry.counter_add(_SNAP + "saves_refused_total")
+        self._count("saves_refused")
+
+    def compaction(self) -> None:
+        self.registry.counter_add(_SNAP + "compactions_total")
+        self._count("compactions")
+
+    def install(self, direction: str) -> None:
+        """An InstallSnapshot ``sent`` to a follower that fell behind the
+        compacted log, or ``received`` whole from a leader."""
+        self.registry.counter_add(
+            _SNAP + "installs_total", 1, {"direction": direction}
+        )
+        self._count("installs_" + direction)
+
+    # ---- the snapshot pool (engine.py) --------------------------------
+
+    def pool_task(self, t0: float, t1: float, queue_depth: int) -> None:
+        """A pool worker was busy from ``t0`` to ``t1``; each whole second
+        gets the part of it that fell in it, so that a window's busy
+        seconds never pass its workers times its length."""
+        r = self.registry
+        r.counter_add(_SNAP + "pool_busy_seconds_total", t1 - t0)
+        r.gauge_set(_SNAP + "pool_queue_depth", queue_depth)
+        sec = int(t0)
+        with self._mu:
+            while t0 < t1:
+                end = min(t1, sec + 1.0)
+                self._add_locked(sec, "pool_busy_s", end - t0)
+                t0, sec = end, sec + 1
+
+    # ---- check-quorum (node.py, under raftMu) -------------------------
+
+    def checkq_window(self, stepped_down: bool) -> None:
+        """A leader's check-quorum window closed (the device's
+        ``checkq_demote`` flag ran the scalar CHECK_QUORUM);
+        ``stepped_down`` if it had not heard from a quorum."""
+        self.registry.counter_add(_CHECKQ + "windows_total")
+        self._count("checkq_windows")
+        if stepped_down:
+            self.registry.counter_add(_CHECKQ + "stepdowns_total")
+            self._count("checkq_stepdowns")

@@ -75,6 +75,11 @@ ANNOTATIONS = {
     "raft_step": "dbtpu:raft_step",      # execution engine step batch
     "wal_sync": "dbtpu:wal_sync",        # log save + fsync
     "apply": "dbtpu:apply",              # apply batch
+    "snapshot_save": "dbtpu:snapshot_save",  # snapshot_save t0..t1: one
+                                         # save on a snapshot-pool worker
+                                         # (its sm_save_ms and commit_ms
+                                         # are self time of this event)
+    "compact": "dbtpu:compact",          # snapshot_save compact_ms
 }
 
 #: what ``with (obs.phase(...) if obs is not None else OFF):`` enters while
@@ -147,7 +152,10 @@ class FlightRecorder:
     ``kind``                ``"dispatch"`` (engine, single-round),
                             ``"fused"`` (engine, K-round block),
                             ``"coord_round"`` (tpuquorum round loop),
-                            ``"warmup"`` (one AOT-warmed program)
+                            ``"warmup"`` (one AOT-warmed program),
+                            ``"snapshot_save"`` (one save, stream or
+                            requested snapshot on a snapshot-pool
+                            worker: ``instruments.ReplicaObs``)
     ``t0`` ``t1``           the span's interval on ``time.perf_counter()``
                             (the tracer's clock): opened at the round's
                             / step's start, ``t1`` moved by every

@@ -96,6 +96,9 @@ class Task:
     new_node: bool = False
     ss: Optional[Snapshot] = None
     ss_request: SSRequest = field(default_factory=SSRequest)
+    # perf_counter at the enqueue of a save / stream task while the
+    # replica instruments are attached (ReplicaObs queue_ms), else 0.0
+    queued_at: float = 0.0
 
     def is_snapshot_task(self) -> bool:
         return self.save or self.stream or self.recover

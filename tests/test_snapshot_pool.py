@@ -171,3 +171,90 @@ def test_slow_snapshot_save_does_not_block_other_groups():
     finally:
         for nh in nhs:
             nh.stop()
+
+
+# ------------------------- replica instruments: inert when off (ISSUE 37)
+
+
+class _CountSM(SlowSnapSM):
+    SAVE_SECONDS = 0.0
+
+
+@pytest.mark.parametrize("on", [False, True], ids=["off", "metrics_on"])
+def test_replica_instruments_are_built_only_when_switched_on(on, monkeypatch):
+    """With tracer and ``enable_metrics`` off a NodeHost that takes
+    snapshots builds no ``ReplicaObs``, no ``snapshot_save`` span, no
+    annotation and no per-second count: ``Node.replica_obs`` and
+    ``Engine.replica_obs`` stay ``None``.  Switched on, the same run
+    writes a span a save."""
+    from dragonboat_tpu import obs
+    from dragonboat_tpu.obs import instruments
+
+    built = []
+    for name in ("_SaveScope", "ReplicaObs"):
+        cls = getattr(instruments, name)
+
+        def counting(*a, _cls=cls, _name=name, **k):
+            built.append(_name)
+            return _cls(*a, **k)
+
+        monkeypatch.setattr(instruments, name, counting)
+    from dragonboat_tpu.obs import recorder
+
+    annotated = []
+    real_annotate = recorder.annotate
+    for mod in (instruments, recorder):  # a scope's, and its phases'
+        monkeypatch.setattr(
+            mod, "annotate",
+            lambda phase: annotated.append(phase) or real_annotate(phase))
+    live0 = len(instruments.replica_obs_live())
+    spans0 = obs.default_recorder().to_json(limit=1)["count"]
+
+    router = ChanRouter()
+    nh = NodeHost(NodeHostConfig(
+        node_host_dir=":memory:", rtt_millisecond=RTT_MS,
+        raft_address="ro1:1", enable_metrics=on,
+        raft_rpc_factory=lambda src, rh, ch: ChanTransport(
+            src, rh, ch, router=router),
+    ))
+    try:
+        nh.start_cluster(
+            {1: "ro1:1"}, False, _CountSM,
+            Config(cluster_id=1, node_id=1, election_rtt=10, heartbeat_rtt=1,
+                   snapshot_entries=5, compaction_overhead=2),
+        )
+        node = nh.get_node(1)
+        deadline = time.time() + 20
+        while not nh.get_leader_id(1)[1] and time.time() < deadline:
+            time.sleep(0.02)
+        s = nh.get_noop_session(1)
+        for _ in range(30):
+            assert nh.propose(s, b"x", timeout=5.0).wait(5.0).completed
+        while (not nh.logdb.list_snapshots(1, 1)
+               or node._snapshotting.locked()) and time.time() < deadline:
+            time.sleep(0.02)
+        assert nh.logdb.list_snapshots(1, 1)  # it did snapshot
+        saves = [sp for sp in obs.default_recorder().spans()
+                 if sp is not None and sp["kind"] == "snapshot_save"
+                 and sp["seq"] >= spans0 and sp["host"] == "ro1:1"]
+        if not on:
+            assert nh.tracer is None
+            assert nh.replica_obs is None and node.replica_obs is None
+            assert nh.engine.replica_obs is None
+            assert built == [] and annotated == [] and saves == []
+            assert len(instruments.replica_obs_live()) == live0
+            assert node._ss_refuse_from == 0  # not even the bookkeeping
+            return
+        assert node.replica_obs is nh.replica_obs is nh.engine.replica_obs
+        assert built.count("ReplicaObs") == 1
+        assert built.count("_SaveScope") == len(saves) >= 1
+        assert "snapshot_save" in annotated and "compact" in annotated
+        assert all(sp["saved"] and sp["save_kind"] == "periodic"
+                   for sp in saves)
+        assert nh.replica_obs in instruments.replica_obs_live()
+        assert nh.metrics_registry.counter_value(
+            "dragonboat_snapshot_saves_total", {"kind": "periodic"}
+        ) == len(saves)
+    finally:
+        nh.stop()
+    assert len(instruments.replica_obs_live()) == live0  # closed with it

@@ -1,0 +1,332 @@
+"""The documented group settings (``upstream48x3snap``, ISSUE 37) on the CPU
+at a small size: ``check_quorum`` on, a snapshot every 10 applied entries,
+the log compacted 5 behind it, under 8 writes in flight a group.
+
+Four groups on three chan-transport NodeHosts (``quorum_engine="tpu"``,
+durable directories) take 400 seeded writes.  One run of that story is
+shared by the cases below, so that each assertion counts as a case:
+
+* every replica snapshots at least twice and its log's first index moves
+  to within ``compaction_overhead`` of its newest snapshot;
+* the replicas hold what the plain reference holds;
+* the replica instruments saw it: a ``snapshot_save`` span a save with its
+  phases, counts by the second, no InstallSnapshot;
+* a follower's NodeHost stopped and restarted from its ``node_host_dir``
+  holds the same with no further write: the snapshot and the log kept
+  behind it are every acknowledged write;
+* a leader cut off from both followers steps down within two election
+  timeouts, and the check-quorum counter says why.
+
+The benchmark can stop no host and cut no link, so the deployment's two
+added guarantees are held here.
+"""
+from __future__ import annotations
+
+import random
+import shutil
+import tempfile
+import time
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from benchmark.cluster import KV  # noqa: E402
+from benchmark.reference import kv as reference  # noqa: E402
+
+pytestmark = pytest.mark.xdist_group("heavy-multiprocess")
+
+GROUPS = (1, 2, 3, 4)
+HOSTS = (1, 2, 3)
+WRITES = 400
+INFLIGHT = 8
+SNAPSHOT_ENTRIES = 10
+COMPACTION_OVERHEAD = 5
+ELECTION_RTT = 10
+RTT_MS = 20
+REPLICAS = [(cid, i) for cid in GROUPS for i in HOSTS]
+
+
+def _wait(pred, timeout_s, what):
+    deadline = time.time() + timeout_s
+    while time.time() < deadline:
+        if pred():
+            return
+        time.sleep(0.05)
+    raise AssertionError(f"{what} not reached in {timeout_s}s")
+
+
+class _Hosts:
+    """Three NodeHosts, built as ``benchmark/cluster.py`` builds them plus
+    the group settings, that can be stopped and started one by one."""
+
+    def __init__(self):
+        from dragonboat_tpu.transport import ChanRouter
+
+        self.base = tempfile.mkdtemp(prefix="snap-nh-")
+        self.router = ChanRouter()
+        self.addrs = {i: f"snap{i}:1" for i in HOSTS}
+        self.nhs = {}
+        self.sms = {}
+        for i in HOSTS:
+            self.start(i)
+
+    def start(self, i):
+        from dragonboat_tpu import Config, NodeHostConfig
+        from dragonboat_tpu.config import ExpertConfig
+        from dragonboat_tpu.nodehost import NodeHost
+        from dragonboat_tpu.transport import ChanTransport
+
+        def make_sm(cid, nid):
+            sm = self.sms[(cid, nid)] = KV(cid, nid)
+            return sm
+
+        nh = self.nhs[i] = NodeHost(NodeHostConfig(
+            node_host_dir=f"{self.base}/nh{i}", rtt_millisecond=RTT_MS,
+            raft_address=self.addrs[i], enable_metrics=True,
+            raft_rpc_factory=lambda src, rh, ch: ChanTransport(
+                src, rh, ch, router=self.router),
+            expert=ExpertConfig(quorum_engine="tpu", fast_lane=False,
+                                engine_block_groups=8),
+        ))
+        for cid in GROUPS:
+            nh.start_cluster(self.addrs, False, make_sm, Config(
+                cluster_id=cid, node_id=i, election_rtt=ELECTION_RTT,
+                heartbeat_rtt=1, check_quorum=True,
+                snapshot_entries=SNAPSHOT_ENTRIES,
+                compaction_overhead=COMPACTION_OVERHEAD))
+        return nh
+
+    def close(self):
+        for nh in self.nhs.values():
+            nh.stop()
+        shutil.rmtree(self.base, ignore_errors=True)
+
+    def leader(self, cid):
+        for nh in self.nhs.values():
+            lid, ok = nh.get_leader_id(cid)
+            if ok and lid in self.nhs:
+                return lid
+        return None
+
+    def lead_from(self, host):
+        """Every group led by ``host`` (campaigns until it is so)."""
+        def led():
+            for cid in GROUPS:
+                if self.leader(cid) != host:
+                    self.nhs[host].get_node(cid).request_campaign()
+                    return False
+            return True
+
+        _wait(led, 60.0, f"host {host} leading every group")
+
+    def write_all(self, writes):
+        """``writes``: cid -> [(key, value)], ``INFLIGHT`` outstanding a
+        group, each retried at the then-leader until it is acknowledged.
+        Returns the acknowledged ``(cid, key, value, apply_seq)``."""
+        todo = {cid: list(reversed(ws)) for cid, ws in writes.items()}
+        flying = {cid: [] for cid in writes}
+        acked = []
+        deadline = time.time() + 180
+        while any(todo.values()) or any(flying.values()):
+            assert time.time() < deadline, "writes not acknowledged"
+            for cid in writes:
+                still = []
+                for key, val, fut in flying[cid]:
+                    if not fut.done():
+                        still.append((key, val, fut))
+                    elif fut.result.completed:
+                        acked.append(
+                            (cid, key, val, fut.result.result.value))
+                    else:
+                        todo[cid].append((key, val))
+                flying[cid] = still
+                lid = self.leader(cid)
+                while lid and todo[cid] and len(flying[cid]) < INFLIGHT:
+                    key, val = todo[cid].pop()
+                    nh = self.nhs[lid]
+                    try:
+                        fut = nh.propose(
+                            nh.get_noop_session(cid), key + val, 3.0)
+                    except Exception:
+                        todo[cid].append((key, val))
+                        break
+                    flying[cid].append((key, val, fut))
+            time.sleep(0.002)
+        return acked
+
+    def applied(self, cid, i):
+        return self.nhs[i].get_node(cid).sm.get_last_applied()
+
+    def settle(self, hosts=HOSTS):
+        """Every replica on ``hosts`` at one applied index a group, and no
+        snapshot task queued or running (one may stay due: an idle group
+        has no update to take it with)."""
+        def settled():
+            for cid in GROUPS:
+                if len({self.applied(cid, i) for i in hosts}) != 1:
+                    return False
+                for i in hosts:
+                    if self.nhs[i].get_node(cid)._snapshotting.locked():
+                        return False
+            return True
+
+        _wait(settled, 60.0, "replicas settled")
+
+
+@pytest.fixture(scope="module")
+def story():
+    from dragonboat_tpu.obs import default_recorder
+
+    rng = random.Random(37)
+    writes = {cid: [] for cid in GROUPS}
+    for n in range(WRITES):
+        cid = GROUPS[n % len(GROUPS)]
+        writes[cid].append((rng.getrandbits(64).to_bytes(8, "little"),
+                            n.to_bytes(8, "little")))
+    hosts = _Hosts()
+    facts = {}
+    try:
+        hosts.lead_from(1)
+        seq0 = len(default_recorder().spans())
+        t0 = float(int(time.perf_counter()))  # counts go by whole seconds
+        acked = hosts.write_all(writes)
+        hosts.settle()
+        t1 = time.perf_counter() + 1.0
+        facts["expected"] = reference.expected_state(acked)
+        facts["contents"] = {
+            (cid, i): dict(hosts.sms[(cid, i)].kv) for cid, i in REPLICAS}
+        facts["snapshots"] = {}
+        facts["first_index"] = {}
+        for cid, i in REPLICAS:
+            node = hosts.nhs[i].get_node(cid)
+            facts["snapshots"][(cid, i)] = [
+                ss.index for ss in
+                hosts.nhs[i].logdb.list_snapshots(cid, i)]
+            facts["first_index"][(cid, i)] = node.logreader.get_range()[0]
+        facts["spans"] = [
+            s for s in default_recorder().spans()[seq0:]
+            if s is not None and s["kind"] == "snapshot_save"]
+        facts["windows"] = {
+            i: hosts.nhs[i].replica_obs.window(t0, t1) for i in HOSTS}
+
+        # a follower's NodeHost stopped and restarted from its directory
+        hosts.nhs.pop(3).stop()
+        for cid in GROUPS:
+            del hosts.sms[(cid, 3)]
+        hosts.start(3)
+        _wait(lambda: all(hosts.applied(cid, 3) == hosts.applied(cid, 1)
+                          for cid in GROUPS), 60.0, "restarted replica")
+        facts["restarted"] = {
+            cid: dict(hosts.sms[(cid, 3)].kv) for cid in GROUPS}
+        facts["recovered_from"] = {
+            cid: hosts.nhs[3].get_node(cid).sm.get_snapshot_index()
+            for cid in GROUPS}
+
+        # the leader cut off from both followers
+        nh1 = hosts.nhs[1]
+        tick0 = nh1.tick_count
+        w0 = nh1.replica_obs.window(0.0, float("inf"))
+        hosts.router.partition(hosts.addrs[1], hosts.addrs[2])
+        hosts.router.partition(hosts.addrs[1], hosts.addrs[3])
+        cut_at = time.time()
+
+        def stepped_down():
+            return all(
+                not nh1.get_node(cid).peer.raft.is_leader() for cid in GROUPS)
+
+        _wait(stepped_down, 30.0, "cut-off leader stepping down")
+        facts["stepdown_ticks"] = nh1.tick_count - tick0
+        facts["stepdown_s"] = time.time() - cut_at
+        w1 = nh1.replica_obs.window(0.0, float("inf"))
+        facts["stepdowns_counted"] = (
+            w1.get("checkq_stepdowns", 0) - w0.get("checkq_stepdowns", 0))
+        facts["stepdowns_registry"] = nh1.metrics_registry.counter_value(
+            "dragonboat_checkq_stepdowns_total")
+        hosts.router.heal()
+        yield facts
+    finally:
+        hosts.close()
+
+
+@pytest.mark.parametrize("replica", REPLICAS, ids=lambda r: f"g{r[0]}n{r[1]}")
+def test_every_replica_snapshots_and_compacts(story, replica):
+    snapshots = story["snapshots"][replica]
+    saved = [s for s in story["spans"]
+             if (s["cluster_id"], s["node_id"]) == replica and s["saved"]]
+    assert len(saved) >= 2, saved
+    assert snapshots and snapshots == sorted(snapshots)
+    newest = snapshots[-1]
+    assert newest >= 2 * SNAPSHOT_ENTRIES
+    assert story["first_index"][replica] >= newest - COMPACTION_OVERHEAD
+
+
+@pytest.mark.parametrize("replica", REPLICAS, ids=lambda r: f"g{r[0]}n{r[1]}")
+def test_replicas_hold_what_the_plain_reference_holds(story, replica):
+    cid = replica[0]
+    assert len(story["expected"][cid]) == WRITES // len(GROUPS)
+    assert story["contents"][replica] == story["expected"][cid]
+
+
+@pytest.mark.parametrize("field", [
+    "queue_ms", "sm_save_ms", "commit_ms", "compact_ms", "save_ms",
+    "image_bytes", "entries_since", "snapshot_entries", "save_kind", "host",
+])
+def test_a_save_is_one_span_with_its_phases(story, field):
+    saved = [s for s in story["spans"] if s["saved"]]
+    assert len(saved) >= 2 * len(REPLICAS)
+    for s in saved:
+        assert s.get(field) is not None, (field, s)
+    if field == "save_kind":
+        assert {s["save_kind"] for s in saved} == {"periodic"}
+    elif field == "snapshot_entries":
+        assert {s[field] for s in saved} == {SNAPSHOT_ENTRIES}
+    elif field == "entries_since":
+        assert all(s[field] >= SNAPSHOT_ENTRIES for s in saved)
+    elif field == "save_ms":
+        for s in saved:
+            parts = s["sm_save_ms"] + s["commit_ms"] + s["compact_ms"]
+            assert parts <= s["save_ms"] + 0.01
+            assert abs((s["t1"] - s["t0"]) * 1e3 - s["save_ms"]) < 0.01
+    elif field == "image_bytes":
+        assert all(s[field] > 16 for s in saved)
+    elif field == "host":
+        assert {s["host"] for s in saved} == {f"snap{i}:1" for i in HOSTS}
+
+
+@pytest.mark.parametrize("host", HOSTS)
+def test_counts_by_the_second_agree_with_the_spans(story, host):
+    w = story["windows"][host]
+    saved = [s for s in story["spans"]
+             if s["saved"] and s["host"] == f"snap{host}:1"]
+    assert w["saves"] == len(saved) >= 2 * len(GROUPS)
+    assert w["compactions"] == w["saves"]
+    assert w.get("installs_sent", 0) == w.get("installs_received", 0) == 0
+    busy_ms = sum(s["save_ms"] for s in story["spans"]
+                  if s["host"] == f"snap{host}:1")
+    assert w["pool_busy_s"] * 1e3 >= busy_ms * 0.99
+    assert w.get("checkq_stepdowns", 0) == 0
+    if host == 1:  # the leader of every group closed windows, and held
+        assert w["checkq_windows"] >= len(GROUPS)
+    else:  # at most the window a group's first leader closed before that
+        assert w.get("checkq_windows", 0) < story["windows"][1][
+            "checkq_windows"]
+
+
+@pytest.mark.parametrize("cid", GROUPS)
+def test_restarted_follower_holds_every_acknowledged_write(story, cid):
+    assert story["recovered_from"][cid] > 0  # it came up from a snapshot
+    assert story["restarted"][cid] == story["expected"][cid]
+
+
+@pytest.mark.parametrize("what", ["in_time", "counter", "registry"])
+def test_cut_off_leader_steps_down_and_the_counter_says_why(story, what):
+    if what == "in_time":
+        # the leader's own clock: two election timeouts of its ticks (and
+        # one tick each for the cut and the poll)
+        assert story["stepdown_ticks"] <= 2 * ELECTION_RTT + 2, story[
+            "stepdown_s"]
+    elif what == "counter":
+        assert story["stepdowns_counted"] == len(GROUPS)
+    else:
+        assert story["stepdowns_registry"] == len(GROUPS)
