@@ -244,6 +244,23 @@ class RDB:
         self._record_snapshot(wb, cluster_id, node_id, ss)
         self.kv.commit_write_batch(wb)
 
+    def commit_snapshot(
+        self, cluster_id: int, node_id: int, ss: Snapshot, stale: List[int],
+    ) -> None:
+        """What a committed save leaves among the snapshot records, as one
+        atomic, fsynced batch (one crc'd record, as ``remove_node_data``
+        mixes ``put`` and ``delete``): the record of ``ss`` and the deletes
+        of the records at the ``stale`` indexes: ``save_snapshot`` +
+        ``delete_snapshot`` x n under one commit.  The log's range delete
+        is NOT in it (``remove_entries_to``, after): an entry may leave
+        only once the LogReader holds the snapshot that covers it, and the
+        reader learns of a snapshot only once this record is durable."""
+        wb = self.kv.get_write_batch()
+        self._record_snapshot(wb, cluster_id, node_id, ss)
+        for index in stale:
+            wb.delete(keys.snapshot_key(cluster_id, node_id, index))
+        self.kv.commit_write_batch(wb)
+
     def delete_snapshot(self, cluster_id: int, node_id: int, index: int) -> None:
         self.kv.delete(keys.snapshot_key(cluster_id, node_id, index))
 

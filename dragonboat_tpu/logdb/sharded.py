@@ -264,6 +264,16 @@ class ShardedDB:
     def save_snapshot(self, cluster_id: int, node_id: int, ss: Snapshot) -> None:
         self._shard(cluster_id).save_snapshot(cluster_id, node_id, ss)
 
+    def commit_snapshot(
+        self, cluster_id: int, node_id: int, ss: Snapshot, stale: List[int],
+    ) -> None:
+        """``save_snapshot`` and ``delete_snapshot`` of the ``stale``
+        indexes as ONE fsynced batch of the group's shard
+        (``RDB.commit_snapshot``)."""
+        if stale:
+            self._journal_barrier()
+        self._shard(cluster_id).commit_snapshot(cluster_id, node_id, ss, stale)
+
     def delete_snapshot(self, cluster_id: int, node_id: int, index: int) -> None:
         self._journal_barrier()
         self._shard(cluster_id).delete_snapshot(cluster_id, node_id, index)

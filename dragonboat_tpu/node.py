@@ -1933,10 +1933,13 @@ class Node:
         try:
             try:
                 cap = self._try_capture_save(req)
-                ss, env = cap if cap is not None else self.sm.save(req)
+                ss, env = (
+                    cap if cap is not None else self.sm.save(req, scope)
+                )
                 if scope is not None:
                     # a regular state machine's save holds the group's
-                    # applies out (rsm.StateMachine._update_mu)
+                    # applies out while the image is captured
+                    # (rsm.StateMachine._update_mu: update_lock_ms)
                     scope.lap("sm_save")
             except SnapshotIgnored:
                 if user_req:
@@ -1970,8 +1973,12 @@ class Node:
                 )
                 return
             try:
-                self.snapshotter.commit(ss, env)
+                # the rename, the root's fsync, then ONE LogDB batch: the
+                # record and the deletes of the records beyond the three
+                # newest
+                stale = self.snapshotter.commit(ss, env)
                 if scope is not None:
+                    scope.logdb_commit()
                     scope.lap("commit")
                 self._publish_event(SystemEventType.SNAPSHOT_CREATED, index=ss.index)
             except FileExistsError:
@@ -1981,18 +1988,28 @@ class Node:
                         RequestResult(code=RequestResultCode.REJECTED)
                     )
                 return
+            # the orders a crash and a lagging follower need, as the
+            # reference keeps them: the reader learns of the snapshot only
+            # once its record is durable, and BEFORE its marker moves (a
+            # marker ahead of the reader's snapshot leaves a follower behind
+            # it neither entries nor an image to be sent); the reader
+            # compacts before the LogDB drops the entries, in a second
+            # batch, and its refusal drops none; a stale record went before
+            # its directory goes
             try:
                 self.logreader.create_snapshot(ss)
             except Exception as e:
                 plog.warning("%s create_snapshot: %s", self.describe(), e)
+                # nothing was compacted, but the stale records are gone
+                self.snapshotter.remove_dirs(stale)
                 if user_req:
                     self.pending_snapshot.notify(
                         RequestResult(code=RequestResultCode.ABORTED)
                     )
                 return
-            with (scope.saved(ss) if scope is not None else _OFF):
-                self._compact_log(ss, req)
-                self.snapshotter.compact()
+            with (scope.saved(ss, env) if scope is not None else _OFF):
+                self._compact_log(ss, req, scope)
+                self.snapshotter.remove_dirs(stale)
             self._publish_event(SystemEventType.SNAPSHOT_COMPACTED, index=ss.index)
             if req.type == SSReqType.USER_REQUESTED:
                 self.pending_snapshot.notify(
@@ -2043,7 +2060,7 @@ class Node:
             plog.error("%s streaming to %d failed: %s", self.describe(), to, e)
             sink.stop()
 
-    def _compact_log(self, ss: Snapshot, req: SSRequest) -> None:
+    def _compact_log(self, ss: Snapshot, req: SSRequest, scope) -> None:
         """Reference ``node.go:689-716``: keep ``compaction_overhead``
         entries behind the snapshot."""
         overhead = (
@@ -2059,6 +2076,8 @@ class Node:
         except Exception:
             return
         self.logdb.remove_entries_to(self.cluster_id, self.node_id, compact_to)
+        if scope is not None:
+            scope.logdb_commit()
         with self._compacted_to_mu:
             self._compacted_to = compact_to
         obs = self.replica_obs

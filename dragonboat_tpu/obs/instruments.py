@@ -1605,14 +1605,32 @@ class _SaveScope:
         self.fields[name + "_ms"] = round((now - self.lap_t) * 1e3, 4)
         self.lap_t = now
 
-    def saved(self, ss) -> Phase:
-        """The image is committed; what is left is the compaction behind
-        it: ``with scope.saved(ss):`` is ``compact_ms`` and an annotation
-        of its own (``dbtpu:compact``)."""
+    def update_lock_held(self, seconds: float) -> None:
+        """``update_lock_ms``: how long a regular state machine's
+        ``_update_mu`` was held for this save (``rsm.StateMachine.save``):
+        what the group's applies waited."""
+        self.fields["update_lock_ms"] = round(seconds * 1e3, 4)
+
+    def logdb_commit(self) -> None:
+        """The save issued one fsynced LogDB batch (``logdb_commits``, and
+        one of ``fsyncs``): told by whoever issued it, right behind the
+        call."""
+        fields = self.fields
+        fields["logdb_commits"] = fields.get("logdb_commits", 0) + 1
+        fields["fsyncs"] = fields.get("fsyncs", 0) + 1
+
+    def saved(self, ss, env) -> Phase:
+        """The image is committed, so what ``env`` counted is final
+        (``image_buffered``, its share of ``fsyncs``); what is left is the
+        compaction behind it: ``with scope.saved(ss, env):`` is
+        ``compact_ms`` and an annotation of its own (``dbtpu:compact``)."""
         fields = self.fields
         fields["saved"] = True
         fields["index"] = ss.index
         fields["image_bytes"] = ss.file_size
+        fields["image_buffered"] = env.image_buffered
+        fields["fsyncs"] = fields.get("fsyncs", 0) + env.fsyncs
+        fields.setdefault("update_lock_ms", 0.0)
         return Phase(fields, "compact")
 
     def __enter__(self):
@@ -1637,11 +1655,17 @@ class ReplicaObs:
     A save is one ``snapshot_save`` span (``t0``..``t1`` on
     ``perf_counter``, the pool worker's time on it): ``queue_ms`` from the
     task's enqueue to the worker taking it, ``sm_save_ms`` the state
-    machine's ``save`` (a regular state machine holds the group's applies
-    out for as long; the wait for that lock is in it), ``commit_ms``
-    ``snapshotter.commit`` (rename, fsync, the LogDB record),
-    ``compact_ms`` the log and snapshot compaction behind it, ``save_ms``
-    all of it, ``image_bytes``, ``entries_since`` (applied less the
+    machine's ``save`` (the wait for a regular state machine's update lock
+    is in it; ``update_lock_ms`` is how long that lock was then held, what
+    the group's applies waited: the image's capture, and its disk writes
+    only where it outgrew a block), ``commit_ms`` ``snapshotter.commit``
+    (rename, fsync, the LogDB batch of the record and the stale records'
+    deletes), ``compact_ms`` the log compaction behind it (the LogReader's,
+    the LogDB's range delete, the stale directories), ``save_ms`` all of
+    it; ``image_buffered`` (the image went out as one write),
+    ``logdb_commits`` and ``fsyncs`` (of files, directories and LogDB
+    batches), counted where they are issued (``SSEnv``, ``Snapshotter``,
+    ``Node``); ``image_bytes``, ``entries_since`` (applied less the
     previous snapshot's index when the worker took the task) beside the
     group's ``snapshot_entries``, ``save_kind`` (``SNAPSHOT_KINDS``; a
     span's ``kind`` is ``snapshot_save``) and ``saved`` once the image was

@@ -19,7 +19,7 @@ from __future__ import annotations
 import os
 import struct
 import zlib
-from typing import BinaryIO, List, Tuple
+from typing import BinaryIO, Callable, List, Optional, Tuple
 
 from .. import vfs
 from ..settings import Hard
@@ -46,10 +46,12 @@ class SnapshotFormatError(ValueError):
 
 
 class BlockWriter:
-    """Buffers payload into crc'd blocks (reference ``rw.go:89-205``)."""
+    """Buffers payload into crc'd blocks (reference ``rw.go:89-205``) and
+    hands each full block to ``write``; the last, partial one goes out
+    through :meth:`flush`, or stays in memory for :meth:`last_block`."""
 
-    def __init__(self, f: BinaryIO):
-        self._f = f
+    def __init__(self, write: Callable[[bytes], object]):
+        self._write = write
         self._buf = bytearray()
         self._crc = 0  # running crc over block crcs
         self.total = 0
@@ -62,11 +64,16 @@ class BlockWriter:
             del self._buf[:BLOCK_SIZE]
         return len(data)
 
-    def _flush_block(self, block) -> None:
-        crc = zlib.crc32(bytes(block))
-        self._f.write(_BLOCK_HDR.pack(len(block), crc))
-        self._f.write(bytes(block))
+    def _frame(self, block) -> Tuple[bytes, bytes]:
+        data = bytes(block)
+        crc = zlib.crc32(data)
         self._crc = zlib.crc32(crc.to_bytes(4, "little"), self._crc)
+        return _BLOCK_HDR.pack(len(data), crc), data
+
+    def _flush_block(self, block) -> None:
+        hdr, data = self._frame(block)
+        self._write(hdr)
+        self._write(data)
 
     def flush(self) -> int:
         """Flush the final partial block; returns the payload checksum."""
@@ -74,6 +81,16 @@ class BlockWriter:
             self._flush_block(self._buf)
             self._buf.clear()
         return self._crc
+
+    def last_block(self) -> Tuple[bytes, int]:
+        """The final partial block as it lies in the file (its header and
+        its bytes, empty where there is none), not written, and the
+        payload checksum."""
+        framed = b""
+        if self._buf:
+            framed = b"".join(self._frame(self._buf))
+            self._buf.clear()
+        return framed, self._crc
 
 
 class BlockReader:
@@ -119,24 +136,57 @@ class SnapshotWriter:
     header's compression_type field) the payload stream — session image and
     user SM image — is compressed before blocking; ``session_size`` always
     refers to UNCOMPRESSED bytes so recovery splits after decompression.
+
+    An image that fits its first block (``BLOCK_SIZE``) touches no file
+    until :meth:`finalize`, which then writes header, block header and
+    block as ONE write: the header's payload checksum is known by then.
+    One that outgrows the block opens the file at that moment and streams
+    (placeholder header, blocks, seek back).  The bytes on disk are the
+    same either way.  ``before_open`` runs once, just before the file is
+    opened (the snapshotter makes the temp directory there).
     """
 
-    def __init__(self, path: str, fs: vfs.IFS = vfs.DEFAULT, compression: int = 0):
+    def __init__(
+        self, path: str, fs: vfs.IFS = vfs.DEFAULT, compression: int = 0,
+        before_open: Optional[Callable[[], None]] = None,
+    ):
         from .. import dio
 
         self.path = path
         self._fs = fs
         self.compression = int(compression)
-        self._f = fs.open(path, "wb")
-        self._f.write(b"\0" * Hard.snapshot_header_size)  # placeholder
-        self._bw = BlockWriter(self._f)
+        self._before_open = before_open
+        self._f = None
+        self._bw = BlockWriter(self._stream)
         self._out = (
             dio.Compressor(dio.CompressionType(self.compression), self._bw)
             if self.compression
             else self._bw
         )
         self.session_size = 0
+        #: no block went to the file: the image is all in memory, and
+        #: finalize writes it as one write
+        self.buffered = True
+        #: bytes of the image file so far; all of it once finalized
+        self.file_size = 0
+        self._created = False
         self._closed = False
+
+    def _prepare(self) -> None:
+        if self._before_open is not None:
+            self._before_open()
+        self._created = True  # from here on abort() has a file to remove
+
+    def _stream(self, data: bytes) -> None:
+        """A full block: the image outgrew the memory it is buffered in."""
+        if self._f is None:
+            self.buffered = False
+            self._prepare()
+            self._f = self._fs.open(self.path, "wb")
+            self._f.write(b"\0" * Hard.snapshot_header_size)  # placeholder
+            self.file_size = Hard.snapshot_header_size
+        self._f.write(data)
+        self.file_size += len(data)
 
     def write_session(self, data: bytes) -> None:
         self.session_size = len(data)
@@ -146,31 +196,48 @@ class SnapshotWriter:
         self._out.write(data)
         return len(data)
 
-    def finalize(self) -> None:
+    def seal(self) -> bool:
+        """The payload is complete (the compressor's last block flushed);
+        True while the whole image is still in memory."""
         if self._out is not self._bw:
-            self._out.close()  # flush the final compressed block
-        payload_crc = self._bw.flush()
+            self._out.close()  # flush the final compressed block (once)
+        return self.buffered
+
+    def _header(self, payload_crc: int) -> bytes:
         header = bytearray(Hard.snapshot_header_size)
         _HEADER_FMT.pack_into(
             header, 0, MAGIC, V2, 0, self.compression, self.session_size, payload_crc
         )
         hcrc = zlib.crc32(bytes(header[:_HEADER_CRC_OFF]))
         struct.pack_into("<I", header, _HEADER_CRC_OFF, hcrc)
-        self._f.flush()
-        self._f.seek(0)
-        self._f.write(bytes(header))
-        self._fs.fsync(self._f)
-        self._f.close()
+        return bytes(header)
+
+    def finalize(self) -> None:
+        if self.seal():
+            block, payload_crc = self._bw.last_block()
+            image = self._header(payload_crc) + block
+            self._prepare()
+            self._fs.write_file(self.path, image)
+            self.file_size = len(image)
+        else:
+            header = self._header(self._bw.flush())
+            self._f.flush()
+            self._f.seek(0)
+            self._f.write(header)
+            self._fs.fsync(self._f)
+            self._f.close()
         self._closed = True
 
     def abort(self) -> None:
         if not self._closed:
-            self._f.close()
-            try:
-                self._fs.remove(self.path)
-            except OSError:
-                pass
             self._closed = True
+            if self._f is not None:
+                self._f.close()
+            if self._created:
+                try:
+                    self._fs.remove(self.path)
+                except OSError:
+                    pass
 
 
 def read_header(f: BinaryIO) -> Tuple[int, int, int, int, int]:

@@ -10,9 +10,10 @@ from __future__ import annotations
 
 import enum
 import threading
+import time
 import zlib
 from dataclasses import dataclass, field
-from typing import List, Optional, Protocol, Tuple
+from typing import Callable, List, Optional, Protocol, Tuple
 
 from ..logger import get_logger
 from ..statemachine import Result, SMEntry, StopChecker
@@ -137,7 +138,13 @@ class ISnapshotter(Protocol):
     """Snapshot file orchestration (reference ``statemachine.go:150``
     ``ISnapshotter``, implemented by the top-level ``snapshotter.go``)."""
 
-    def save(self, savable, meta: SSMeta) -> Tuple[Snapshot, object]: ...
+    def save(
+        self, savable, meta: SSMeta,
+        captured: Optional[Callable[[], None]] = None,
+    ) -> Tuple[Snapshot, object]:
+        """``captured`` is called once the image no longer depends on
+        ``savable`` (a failed save may not call it)."""
+        ...
 
     def recover(self, recoverable, ss: Snapshot) -> None: ...
 
@@ -149,6 +156,27 @@ class ISnapshotter(Protocol):
     def get_snapshot(self, index: int) -> Snapshot: ...
 
     def is_no_snapshot_error(self, e: Exception) -> bool: ...
+
+
+class _Held:
+    """A lock taken now and released once, by whichever comes first: the
+    snapshotter's ``captured`` or the end of the save."""
+
+    __slots__ = ("mu", "scope", "t0")
+
+    def __init__(self, mu, scope) -> None:
+        mu.acquire()
+        self.mu = mu
+        self.scope = scope
+        self.t0 = time.perf_counter() if scope is not None else 0.0
+
+    def release(self) -> None:
+        mu, self.mu = self.mu, None
+        if mu is None:
+            return
+        mu.release()
+        if self.scope is not None:
+            self.scope.update_lock_held(time.perf_counter() - self.t0)
 
 
 class _CaptureSavable:
@@ -436,7 +464,7 @@ class StateMachine:
         if not self.is_witness:
             self.managed.save_snapshot(meta.ctx, writer, None, self.stopc)
 
-    def save(self, req: SSRequest) -> Tuple[Snapshot, object]:
+    def save(self, req: SSRequest, scope=None) -> Tuple[Snapshot, object]:
         """Full snapshot save via the snapshotter.
 
         ``_save_mu`` serializes saves of this SM (a user-requested and a
@@ -444,10 +472,16 @@ class StateMachine:
         clobber each other's identically-named temp dir — the reference
         serializes per group via the snapshotState single-slot handoff,
         ``snapshotstate.go:65``).  Regular SMs additionally hold
-        ``_update_mu`` across BOTH the meta capture and the image write:
-        capturing meta.index first and locking later would let applies land
-        in between and the image would reflect state newer than its label —
-        double-apply after recovery."""
+        ``_update_mu`` across BOTH the meta capture and the image's
+        capture: capturing meta.index first and locking later would let
+        applies land in between and the image would reflect state newer
+        than its label — double-apply after recovery.  The lock goes when
+        the snapshotter says the image is ``captured``: in memory for one
+        that fits a block, so the group's applies run beside every disk
+        call of a small save; with its last block written for one that
+        spilled.  ``scope`` is the save's ``snapshot_save`` span while the
+        replica instruments are attached (``ReplicaObs.save``), else None:
+        it is told how long the lock was held (``update_lock_ms``)."""
         if self.snapshotter is None:
             raise RuntimeError("no snapshotter configured")
         with self._save_mu:
@@ -455,9 +489,12 @@ class StateMachine:
                 meta = self._checked_meta(req)
                 ss, env = self.snapshotter.save(self, meta)
             else:
-                with self._update_mu:
+                held = _Held(self._update_mu, scope)
+                try:
                     meta = self._checked_meta(req)
-                    ss, env = self.snapshotter.save(self, meta)
+                    ss, env = self.snapshotter.save(self, meta, held.release)
+                finally:
+                    held.release()
         with self._mu:
             if not req.exported and ss.index > self.snapshot_index:
                 self.snapshot_index = ss.index

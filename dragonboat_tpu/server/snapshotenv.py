@@ -36,15 +36,13 @@ def snapshot_dir_name(index: int) -> str:
     return f"snapshot-{index:016X}"
 
 
-def _fsync_dir(path: str, fs: vfs.IFS = vfs.DEFAULT) -> None:
-    try:
-        fs.fsync_dir(path)
-    except OSError:
-        return
-
-
 class SSEnv:
-    """Reference ``snapshotenv.go`` ``SSEnv``."""
+    """Reference ``snapshotenv.go`` ``SSEnv``.
+
+    ``fsyncs`` counts the fsyncs of files and directories issued for the
+    snapshot in this env (the snapshotter adds the image's) and
+    ``image_buffered`` says the image went out as one write: what one
+    save's ``snapshot_save`` span reports."""
 
     def __init__(
         self,
@@ -64,12 +62,37 @@ class SSEnv:
         else:
             tmp = f"{final}-{from_node_id:X}.{RECEIVING_SUFFIX}"
         self.tmp_dir = os.path.join(root_dir, tmp)
+        self.fsyncs = 0
+        self.image_buffered = False
+
+    def _fsync_dir(self, path: str) -> None:
+        self.fsyncs += 1
+        try:
+            self.fs.fsync_dir(path)
+        except OSError:
+            return
 
     # ---- temp stage ----
 
     def create_tmp_dir(self) -> None:
-        self.fs.makedirs(self.tmp_dir, exist_ok=False)
-        _fsync_dir(self.root_dir, self.fs)
+        """An empty temp dir: one ``mkdir``; only where a crashed save or
+        transfer left one under this name is that removed first.
+
+        The root is NOT fsynced here.  That fsync made durable only the
+        NAME of a ``.generating`` / ``.receiving`` directory, and no
+        recovery reads that name: ``process_orphans`` deletes every temp
+        dir on sight, whatever it holds.  What a crash needs is durable
+        further on: the image and the flag file by their own fsyncs, their
+        names by ``fsync_dir(tmp)``, the final name by the root's fsync
+        after the rename (``finalize_snapshot``), all before the LogDB
+        record."""
+        try:
+            self.fs.mkdir(self.tmp_dir)
+        except FileExistsError:
+            self.remove_tmp_dir()
+            self.fs.mkdir(self.tmp_dir)
+        except FileNotFoundError:  # an export directory not made yet
+            self.fs.makedirs(self.tmp_dir, exist_ok=False)
 
     def get_tmp_dir(self) -> str:
         return self.tmp_dir
@@ -88,11 +111,9 @@ class SSEnv:
         ``fileutil.CreateFlagFile``)."""
         flag = os.path.join(self.tmp_dir, SNAPSHOT_FLAG_FILE)
         data = encode_snapshot(ss)
-        with self.fs.open(flag, "wb") as f:
-            f.write(len(data).to_bytes(8, "little"))
-            f.write(data)
-            self.fs.fsync(f)
-        _fsync_dir(self.tmp_dir, self.fs)
+        self.fsyncs += 1
+        self.fs.write_file(flag, len(data).to_bytes(8, "little") + data)
+        self._fsync_dir(self.tmp_dir)
 
     # ---- finalize ----
 
@@ -103,7 +124,7 @@ class SSEnv:
         if self.fs.exists(self.final_dir):
             raise FileExistsError(self.final_dir)
         self.fs.replace(self.tmp_dir, self.final_dir)
-        _fsync_dir(self.root_dir, self.fs)
+        self._fsync_dir(self.root_dir)
 
     def has_flag_file(self) -> bool:
         return self.fs.exists(os.path.join(self.final_dir, SNAPSHOT_FLAG_FILE))
@@ -115,7 +136,16 @@ class SSEnv:
         _rmtree(self.tmp_dir, self.fs)
 
     def remove_final_dir(self) -> None:
-        _rmtree(self.final_dir, self.fs)
+        """By the layout a save leaves (the image, the flag file): two
+        ``unlink`` and an ``rmdir``.  Anything else (external files, a
+        half-removed directory) goes the long way, by ``rmtree``."""
+        fs = self.fs
+        try:
+            fs.remove(self.get_filepath())
+            fs.remove(os.path.join(self.final_dir, SNAPSHOT_FLAG_FILE))
+            fs.rmdir(self.final_dir)
+        except OSError:
+            _rmtree(self.final_dir, fs)
 
 
 def read_ss_metadata(

@@ -9,7 +9,7 @@ Implements the RSM layer's ``ISnapshotter`` contract.
 from __future__ import annotations
 
 import os
-from typing import Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 from . import vfs
 from .logger import get_logger
@@ -54,54 +54,97 @@ class Snapshotter:
 
     # ---- ISnapshotter ----
 
-    def save(self, savable, meta: SSMeta) -> Tuple[Snapshot, SSEnv]:
+    def save(
+        self, savable, meta: SSMeta,
+        captured: Optional[Callable[[], None]] = None,
+    ) -> Tuple[Snapshot, SSEnv]:
         """Write a snapshot image into a temp dir (reference
         ``snapshotter.go:103-150`` ``Save``).  Exported snapshots land in
         the user-provided directory instead of the node's snapshot root
         (reference custom-SSEnv path for ``Exported`` requests) and are
-        never recorded in the LogDB."""
+        never recorded in the LogDB.
+
+        ``captured`` is called once the image no longer depends on
+        ``savable``, and whoever holds updates out for the image's sake
+        lets them in again there.  An image that fits a block
+        (``SnapshotWriter``) is whole in memory when the payload ends, so
+        that is before anything touches the disk: the temp dir, the one
+        image write, the flag file and every fsync run beside the group's
+        applies.  One that spilled is captured with its last block
+        written.  A failed save may not have called it."""
         root = self.root_dir
         if meta.request is not None and meta.request.exported:
             if not meta.request.path:
                 raise ValueError("exported snapshot request without a path")
             root = meta.request.path
         env = SSEnv(root, meta.index, self.node_id, SSMode.SNAPSHOT, self.fs)
-        env.remove_tmp_dir()
-        env.create_tmp_dir()
-        path = env.get_tmp_filepath()
-        # writer construction is inside the cleanup scope: __init__ already
-        # writes the header placeholder, and a fault there (ErrorFS write
-        # injection, ENOSPC) must not leak the .generating temp dir
-        # (tests/test_rsm.py fault table caught exactly this)
-        w = None
+        # whatever fails from here on, a write of the image or of the flag
+        # file (ErrorFS injection, ENOSPC), must not leak the .generating
+        # temp dir (tests/test_rsm.py fault table caught exactly this)
+        w = SnapshotWriter(
+            env.get_tmp_filepath(), self.fs, compression=meta.compression,
+            before_open=env.create_tmp_dir,
+        )
         try:
-            w = SnapshotWriter(path, self.fs, compression=meta.compression)
             savable.save_snapshot_payload(meta, w)
+            env.image_buffered = w.seal()
+            if captured is not None and env.image_buffered:
+                captured()
             w.finalize()
+            env.fsyncs += 1
+            if captured is not None and not env.image_buffered:
+                captured()
+            ss = Snapshot(
+                filepath=env.get_filepath(),
+                file_size=w.file_size,
+                index=meta.index,
+                term=meta.term,
+                membership=meta.membership,
+                cluster_id=self.cluster_id,
+                type=meta.type,
+                on_disk_index=meta.on_disk_index,
+                witness=False,
+            )
+            env.save_ss_metadata(ss)
         except Exception:
-            if w is not None:
-                w.abort()
+            w.abort()
             env.remove_tmp_dir()
             raise
-        ss = Snapshot(
-            filepath=env.get_filepath(),
-            file_size=self.fs.getsize(path),
-            index=meta.index,
-            term=meta.term,
-            membership=meta.membership,
-            cluster_id=self.cluster_id,
-            type=meta.type,
-            on_disk_index=meta.on_disk_index,
-            witness=False,
-        )
-        env.save_ss_metadata(ss)
         return ss, env
 
-    def commit(self, ss: Snapshot, env: SSEnv) -> None:
-        """Promote temp → final and record in the LogDB (reference
-        ``snapshotter.go:181`` ``Commit``)."""
+    def commit(self, ss: Snapshot, env: SSEnv) -> List[Snapshot]:
+        """Promote the temp dir, then make the snapshot known to the LogDB
+        (reference ``snapshotter.go:181`` ``Commit``): ONE atomic,
+        fsynced batch, issued once ``finalize_snapshot`` made the directory
+        final and its name durable, that holds the record of ``ss`` and the
+        deletes of the records beyond the ``SNAPSHOTS_TO_KEEP`` newest
+        (reference ``snapshotter.go`` ``Compact``).  Returns the snapshots
+        whose records went: their directories are removed after, by
+        :meth:`remove_dirs` (a crash between the two leaves an unrecorded
+        directory, which ``process_orphans`` removes; the other order would
+        leave a record without its file)."""
         env.finalize_snapshot()
-        self.logdb.save_snapshot(self.cluster_id, self.node_id, ss)
+        have = [
+            s
+            for s in self.logdb.list_snapshots(self.cluster_id, self.node_id)
+            if s.index != ss.index
+        ]
+        have.append(ss)
+        have.sort(key=lambda s: s.index)
+        stale = have[:-SNAPSHOTS_TO_KEEP]
+        self.logdb.commit_snapshot(
+            self.cluster_id, self.node_id, ss, [s.index for s in stale]
+        )
+        return stale
+
+    def remove_dirs(self, stale: List[Snapshot]) -> None:
+        """The directories of the snapshots :meth:`commit` dropped the
+        records of."""
+        for ss in stale:
+            SSEnv(
+                self.root_dir, ss.index, self.node_id, SSMode.SNAPSHOT,
+                self.fs,
+            ).remove_final_dir()
 
     def recover(self, recoverable, ss: Snapshot) -> None:
         """Reference ``snapshotter.go`` recover path: open + validate the
@@ -145,14 +188,6 @@ class Snapshotter:
 
     # ---- retention / GC ----
 
-    def compact(self, keep: int = SNAPSHOTS_TO_KEEP) -> None:
-        """Drop all but the ``keep`` newest snapshot records + dirs
-        (reference ``snapshotter.go`` ``Compact``)."""
-        snapshots = self.logdb.list_snapshots(self.cluster_id, self.node_id)
-        for ss in snapshots[:-keep] if keep else snapshots:
-            self.logdb.delete_snapshot(self.cluster_id, self.node_id, ss.index)
-            self._remove_snapshot_dir(ss.index)
-
     def shrink(self, shrink_to: int) -> None:
         """Shrink images older than ``shrink_to`` (reference
         ``snapshotter.go`` ``Shrink``) — used by on-disk SMs whose old full
@@ -186,8 +221,3 @@ class Snapshotter:
                 if snapshot_index_from_dir(name) not in recorded:
                     plog.info("removing unrecorded snapshot dir %s", full)
                     _rmtree(full, self.fs)
-
-    def _remove_snapshot_dir(self, index: int) -> None:
-        env = SSEnv(self.root_dir, index, self.node_id, SSMode.SNAPSHOT, self.fs)
-        env.remove_final_dir()
-

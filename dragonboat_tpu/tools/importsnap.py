@@ -105,7 +105,6 @@ def import_snapshot(
     dst_root = _snapshot_dir(nhconfig, cluster_id, node_id)
     vfs.DEFAULT.makedirs(dst_root, exist_ok=True)
     env = SSEnv(dst_root, ss.index, node_id, SSMode.SNAPSHOT)
-    env.remove_tmp_dir()
     env.remove_final_dir()
     env.create_tmp_dir()
     dst_image = env.get_tmp_filepath()

@@ -124,7 +124,6 @@ class Chunks:
             return None
         os.makedirs(root, exist_ok=True)
         env = SSEnv(root, c.index, c.from_, SSMode.RECEIVING)
-        env.remove_tmp_dir()
         env.create_tmp_dir()
         return _Track(first_chunk=c, env=env, tick=self._tick)
 

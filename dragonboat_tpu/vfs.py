@@ -38,6 +38,11 @@ class IFS:
     def makedirs(self, path: str, exist_ok: bool = True) -> None:
         raise NotImplementedError
 
+    def mkdir(self, path: str) -> None:
+        """One directory under a parent that is there: FileExistsError
+        where ``path`` is, FileNotFoundError where the parent is not."""
+        raise NotImplementedError
+
     def rmdir(self, path: str) -> None:
         raise NotImplementedError
 
@@ -62,6 +67,14 @@ class IFS:
     def fsync_dir(self, path: str) -> None:
         raise NotImplementedError
 
+    def write_file(self, path: str, data: bytes) -> None:
+        """A new file that holds ``data``, fsynced and closed: open, ONE
+        write, fsync, close.  Here through this FS's own ``open`` /
+        ``fsync``, so a wrapper (``ErrorFS``) sees every step."""
+        with self.open(path, "wb") as f:
+            f.write(data)
+            self.fsync(f)
+
 
 class OSFS(IFS):
     """Pass-through to the real filesystem."""
@@ -77,6 +90,9 @@ class OSFS(IFS):
 
     def makedirs(self, path: str, exist_ok: bool = True) -> None:
         os.makedirs(path, exist_ok=exist_ok)
+
+    def mkdir(self, path: str) -> None:
+        os.mkdir(path)
 
     def rmdir(self, path: str) -> None:
         os.rmdir(path)
@@ -105,6 +121,19 @@ class OSFS(IFS):
     def fsync_dir(self, path: str) -> None:
         fd = os.open(path, os.O_RDONLY)
         try:
+            os.fsync(fd)
+        finally:
+            os.close(fd)
+
+    def write_file(self, path: str, data: bytes) -> None:
+        # on the descriptor: four calls that let the interpreter go, where
+        # a buffered file object makes eight (open is open + fstat +
+        # isatty)
+        fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666)
+        try:
+            view = memoryview(data)
+            while view:
+                view = view[os.write(fd, view):]
             os.fsync(fd)
         finally:
             os.close(fd)
@@ -220,6 +249,15 @@ class MemFS(IFS):
                     continue
                 cur = os.path.join(cur, p) if cur else p
                 self._dirs.add(cur)
+
+    def mkdir(self, path: str) -> None:
+        path = self._norm(path)
+        with self._mu:
+            if path in self._dirs or path in self._files:
+                raise FileExistsError(path)
+            if os.path.dirname(path) not in self._dirs:
+                raise FileNotFoundError(path)
+            self._dirs.add(path)
 
     def rmdir(self, path: str) -> None:
         path = self._norm(path)
@@ -366,6 +404,10 @@ class ErrorFS(IFS):
     def makedirs(self, path: str, exist_ok: bool = True) -> None:
         self.injector.maybe_fail("makedirs", path)
         self.fs.makedirs(path, exist_ok=exist_ok)
+
+    def mkdir(self, path: str) -> None:
+        self.injector.maybe_fail("mkdir", path)
+        self.fs.mkdir(path)
 
     def rmdir(self, path: str) -> None:
         self.injector.maybe_fail("rmdir", path)

@@ -493,6 +493,10 @@ class _FakeLogDB:
     def save_snapshot(self, cluster_id, node_id, ss):
         self.snapshots.append(ss)
 
+    def commit_snapshot(self, cluster_id, node_id, ss, stale):
+        self.snapshots = [s for s in self.snapshots if s.index not in stale]
+        self.snapshots.append(ss)
+
     def list_snapshots(self, cluster_id, node_id):
         return list(self.snapshots)
 

@@ -207,6 +207,19 @@ def test_replica_instruments_are_built_only_when_switched_on(on, monkeypatch):
         monkeypatch.setattr(
             mod, "annotate",
             lambda phase: annotated.append(phase) or real_annotate(phase))
+    from dragonboat_tpu.rsm import statemachine
+
+    clocked = []
+    real_held = statemachine._Held
+
+    def held(mu, scope):
+        if scope is not None:
+            clocked.append(scope)
+        h = real_held(mu, scope)
+        assert (h.t0 != 0.0) == (scope is not None)  # off: no clock read
+        return h
+
+    monkeypatch.setattr(statemachine, "_Held", held)
     live0 = len(instruments.replica_obs_live())
     spans0 = obs.default_recorder().to_json(limit=1)["count"]
 
@@ -244,7 +257,13 @@ def test_replica_instruments_are_built_only_when_switched_on(on, monkeypatch):
             assert built == [] and annotated == [] and saves == []
             assert len(instruments.replica_obs_live()) == live0
             assert node._ss_refuse_from == 0  # not even the bookkeeping
+            assert clocked == []  # nor a stamp around the update lock
             return
+        assert len(clocked) == len(saves)
+        for sp in saves:  # ISSUE 38: what the save issued, and its lock
+            assert sp["image_buffered"] is True
+            assert sp["logdb_commits"] == 2 and sp["fsyncs"] == 6
+            assert 0 < sp["update_lock_ms"] < sp["sm_save_ms"]
         assert node.replica_obs is nh.replica_obs is nh.engine.replica_obs
         assert built.count("ReplicaObs") == 1
         assert built.count("_SaveScope") == len(saves) >= 1

@@ -271,6 +271,7 @@ def test_replicas_hold_what_the_plain_reference_holds(story, replica):
 @pytest.mark.parametrize("field", [
     "queue_ms", "sm_save_ms", "commit_ms", "compact_ms", "save_ms",
     "image_bytes", "entries_since", "snapshot_entries", "save_kind", "host",
+    "image_buffered", "logdb_commits", "fsyncs", "update_lock_ms",
 ])
 def test_a_save_is_one_span_with_its_phases(story, field):
     saved = [s for s in story["spans"] if s["saved"]]
@@ -292,6 +293,15 @@ def test_a_save_is_one_span_with_its_phases(story, field):
         assert all(s[field] > 16 for s in saved)
     elif field == "host":
         assert {s["host"] for s in saved} == {f"snap{i}:1" for i in HOSTS}
+    elif field == "image_buffered":  # ISSUE 38: small images, one write
+        assert {s[field] for s in saved} == {True}
+    elif field == "logdb_commits":  # record + stale deletes; the range
+        assert {s[field] for s in saved} == {2}
+    elif field == "fsyncs":  # image, flag file, temp dir, root, 2 batches
+        assert {s[field] for s in saved} == {6}
+    elif field == "update_lock_ms":  # the capture, not the disk calls
+        for s in saved:
+            assert 0 < s[field] < s["sm_save_ms"]
 
 
 @pytest.mark.parametrize("host", HOSTS)
