@@ -619,6 +619,12 @@ class Node:
             if commit_q > self.devsm_release_floor:
                 self.devsm_release_floor = commit_q
             self.pending_reads.applied(commit_q)
+        # the coordinator's sampled ReadIndex contexts (ISSUE 39): empty
+        # while nothing is sampled, and nothing below reads a clock then
+        rt = (
+            getattr(r.offload, "_read_traces", None)
+            if reads or echoes else None
+        )
         if reads and r.is_leader():
             for low, high, term in reads:
                 # term-pinned like offload_election: a confirmation tallied
@@ -628,12 +634,19 @@ class Node:
                 if r.term != term:
                     continue
                 ctx = SystemCtx(low=low, high=high)
-                r.apply_read_releases(r.read_index.release(ctx))
+                ris = r.read_index.release(ctx)
+                r.apply_read_releases(ris)
+                if rt:
+                    r.offload.read_released(self.cluster_id, ris)
         if echoes and r.is_leader():
             for from_, low, high in echoes:
-                r.handle_read_index_leader_confirmation(
+                ris = r.handle_read_index_leader_confirmation(
                     Message(from_=from_, hint=low, hint_high=high)
                 )
+                if rt and ris:
+                    r.offload.read_released(
+                        self.cluster_id, ris, scalar=True
+                    )
         if election is not None:
             won, term = election
             if r.is_candidate() and r.term == term:
@@ -905,7 +918,7 @@ class Node:
             self.fast_eject()
             with self.raft_mu:
                 if self.peer is not None:
-                    self.peer.read_index(ctx)
+                    self._read_index(ctx)
         self.nh.engine.set_step_ready(self.cluster_id)
         return rs
 
@@ -1129,7 +1142,7 @@ class Node:
             ):
                 self._count_eject("read-fallback")
                 self.fast_eject()
-                self.peer.read_index(ctx)
+                self._read_index(ctx)
                 return True
         # proposals racing an enrollment land in the scalar queue; route
         # them into the native lane in order (indices assigned there)
@@ -1468,7 +1481,7 @@ class Node:
             # confirmations are harmless) so in-flight reads don't strand
             if r.is_leader():
                 for ctx in self.pending_reads.pending_ctxs():
-                    self.peer.read_index(ctx)
+                    self._read_index(ctx)
             if contact_lost or reenroll_backoff:
                 # the native clock already waited out the election window
                 # with zero leader contact — without this the group would
@@ -1613,7 +1626,19 @@ class Node:
             ctx = self.pending_reads.next_ctx()
             if self.pending_reads.take_pending(ctx):
                 self.quiesce_mgr.record_activity(MT.READ_INDEX)
-                self.peer.read_index(ctx)
+                self._read_index(ctx)
+
+    def _read_index(self, ctx: SystemCtx) -> None:
+        """Hand a taken ReadIndex ctx to raft (under raftMu).  Where the
+        tracer is on and the batch holds a sampled request, the READ_INDEX
+        carries that request's identifier to the leader's step, which
+        follows the ctx through its read plane (ISSUE 39)."""
+        wire = None
+        if self.tracer is not None:
+            wire = self.pending_reads.trace_ctx(
+                ctx, "local" if self.peer.raft.is_leader() else "forwarded"
+            )
+        self.peer.read_index(ctx, wire)
 
     def _handle_config_change(self) -> None:
         cc = self.pending_config_change.take()

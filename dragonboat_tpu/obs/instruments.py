@@ -38,7 +38,7 @@ Families (device plane, published by ``EngineObs``):
 Coordinator plane (``CoordObs``): ``dragonboat_coord_rounds_total``,
 ``…round_latency_ms`` (histogram), ``…ops_drained_total``,
 ``…tick_deficit_total``, ``…commits_offloaded_total``,
-``…reads_confirmed_total``, ``…fused_dispatch_total`` /
+``…fused_dispatch_total`` /
 ``…fused_rounds_total`` (adaptive-K live batching); gauges
 ``…staged_depth``; ``…read_fallbacks_total{cause}`` — heartbeat read
 echoes tallied scalar-side, by cause (``slot_overflow`` /
@@ -174,7 +174,6 @@ _HELP = {
     _COORD + "ops_drained_total": "staged ops drained into the engine",
     _COORD + "tick_deficit_total": "host ticks replayed by rounds",
     _COORD + "commits_offloaded_total": "group commits offloaded to nodes",
-    _COORD + "reads_confirmed_total": "ReadIndex ctxs confirmed on device",
     _COORD + "fused_dispatch_total": "rounds served by one fused dispatch",
     _COORD + "fused_rounds_total": "rounds carried by fused dispatches",
     _COORD + "staged_depth": "ops staged for the next round",
@@ -1353,6 +1352,53 @@ class MeshObs:
             )
 
 
+class ReadCtx:
+    """One sampled ReadIndex context on its way through a leader's read
+    plane (ISSUE 39): the instants ``CoordObs.read_ctx`` writes its span
+    from, each taken on ``perf_counter`` at its site (the table in
+    ``docs/overview.md``).  The step workers write ``a`` / ``e1`` / ``eq``
+    / ``r`` (and ``c`` on the scalar path), the round thread ``s`` / ``d``
+    / ``c``: no field has two writers."""
+
+    __slots__ = (
+        "cluster_id", "low", "high", "term", "origin", "tid",
+        "trace_origin", "need", "peers", "eq_peer", "path", "round0",
+        "rounds", "stage_round", "confirm_round",
+        "a", "s", "e1", "eq", "d", "c", "r",
+    )
+
+    def __init__(self, cluster_id: int, low: int, high: int, term: int,
+                 remote: bool, trace, need: int, round0: int):
+        self.cluster_id = cluster_id
+        self.low = low
+        self.high = high
+        self.term = term
+        self.origin = "remote" if remote else "local"
+        # the requester's identifier: its tracer's host and trace id
+        self.tid = trace.tid
+        self.trace_origin = trace.origin
+        # echoes of distinct followers that make the quorum with the
+        # leader's own; the followers heard from; whose echo made it
+        self.need = need
+        self.peers: set = set()
+        self.eq_peer = None
+        # ``device`` (given a slot), ``scalar:<cause>`` (the echoes are
+        # tallied by the step worker), ``dropped`` (a transition took it)
+        self.path = None
+        self.round0 = round0  # the host's dispatched rounds as of ``a``
+        self.rounds = None
+        self.stage_round = None
+        self.confirm_round = None
+        self.a = time.perf_counter()
+        self.s = self.e1 = self.eq = self.d = self.c = self.r = None
+
+
+def _leg_ms(t_from, t_to):
+    if t_from is None or t_to is None:
+        return None
+    return round((t_to - t_from) * 1e3, 4)
+
+
 class CoordObs:
     """Round-loop instruments for one ``TpuQuorumCoordinator``."""
 
@@ -1363,7 +1409,6 @@ class CoordObs:
         _COORD + "ops_drained_total",
         _COORD + "tick_deficit_total",
         _COORD + "commits_offloaded_total",
-        _COORD + "reads_confirmed_total",
         # adaptive K-round batching (ISSUE 7): rounds served by ONE fused
         # multi-round dispatch, and the fused rounds they carried — the
         # ratio to rounds_total is the live fused duty cycle
@@ -1432,6 +1477,41 @@ class CoordObs:
             wait_ms=round(wait_ms, 4),
         )
 
+    def read_ctx(self, rc: ReadCtx) -> dict:
+        """A sampled ReadIndex context reached its end on this leader:
+        write its one ``read_ctx`` span, ``t0`` the instant the leader
+        accepted it (``a``), ``t1`` the instant its requesters were
+        answered (``r``; a dropped context ends where it was dropped).
+        The chain ``echo_trip_ms`` + ``echo_wait_ms`` + ``confirm_ms`` +
+        ``release_ms`` is ``leader_ms``; a leg whose ends were not both
+        seen (a context released by a later one's quorum before its own
+        echo was drained) is left out, and so is ``leader_ms`` of a
+        dropped context."""
+        fields = {
+            "cluster_id": rc.cluster_id, "low": rc.low, "high": rc.high,
+            "origin": rc.origin, "path": rc.path or "unstaged",
+            "tid": rc.tid, "trace_origin": rc.trace_origin,
+            "echoes": len(rc.peers),
+        }
+        for name, value in (
+            ("leader_ms", _leg_ms(rc.a, rc.r)),
+            ("echo_trip_ms", _leg_ms(rc.a, rc.eq)),
+            ("echo_wait_ms", _leg_ms(rc.eq, rc.d)),
+            ("confirm_ms", _leg_ms(rc.d, rc.c)),
+            ("release_ms", _leg_ms(rc.c, rc.r)),
+            ("stage_wait_ms", _leg_ms(rc.a, rc.s)),
+            ("first_echo_ms", _leg_ms(rc.a, rc.e1)),
+            ("rounds", rc.rounds),
+            ("stage_round", rc.stage_round),
+            ("confirm_round", rc.confirm_round),
+        ):
+            if value is not None:
+                fields[name] = value
+        t1 = rc.r if rc.r is not None else time.perf_counter()
+        return self.recorder.record(
+            "read_ctx", t0=rc.a, t1=t1, host=self.host, **fields
+        )
+
     def round(
         self,
         span: dict,
@@ -1439,7 +1519,6 @@ class CoordObs:
         ops: int,
         deficit: int,
         commits: int,
-        reads_confirmed: int,
         staged_depth: int,
         k_rounds: int = 1,
         fused: bool = False,
@@ -1477,8 +1556,7 @@ class CoordObs:
         registered.  ``fan_in`` is what the drains since the last recorded
         round handed on: ``acks_drained`` (follower acknowledgements),
         ``reads_local`` / ``reads_remote`` (ReadIndex contexts a leader
-        staged for the host's own clients / that a follower forwarded),
-        and ``voters``, the largest voter count of the host's rows."""
+        staged for the host's own clients / that a follower forwarded)."""
         r = self.registry
         t1 = time.perf_counter()
         wall_ms = (t1 - span["t0"]) * 1e3
@@ -1489,8 +1567,6 @@ class CoordObs:
             r.counter_add(_COORD + "tick_deficit_total", deficit)
         if commits:
             r.counter_add(_COORD + "commits_offloaded_total", commits)
-        if reads_confirmed:
-            r.counter_add(_COORD + "reads_confirmed_total", reads_confirmed)
         if fused:
             r.counter_add(_COORD + "fused_dispatch_total")
             r.counter_add(_COORD + "fused_rounds_total", k_rounds)
@@ -1562,7 +1638,6 @@ class CoordObs:
             deficit=deficit,
             k_rounds=k_rounds,
             commits=commits,
-            reads_confirmed=reads_confirmed,
             read_acks=read_acks,
             reads_staged=reads_staged,
             reads_refused=reads_refused,

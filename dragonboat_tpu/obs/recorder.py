@@ -42,10 +42,13 @@ from ..logger import get_logger
 
 plog = get_logger("obs")
 
-#: a span is a small dict and the ring exists only while obs is on: at
-#: ~10 rounds/s on three co-hosted NodeHosts with a span per round and per
-#: dispatch, 16k spans hold several minutes
-DEFAULT_CAPACITY = 16384
+#: a span is a small dict and the ring exists only while obs is on.  It has
+#: to hold a traced benchmark window whole, or every reader of it describes
+#: the window's tail: three co-hosted NodeHosts with a 7 ms round write a
+#: ``coord_round`` and a ``dispatch`` span a round each, ~900 spans a
+#: second, 43,710-59,165 in a 48 s window and its drain (ISSUE 39; 16,384
+#: wrapped inside every such window).  ~1.5 KB a span: ~200 MB when full
+DEFAULT_CAPACITY = 131072
 
 #: the profiler-annotation vocabulary: constant names, one per phase of a
 #: coordinator round / engine dispatch / execution-engine batch.  Phases
@@ -155,7 +158,14 @@ class FlightRecorder:
                             ``"warmup"`` (one AOT-warmed program),
                             ``"snapshot_save"`` (one save, stream or
                             requested snapshot on a snapshot-pool
-                            worker: ``instruments.ReplicaObs``)
+                            worker: ``instruments.ReplicaObs``),
+                            ``"read_ctx"`` (one sampled ReadIndex
+                            context from a leader's accept to its
+                            release: ``instruments.CoordObs.read_ctx``;
+                            ``t0``/``t1`` are those two instants, the
+                            legs ``echo_trip_ms`` / ``echo_wait_ms`` /
+                            ``confirm_ms`` / ``release_ms`` add up to
+                            ``leader_ms``)
     ``t0`` ``t1``           the span's interval on ``time.perf_counter()``
                             (the tracer's clock): opened at the round's
                             / step's start, ``t1`` moved by every
@@ -310,13 +320,12 @@ class FlightRecorder:
 
     def spans(self) -> List[dict]:
         """Recorded spans, oldest → newest."""
-        with self._mu:
+        with self._mu:  # two slices: every writer waits out this hold
             n = self._n
             if n <= self.capacity:
-                return [s for s in self._buf[:n]]
-            return [
-                self._buf[i % self.capacity] for i in range(n - self.capacity, n)
-            ]
+                return self._buf[:n]
+            i = n % self.capacity
+            return self._buf[i:] + self._buf[:i]
 
     def to_json(self, limit: Optional[int] = None) -> dict:
         """JSON-serializable snapshot (``limit`` keeps only the newest N

@@ -50,7 +50,12 @@ stage           stamped when
                 span are linked into ``Trace.spans``; the round span's
                 ``t0``/``t1`` are on this module's clock and hold the
                 stamp)
-``read_confirm``the ReadIndex ctx was quorum-confirmed (reads only)
+``read_confirm``the ReadIndex ctx was quorum-confirmed (reads only): the
+                requester filed its ``ready_to_read``.  Between this
+                stamp and ``raft_step`` lies the leader's half of the
+                read, which the leader's coordinator writes as one
+                ``read_ctx`` span (``instruments.CoordObs.read_ctx``)
+                named by this trace's ``(tracer.host, tid)``
 ``lease_read``  the read was served locally under a valid leader lease
                 (ISSUE 10) — replaces ``read_confirm``; no confirmation
                 round ran, so the trace shows the short path
@@ -104,6 +109,12 @@ _OUTCOME_KIND = {"write": "propose", "read": "read"}
 OUTCOME_EVENTS_KEEP = 65536
 #: whole seconds of ``outcomes()["by_second"]`` kept (oldest dropped)
 OUTCOME_SECONDS_KEEP = 900
+#: finished traces ``traces()`` returns (the newest: dumps, stage stats)
+DEFAULT_KEEP = 256
+#: finished traces a tracer remembers in all (``finished()``): a 48 s
+#: window of the busiest cell samples ~6,000 requests a host (3,000 ops/s
+#: over three hosts, 1 in 8); a finished trace is ~1.6 KB: ~13 MB when full
+DEFAULT_HISTORY = 8192
 
 
 def _default_stall_ms() -> float:
@@ -124,7 +135,7 @@ class Trace:
     __slots__ = (
         "tracer", "tid", "kind", "cluster_id", "key", "t0",
         "events", "spans", "outcome", "stalled", "done",
-        "applied", "_round_ev", "repl",
+        "applied", "_round_ev", "repl", "read_ctx", "read_origin",
     )
 
     def __init__(self, tracer: "Tracer", tid: int, kind: str,
@@ -146,6 +157,13 @@ class Trace:
         # ISSUE 14): set by the leader's ReplAttr when the commit
         # covering this proposal closes — None until then / off-plane
         self.repl: Optional[dict] = None
+        # reads only (ISSUE 39): the ReadIndex context ``(low, high)``
+        # whose batch covers this request, and whether the replica that
+        # formed it led the group (``local``) or forwarded the context
+        # to the leader (``forwarded``); the key a leader's ``read_ctx``
+        # span is joined by
+        self.read_ctx: Optional[tuple] = None
+        self.read_origin: Optional[str] = None
 
     def add(self, stage: str) -> None:
         self.events.append([stage, time.perf_counter(), _tname()])
@@ -193,6 +211,8 @@ class Trace:
             "done": self.done,
             "spans": list(self.spans),
             "repl": self.repl,
+            "read_ctx": self.read_ctx,
+            "read_origin": self.read_origin,
             "events": [
                 {
                     "stage": s,
@@ -225,7 +245,7 @@ class Tracer:
         registry: Optional[MetricsRegistry] = None,
         recorder=None,
         stall_ms: Optional[float] = None,
-        keep: int = 256,
+        keep: int = DEFAULT_KEEP,
     ):
         if sample_every < 1:
             raise ValueError("sample_every must be >= 1")
@@ -236,12 +256,30 @@ class Tracer:
             _default_stall_ms() if stall_ms is None else float(stall_ms)
         )
         self.dump_path = os.environ.get("DBTPU_TRACE_DUMP")
+        # Who takes which lock (ISSUE 39).  ``_mu`` guards the in-flight
+        # index and the folded accumulators: a SAMPLED request's attach
+        # and finish, the round thread's ``mark_clusters``, the tick
+        # worker's flush.  The submitting thread's sampling counter has
+        # ``_n_mu`` to itself, and the per-message hooks (``observe_done``,
+        # ``add_repl_leg``, the accounting half of ``finish``) take no
+        # lock at all: they append to a deque, which ``_fold_locked``
+        # drains.  With one unfair lock for all of it, ten committers and
+        # apply workers a host took it per message and the one submitting
+        # thread could not win it: every attempt at that host's groups
+        # timed out while its peers ran.
         self._mu = threading.Lock()
+        self._n_mu = threading.Lock()
         self._n = 0          # requests seen (sampling counter)
         self._tid = 0        # trace ids
         self._by_key: Dict[int, Trace] = {}
         self._by_cluster: Dict[int, set] = {}
         self._done: deque = deque(maxlen=max(1, keep))
+        # the same traces, a window's worth of them: a reader that cuts
+        # by a window's instants takes ``finished()``; ``traces()`` stays
+        # the newest ``keep`` (its readers take all they are given, and
+        # the thousands of writes a benchmark's prefill makes would be
+        # most of a longer list)
+        self._history: deque = deque(maxlen=max(keep, DEFAULT_HISTORY))
         self.sampled = 0
         self.completed = 0
         self.discarded = 0  # contexts whose submission was rejected
@@ -257,13 +295,19 @@ class Tracer:
         # completed leg so dump_trace renders the follower half of the
         # flow and tools/trace_merge.py can join it to the leader's
         self._repl_legs: deque = deque(maxlen=max(16, keep))
+        # completions not yet accounted: ``(now, t0, kind, CODE, events)``
+        # appended by whichever thread notified the future (``events`` the
+        # time-sorted stamps of a sampled trace, None otherwise), folded
+        # into the accumulators below under ``_mu``
+        self._fin_q: deque = deque()
         # ---- local metric accumulators (hot-path cost control) -------
         # The propose/notify paths run at full request rate; a registry
         # histogram observe per completion (lock + label-key build)
-        # measured ~20% off on the 1-vCPU e2e loop.  Observations land
-        # in these plain lists under the tracer's own lock and flush to
-        # the registry in ONE merge per tick (check_stalls) or when the
-        # last in-flight trace completes — exposition lag <= one RTT.
+        # measured ~20% off on the 1-vCPU e2e loop.  Observations are
+        # queued lock-free (``_fin_q``), folded into these plain lists and
+        # flushed to the registry in ONE merge per tick (check_stalls) or
+        # when the last in-flight trace completes — exposition lag <= one
+        # RTT.
         self._bk = STAGE_BUCKETS_S
         nb = len(self._bk) + 1
         self._e2e_acc = [[0] * nb, 0.0, 0]        # counts, sum, n
@@ -331,8 +375,9 @@ class Tracer:
         1-in-N gets a :class:`Trace` (registered by key + cluster), the
         rest share one ``(tracer, t0)`` token (the always-on enqueue
         timestamp feeding the e2e histogram at notify).  The common
-        no-sample-in-this-burst case touches one lock and one attribute
-        store per future — nothing else."""
+        no-sample-in-this-burst case touches the counter's own lock,
+        which only submitting threads take, and one attribute store per
+        future — nothing else."""
         n = self.sample_every
         nstates = len(states)
         tok = (self, t0, kind)  # ONE shared token per burst: non-sampled
@@ -340,30 +385,36 @@ class Tracer:
         # and its result code into the tracer that owns them (a
         # module-global sink misattributed multi-NodeHost processes), at
         # zero per-request allocation
-        with self._mu:
+        with self._n_mu:
             base = self._n
             self._n = base + nstates
             self._pend_requests += nstates
             first = (-base) % n  # index of the first sampled slot
-            if first >= nstates:
-                for rs in states:
-                    rs.trace = tok
-                return
-            sampled = []
-            for i, rs in enumerate(states):
-                if (i - first) % n == 0:
-                    self._tid += 1
-                    tr = Trace(self, self._tid, kind, cluster_id,
-                               rs.key, t0)
-                    rs.trace = tr
-                    if rs.key:
-                        self._by_key[rs.key] = tr
-                    self._by_cluster.setdefault(cluster_id, set()).add(tr)
-                    self.sampled += 1
-                    self._pend_sampled += 1
-                    sampled.append(rs)
-                else:
-                    rs.trace = tok
+            if first < nstates:
+                tid = self._tid
+                self._tid = tid + (nstates - first + n - 1) // n
+        if first >= nstates:
+            for rs in states:
+                rs.trace = tok
+            return
+        sampled = []
+        for i, rs in enumerate(states):
+            if (i - first) % n == 0:
+                tid += 1
+                rs.trace = Trace(self, tid, kind, cluster_id, rs.key, t0)
+                sampled.append(rs)
+            else:
+                rs.trace = tok
+        with self._mu:
+            for rs in sampled:
+                tr = rs.trace
+                if tr.done:
+                    continue  # notified since the store above: finished
+                if rs.key:
+                    self._by_key[rs.key] = tr
+                self._by_cluster.setdefault(cluster_id, set()).add(tr)
+            self.sampled += len(sampled)
+            self._pend_sampled += len(sampled)
         # a future that completed before its context landed (the pipeline
         # can beat the attach on a hot box) must not leak in flight
         for rs in sampled:
@@ -474,20 +525,19 @@ class Tracer:
         ``ack_send`` slices in this host's Perfetto dump, carrying the
         LEADER's trace id + origin so ``tools/trace_merge.py`` can bind
         it into the leader's flow."""
-        with self._mu:
-            self._repl_legs.append({
-                "tid": ctx.tid,
-                "origin": ctx.origin,
-                "index": ctx.index,
-                "t_recv": ctx.t_recv,
-                "t_append": ctx.t_append,
-                "t_fsync": ctx.t_fsync,
-                "t_ack": ctx.t_ack,
-            })
+        # a bounded deque's append is atomic: the committers take no lock
+        self._repl_legs.append({
+            "tid": ctx.tid,
+            "origin": ctx.origin,
+            "index": ctx.index,
+            "t_recv": ctx.t_recv,
+            "t_append": ctx.t_append,
+            "t_fsync": ctx.t_fsync,
+            "t_ack": ctx.t_ack,
+        })
 
     def repl_legs(self) -> List[dict]:
-        with self._mu:
-            return list(self._repl_legs)
+        return list(self._repl_legs)
 
     # ------------------------------------------------------------------
     # completion
@@ -518,11 +568,31 @@ class Tracer:
 
     def observe_done(self, t0: float, kind: str, code: str) -> None:
         """A non-sampled request completed: its e2e latency and its
-        ``(kind, code)`` count, under one lock."""
-        now = time.perf_counter()
-        with self._mu:
-            self._acc(self._e2e_acc, now - t0)
+        ``(kind, code)`` count, queued for the next fold.  No lock: this
+        runs on every apply worker, once a request."""
+        self._fin_q.append((time.perf_counter(), t0, kind, code, None))
+
+    def _fold_locked(self) -> None:
+        """Account the completions queued since the last fold (caller
+        holds ``_mu``): outcome counts, e2e and, for a sampled trace, its
+        stage observations."""
+        q = self._fin_q
+        for _ in range(len(q)):
+            now, t0, kind, code, evs = q.popleft()
             self._count_outcome(kind, code, now)
+            self._acc(self._e2e_acc, max(0.0, now - t0))
+            if evs is None:
+                continue
+            prev = evs[0][1]
+            for stage, t, _th in evs[1:]:
+                acc = self._stage_acc.get(stage)
+                if acc is None:
+                    acc = self._stage_acc[stage] = [
+                        [0] * (len(self._bk) + 1), 0.0, 0,
+                    ]
+                self._acc(acc, max(0.0, t - prev))
+                prev = t
+            self._pend_completed += 1
 
     def outcomes(self) -> dict:
         """Attempt outcomes by result code since construction:
@@ -536,6 +606,7 @@ class Tracer:
         Counts every request notified while tracing is on, sampled or
         not."""
         with self._mu:
+            self._fold_locked()
             return {
                 "counts": dict(self._outcomes),
                 "events": list(self._outcome_events),
@@ -546,8 +617,9 @@ class Tracer:
 
     def finish(self, trace: Trace, outcome: str) -> None:
         """Trace completes (future notified): final ``egress`` stamp,
-        stage + e2e observations (accumulated locally; flushed to the
-        registry on the tick cadence), move to the completed ring."""
+        move to the completed ring; its stage + e2e observations are
+        queued like any other completion's (folded and flushed to the
+        registry on the tick cadence)."""
         with self._mu:
             # atomic claim: attach_all's already-done cleanup and the
             # notify thread's request_done can race here — exactly one
@@ -555,11 +627,6 @@ class Tracer:
             if trace.done:
                 return
             trace.done = True
-        trace.outcome = outcome
-        trace.add("egress")
-        evs = sorted(trace.events, key=lambda e: e[1])
-        with self._mu:
-            self._count_outcome(trace.kind, outcome.upper(), evs[-1][1])
             if trace.key:
                 self._by_key.pop(trace.key, None)
             s = self._by_cluster.get(trace.cluster_id)
@@ -567,20 +634,16 @@ class Tracer:
                 s.discard(trace)
                 if not s:
                     del self._by_cluster[trace.cluster_id]
-            self._done.append(trace)
-            prev = evs[0][1]
-            for stage, t, _th in evs[1:]:
-                acc = self._stage_acc.get(stage)
-                if acc is None:
-                    acc = self._stage_acc[stage] = [
-                        [0] * (len(self._bk) + 1), 0.0, 0,
-                    ]
-                self._acc(acc, max(0.0, t - prev))
-                prev = t
-            self._acc(self._e2e_acc, max(0.0, evs[-1][1] - trace.t0))
-            self._pend_completed += 1
+            self.completed += 1
             idle = not self._by_cluster
-        self.completed += 1
+        trace.outcome = outcome
+        trace.add("egress")
+        evs = sorted(trace.events, key=lambda e: e[1])
+        self._done.append(trace)
+        self._history.append(trace)
+        self._fin_q.append(
+            (evs[-1][1], trace.t0, trace.kind, outcome.upper(), evs)
+        )
         if idle:
             # the last in-flight trace just completed: flush now so a
             # quiet scrape (or a test right after the load) sees it —
@@ -591,12 +654,14 @@ class Tracer:
         """Publish the locally accumulated observations to the registry
         in one pass (called by the NodeHost tick worker via
         :meth:`check_stalls`, on going idle, and at :meth:`close`)."""
+        with self._n_mu:
+            reqs, self._pend_requests = self._pend_requests, 0
         with self._mu:
+            self._fold_locked()
             e2e, self._e2e_acc = self._e2e_acc, [
                 [0] * (len(self._bk) + 1), 0.0, 0,
             ]
             stages, self._stage_acc = self._stage_acc, {}
-            reqs, self._pend_requests = self._pend_requests, 0
             samp, self._pend_sampled = self._pend_sampled, 0
             comp, self._pend_completed = self._pend_completed, 0
             outs, self._pend_outcomes = self._pend_outcomes, {}
@@ -651,7 +716,7 @@ class Tracer:
         nothing sampled in flight, nothing pending — is a few
         truthiness checks."""
         if (
-            self._pend_requests or self._pend_completed
+            self._pend_requests or self._fin_q or self._pend_completed
             or self._e2e_acc[2] or self._pend_outcomes
         ):
             self.flush_metrics()
@@ -724,6 +789,7 @@ class Tracer:
         way; steady state keeps the bounded default."""
         with self._mu:
             self._done = deque(maxlen=max(1, keep or self._done.maxlen))
+            self._history = deque(maxlen=self._history.maxlen)
 
     def inflight(self) -> List[Trace]:
         with self._mu:
@@ -733,9 +799,15 @@ class Tracer:
 
     def traces(self) -> List[Trace]:
         """Completed (oldest→newest) then in-flight traces."""
-        with self._mu:
+        with self._mu:  # reset_completed swaps the ring under it
             done = list(self._done)
-        return done + [t for t in self.inflight() if t not in done]
+        seen = set(map(id, done))
+        return done + [t for t in self.inflight() if id(t) not in seen]
+
+    def finished(self) -> List[Trace]:
+        """Every finished trace still remembered, oldest first
+        (``DEFAULT_HISTORY``: a traced window's worth)."""
+        return list(self._history)
 
     def to_json(self) -> dict:
         return {
@@ -895,6 +967,20 @@ class Tracer:
                         if k not in ("ts", "t0", "t1")
                     },
                 })
+                if span.get("kind") == "read_ctx" and span.get("tid"):
+                    # the leader's half of a sampled read (ISSUE 39) steps
+                    # the REQUESTER's flow: its trace id, and its host as
+                    # the origin tools/trace_merge.py remaps ids by
+                    events.append({
+                        "name": f"read-{span['tid']}",
+                        "cat": "request",
+                        "ph": "t",
+                        "id": span["tid"],
+                        "pid": 1,
+                        "tid": dev_tid,
+                        "ts": round(self._wall_us(t0), 1),
+                        "args": {"origin": span.get("trace_origin")},
+                    })
         ra = self.replattr
         return {
             "displayTimeUnit": "ms",

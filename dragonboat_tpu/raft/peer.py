@@ -252,9 +252,16 @@ class Peer:
         if known or not is_response_message_type(m.type):
             self.raft.handle(m)
 
-    def read_index(self, ctx: SystemCtx) -> None:
+    def read_index(self, ctx: SystemCtx, trace=None) -> None:
+        """``trace`` is the wire context of a sampled request the ctx
+        covers (``PendingReadIndex.trace_ctx``; None otherwise): it rides
+        the READ_INDEX to the leader's step, this replica's own or the
+        one the message is forwarded to."""
         self.raft.handle(
-            Message(type=MT.READ_INDEX, hint=ctx.low, hint_high=ctx.high)
+            Message(
+                type=MT.READ_INDEX, hint=ctx.low, hint_high=ctx.high,
+                trace=trace,
+            )
         )
 
     def notify_raft_last_applied(self, last_applied: int) -> None:

@@ -1461,6 +1461,7 @@ class Raft:
                     self.cluster_id, self.log.committed, ctx.low, ctx.high,
                     self.term,
                     remote=m.from_ not in (NO_NODE, self.node_id),
+                    trace=m.trace,
                 )
             self.broadcast_heartbeat_message_with_hint(ctx)
         else:
@@ -1572,11 +1573,12 @@ class Raft:
         if rp.match == self.log.last_index():
             self.send_timeout_now_message(target)
 
-    def handle_read_index_leader_confirmation(self, m: Message) -> None:
-        # reference raft.go:1740-1760
+    def handle_read_index_leader_confirmation(self, m: Message):
+        # reference raft.go:1740-1760; returns the released statuses
         ctx = SystemCtx(low=m.hint, high=m.hint_high)
         ris = self.read_index.confirm(ctx, m.from_, self.quorum())
         self.apply_read_releases(ris)
+        return ris
 
     def apply_read_releases(self, ris) -> None:
         """Route released ReadStatuses: local requesters land in

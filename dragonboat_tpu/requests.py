@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Tuple
 from .obs import trace as _trace
 from .settings import Soft
 from .statemachine import Result
-from .wire import Entry, ReadyToRead, SystemCtx
+from .wire import Entry, ReadyToRead, ReplTrace, SystemCtx
 
 
 class RequestError(Exception):
@@ -441,6 +441,24 @@ class PendingReadIndex:
                 if rs.trace is not None:
                     self._tracer.mark(rs, "raft_step")
         return True
+
+    def trace_ctx(self, ctx: SystemCtx, origin: str):
+        """Tracer on: note on every sampled request of the batch ``ctx``
+        covers the context and where it is confirmed from (``local``: this
+        replica leads; ``forwarded``), and return the wire context that
+        names the first of them (``Message.trace`` of the READ_INDEX), or
+        None where the batch holds no sampled request."""
+        with self._mu:
+            batch = self._batches.get(ctx, ())
+        wire = None
+        for rs in batch:
+            t = rs.trace
+            if t.__class__ is _trace.Trace:
+                t.read_ctx = (ctx.low, ctx.high)
+                t.read_origin = origin
+                if wire is None:
+                    wire = ReplTrace(tid=t.tid, origin=t.tracer.host)
+        return wire
 
     def pending_ctxs(self) -> List[SystemCtx]:
         """Contexts taken for confirmation but not yet ready — after a

@@ -35,7 +35,7 @@ from typing import Dict, Optional, TYPE_CHECKING
 
 from . import obs as _obs
 from .logger import get_logger
-from .obs.instruments import HB_SINGLE_CAUSES
+from .obs.instruments import HB_SINGLE_CAUSES, ReadCtx
 from .obs.recorder import OFF as _OFF, annotate as _annotate
 from .wire import Message, MessageType, pack_hb_rows, unpack_hb_rows
 
@@ -47,6 +47,10 @@ plog = get_logger("tpuquorum")
 #: released/refused ReadIndex contexts remembered per group, so that a late
 #: heartbeat echo can be put down to its cause (bounded: oldest forgotten)
 _READ_GONE_KEEP = 512
+#: sampled ReadIndex contexts followed at once (``_read_traces``); one more
+#: closes the oldest as ``dropped``, so a context that no release and no
+#: transition ever names cannot stay
+_READ_TRACES_KEEP = 4096
 
 
 class TpuQuorumCoordinator:
@@ -201,6 +205,22 @@ class TpuQuorumCoordinator:
         # cid -> {(low, high): cause} of ctxs no longer (or never)
         # device-tracked, newest last; guarded by _mu like _read_pending
         self._read_gone: Dict[int, dict] = {}
+        # the sampled ReadIndex contexts this host's leaders hold (ISSUE
+        # 39): (cid, low, high) -> ReadCtx, from the step worker that
+        # accepted one carrying a requester's trace to the step worker
+        # that answered it, which writes its ``read_ctx`` span.  EMPTY
+        # while nothing is sampled (tracer off: always), and every site on
+        # a read's way tests its truthiness and nothing else.  Single
+        # dict operations from both sides, no lock.
+        self._read_traces: Dict[tuple, ReadCtx] = {}
+        # of those, the ones the drain in progress gave a slot (they get
+        # the round's span seq once it opens) and the ones the step
+        # confirmed (stamped at the fan-out); round thread only
+        self._rt_staged: list = []
+        self._rt_confirmed: list = []
+        # rounds that dispatched while _obs is attached (a ReadCtx counts
+        # the rounds from its acceptance to its confirmation)
+        self._rounds_recorded = 0
         # device state machine plane (devsm, ISSUE 11; DevKVPlane):
         # created by the FIRST DeviceKVStateMachine registration
         # (NodeHost.start_cluster with Config.device_kv).  None keeps the
@@ -484,6 +504,8 @@ class TpuQuorumCoordinator:
             self._nodes.pop(cluster_id, None)
             self._read_pending.pop(cluster_id, None)
             self._read_gone.pop(cluster_id, None)
+            if self._read_traces:
+                self._read_traces_drop(cluster_id)
             if self.lease_table is not None:
                 self.lease_table.remove(cluster_id)
             if cluster_id in self.eng.groups:
@@ -840,12 +862,19 @@ class TpuQuorumCoordinator:
 
     def read_stage(
         self, cluster_id: int, committed: int, low: int, high: int, term: int,
-        remote: bool = False,
+        remote: bool = False, trace=None,
     ) -> None:
         """A leader accepted a ReadIndex ctx (``handle_leader_read_index``
         under raftMu): stage it into the group's pending-read slot,
         captured at scalar raft's own committed watermark.  ``remote``
-        says a follower forwarded it (counted by origin, obs on)."""
+        says a follower forwarded it (counted by origin, obs on);
+        ``trace`` is the requester's wire context where the batch holds a
+        sampled request (``Message.trace``, None otherwise): the ctx is
+        then followed to its release (instant ``a``)."""
+        if trace is not None and self._obs is not None:
+            self._read_trace_open(
+                cluster_id, low, high, term, remote, trace
+            )
         self._stage(
             ("rstage", cluster_id, committed, low, high, term, remote)
         )
@@ -855,7 +884,83 @@ class TpuQuorumCoordinator:
     ) -> None:
         """A heartbeat response echoed a ReadIndex hint: joins the ctx's
         pending-read slot; the device row-sum decides the quorum."""
+        rt = self._read_traces
+        if rt:
+            # instants ``e1`` / ``eq``: the first echo, and the echo of
+            # the follower that completes the quorum, as staged
+            rc = rt.get((cluster_id, low, high))
+            if rc is not None and rc.c is None and node_id not in rc.peers:
+                now = time.perf_counter()
+                rc.peers.add(node_id)
+                if rc.e1 is None:
+                    rc.e1 = now
+                if rc.eq is None and len(rc.peers) >= rc.need:
+                    rc.eq = now
+                    rc.eq_peer = node_id
         self._stage(("rack", cluster_id, node_id, low, high))
+
+    # ------------------------------------------------------------------
+    # a sampled ReadIndex context, followed (ISSUE 39; obs on, and only
+    # for a ctx whose READ_INDEX carried a requester's trace)
+    # ------------------------------------------------------------------
+
+    def _read_trace_open(self, cluster_id: int, low: int, high: int,
+                         term: int, remote: bool, trace) -> None:
+        """Instant ``a`` (step worker, under the group's raftMu)."""
+        rt = self._read_traces
+        if len(rt) >= _READ_TRACES_KEEP:
+            old = rt.pop(next(iter(rt)), None)
+            if old is not None:
+                self._read_trace_close(old, "dropped")
+        node = self._nodes.get(cluster_id)
+        need = 1
+        if node is not None and node.peer is not None:
+            need = max(1, node.peer.raft.quorum() - 1)
+        rt[(cluster_id, low, high)] = ReadCtx(
+            cluster_id, low, high, term, remote, trace, need,
+            self._rounds_recorded,
+        )
+
+    def _read_trace_close(self, rc: ReadCtx, path: Optional[str] = None
+                          ) -> None:
+        if path is not None:
+            rc.path = path
+        obs = self._obs
+        if obs is not None:
+            obs.read_ctx(rc)
+
+    def _read_traces_drop(self, cluster_id: int,
+                          below_term: Optional[int] = None) -> None:
+        """A transition took the group's pending reads: close the sampled
+        ctxs it held (those accepted in a term under ``below_term``, all
+        of them with None) with ``path = dropped``."""
+        rt = self._read_traces
+        for key, rc in list(rt.items()):
+            if key[0] == cluster_id and (
+                below_term is None or rc.term < below_term
+            ):
+                if rt.pop(key, None) is not None:
+                    self._read_trace_close(rc, "dropped")
+
+    def read_released(self, cluster_id: int, ris, scalar: bool = False
+                      ) -> None:
+        """Instant ``r``: ``apply_read_releases`` answered the requesters
+        of ``ris`` (a READ_INDEX_RESP sent, or ``ready_to_read`` filed);
+        the sampled ones' spans are written (called only while
+        ``_read_traces`` holds something).  ``scalar``: the step worker's
+        own ``read_index.confirm`` released them in this same turn, which
+        is then their instant ``c`` too (the wait for that turn is the
+        scalar path's ``confirm_ms``)."""
+        rt = self._read_traces
+        now = time.perf_counter()
+        for s in ris:
+            rc = rt.pop((cluster_id, s.ctx.low, s.ctx.high), None)
+            if rc is not None:
+                if scalar and rc.c is None:
+                    rc.c = now
+                    rc.rounds = self._rounds_recorded - rc.round0
+                rc.r = now
+                self._read_trace_close(rc)
 
     def stage_sm_ops(self, cluster_id: int, ops) -> None:
         """A devsm leader appended application entries
@@ -908,6 +1013,7 @@ class TpuQuorumCoordinator:
         lt = self.lease_table
         lease_acks: Dict[int, set] = {}
         obs_on = self._obs is not None
+        rt = self._read_traces
         # bulk-pull every row a transition below will mutate: one device
         # gather per field for the whole set, instead of ~20 single-row
         # reads inside each set_* call (the dominant cost of election
@@ -963,11 +1069,21 @@ class TpuQuorumCoordinator:
                         self._read_gone_note(
                             cid, (op[3], op[4]), "slot_overflow"
                         )
+                        slot = None
                     else:
                         self.reads_staged += 1
                         self._read_pending.setdefault(cid, []).append(
                             (slot, op[3], op[4], op[5])
                         )
+                    if rt:  # instant ``s``: given a slot, or refused
+                        rc = rt.get((cid, op[3], op[4]))
+                        if rc is not None and rc.s is None:
+                            rc.s = time.perf_counter()
+                            if slot is None:
+                                rc.path = "scalar:slot_overflow"
+                            else:
+                                rc.path = "device"
+                                self._rt_staged.append(rc)
                 elif kind == "rack":
                     node_id, low, high = op[2], op[3], op[4]
                     slot = None
@@ -992,11 +1108,20 @@ class TpuQuorumCoordinator:
                         node = self._nodes.get(cid)
                         if node is not None:
                             node.offload_read_echo(node_id, low, high)
+                    if rt:  # instant ``d``: the quorum's echo, drained
+                        rc = rt.get((cid, low, high))
+                        if (
+                            rc is not None and rc.d is None
+                            and rc.c is None and rc.eq_peer == node_id
+                        ):
+                            rc.d = time.perf_counter()
                 elif kind == "kvops":
                     if self.devsm is not None:
                         self.devsm.handle_ops(cid, op[2])
                 elif kind == "leader":
                     self._read_pending.pop(cid, None)
+                    if rt:  # the ctxs of the new term are staged behind
+                        self._read_traces_drop(cid, below_term=op[2])
                     if lt is not None:
                         lt.drop(cid)
                     self.eng.set_leader(
@@ -1006,6 +1131,8 @@ class TpuQuorumCoordinator:
                         self.devsm.on_leader(cid, op[4])
                 elif kind == "candidate":
                     self._read_pending.pop(cid, None)
+                    if rt:
+                        self._read_traces_drop(cid, below_term=op[2] + 1)
                     if lt is not None:
                         lt.drop(cid)
                     self.eng.set_candidate(cid, term=op[2])
@@ -1013,6 +1140,8 @@ class TpuQuorumCoordinator:
                         self.devsm.on_unbind(cid)
                 elif kind == "follower":
                     self._read_pending.pop(cid, None)
+                    if rt:
+                        self._read_traces_drop(cid, below_term=op[2] + 1)
                     if lt is not None:
                         lt.drop(cid)
                     self.eng.set_follower(cid, term=op[2])
@@ -1020,6 +1149,12 @@ class TpuQuorumCoordinator:
                         self.devsm.on_unbind(cid)
                 else:  # resync
                     self._read_pending.pop(cid, None)
+                    if rt:
+                        # the scalar ReadIndex keeps these ctxs: their
+                        # echoes are tallied by the step worker now
+                        for key, rc in list(rt.items()):
+                            if key[0] == cid and rc.path == "device":
+                                rc.path = "scalar:purged"
                     if lt is not None:
                         lt.drop(cid)
                     if self.devsm is not None:
@@ -1192,6 +1327,11 @@ class TpuQuorumCoordinator:
                     ),
                 )
                 self.eng.set_span_parent(span["seq"])
+                self._rounds_recorded += 1
+                if self._rt_staged:
+                    for rc in self._rt_staged:
+                        rc.stage_round = span["seq"]
+                    self._rt_staged = []
             # Adaptive K-round batching (ISSUE 7 tentpole).  The fused
             # K-round program (step_rounds, the ladder's workhorse) was
             # once measured here and reverted because each first-use XLA
@@ -1356,7 +1496,6 @@ class TpuQuorumCoordinator:
                 ops=n_ops,
                 deficit=deficit,
                 commits=len(res.commit),
-                reads_confirmed=len(read_confirms),
                 staged_depth=len(self._staged),
                 k_rounds=k_rounds,
                 fused=fused,
@@ -1436,6 +1575,16 @@ class TpuQuorumCoordinator:
         # wake_kw stays EMPTY without the host plane so duck-typed test
         # nodes that predate the wake kwarg keep working unchanged
         wake_kw: dict = {} if hp is None else {"wake": False}
+        if self._rt_confirmed:
+            # instant ``c``: the step confirmed these sampled ctxs (or a
+            # later ctx of their group, whose release takes them along)
+            now = time.perf_counter()
+            for rc in self._rt_confirmed:
+                if rc.c is None:
+                    rc.c = now
+                    rc.confirm_round = round_seq
+                    rc.rounds = self._rounds_recorded - rc.round0
+            self._rt_confirmed = []
         for cid, low, high, term in read_confirms:
             node = self._nodes.get(cid)
             if node is not None:
@@ -1548,19 +1697,10 @@ class TpuQuorumCoordinator:
         self._drained_spanned = (
             self.acks_drained, self.reads_local, self.reads_remote
         )
-        # a descriptor: the widest majority the kernel computes here, read
-        # off the masks it computes with (a mesh engine has one a shard)
-        voters = 0
-        for shard in getattr(self.eng, "shards", (self.eng,)):
-            a = shard.mirror.arrays
-            voters = max(
-                voters, int((a["voting"].sum(axis=1) * a["live"]).max())
-            )
         return {
             "acks_drained": self.acks_drained - acks0,
             "reads_local": self.reads_local - local0,
             "reads_remote": self.reads_remote - remote0,
-            "voters": voters,
         }
 
     def _collect_read_confirms(self, res, out: list) -> None:
@@ -1584,6 +1724,11 @@ class TpuQuorumCoordinator:
             if pos is None:
                 continue
             _slot, low, high, term = fifo[pos]
+            if self._read_traces:
+                for e in fifo[: pos + 1]:
+                    rc = self._read_traces.get((cid, e[1], e[2]))
+                    if rc is not None:
+                        self._rt_confirmed.append(rc)
             for e in fifo[:pos]:  # prefix-released scalar-side
                 self._read_gone_note(cid, (e[1], e[2]), "after_confirm")
                 try:

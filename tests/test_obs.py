@@ -913,9 +913,9 @@ def test_round_span_counts_the_fan_in_by_origin(obs_on):
     """ISSUE 34: with the instruments attached a round's span carries the
     follower acknowledgements its drains handed on, the ReadIndex contexts
     a leader staged by origin (the host's own clients' against those a
-    follower forwarded; a context refused a device slot counts too) and
-    the largest voter count of the host's rows, and the contexts feed
-    ``dragonboat_coord_reads_total{origin}``.  Detached, nothing of it is
+    follower forwarded; a context refused a device slot counts too), and
+    the contexts feed ``dragonboat_coord_reads_total{origin}``.  (The span
+    no longer carries ``voters``, ISSUE 39: nothing read it.)  Detached, nothing of it is
     counted."""
     from dragonboat_tpu.ops.state import READ_SLOTS
 
@@ -952,7 +952,7 @@ def test_round_span_counts_the_fan_in_by_origin(obs_on):
         assert sum(s["acks_drained"] for s in rounds) == 3
         assert sum(s["reads_local"] for s in rounds) == 1
         assert sum(s["reads_remote"] for s in rounds) == n_remote
-        assert {s["voters"] for s in rounds} == {3}
+        assert not any("voters" in s for s in rounds)
         assert sum(s["commits"] for s in rounds) == 2  # both groups
         for origin, n in (("local", 1), ("remote", n_remote)):
             assert reg.counter_value(

@@ -222,6 +222,12 @@ def test_replica_instruments_are_built_only_when_switched_on(on, monkeypatch):
     monkeypatch.setattr(statemachine, "_Held", held)
     live0 = len(instruments.replica_obs_live())
     spans0 = obs.default_recorder().to_json(limit=1)["count"]
+    from dragonboat_tpu.events import DEFAULT_REGISTRY
+
+    # the process's registry: a test file that ran before on this worker
+    # may have counted saves of its own
+    saved0 = DEFAULT_REGISTRY.counter_value(
+        "dragonboat_snapshot_saves_total", {"kind": "periodic"})
 
     router = ChanRouter()
     nh = NodeHost(NodeHostConfig(
@@ -273,7 +279,7 @@ def test_replica_instruments_are_built_only_when_switched_on(on, monkeypatch):
         assert nh.replica_obs in instruments.replica_obs_live()
         assert nh.metrics_registry.counter_value(
             "dragonboat_snapshot_saves_total", {"kind": "periodic"}
-        ) == len(saves)
+        ) - saved0 == len(saves)
     finally:
         nh.stop()
     assert len(instruments.replica_obs_live()) == live0  # closed with it
