@@ -170,7 +170,12 @@ class Node:
         # writer-vs-swap-and-clear race: without it a flag written between
         # the consumer's load and its clearing store is silently lost, and
         # the engine's edge-triggered commit reporting never resends it.
+        # _off_pending is the one fact step_node's gate reads: every
+        # offload_* sets it under _off_mu beside its own field, and only
+        # the swap in _apply_offload_effects clears it, so an effect
+        # flagged while a turn applies the last ones opens the next turn.
         self._off_mu = threading.Lock()
+        self._off_pending = False
         self._off_commit = 0
         self._off_election = None
         self._off_hb = False
@@ -329,6 +334,7 @@ class Node:
         with self._off_mu:
             if q > self._off_commit:
                 self._off_commit = q
+                self._off_pending = True
             if self._health_track and q > self._dev_commit_seen:
                 self._dev_commit_seen = q
         if wake:
@@ -340,6 +346,7 @@ class Node:
         campaign restarted at a higher term is discarded at apply time."""
         with self._off_mu:
             self._off_election = (won, term)
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
@@ -354,6 +361,7 @@ class Node:
         rejected, never applied."""
         with self._off_mu:
             self._off_reads.append((low, high, term))
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
@@ -366,24 +374,28 @@ class Node:
         no-op for unknown ctxs."""
         with self._off_mu:
             self._off_read_echoes.append((from_, low, high))
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
     def offload_tick_elect(self, wake: bool = True) -> None:
         with self._off_mu:
             self._off_elect = True
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
     def offload_tick_heartbeat(self, wake: bool = True) -> None:
         with self._off_mu:
             self._off_hb = True
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
     def offload_tick_demote(self, wake: bool = True) -> None:
         with self._off_mu:
             self._off_demote = True
+            self._off_pending = True
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
@@ -559,6 +571,7 @@ class Node:
         is rejected, never applied."""
         r = self.peer.raft
         with self._off_mu:
+            self._off_pending = False
             commit_q, self._off_commit = self._off_commit, 0
             election, self._off_election = self._off_election, None
             hb, self._off_hb = self._off_hb, False
@@ -1071,13 +1084,7 @@ class Node:
                 return None
             if not self.initialized():
                 return None
-            if (
-                self._off_commit
-                or self._off_election is not None
-                or self._off_hb
-                or self._off_elect
-                or self._off_demote
-            ):
+            if self._off_pending:
                 self._apply_offload_effects()
             delta = self._catch_up_ticks()
             if self.fast_lane:

@@ -764,3 +764,126 @@ def test_live_coordinator_batches_read_confirmations():
     finally:
         for nh in nhs:
             nh.stop()
+
+
+def test_a_prompt_release_keeps_the_guards_and_answers_once(monkeypatch):
+    """ISSUE 40: a confirmed context is released in the turn the fan-out
+    wakes, no longer at the group's next tick.  Three hosts under a clock
+    that never ticks (so nothing but the flagged confirmation opens the
+    leader's gate), the leader's echoes swallowed so every context stays
+    pending until the test confirms it as the coordinator does:
+
+    - a confirmation releases its context and every one queued before it,
+      each requester answered ONCE with its OWN context (the benchmark's
+      ``correct`` would not see a second READ_INDEX_RESP); a repeated
+      confirmation and one of another term release nothing;
+    - a confirmation flagged at term ``t``, the leadership gone to ``t + 1``
+      before the woken turn runs: the turn opens the gate (the effect is
+      counted as delivered), releases nothing and answers no requester.
+    """
+    from dragonboat_tpu import Config, NodeHostConfig
+    from dragonboat_tpu.config import ExpertConfig
+    from dragonboat_tpu.nodehost import NodeHost
+    from dragonboat_tpu.testing import CounterSM
+    from dragonboat_tpu.transport import ChanRouter, ChanTransport
+    from dragonboat_tpu.wire import MessageType
+    from tests.loadwait import wait_until
+
+    CID = 4031
+    FOREVER_S = 1e9  # request clocks count ticks, and none comes
+
+    router = ChanRouter()
+    addrs = {i: f"rg{i}:1" for i in (1, 2, 3)}
+    nhs = {
+        i: NodeHost(NodeHostConfig(
+            node_host_dir=":memory:",
+            rtt_millisecond=1_000_000,
+            raft_address=addrs[i],
+            enable_metrics=True,
+            raft_rpc_factory=lambda src, rh, ch: ChanTransport(
+                src, rh, ch, router=router),
+            expert=ExpertConfig(quorum_engine="tpu", engine_block_groups=8,
+                                engine_warm_fused=False),
+        ))
+        for i in (1, 2, 3)
+    }
+    try:
+        for i, nh in nhs.items():
+            nh.start_cluster(
+                addrs, False, CounterSM,
+                Config(cluster_id=CID, node_id=i, election_rtt=10,
+                       heartbeat_rtt=1))
+        node = nhs[1].get_node(CID)
+
+        def led_by_1():
+            if all(nh.get_leader_id(CID) == (1, True)
+                   for nh in nhs.values()):
+                return True
+            node.request_campaign()
+            return False
+
+        wait_until(led_by_1, timeout=60.0, interval=0.2, what="host 1 leads")
+        nhs[1].sync_propose(
+            nhs[1].get_noop_session(CID), b"w", timeout=FOREVER_S)
+        r = node.peer.raft
+        term = r.term
+        monkeypatch.setattr(
+            nhs[1].quorum_coordinator, "read_ack_hint", lambda *a, **k: None)
+        answered = []  # every READ_INDEX_RESP that leaves the leader
+        send = r.send
+
+        def noting(m):
+            if m.type == MessageType.READ_INDEX_RESP:
+                answered.append((m.to, m.hint, m.hint_high))
+            send(m)
+
+        monkeypatch.setattr(r, "send", noting)
+
+        def pend(host):
+            """One read at ``host``, accepted by the leader and pending."""
+            have = len(r.read_index.queue)
+            rs = nhs[host].get_node(CID).read(FOREVER_S)
+            wait_until(lambda: len(r.read_index.queue) == have + 1,
+                       timeout=30.0, interval=0.01,
+                       what=f"host {host}'s context at the leader")
+            return rs, r.read_index.queue[-1]
+
+        def delivered():
+            return nhs[1].metrics_registry.counter_value(
+                "dragonboat_node_offload_applied_total",
+                {"kind": "read_confirm"})
+
+        def confirm(ctx, at_term):
+            """What the coordinator's fan-out does, and the woken turn."""
+            before = delivered()
+            node.offload_read_confirm(ctx.low, ctx.high, at_term)
+            wait_until(lambda: delivered() == before + 1, timeout=30.0,
+                       interval=0.01, what="the woken turn")
+            with node.raft_mu:  # the turn has left the group
+                assert not node._off_reads
+
+        (rs1, c1), (rs2, c2), (rs3, c3), (rs4, c4) = (
+            pend(1), pend(2), pend(3), pend(2))
+        assert r.read_index.queue == [c1, c2, c3, c4]
+        confirm(c3, term)
+        assert all(rs.wait(30.0).completed for rs in (rs1, rs2, rs3))
+        assert answered == [(2, c2.low, c2.high), (3, c3.low, c3.high)]
+        assert r.read_index.queue == [c4]
+        confirm(c3, term)        # the echo that raced: nothing left of it
+        confirm(c4, term - 1)    # tallied under another term: refused
+        assert len(answered) == 2 and r.read_index.queue == [c4]
+        # leadership moves between the flag and the turn it wakes
+        before = delivered()
+        with node.raft_mu:
+            node.offload_read_confirm(c4.low, c4.high, term)
+            r.become_follower(term + 1, 0)
+        wait_until(lambda: delivered() == before + 1, timeout=30.0,
+                   interval=0.01, what="the woken turn")
+        with node.raft_mu:
+            assert not node._off_reads and not r.is_leader()
+            assert not r.ready_to_read
+        assert len(answered) == 2
+        assert not rs4.wait(0.2).completed
+    finally:
+        for nh in nhs.values():
+            nh.stop()

@@ -62,13 +62,14 @@ class KV(IStateMachine):
 class Cluster:
     """Three NodeHosts on the tpu engine, one group, host 1 leading."""
 
-    def __init__(self, prefix: str, trace: int):
+    def __init__(self, prefix: str, trace: int, rtt: int = 20,
+                 rotate: bool = True):
         router = ChanRouter()
         self.addrs = {i: f"{prefix}{i}:1" for i in (1, 2, 3)}
         self.nhs = {
             i: NodeHost(NodeHostConfig(
                 node_host_dir=":memory:",
-                rtt_millisecond=20,
+                rtt_millisecond=rtt,
                 raft_address=self.addrs[i],
                 raft_rpc_factory=lambda src, rh, ch: ChanTransport(
                     src, rh, ch, router=router),
@@ -96,7 +97,7 @@ class Cluster:
                     lambda: self.nhs[i].get_node(CID).read(5.0)
                     .wait(5.0).completed,
                     timeout=60.0, interval=0.05, what=f"host {i} reads")
-            for i in (1, 2, 3):
+            for i in (1, 2, 3) if rotate else ():
                 self.lead(i)
                 assert self.read(i) is not None
             self.lead(1)
@@ -207,6 +208,60 @@ def test_a_local_read_leaves_one_span_on_the_device_path(traced):
     for key in ("stage_round", "confirm_round"):
         assert by_seq[span[key]]["kind"] == "coord_round"
         assert by_seq[span[key]]["host"] == c.addrs[1]
+    assert all(not c.coord(i)._read_traces for i in (1, 2, 3))
+
+
+#: a tick long enough that a quarter of it is no step worker's turn on any
+#: host that runs this suite, and that a release that waits for one shows
+TICK_MS = 1000
+
+
+@pytest.fixture(scope="module")
+def slow_tick():
+    c = Cluster("rtslow", trace=1, rtt=TICK_MS, rotate=False)
+    yield c
+    c.stop()
+
+
+def test_a_release_is_the_woken_turn_not_the_next_tick(slow_tick):
+    """ISSUE 40: the fan-out flags a confirmed context and wakes the group,
+    and that turn releases it.  On the parent the turn's gate did not see
+    ``_off_reads`` and the context waited for the group's next heartbeat
+    tick: ``release_ms`` uniform over the tick, a quarter of these twenty
+    under a quarter of it."""
+    c = slow_tick
+    c.lead(1)
+    spans = []
+    for k in range(20):
+        t = c.read((1, 2, 3, 2)[k % 4]).trace
+        spans.append(_span_of(t, c.addrs[1]))
+    assert {s["origin"] for s in spans} == {"local", "remote"}
+    for s in spans:
+        _assert_chain_in_order(s)
+        assert s["path"] == "device"
+        assert s["release_ms"] < TICK_MS / 4, s
+    assert all(not c.coord(i)._read_traces for i in (1, 2, 3))
+
+
+def test_the_scalar_paths_tally_is_the_woken_turn_too(slow_tick, monkeypatch):
+    """A context refused a slot is tallied by the step worker
+    (``_off_read_echoes``): the wait for that turn is its ``confirm_ms``,
+    and on the parent it was the same wait for a tick one leg earlier."""
+    c = slow_tick
+    c.lead(1)
+
+    def full(cid, **kw):
+        raise RuntimeError("every pending-read slot holds a batch")
+
+    monkeypatch.setattr(c.coord(1).eng, "stage_read", full)
+    traces = [c.read(host).trace for host in (3, 1, 2, 3, 1, 2, 3, 1)]
+    monkeypatch.undo()
+    for t in traces:
+        span = _span_of(t, c.addrs[1])
+        _assert_chain_in_order(span)
+        assert span["path"] == "scalar:slot_overflow"
+        assert span["confirm_ms"] < TICK_MS / 4, span
+        assert span["release_ms"] < TICK_MS / 4, span
     assert all(not c.coord(i)._read_traces for i in (1, 2, 3))
 
 
