@@ -374,16 +374,6 @@ class NodeHostConfig:
     # standby_witness_addrs) — merged over the controller defaults;
     # unknown keys raise at construction.
     auto_recover_knobs: Dict[str, object] = field(default_factory=dict)
-    # wall-clock lease guard (lease.py, ISSUE 17 churn-soak caught): the
-    # leader lease's validity clock is the event loop's tick counter — a
-    # CPU-starved or descheduled leader ticks slower than wall time, so
-    # its tick-valid lease can outlive the majority's wall-time election
-    # and serve a stale read.  True additionally bounds validity by
-    # monotonic wall time (quorum-th newest ack within
-    # duration * rtt_millisecond wall seconds) — strictly conservative:
-    # starvation can only expire the lease early, never extend it.
-    # Default off: tick-driven virtual-clock tests stay deterministic.
-    lease_wall_guard: bool = False
     # device capacity & profiling plane (obs/devprof.py, ISSUE 15):
     # N > 0 attaches a DevProf to the batched quorum engine — the HBM
     # memory ledger + capacity model (dragonboat_devprof_hbm_bytes /

@@ -2,14 +2,16 @@
 
 The ReadIndex protocol (thesis §6.4, ``raft/readindex.py``) makes every
 linearizable read pay one heartbeat-echo confirmation round; on the device
-read plane that round additionally rides the write-round gate (the measured
-1.08s mixed-phase read-dispatch p99, BENCH_r07).  A **leader lease** removes
-the round entirely: a leader that heard heartbeat acks from a quorum within
-the last ``election_timeout − drift_epsilon`` ticks knows no other leader
-can exist yet — §6.4.1 of the raft thesis, plus the §6 CheckQuorum vote
-lease that makes the bound hold even against forced campaigns — so it may
-serve reads at its committed watermark locally, with the ReadIndex plane as
-the always-correct fallback.
+read plane that round additionally waits for the coordinator's round (the
+``read_echo_wait_ms.read`` + ``read_confirm_ms.read`` legs of the benchmark's
+``mixed91`` cells; the cell ``upstream48x3lease.lease91`` reads the lease
+path beside ``upstream48x3.mixed91``'s ReadIndex, PERF.md). A **leader
+lease** removes the round entirely: a leader that heard heartbeat acks from
+a quorum within the last ``election_timeout − drift_epsilon`` ticks knows no
+other leader can exist yet — §6.4.1 of the raft thesis, plus the §6
+CheckQuorum vote lease that makes the bound hold even against forced
+campaigns — so it may serve reads at its committed watermark locally, with
+the ReadIndex plane as the always-correct fallback.
 
 Validity rule (tick-based — ticks are the protocol's native clock, shared
 with the election/heartbeat timers the bound is measured against):
@@ -26,7 +28,19 @@ with the election/heartbeat timers the bound is measured against):
   reduction ``try_commit``/``commit_quorum`` run over match indexes;
 - the lease is valid while ``now < basis + election_timeout − epsilon``,
   where ``epsilon`` (default ``election_timeout // 5``, min 1) absorbs
-  tick-delivery jitter and cross-host tick-cadence drift.
+  tick-delivery jitter and cross-host tick-cadence drift;
+- and, under a NodeHost, only while the quorum-th newest ack is no older
+  than ``duration × rtt_millisecond`` of monotonic WALL time (the wall
+  guard: a starved tick loop expires the lease, never stretches it).
+  ``start_cluster`` gives it to every ``read_lease`` group; it is no
+  option (ISSUE 41).
+
+The heartbeat sends and acks are booked wherever a heartbeat leaves or an
+ack lands: ``broadcast_heartbeat_message_with_hint`` /
+``handle_leader_heartbeat_resp`` for the per-group message, and
+``heartbeat_block_sent`` / ``heartbeat_block_resp`` for a row of the
+batched heartbeat plane (``tpuquorum.py``; ``tests/test_hb_block.py``
+holds the two equal).
 
 Invalidation matrix (all enforced in ``raft/raft.py``):
 
@@ -184,7 +198,9 @@ class LeaderLease:
         # within ``duration * tick_interval_s`` WALL seconds — monotonic
         # time keeps running while the process is starved or SIGSTOPped,
         # so starvation can only expire the lease, never extend it.
-        # Default off: purely tick-driven tests stay deterministic.
+        # ``NodeHost.start_cluster`` sets it for every ``read_lease``
+        # group (no option: ISSUE 41); None is a bare lease built by a
+        # purely tick-driven unit test.
         self.tick_interval_s = tick_interval_s
         self.wall_clock = time.monotonic
         self._ack_walls: Dict[int, float] = {}

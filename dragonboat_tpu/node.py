@@ -259,11 +259,10 @@ class Node:
         hier_obs = getattr(self, "hier_obs", None)
         if hier_obs is not None and self.peer.raft.hier is not None:
             self.peer.raft.hier.obs = hier_obs
-        # wall-clock lease guard (ISSUE 17; set by NodeHost when
-        # Config.read_lease and NodeHostConfig.lease_wall_guard): the
-        # host's tick period in seconds — validity then also requires
-        # wall-fresh quorum acks, so tick starvation expires the lease
-        # instead of extending it
+        # wall-clock lease guard (ISSUE 17; set by NodeHost for every
+        # Config.read_lease group): the host's tick period in seconds —
+        # validity also requires wall-fresh quorum acks, so tick
+        # starvation expires the lease instead of extending it
         lease_wall_s = getattr(self, "lease_wall_s", None)
         if lease_wall_s is not None and self.peer.raft.lease is not None:
             self.peer.raft.lease.tick_interval_s = lease_wall_s
@@ -488,6 +487,8 @@ class Node:
                 r = self.peer.raft
                 rows = r.heartbeat_block_rows()
                 if rows.__class__ is not str:
+                    if r.lease is not None:
+                        r.heartbeat_block_sent(rows)
                     r.heartbeat_tick = 0
                     term = r.term
                     if demote and r.check_quorum:
@@ -506,7 +507,12 @@ class Node:
                 self._hb_block_leave()
         elif cause == "busy":
             r = self._hb_block_raft()
-            if r is not None:
+            # (a lease group's sends are booked under raftMu or not made:
+            # the ack of an unbooked send pops the NEXT booked one, a
+            # newer tick than it answers, the direction ``PENDING_CAP``
+            # exists to exclude; a busy lease group takes the per-group
+            # heartbeat, whose step books it)
+            if r is not None and r.lease is None:
                 try:
                     term = r.term
                     rows = r.heartbeat_block_rows()  # reads only

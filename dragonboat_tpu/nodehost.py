@@ -994,10 +994,11 @@ class NodeHost:
 
                 self._hier_obs = HierObs(self.raft_events.registry)
             node.hier_obs = self._hier_obs
-        if config.read_lease and self.nhconfig.lease_wall_guard:
-            # wall-clock lease guard (ISSUE 17): bound lease validity by
-            # monotonic wall time so a starved tick loop cannot
-            # overextend it past the majority's wall-time election
+        if config.read_lease:
+            # wall-clock lease guard (ISSUE 17; what a lease group has
+            # since ISSUE 41): bound lease validity by monotonic wall
+            # time so a starved tick loop cannot overextend it past the
+            # majority's wall-time election
             node.lease_wall_s = self.nhconfig.rtt_millisecond / 1000.0
         if self.hostplane is not None:
             node.ingress = self.hostplane.ingress

@@ -80,8 +80,8 @@ READ_FALLBACK_CAUSES = ("slot_overflow", "after_confirm", "purged")
 #: mid-step (``busy``), not a plain device-ticked leader / follower
 #: (``state``), at another term, following no leader yet, carrying a
 #: ReadIndex context as its hint, mid snapshot, of a membership with
-#: handlers of its own (lease, observer, witness), or a response from a
-#: remote that lags and must be probed
+#: handlers of its own (observer, witness; a lease group too until ISSUE
+#: 41), or a response from a remote that lags and must be probed
 HB_SINGLE_CAUSES = (
     "busy", "state", "term", "unknown_leader", "read_ctx", "snapshot",
     "membership", "lagging",
@@ -1365,6 +1365,7 @@ class ReadCtx:
         "trace_origin", "need", "peers", "eq_peer", "path", "round0",
         "rounds", "stage_round", "confirm_round",
         "a", "s", "e1", "eq", "d", "c", "r",
+        "lease_fallback", "remaining_ticks",
     )
 
     def __init__(self, cluster_id: int, low: int, high: int, term: int,
@@ -1391,6 +1392,11 @@ class ReadCtx:
         self.confirm_round = None
         self.a = time.perf_counter()
         self.s = self.e1 = self.eq = self.d = self.c = self.r = None
+        # a lease group (ISSUE 41): its leader found the lease not valid
+        # and the ctx took the ReadIndex plane; or, ``path = lease``, the
+        # ticks of validity left when the lease answered it
+        self.lease_fallback = False
+        self.remaining_ticks = None
 
 
 def _leg_ms(t_from, t_to):
@@ -1486,7 +1492,10 @@ class CoordObs:
         ``release_ms`` is ``leader_ms``; a leg whose ends were not both
         seen (a context released by a later one's quorum before its own
         echo was drained) is left out, and so is ``leader_ms`` of a
-        dropped context."""
+        dropped context.  A read answered under the leader's lease
+        (``path = lease``) has ``leader_ms`` and ``remaining_ticks`` and
+        none of the chain; one that found the lease not valid is today's
+        span with ``lease_fallback``."""
         fields = {
             "cluster_id": rc.cluster_id, "low": rc.low, "high": rc.high,
             "origin": rc.origin, "path": rc.path or "unstaged",
@@ -1504,6 +1513,8 @@ class CoordObs:
             ("rounds", rc.rounds),
             ("stage_round", rc.stage_round),
             ("confirm_round", rc.confirm_round),
+            ("remaining_ticks", rc.remaining_ticks),
+            ("lease_fallback", rc.lease_fallback or None),
         ):
             if value is not None:
                 fields[name] = value

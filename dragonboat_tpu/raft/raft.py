@@ -1098,14 +1098,15 @@ class Raft:
         """Leader leg: ``[(to, commit), ...]`` of exactly the HEARTBEATs
         ``broadcast_heartbeat_message`` would send now, or the cause that
         keeps the group on the per-group path: a pending ReadIndex ctx
-        rides the heartbeat as its hint, a lease books the send tick, an
-        observer or witness has handlers of its own, and a remote mid
-        snapshot stays with the scalar flow control."""
+        rides the heartbeat as its hint, an observer or witness has
+        handlers of its own, and a remote mid snapshot stays with the
+        scalar flow control.  Reads only: a lease group's caller books
+        the sends it makes of the rows (``heartbeat_block_sent``)."""
         if self.state != RaftState.LEADER:
             return "state"
         if self.read_index.has_pending_request():
             return "read_ctx"
-        if self.lease is not None or self.observers or self.witnesses:
+        if self.observers or self.witnesses:
             return "membership"
         committed = self.log.committed
         rows = []
@@ -1116,6 +1117,18 @@ class Raft:
                 return "snapshot"
             rows.append((nid, rp.match if rp.match < committed else committed))
         return rows
+
+    def heartbeat_block_sent(self, rows) -> None:
+        """The rows ``heartbeat_block_rows`` returned are leaving as a
+        block (a lease group's only): book what
+        ``broadcast_heartbeat_message_with_hint`` books, the send tick of
+        every voting peer heartbeaten (the rows are exactly the remotes,
+        and a group with a witness never comes here).  Under raftMu, the
+        scalar clock caught up (``Node._hb_block_enter``): the tick is
+        the one a step would have booked."""
+        self.lease.record_send(
+            self.tick_count, (nid for nid, _commit in rows)
+        )
 
     def heartbeat_block_contact(self, from_: int, term: int, commit: int):
         """Follower leg, the twin of ``handle(HEARTBEAT)`` where that is
@@ -1144,17 +1157,21 @@ class Raft:
         Marks the remote active (check-quorum's contact) and ends its
         wait; the caller stages the device's activity bit.  A lagging
         remote returns the cause: the scalar handler probes it with a
-        REPLICATE of its own."""
+        REPLICATE of its own (and books the lease's ack itself).  A lease
+        group's ack is booked as the scalar handler books it: a remote is
+        a voting member, and the wall stamp is ``record_ack``'s own."""
         if term != self.term:
             return "term"
         if self.state != RaftState.LEADER:
             return "state"
         rp = self.remotes.get(from_)
-        if rp is None or self.lease is not None:
+        if rp is None:
             return "membership"
         if rp.match < self.log.last_index():
             return "lagging"
         rp.active = True
+        if self.lease is not None:
+            self.lease.record_ack(from_, self.tick_count)
         rp.wait_to_retry()
         return None
 
@@ -1413,6 +1430,10 @@ class Raft:
         confirmed release keeps released indices identical to the
         ReadIndex path (differential: tests/test_lease.py)."""
         lease = self.lease
+        # a sampled read (the READ_INDEX names its requester's trace) on
+        # the device plane is followed: the accept, for its one span
+        followed = m.trace is not None and self.offload is not None
+        accepted = _time.perf_counter() if followed else None
         remaining = lease.check(
             self.tick_count, self.quorum(),
             self.voting_members(), self.node_id,
@@ -1422,7 +1443,8 @@ class Raft:
             return False
         lease.note_read_local(remaining)
         # same routing as apply_read_releases on a confirmed ctx
-        if m.from_ == NO_NODE or m.from_ == self.node_id:
+        remote = not (m.from_ == NO_NODE or m.from_ == self.node_id)
+        if not remote:
             self.add_ready_to_read(self.log.committed, ctx, lease=True)
         else:
             self.send(
@@ -1433,6 +1455,11 @@ class Raft:
                     hint=ctx.low,
                     hint_high=ctx.high,
                 )
+            )
+        if followed:
+            self.offload.read_leased(
+                self.cluster_id, ctx.low, ctx.high, self.term, remote,
+                m.trace, accepted, remaining,
             )
         return True
 

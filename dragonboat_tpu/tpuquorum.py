@@ -904,6 +904,26 @@ class TpuQuorumCoordinator:
     # for a ctx whose READ_INDEX carried a requester's trace)
     # ------------------------------------------------------------------
 
+    def read_leased(
+        self, cluster_id: int, low: int, high: int, term: int, remote: bool,
+        trace, accepted: float, remaining_ticks: int,
+    ) -> None:
+        """A leader answered a SAMPLED read under its lease in the step
+        that took it (``Raft.try_lease_read``, under raftMu; ISSUE 41):
+        nothing was staged and no round will see it, so its one
+        ``read_ctx`` span is written here, ``path = lease``, from the
+        accept to the answer, with what ``lease.check`` returned."""
+        obs = self._obs
+        if obs is None:
+            return
+        rc = ReadCtx(cluster_id, low, high, term, remote, trace, 0,
+                     self._rounds_recorded)
+        rc.a = accepted
+        rc.r = time.perf_counter()
+        rc.path = "lease"
+        rc.remaining_ticks = remaining_ticks
+        obs.read_ctx(rc)
+
     def _read_trace_open(self, cluster_id: int, low: int, high: int,
                          term: int, remote: bool, trace) -> None:
         """Instant ``a`` (step worker, under the group's raftMu)."""
@@ -914,12 +934,18 @@ class TpuQuorumCoordinator:
                 self._read_trace_close(old, "dropped")
         node = self._nodes.get(cluster_id)
         need = 1
+        lease_fallback = False
         if node is not None and node.peer is not None:
-            need = max(1, node.peer.raft.quorum() - 1)
-        rt[(cluster_id, low, high)] = ReadCtx(
+            r = node.peer.raft
+            need = max(1, r.quorum() - 1)
+            # a lease group's context is staged only where its leader
+            # found the lease not valid (``try_lease_read``)
+            lease_fallback = r.lease is not None
+        rc = rt[(cluster_id, low, high)] = ReadCtx(
             cluster_id, low, high, term, remote, trace, need,
             self._rounds_recorded,
         )
+        rc.lease_fallback = lease_fallback
 
     def _read_trace_close(self, rc: ReadCtx, path: Optional[str] = None
                           ) -> None:
@@ -1030,6 +1056,11 @@ class TpuQuorumCoordinator:
             kind, cid = op[0], op[1]
             if kind in ("contact_block", "hbresp_block"):
                 self._drain_block(op, recover)
+                if lt is not None and kind == "hbresp_block":
+                    # the advisory lease tally, as the "hbresp" op below
+                    for c, peer in zip(op[1], op[2]):
+                        if lt.tracks(c):
+                            lease_acks.setdefault(c, set()).add(peer)
                 continue
             if cid not in self.eng.groups:
                 continue

@@ -148,12 +148,6 @@ def rank_main() -> int:
         nhc_kw.update(
             health_sample_ms=int(os.environ.get("SOAK_HEALTH_MS", "100")),
             enable_metrics=True,
-            # both arms: on the oversubscribed box a partitioned
-            # leader's tick loop starves, and a purely tick-valid lease
-            # can outlive the majority's wall-time election (a stale
-            # read the checker caught at 100 groups) — the wall guard
-            # expires it instead
-            lease_wall_guard=True,
         )
         if recover:
             nhc_kw.update(

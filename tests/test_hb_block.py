@@ -12,7 +12,10 @@ so what a tick costs and what it leaves behind is a count, not a timing:
       election clocks (scalar and device), check-quorum contacts, commit
       indexes and, row for row, the same messages on the wire whether the
       heartbeats go by the block or by today's per-group message (the
-      scalar path is the oracle);
+      scalar path is the oracle); a quarter of the groups are lease
+      groups (``read_lease``), and the block legs leave their
+      ``LeaderLease`` what the scalar handlers leave it: the ack bases,
+      the send FIFOs, ``remaining()`` and the reads answered under it;
 (iii) the tick deficit is counted (replayed, dropped) and a host that was
       away for an election timeout holds its elections instead of
       deposing sound leaders;
@@ -39,7 +42,7 @@ GROUPS = 128
 #: what ``World.state`` holds for a replica, in order
 STATE_FIELDS = ("state", "term", "leader", "election_tick", "committed",
                 "last_index", "remotes", "device_election_tick",
-                "device_active")
+                "device_active", "lease")
 #: request timeouts count TICKS of the virtual clock (a tick is 1000 s of
 #: it): long enough that no tick the script drives can time a request out
 FOREVER_S = 1e9
@@ -58,6 +61,33 @@ def _wait(pred, timeout_s: float, what: str) -> None:
     from tests.loadwait import wait_until
 
     wait_until(pred, timeout_s, interval=0.02, what=what)
+
+
+def leased(cid: int) -> bool:
+    """A quarter of the groups read under a leader lease, some led by
+    every host (leaders sit on host ``cid % 3``)."""
+    return cid % 4 == 1
+
+
+def lease_state(r):
+    """What a replica's ``LeaderLease`` holds, on the tick clock: the ack
+    bases, the send FIFOs with their counts, the refused sends, who has a
+    wall stamp (the instants are the wall's own), ceded, the ticks of
+    validity left, and the reads it answered and turned away."""
+    lease = r.lease
+    if lease is None:
+        return None
+    return (
+        tuple(sorted(lease.bases.items())),
+        tuple(sorted((nid, tuple(tuple(e) for e in dq))
+                     for nid, dq in lease._pending.items() if dq)),
+        tuple(sorted((n, c) for n, c in lease._unrecorded.items() if c)),
+        tuple(sorted(lease._ack_walls)),
+        lease.ceded,
+        lease.remaining(r.tick_count, r.quorum(), r.voting_members(),
+                        r.node_id),
+        lease.reads_local, lease.reads_fallback,
+    )
 
 
 class CountSM:
@@ -111,7 +141,7 @@ class World:
                     self.addrs, False, CountSM,
                     Config(cluster_id=cid, node_id=i, election_rtt=10,
                            heartbeat_rtt=1, check_quorum=True,
-                           snapshot_entries=0),
+                           read_lease=leased(cid), snapshot_entries=0),
                 )
         self.coords = [nh.quorum_coordinator for nh in self.nhs]
         # what a World stopped before this one left undelivered stays so:
@@ -313,6 +343,7 @@ class World:
                                  for nid, rp in r.remotes.items())),
                     int(etick[rows[cid]]),
                     tuple(active[rows[cid]].tolist()),
+                    lease_state(r),
                 )
         return out
 
@@ -408,6 +439,16 @@ def _script(w: World):
     for f in reads:
         assert f.wait(60.0).completed
     yield "read"
+    # reads at the leaders of the lease groups: a valid lease answers in
+    # the step that takes the read, with no message at all; the next
+    # tick's heartbeats find no context pending
+    lease_reads = [w.nhs[cid % 3].read_index(cid, FOREVER_S)
+                   for cid in w.cids if leased(cid)]
+    for f in lease_reads:
+        assert f.wait(60.0).completed
+    w.settle()
+    w.tick(1)
+    yield "lease_read"
     # a term change mid-block: host 1 hands a dozen groups to host 2, and
     # a block it stamped BEFORE (old term, old leader) reaches host 3
     # after, next to rows that are sound and rows for groups that host 3
@@ -459,6 +500,13 @@ def test_block_path_leaves_what_the_per_group_path_leaves():
                   for k in block.coords[0].hb_single_causes}
         assert causes["lagging"] > 0 and causes["read_ctx"] > 0
         assert causes["term"] + causes["state"] + causes["unknown_leader"] > 0
+        # a lease keeps no group off the block plane, and the lease the
+        # block legs fed answered reads in both worlds
+        assert causes["membership"] == 0
+        for w in (block, single):
+            served = [w.nhs[c % 3].get_node(c).peer.raft.lease.reads_local
+                      for c in w.cids if leased(c)]
+            assert min(served) > 0, served
     finally:
         block.stop()
         single.stop()

@@ -450,8 +450,9 @@ def test_lease_wall_guard_expires_starved_tick_clock():
     lease.record_send(6, [2, 3])
     lease.record_ack(2, 7)
     assert lease.valid(7, quorum, voters, self_id)
-    # without the knob the same freeze stays (unsafely) valid — the
-    # default-off contract tick-driven tests rely on
+    # a bare LeaderLease (no NodeHost gave it the tick period: what the
+    # tick-driven unit tests build) has only the tick clock, and the
+    # same freeze stays valid — start_cluster never leaves one so
     bare = LeaderLease(10)
     bare.record_send(5, [2, 3])
     bare.record_ack(2, 6)
