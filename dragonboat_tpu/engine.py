@@ -79,7 +79,9 @@ class _Committer:
     round (non-Replicate messages out, committed entries to apply,
     ``Peer.Commit``) in submission order.  Per-group ordering is preserved
     by the node's ``commit_inflight`` flag: a group is never stepped again
-    until its previous update has been committed.
+    until its previous update has been committed.  Committed entries that
+    an earlier update made durable do not wait here: ``process_steps`` has
+    handed them to the apply queue before the submit.
     """
 
     def __init__(self, engine: "Engine", idx: int):
@@ -148,14 +150,16 @@ class _Committer:
             # the merged batch is durable here — whichever tier fsynced
             # it (group-commit WAL or the classic per-committer save)
             tr.mark_updates(merged, "wal")
+        after = 0
         for pairs, _ in batch:
             for n, ud in pairs:
-                n.process_raft_update(ud)
+                after += n.process_raft_update(ud)
                 n.commit_raft_update(ud)
                 n.commit_inflight = False
                 # re-check inputs that arrived while the commit was in
                 # flight (the step worker skipped this group meanwhile)
                 self.engine.set_step_ready(n.cluster_id)
+        self.engine.count_apply_handoffs(self.idx, 0, after)
         self.cycles += 1
         self.merged += len(merged)
         self.commit_s += t1 - t0
@@ -200,8 +204,13 @@ class Engine:
         self._step_cache: List = [(-1, {}) for _ in range(step_workers)]
         self._apply_cache: List = [(-1, {}) for _ in range(apply_workers)]
         # diagnostics per step worker: [rounds, groups_stepped, skipped,
-        # step_s]
-        self._step_stats = [[0, 0, 0, 0.0] for _ in range(step_workers)]
+        # step_s, applied_early, applied_after_sync] (the last two: updates
+        # whose committed entries went to the apply queue before their
+        # persist / after it; one writer each, the step worker and its
+        # committer)
+        self._step_stats = [
+            [0, 0, 0, 0.0, 0, 0] for _ in range(step_workers)
+        ]
         self._committers = [_Committer(self, i) for i in range(step_workers)]
         # dedicated snapshot worker pool (reference execengine.go:240-635,
         # 64 workers): multi-second SM save/recover/stream work must never
@@ -338,7 +347,25 @@ class Engine:
         self, active: List["Node"], committer: Optional[_Committer] = None
     ) -> Tuple[int, int]:
         """The hot loop (reference ``processSteps`` ``execengine.go:923``):
-        step → send replicates → one batched fsync → execute → commit.
+        step → send replicates → apply what is already durable → one
+        batched fsync → execute → commit.
+
+        An update's committed entries that an EARLIER update saved
+        (``Update.fast_apply``, ``raft/peer.py`` ``set_fast_apply``) go to
+        the apply queue before this update's persist, as the reference's
+        first ``applySnapshotAndUpdate(updates, nodes, true)`` does: the
+        persist they would wait for adds nothing to them but the hard
+        state's commit index, which a restart rebuilds.  The rest of the
+        post-persist half keeps its place.
+
+        Such an update that saves no entry and moves nothing of the hard
+        state but ``commit`` (``Node.persists_commit_alone``: a leader's
+        commit advance, a follower's from a heartbeat) completes inline
+        like a message-only update, and its record goes to the committer
+        with no post-fsync half: no message of it acknowledges anything its
+        save makes durable, and holding the group ``commit_inflight`` for
+        that save made the callers' next proposals wait out the WAL cycle
+        the early hand-off had just taken off their acknowledgement.
 
         The fsync + post-fsync half is pipelined through the worker's
         committer (see :class:`_Committer`); groups whose previous update is
@@ -363,26 +390,40 @@ class Engine:
             n.send_replicate_messages(ud)  # before fsync (thesis §10.2.1)
         # only updates that can put a record on disk need the committer;
         # the rest complete inline
-        persist = []
-        updates = []
+        persist = []  # wait for their save
+        updates = []  # what the save carries
         inline = []
+        early = 0
         for n, ud in pairs:
             if (
                 ud.entries_to_save
                 or not ud.state.is_empty()
                 or (ud.snapshot is not None and not ud.snapshot.is_empty())
             ):
-                persist.append((n, ud))
                 updates.append(ud)
+                fast = ud.fast_apply
+                if (
+                    fast
+                    and not ud.entries_to_save
+                    and n.persists_commit_alone(ud)
+                ):
+                    # nothing of it waits for its own save
+                    inline.append((n, ud))
+                else:
+                    persist.append((n, ud))
+                    if committer is not None:
+                        n.commit_inflight = True
+                if fast:
+                    early += n.apply_committed(ud)
             else:
                 inline.append((n, ud))
         for n, ud in inline:
             n.process_raft_update(ud)
             n.commit_raft_update(ud)
-        if persist:
+        if updates:
+            idx = committer.idx if committer is not None else 0
+            self.count_apply_handoffs(idx, early, 0)
             if committer is not None:
-                for n, _ in persist:
-                    n.commit_inflight = True
                 committer.submit(persist, updates)
             else:
                 tr = self.tracer
@@ -390,10 +431,25 @@ class Engine:
                     self.logdb.save_raft_state(updates)
                 if tr is not None:
                     tr.mark_updates(updates, "wal")
+                after = 0
                 for n, ud in persist:
-                    n.process_raft_update(ud)
+                    after += n.process_raft_update(ud)
                     n.commit_raft_update(ud)
+                self.count_apply_handoffs(idx, 0, after)
         return len(pairs), skipped
+
+    def count_apply_handoffs(self, idx: int, early: int, after: int) -> None:
+        """``early`` / ``after`` updates of step worker ``idx`` handed their
+        committed entries to the apply queue before / after their persist:
+        ``stats()`` always, the tracer's series by the second while it is
+        on."""
+        if early or after:
+            st = self._step_stats[idx]
+            st[4] += early
+            st[5] += after
+            tr = self.tracer
+            if tr is not None:
+                tr.count_apply_handoffs(early, after)
 
     def stats(self) -> dict:
         """Diagnostic counters (benchmarks; not part of the public API)."""
@@ -404,6 +460,8 @@ class Engine:
                     "groups_stepped": s[1],
                     "skipped_inflight": s[2],
                     "step_s": round(s[3], 3),
+                    "applied_early": s[4],
+                    "applied_after_sync": s[5],
                 }
                 for s in self._step_stats
             ],

@@ -9,6 +9,7 @@ dummy entry carrying the snapshot boundary term.
 from __future__ import annotations
 
 import threading
+from dataclasses import replace
 from typing import List, Tuple
 
 from ..wire import Entry, Membership, Snapshot, State
@@ -195,6 +196,15 @@ class LogReader:
         if rs is not None:
             if not rs.state.is_empty():
                 lr.state = rs.state
+                if lr.state.commit < lr.marker:
+                    # a snapshot is of applied, hence committed, entries,
+                    # and the commit index is the one part of the hard
+                    # state a restart may rebuild: a replica that applied
+                    # entries beside the persist of the update that
+                    # committed them (``Node.apply_committed``), saved a
+                    # snapshot of them and stopped before that persist
+                    # comes up with the snapshot ahead of its record
+                    lr.state = replace(lr.state, commit=lr.marker)
             if rs.entry_count > 0:
                 lr.set_range(rs.first_index, rs.entry_count)
         return lr

@@ -117,6 +117,11 @@ class Node:
         self.pending_leader_transfer = PendingLeaderTransfer()
         # apply pipeline
         self.to_apply = TaskQueue()
+        # highest entry index handed to ``to_apply``: an update's committed
+        # entries may be handed over BEFORE its persist (``apply_committed``),
+        # and an update whose persist failed is produced again by the next
+        # ``get_update`` with the same committed entries
+        self._applied_handed = 0
         self.quiesce_mgr = QuiesceManager(
             self.cluster_id, self.node_id, config.election_rtt, config.quiesce
         )
@@ -1105,7 +1110,16 @@ class Node:
             self._handle_events(extra_ticks=delta)
             more = self.to_apply.more_entries_to_apply()
             if self.peer.has_update(more):
-                ud = self.peer.get_update(more, self.sm.get_last_applied())
+                # after a failed persist the state machine may be ahead of
+                # the log's ``processed`` (its entries were handed over
+                # early); the log never hears of an applied index above it
+                ud = self.peer.get_update(
+                    more,
+                    min(
+                        self.sm.get_last_applied(),
+                        self.peer.raft.log.processed,
+                    ),
+                )
                 self._update_out = True
                 return ud
             self._maybe_enroll()
@@ -1718,7 +1732,10 @@ class Node:
             if m.type == MT.REPLICATE:
                 self.nh.send_message(m)
 
-    def process_raft_update(self, ud: Update) -> None:
+    def process_raft_update(self, ud: Update) -> bool:
+        """The half of an update that waits for its persist; True where it
+        handed committed entries to the apply queue (they were not handed
+        over before the persist: ``apply_committed``)."""
         # a restore update can carry BOTH the snapshot and the log tail
         # past it: the snapshot must move the logreader window FIRST or the
         # append trips the gap check and the committer retries the same
@@ -1765,10 +1782,11 @@ class Node:
             self.pending_reads.applied(
                 max(self.sm.get_last_applied(), self.devsm_release_floor)
             )
-        self._apply_snapshot_and_update(ud)
+        handed = self._apply_snapshot_and_update(ud)
         self._save_snapshot_required()
+        return handed
 
-    def _apply_snapshot_and_update(self, ud: Update) -> None:
+    def _apply_snapshot_and_update(self, ud: Update) -> bool:
         if not is_empty_snapshot(ud.snapshot):
             ss = ud.snapshot
             plog.info(
@@ -1786,17 +1804,49 @@ class Node:
                 )
             )
             self.nh.engine.set_apply_ready(self.cluster_id)
-        if ud.committed_entries:
-            self.to_apply.enqueue(
-                Task(
-                    cluster_id=self.cluster_id,
-                    node_id=self.node_id,
-                    entries=ud.committed_entries,
-                )
-            )
-            self.nh.engine.set_apply_ready(self.cluster_id)
+        handed = self.apply_committed(ud)
         if ud.more_committed_entries:
             self.nh.engine.set_step_ready(self.cluster_id)
+        return handed
+
+    def apply_committed(self, ud: Update) -> bool:
+        """Hand ``ud``'s committed entries to the apply queue and wake an
+        apply worker; True where any went.  Only entries above the highest
+        index already handed over go, so the engine may call this before the
+        update's persist (reference ``execengine.go`` ``processSteps``:
+        ``applySnapshotAndUpdate(..., true)`` for an update whose
+        ``fast_apply`` holds: what it commits an EARLIER update made
+        durable) and ``process_raft_update`` again after it, and a persist
+        that failed, whose update ``get_update`` produces again, applies
+        nothing twice."""
+        ents = ud.committed_entries
+        if not ents or ents[-1].index <= self._applied_handed:
+            return False
+        if ents[0].index <= self._applied_handed:
+            ents = ents[self._applied_handed + 1 - ents[0].index:]
+        self._applied_handed = ents[-1].index
+        self.to_apply.enqueue(
+            Task(
+                cluster_id=self.cluster_id,
+                node_id=self.node_id,
+                entries=ents,
+            )
+        )
+        self.nh.engine.set_apply_ready(self.cluster_id)
+        return True
+
+    def persists_commit_alone(self, ud: Update) -> bool:
+        """True where the hard state ``ud`` carries differs in nothing but
+        ``commit`` from the last one this replica's raft gave out (term and
+        vote, which must be durable before anything that follows from them
+        leaves, are those an earlier update persisted).  The step worker
+        asks right after ``step_node``, while nothing of the group is in
+        flight."""
+        peer = self.peer
+        if peer is None:
+            return False
+        prev = peer.prev_state
+        return ud.state.term == prev.term and ud.state.vote == prev.vote
 
     def _save_snapshot_required(self) -> None:
         """Auto snapshot every ``snapshot_entries`` applied (reference

@@ -324,6 +324,13 @@ class Tracer:
         self._pend_outcomes: Dict[tuple, int] = {}
         self._outcome_events: deque = deque(maxlen=OUTCOME_EVENTS_KEEP)
         self._outcome_secs: Dict[int, Dict[tuple, int]] = {}
+        # ---- apply hand-offs (ISSUE 42) -------------------------------
+        # updates whose committed entries went to the apply queue before
+        # their persist / after it, queued by the engine's step workers
+        # and committers as (perf_counter, early, after) and folded by
+        # whole second like the outcomes
+        self._handoff_q: deque = deque()
+        self._handoff_secs: Dict[int, List[int]] = {}
         # clock anchor: stamps are perf_counter (monotonic); the export
         # maps them onto the wall clock the recorder spans already use
         self._wall0 = time.time()
@@ -572,10 +579,38 @@ class Tracer:
         runs on every apply worker, once a request."""
         self._fin_q.append((time.perf_counter(), t0, kind, code, None))
 
+    def count_apply_handoffs(self, early: int, after: int) -> None:
+        """``early`` / ``after`` updates handed their committed entries to
+        the apply queue before / after their persist
+        (``Engine.count_apply_handoffs``).  No lock: queued for the next
+        fold."""
+        self._handoff_q.append((time.perf_counter(), early, after))
+
+    def apply_handoffs(self) -> Dict[int, tuple]:
+        """``{int(perf_counter): (early, after_sync)}``: commit-carrying
+        updates by the whole second in which their committed entries went
+        to the apply queue, before their persist against after it (the
+        newest ``OUTCOME_SECONDS_KEEP`` seconds), so a reader can take a
+        window's."""
+        with self._mu:
+            self._fold_locked()
+            return {s: tuple(c) for s, c in self._handoff_secs.items()}
+
     def _fold_locked(self) -> None:
         """Account the completions queued since the last fold (caller
         holds ``_mu``): outcome counts, e2e and, for a sampled trace, its
-        stage observations."""
+        stage observations; and the apply hand-offs."""
+        hq = self._handoff_q
+        secs = self._handoff_secs
+        for _ in range(len(hq)):
+            now, early, after = hq.popleft()
+            sec = secs.get(int(now))
+            if sec is None:
+                sec = secs[int(now)] = [0, 0]
+                if len(secs) > OUTCOME_SECONDS_KEEP:
+                    del secs[min(secs)]
+            sec[0] += early
+            sec[1] += after
         q = self._fin_q
         for _ in range(len(q)):
             now, t0, kind, code, evs = q.popleft()
@@ -717,7 +752,7 @@ class Tracer:
         truthiness checks."""
         if (
             self._pend_requests or self._fin_q or self._pend_completed
-            or self._e2e_acc[2] or self._pend_outcomes
+            or self._e2e_acc[2] or self._pend_outcomes or self._handoff_q
         ):
             self.flush_metrics()
         if not self._by_cluster and not self._by_key:
