@@ -125,6 +125,16 @@ class Node:
         self.quiesce_mgr = QuiesceManager(
             self.cluster_id, self.node_id, config.election_rtt, config.quiesce
         )
+        # a group's sleep on the device tick plane (``quorum_engine="tpu"``,
+        # set at registration where the coordinator ticks on one device):
+        # the row's idle clock is the tick kernel's and ``quiesce_mgr`` is
+        # off; what the host keeps is whether this replica sleeps (the
+        # rule "a heartbeat is no activity while awake" needs it) and the
+        # host tick it went to sleep at.  Both under raftMu.
+        self.dev_quiesce = False
+        self._asleep = False
+        self._asleep_tick = 0
+        self._active_tick = -(1 << 30)  # host tick of the last activity
         self._stopped = threading.Event()
         self._initialized = threading.Event()
         self.current_tick = 0
@@ -186,6 +196,7 @@ class Node:
         self._off_hb = False
         self._off_elect = False
         self._off_demote = False
+        self._off_quiesce = False
         # device read plane: quorum-confirmed ReadIndex ctxs awaiting the
         # scalar prefix release, and fallback echoes for ctxs the device
         # is not tracking (slot overflow / stale) — both applied under
@@ -282,10 +293,18 @@ class Node:
         if coord is not None:
             self.peer.raft.offload = coord
             # device-tick mode: the tick kernel owns election/heartbeat/
-            # check-quorum firing; quiesce-enabled groups keep scalar ticks
-            # (their idle detection is host-side state)
-            if coord.drive_ticks and not self.config.quiesce:
+            # check-quorum firing, and a quiesce group's idle clock and
+            # sleep beside them (its row can sleep: ops/kernels.tick_step).
+            # Only on a mesh-sharded coordinator, whose shards have no
+            # idle columns wired, a quiesce group keeps scalar ticks and
+            # the host-side ``quiesce_mgr``.
+            if coord.drive_ticks and (
+                not self.config.quiesce or coord.quiesce_on_device
+            ):
                 self.peer.raft.device_ticks = True
+                if self.config.quiesce:
+                    self.dev_quiesce = True
+                    self.quiesce_mgr.enabled = False
             coord.register(self)
             # device state machine (devsm, ISSUE 11): Config.device_kv +
             # a DeviceKVStateMachine factory moves the group's apply
@@ -403,6 +422,16 @@ class Node:
         if wake:
             self.nh.engine.set_step_ready(self.cluster_id)
 
+    def offload_quiesce_enter(self, wake: bool = True) -> None:
+        """The row's idle clock crossed its threshold and the row went to
+        sleep (``TickFlags.quiesce_enter``): the replica's one step-worker
+        turn of the sleep, which tells the peers."""
+        with self._off_mu:
+            self._off_quiesce = True
+            self._off_pending = True
+        if wake:
+            self.nh.engine.set_step_ready(self.cluster_id)
+
     # ---- batched heartbeat plane (tpuquorum.py) ----
     #
     # The three legs of a heartbeat exchange, run by the coordinator for
@@ -454,7 +483,9 @@ class Node:
 
     def _hb_block_raft(self):
         """The raft of a replica that is on the block plane at all: a
-        plain device-ticked group.  None keeps it on the per-group path.
+        device-ticked group (a quiesce group among them while it is awake:
+        a sleeping leader's row raises no heartbeat).  None keeps it on
+        the per-group path.
 
         Read without raftMu for a BUSY group (mid-step, or its update not
         committed yet): such a group cannot be touched under the lock
@@ -469,7 +500,7 @@ class Node:
         p = self.peer
         if (
             p is None or self._stopped.is_set() or self.fast_lane
-            or self.quiesce_mgr.enabled or not p.raft.device_ticks
+            or not p.raft.device_ticks
             or not self._initialized.is_set()
         ):
             return None
@@ -547,6 +578,8 @@ class Node:
             return cause
         try:
             r = self.peer.raft
+            if self._asleep:
+                self._activity(MT.HEARTBEAT)
             before = r.log.committed
             cause = r.heartbeat_block_contact(from_, term, commit)
             if cause is None and r.log.committed != before:
@@ -572,6 +605,8 @@ class Node:
                         return "lite"
             return cause
         try:
+            if self._asleep:
+                self._activity(MT.HEARTBEAT_RESP)
             return self.peer.raft.heartbeat_block_resp(from_, term)
         finally:
             self._hb_block_leave()
@@ -588,6 +623,7 @@ class Node:
             hb, self._off_hb = self._off_hb, False
             elect, self._off_elect = self._off_elect, False
             demote, self._off_demote = self._off_demote, False
+            qenter, self._off_quiesce = self._off_quiesce, False
             reads, self._off_reads = self._off_reads, []
             echoes, self._off_read_echoes = self._off_read_echoes, []
         m = self.obs_registry
@@ -604,10 +640,20 @@ class Node:
                 m.counter_add(name, len(reads), labels={"kind": "read_confirm"})
             if echoes:
                 m.counter_add(name, len(echoes), labels={"kind": "read_echo"})
-            if elect or hb or demote:
+            if elect or hb or demote or qenter:
                 m.counter_add(name, labels={"kind": "tick"})
         if self.fast_lane:
             return  # native core owns the group; flags are stale
+        if qenter and self.dev_quiesce and (
+            # the scalar guard of this flag: a row crosses only after a
+            # threshold of ticks without a DRAINED mark, so activity
+            # newer than half of that was staged behind the round that
+            # raised the flag; its mark wakes the row with the next round
+            # and this replica never slept
+            self.nh.tick_count - self._active_tick
+            >= self.quiesce_mgr.threshold // 2
+        ):
+            self._sleep(own=True)
         if commit_q and r.is_leader() and r.log.try_commit(commit_q, r.term):
             # device-plane commits attribute too (ISSUE 14): the same
             # close hook the scalar commit site runs, under raftMu with
@@ -697,7 +743,7 @@ class Node:
             and not r.is_observer()
             and not r.is_witness()
             and not r.self_removed()
-            and not self.quiesce_mgr.quiesced()
+            and not self.quiesced()
             # scalar clock must agree: it resets synchronously under
             # raftMu on leader contact, so a device row whose staged
             # contact reset is still riding a round cannot disrupt a
@@ -1566,7 +1612,10 @@ class Node:
             if m.type == MT.LOCAL_TICK:
                 ticks += 1
             elif m.type == MT.QUIESCE:
-                self.quiesce_mgr.try_enter_quiesce()
+                if self.dev_quiesce:
+                    self._sleep(own=False)
+                else:
+                    self.quiesce_mgr.try_enter_quiesce()
             elif m.type == MT.UNREACHABLE:
                 # local report from the transport, not a wire message
                 # (reference node.go:1257-1286 handleReceivedMessages)
@@ -1580,16 +1629,19 @@ class Node:
                 # not be able to force a follower to campaign against a
                 # healthy leader (reference treats ELECTION as local-only)
                 if m.from_ == self.node_id:
-                    self.quiesce_mgr.record_activity(m.type)
+                    self._activity(m.type)
                     self.peer.campaign()
             else:
-                if self.quiesce_mgr.enabled:
-                    self.quiesce_mgr.record_activity(m.type)
+                if self.config.quiesce:
+                    self._activity(m.type)
                 if m.type == MT.INSTALL_SNAPSHOT and m.snapshot is not None:
                     self._handle_install_snapshot(m)
                 else:
                     self.peer.handle(m)
         if ticks:
+            obs = self.replica_obs
+            if obs is not None:
+                obs.scalar_ticks(ticks)
             self._tick(ticks)
         if self.quiesce_mgr.just_entered_quiesce():
             self._broadcast_quiesce()
@@ -1600,6 +1652,69 @@ class Node:
         if obs is not None:
             obs.install("received")
         self.peer.handle(m)
+
+    # ---- a group's sleep (Config.quiesce) ----
+    #
+    # Scalar engine: ``quiesce_mgr`` (quiesce.py), ticked by ``_tick``.
+    # Device tick plane (``dev_quiesce``): the row's idle clock and its
+    # sleep are the tick kernel's; the host keeps ``_asleep`` beside it
+    # and tells the row what the kernel cannot see: activity (which also
+    # ends a sleep) and a peer's QUIESCE.  All under raftMu.
+
+    def quiesced(self) -> bool:
+        """Whether this replica sleeps."""
+        if self.dev_quiesce:
+            return self._asleep
+        return self.quiesce_mgr.quiesced()
+
+    def _activity(self, msg_type) -> Optional[str]:
+        """A message or a request reached this replica
+        (``QuiesceManager.record_activity``'s rule: anything but a
+        heartbeat or its response while awake resets the idle clock,
+        anything at all ends a sleep).  Where it woke the replica, the
+        role it woke in (``leader`` / ``follower``), else None.  A woken
+        replica's election clock then starts from the wake, not from the ticks
+        the sleep counted, so a woken follower does not campaign against
+        a leader it has not heard from YET (a leader that died during the
+        sleep is replaced one election timeout after the wake)."""
+        if not self.dev_quiesce:
+            self.quiesce_mgr.record_activity(msg_type)
+            return None
+        hb = msg_type == MT.HEARTBEAT or msg_type == MT.HEARTBEAT_RESP
+        coord = self.quorum_coordinator
+        if not self._asleep:
+            if not hb:
+                self._active_tick = self.nh.tick_count
+                coord.quiesce_activity(self.cluster_id)
+            return None
+        if hb and (
+            self.nh.tick_count - self._asleep_tick < self.config.election_rtt
+        ):
+            # new to its sleep: a heartbeat that was on its way when the
+            # group went to sleep (the leader falls asleep a message
+            # later) wakes nobody, or the woken follower would wait out
+            # an election timeout beside a sleeping leader and depose it
+            return None
+        self._asleep = False
+        self._active_tick = self.nh.tick_count
+        r = self.peer.raft
+        role = "leader" if r.is_leader() else "follower"
+        if role == "follower":
+            r.election_tick = 0
+        coord.quiesce_woke(self.cluster_id)
+        return role
+
+    def _sleep(self, own: bool) -> None:
+        """This replica goes to sleep: its own row's idle clock crossed
+        the threshold (``own``: the peers are told, once) or a peer said
+        so (the row is put to sleep with the next round)."""
+        if self._asleep:
+            return
+        self._asleep = True
+        self._asleep_tick = self.nh.tick_count
+        self.quorum_coordinator.quiesce_slept(self.cluster_id, own)
+        if own:
+            self._broadcast_quiesce()
 
     def _broadcast_quiesce(self) -> None:
         for nid in list(self.peer.raft.remotes):
@@ -1642,18 +1757,25 @@ class Node:
     def _handle_proposals(self) -> None:
         entries = self.entry_q.get()
         if entries:
-            self.quiesce_mgr.record_activity(MT.PROPOSE)
+            woke = self._activity(MT.PROPOSE) if self.config.quiesce else None
             self.peer.propose_entries(entries)
             tr = self.tracer
             if tr is not None:
                 tr.mark_entries(entries, "raft_step")
+                if woke is not None:
+                    tr.mark_woke(entries, woke)
 
     def _handle_read_index(self) -> None:
         if self.pending_reads.peep():
             ctx = self.pending_reads.next_ctx()
             if self.pending_reads.take_pending(ctx):
-                self.quiesce_mgr.record_activity(MT.READ_INDEX)
+                woke = (
+                    self._activity(MT.READ_INDEX) if self.config.quiesce
+                    else None
+                )
                 self._read_index(ctx)
+                if woke is not None and self.tracer is not None:
+                    self.pending_reads.trace_woke(ctx, woke)
 
     def _read_index(self, ctx: SystemCtx) -> None:
         """Hand a taken ReadIndex ctx to raft (under raftMu).  Where the
@@ -1672,7 +1794,7 @@ class Node:
         if cc is not None:
             rs = self.pending_config_change.pending()
             key = rs.key if rs is not None else 0
-            self.quiesce_mgr.record_activity(MT.CONFIG_CHANGE_EVENT)
+            self._activity(MT.CONFIG_CHANGE_EVENT)
             self.peer.propose_config_change(cc, key)
 
     def _handle_leader_transfer(self) -> None:

@@ -432,6 +432,11 @@ class NodeHost:
             if self.quorum_coordinator is not None:
                 self.quorum_coordinator.tracer = self.tracer
                 self.quorum_coordinator.replattr = self.replattr
+                # a sampled request that found its group asleep leaves a
+                # ``quiesce_wake`` span at its end (ISSUE 44)
+                cobs = self.quorum_coordinator._obs
+                if cobs is not None:
+                    self.tracer.wake_sink = cobs.quiesce_wake
         # cluster health plane (obs/health.py, ISSUE 13): low-rate
         # per-group/host health sampling + anomaly detectors + the live
         # scrape endpoint.  OFF by default (health_sample_ms=0 and no
@@ -1611,8 +1616,12 @@ class NodeHost:
                     # owns this group's raft clock; wake it only when its
                     # pending-request GC could be overdue.  This is the
                     # O(groups)→O(active) tick-cost cut that lets one
-                    # process hold tens of thousands of groups (reference
-                    # quiesce.go solves the same scaling axis).
+                    # process hold tens of thousands of groups.  The
+                    # reference's answer on the same axis, quiesce.go,
+                    # rides it: a ``Config.quiesce`` group on the device
+                    # engine is lite too, its idle clock a column of the
+                    # tick kernel, and asleep it gets no message and no
+                    # step-worker turn a tick.
                     if (
                         now_tick - n._seen_tick >= sweep
                         and n.has_pending_requests()

@@ -197,6 +197,14 @@ _HELP = {
     _COORD + "hb_single_total": "heartbeats and responses that took the "
     "per-group message, by cause",
     _COORD + "rows": "groups registered on the engine at the last round",
+    _COORD + "rows_quiesced": "replicas of quiesce groups asleep on this "
+    "host at the last round",
+    _COORD + "quiesce_enters_total": "replicas whose own idle clock "
+    "crossed the quiesce threshold and put them to sleep",
+    _COORD + "quiesce_wakes_total": "sleeping replicas that a message or "
+    "a request woke",
+    "dragonboat_node_scalar_ticks_total": "LOCAL_TICK messages delivered "
+    "to step workers (replicas whose raft clock the host ticks)",
     _COORD + "reads_total": "ReadIndex contexts a leader's coordinator "
     "staged, by origin: the host's own clients' (local) or forwarded by a "
     "follower (remote)",
@@ -1426,6 +1434,8 @@ class CoordObs:
         _COORD + "elections_held_total",
         _COORD + "hb_block_rows_total",
         _COORD + "hb_lite_rows_total",
+        _COORD + "quiesce_enters_total",
+        _COORD + "quiesce_wakes_total",
     )
 
     def __init__(
@@ -1443,7 +1453,7 @@ class CoordObs:
             _COORD + "staged_depth", _COORD + "read_fallbacks_total",
             _COORD + "round_latency_ms", _COORD + "tick_flags_total",
             _COORD + "hb_single_total", _COORD + "rows",
-            _COORD + "reads_total",
+            _COORD + "reads_total", _COORD + "rows_quiesced",
         ))
         for name in self._COUNTERS:
             r.counter_add(name, 0)
@@ -1459,6 +1469,7 @@ class CoordObs:
             r.counter_add(_COORD + "reads_total", 0, {"origin": origin})
         r.gauge_set(_COORD + "staged_depth", 0)
         r.gauge_set(_COORD + "rows", 0)
+        r.gauge_set(_COORD + "rows_quiesced", 0)
         r.histogram_declare(
             _COORD + "round_latency_ms", buckets=LATENCY_BUCKETS_MS
         )
@@ -1481,6 +1492,35 @@ class CoordObs:
             host=self.host,
             gate=gate,
             wait_ms=round(wait_ms, 4),
+        )
+
+    @staticmethod
+    def campaign(cluster_id: int) -> None:
+        """A replica of ``cluster_id`` on some NodeHost of this process
+        became a candidate (``quiesce_wake``'s ``elected``)."""
+        _CAMPAIGNS[cluster_id] = time.perf_counter()
+
+    def quiesce_wake(self, trace, events) -> dict:
+        """A SAMPLED request that found its group asleep reached its end
+        (``Tracer.finish``; ``trace.woke`` is the instant and the role of
+        the replica its step woke, the first of the group to wake): one
+        ``quiesce_wake`` span from that wake to the operation's commit (a
+        write: its last ``device_round`` stamp) or confirmation (a read:
+        ``read_confirm``), or to its end where it has no such stamp
+        (``events`` are its stamps in time order).  ``elected``: a
+        replica of the group on a NodeHost of this process campaigned
+        between the two."""
+        t0, role = trace.woke
+        stage = "device_round" if trace.kind == "write" else "read_confirm"
+        t1 = next(
+            (t for s, t, _th in reversed(events) if s == stage and t >= t0),
+            events[-1][1],
+        )
+        return self.recorder.record(
+            "quiesce_wake", t0=t0, t1=t1, host=self.host,
+            wake_ms=round((t1 - t0) * 1e3, 4), op=trace.kind,
+            woke=role, elected=t0 <= _CAMPAIGNS.get(trace.cluster_id, -1.0) <= t1,
+            cluster_id=trace.cluster_id, outcome=trace.outcome, tid=trace.tid,
         )
 
     def read_ctx(self, rc: ReadCtx) -> dict:
@@ -1630,6 +1670,13 @@ class CoordObs:
             extra["hb_single"] = single
             extra["rows"] = plane["rows"]
             r.gauge_set(_COORD + "rows", plane["rows"])
+            if "rows_quiesced" in plane:  # a host with a quiesce group
+                for key in ("quiesce_enters", "quiesce_wakes"):
+                    if plane[key]:
+                        r.counter_add(_COORD + key + "_total", plane[key])
+                    extra[key] = plane[key]
+                extra["rows_quiesced"] = plane["rows_quiesced"]
+                r.gauge_set(_COORD + "rows_quiesced", plane["rows_quiesced"])
         if fan_in is not None:
             for origin in ("local", "remote"):
                 if fan_in["reads_" + origin]:
@@ -1665,6 +1712,9 @@ SNAPSHOT_KINDS = ("periodic", "requested", "stream")
 REPLICA_SECONDS_KEEP = 900
 
 _LIVE_REPLICA_OBS: list = []
+#: cluster id -> ``perf_counter`` of the newest campaign any replica of the
+#: group on a NodeHost of this process started (``CoordObs.campaign``)
+_CAMPAIGNS: dict = {}
 
 
 def replica_obs_live() -> list:
@@ -1773,6 +1823,7 @@ class ReplicaObs:
         _SNAP + "pool_busy_seconds_total",
         _CHECKQ + "windows_total",
         _CHECKQ + "stepdowns_total",
+        "dragonboat_node_scalar_ticks_total",
     )
 
     def __init__(
@@ -1832,7 +1883,8 @@ class ReplicaObs:
         """name -> count over the whole seconds whose middle lies in
         ``[lo, hi)`` on ``perf_counter``: ``saves``, ``saves_refused``,
         ``compactions``, ``installs_sent``, ``installs_received``,
-        ``pool_busy_s``, ``checkq_windows``, ``checkq_stepdowns``."""
+        ``pool_busy_s``, ``checkq_windows``, ``checkq_stepdowns``,
+        ``scalar_ticks``."""
         out: dict = {}
         with self._mu:
             for sec, bucket in self._secs.items():
@@ -1911,6 +1963,17 @@ class ReplicaObs:
                 end = min(t1, sec + 1.0)
                 self._add_locked(sec, "pool_busy_s", end - t0)
                 t0, sec = end, sec + 1
+
+    # ---- scalar ticks (node.py, under raftMu) -------------------------
+
+    def scalar_ticks(self, n: int) -> None:
+        """A step worker's turn took ``n`` LOCAL_TICK messages off a
+        replica's queue: what a replica costs the host a tick where its
+        raft clock is the host's (``window``: ``scalar_ticks``)."""
+        self.registry.counter_add("dragonboat_node_scalar_ticks_total", n)
+        sec = int(time.perf_counter())
+        with self._mu:
+            self._add_locked(sec, "scalar_ticks", n)
 
     # ---- check-quorum (node.py, under raftMu) -------------------------
 

@@ -83,6 +83,10 @@ ANNOTATIONS = {
                                          # (its sm_save_ms and commit_ms
                                          # are self time of this event)
     "compact": "dbtpu:compact",          # snapshot_save compact_ms
+    "quiesce_wake": "dbtpu:quiesce_wake",  # the instant a message or a
+                                         # request woke a sleeping
+                                         # replica (a quiesce_wake span
+                                         # starts at its group's first)
 }
 
 #: what ``with (obs.phase(...) if obs is not None else OFF):`` enters while

@@ -135,7 +135,7 @@ class Trace:
     __slots__ = (
         "tracer", "tid", "kind", "cluster_id", "key", "t0",
         "events", "spans", "outcome", "stalled", "done",
-        "applied", "_round_ev", "repl", "read_ctx", "read_origin",
+        "applied", "_round_ev", "repl", "read_ctx", "read_origin", "woke",
     )
 
     def __init__(self, tracer: "Tracer", tid: int, kind: str,
@@ -164,6 +164,11 @@ class Trace:
         # span is joined by
         self.read_ctx: Optional[tuple] = None
         self.read_origin: Optional[str] = None
+        # a request that found its group asleep (ISSUE 44): ``(instant,
+        # role)`` of the replica it woke, the first its step reached;
+        # at its end the tracer's ``wake_sink`` writes a ``quiesce_wake``
+        # span from it
+        self.woke: Optional[tuple] = None
 
     def add(self, stage: str) -> None:
         self.events.append([stage, time.perf_counter(), _tname()])
@@ -290,6 +295,10 @@ class Tracer:
         # leader-side attribution plane — both wired by NodeHost
         self.host = ""
         self.replattr = None
+        # where a finished request that woke its group is written
+        # (``CoordObs.quiesce_wake``; wired by NodeHost on the device
+        # quorum engine, None otherwise)
+        self.wake_sink = None
         # follower-leg records: a sampled REPLICATE from ANOTHER host's
         # leader stamped its stages here; the ack-send hook files the
         # completed leg so dump_trace renders the follower half of the
@@ -483,6 +492,18 @@ class Tracer:
             t = bk.get(e.key)
             if t is not None and not t.done:
                 t.add(stage)
+
+    def mark_woke(self, entries, role: str) -> None:
+        """The step that took ``entries`` woke its sleeping replica
+        (``role``): noted on their sampled requests (``Trace.woke``)."""
+        bk = self._by_key
+        if not bk:
+            return
+        now = time.perf_counter()
+        for e in entries:
+            t = bk.get(e.key)
+            if t is not None and not t.done and t.woke is None:
+                t.woke = (now, role)
 
     def mark_updates(self, updates, stage: str) -> None:
         """Stamp every sampled entry carried by a persisted update batch
@@ -679,6 +700,8 @@ class Tracer:
         self._fin_q.append(
             (evs[-1][1], trace.t0, trace.kind, outcome.upper(), evs)
         )
+        if trace.woke is not None and self.wake_sink is not None:
+            self.wake_sink(trace, evs)
         if idle:
             # the last in-flight trace just completed: flush now so a
             # quiet scrape (or a test right after the load) sees it —

@@ -42,6 +42,9 @@ from . import packed as _pk
 from .kernels import TELEM_TOPK
 from .sharding import block_sharding
 from .state import (
+    QUIESCE_FIELDS,
+    QUIESCE_MARK_SLEEP,
+    QUIESCE_MARK_WAKE,
     CANDIDATE,
     FOLLOWER,
     KV_ENT_SLOTS,
@@ -307,7 +310,7 @@ class StepResult:
     engine's ``committed_view`` and never pay the per-row dict build."""
 
     __slots__ = (
-        "won", "lost", "elect", "heartbeat", "demote",
+        "won", "lost", "elect", "heartbeat", "demote", "quiesce",
         "_commit_cids", "_commit_abs", "_commit_dict",
         "read_cids", "read_slots", "read_index_abs", "read_counts",
         "_reads_list",
@@ -324,6 +327,9 @@ class StepResult:
         self.elect: List[int] = []
         self.heartbeat: List[int] = []
         self.demote: List[int] = []
+        # groups whose idle clock crossed its threshold: asleep from this
+        # dispatch on (``TickFlags.quiesce_enter``)
+        self.quiesce: List[int] = []
         # confirmed-read egress, vectorized (None when the dispatch ran
         # read-free): per confirmed pending-read slot, the cluster, the
         # slot, the ABSOLUTE release index, and how many client reads
@@ -542,7 +548,7 @@ class BatchedQuorumEngine:
         #: the slot counts the layout rule needs besides G and P
         self._dims = (n_read_slots, n_kv_slots, n_kv_ents, n_kv_reads)
         # --- the packed carry -------------------------------------------
-        # Between steps the 31 leaves live as two blocks
+        # Between steps the 34 leaves live as two blocks
         # (state.StateBlocks): a step retires the blocks and one egress
         # block, not ~90 arrays, each of which cost the round thread a
         # hand-off of the interpreter.  ``dev`` unpacks on demand;
@@ -715,6 +721,18 @@ class BatchedQuorumEngine:
         # flip compiles each variant's has_telem=True twin on next use
         # (the late-devsm precedent).
         self._telem_used = False
+        # --- a group's sleep (Config.quiesce, ISSUE 44) ------------------
+        # LATCH, same contract as _hier_used: until ``enable_quiesce``
+        # flips it the three idle columns are provably at their reset
+        # values, every dispatch runs has_quiesce=False (the program set
+        # of an engine with no quiesce group is the one built without the
+        # columns' arithmetic) and the row syncs skip them (_sync_keys).
+        # The coordinator flips it at the first quiesce group's
+        # registration; a warm-up under way or done starts over, so the
+        # live path never meets an unwarmed has_quiesce twin.
+        self._quiesce_used = False
+        self._warm_gen = 0
+        self._warm_args = None
         # static top-K width of the fold's drill-down egress; changing it
         # after programs compiled recompiles them, so it is ctor/enable
         # time configuration, not a per-dispatch knob
@@ -879,6 +897,33 @@ class BatchedQuorumEngine:
     def telem_enabled(self) -> bool:
         return self._telem_used
 
+    def enable_quiesce(self) -> None:
+        """Flip the quiesce latch (one-way, idempotent): from the next
+        dispatch on the programs carry ``has_quiesce`` (the tick kernel's
+        idle clocks, the sleep / wake marks on the reserved last peer
+        slot of the ack plane).  A warm-up that has started, or finished,
+        compiled the other program set: it is abandoned and started over
+        with the same arguments, and ``fused_ready`` is False until the
+        new set is compiled."""
+        with self._warmup_mu:
+            if self._quiesce_used:
+                return
+            self._quiesce_used = True
+            args = self._warm_args
+            rewarm = args is not None and (
+                self._warmup_thread is not None or self._fused_ready.is_set()
+            )
+            if rewarm:
+                self._warm_gen += 1  # the thread under way gives up
+                self._warmup_thread = None
+                self._fused_ready.clear()
+        if rewarm:
+            self.warmup_fused(*args)
+
+    @property
+    def quiesce_enabled(self) -> bool:
+        return self._quiesce_used
+
     def telem_snapshot(self) -> dict | None:
         """The last harvested telemetry aggregate, or None before the
         first telem-carrying harvest (or while the plane is off).
@@ -991,15 +1036,21 @@ class BatchedQuorumEngine:
         with self._warmup_mu:
             if self._warmup_thread is not None or self._fused_ready.is_set():
                 return self._warmup_thread
+            # (what ``enable_quiesce`` starts over with)
+            self._warm_args = (
+                tuple(k_buckets), include_reads, include_single, background,
+                include_kv,
+            )
+            gen = self._warm_gen
             if background:
                 t = threading.Thread(
-                    target=self._warmup_main, args=args,
+                    target=self._warmup_main, args=args + (gen,),
                     name="engine-warmup", daemon=True,
                 )
                 self._warmup_thread = t
                 t.start()
                 return t
-        self._warmup_main(*args)
+        self._warmup_main(*args, gen)
         return self.warmup_stats
 
     def warmup_devsm(self, k_buckets=WARM_K_BUCKETS, background: bool = True):
@@ -1129,7 +1180,8 @@ class BatchedQuorumEngine:
         self._warmup_cancel.set()
 
     def _warmup_main(
-        self, k_buckets, include_reads, include_single, include_kv=False
+        self, k_buckets, include_reads, include_single, include_kv=False,
+        gen: int = 0,
     ) -> None:
         t0 = time.perf_counter()
         try:
@@ -1157,6 +1209,8 @@ class BatchedQuorumEngine:
                 if self._warmup_cancel.is_set():
                     self.warmup_stats["error"] = "cancelled"
                     return
+                if gen != self._warm_gen:
+                    return  # enable_quiesce started the warm-up over
                 tv = time.perf_counter()
                 scratch = self._warm_one(scratch, kind, a, hr, kv)
                 dt_s = time.perf_counter() - tv
@@ -1167,10 +1221,13 @@ class BatchedQuorumEngine:
                         variant=self.variant_label(kind, a, hr, kv),
                         seconds=dt_s,
                     )
-            self.warmup_stats["seconds"] = time.perf_counter() - t0
-            self.warmup_stats["cache_hits"] = _CC["hits"] - hits0
-            self.warmup_stats["cache_misses"] = _CC["misses"] - miss0
-            self._fused_ready.set()
+            with self._warmup_mu:
+                if gen != self._warm_gen:
+                    return
+                self.warmup_stats["seconds"] = time.perf_counter() - t0
+                self.warmup_stats["cache_hits"] = _CC["hits"] - hits0
+                self.warmup_stats["cache_misses"] = _CC["misses"] - miss0
+                self._fused_ready.set()
             if include_kv:
                 self._kv_fused_ready.set()
             elog.info(
@@ -1256,6 +1313,7 @@ class BatchedQuorumEngine:
             has_hier=self._hier_used,
             has_telem=self._telem_used,
             telem_k=self.n_telem_topk,
+            has_quiesce=self._quiesce_used,
         )
 
     def _fold_hints(self) -> dict:
@@ -1389,15 +1447,22 @@ class BatchedQuorumEngine:
         check_quorum: bool = False,
         witnesses: Tuple[int, ...] = (),
         observers: Tuple[int, ...] = (),
+        quiesce_threshold: int = 0,
     ) -> GroupInfo:
+        """``quiesce_threshold`` > 0 (``enable_quiesce`` first): the row
+        goes to sleep after that many ticks without activity; its sleep /
+        wake marks ride the LAST peer slot of the ack plane, which such a
+        group's members therefore leave free."""
         if cluster_id in self.groups:
             raise ValueError(f"group {cluster_id} already registered")
         if not self._free:
             raise RuntimeError("quorum engine full")
-        row = self._free.pop()
         all_ids = sorted(set(node_ids) | set(witnesses) | set(observers))
-        if len(all_ids) > self.n_peers:
+        if quiesce_threshold > 0 and not self._quiesce_used:
+            raise ValueError("quiesce row on an engine without enable_quiesce")
+        if len(all_ids) > self.n_peers - (1 if quiesce_threshold > 0 else 0):
             raise ValueError("too many peers for tensor width")
+        row = self._free.pop()
         slots = {nid: i for i, nid in enumerate(all_ids)}
         gi = GroupInfo(
             cluster_id, row, slots, node_ids=all_ids,
@@ -1446,8 +1511,32 @@ class BatchedQuorumEngine:
         if self._hier_used:  # else provably already clear
             a["near"][row, :] = False
             a["sub_quorum"][row] = 0
+        if self._quiesce_used:  # else provably already clear
+            a["quiesce_threshold"][row] = max(int(quiesce_threshold), 0)
+            self._wake_mirror_row(row)
         self._dirty.add(row)
         return gi
+
+    def _wake_mirror_row(self, row: int) -> None:
+        """A row (re)built or moved by a transition is awake with a fresh
+        idle clock: whatever caused it was activity."""
+        a = self.mirror.arrays
+        a["idle_tick"][row] = 0
+        a["quiesced"][row] = False
+
+    def quiesce_mark(self, cluster_id: int, wake: bool) -> None:
+        """Stage a quiesce row's mark for the next dispatch: ``wake`` (any
+        activity: the idle clock restarts, a sleeping row wakes with both
+        raft clocks at zero) or sleep (a peer's QUIESCE).  It rides the
+        ack plane on the reserved last peer slot, so it is ordered, epoch
+        filtered and dispatched with the round's acknowledgements; where
+        both are staged for one round the wake wins."""
+        gi = self.groups[cluster_id]
+        self._acks.append((
+            gi.row, self.n_peers - 1,
+            QUIESCE_MARK_WAKE if wake else QUIESCE_MARK_SLEEP,
+            int(self._row_epoch[gi.row]),
+        ))
 
     def _purge_row_events(self, row: int) -> None:
         """Invalidate queued acks/votes for a row.  Called on every state
@@ -1584,6 +1673,8 @@ class BatchedQuorumEngine:
         a["next"][row, :] = self._rel(gi, last_index) + 1
         a["match"][row, a["self_slot"][row]] = self._rel(gi, last_index)
         a["active"][row, :] = False
+        if self._quiesce_used:
+            self._wake_mirror_row(row)
         self._purge_row_events(row)
         self._dirty.add(row)
 
@@ -1625,6 +1716,8 @@ class BatchedQuorumEngine:
         a["term"][row] = term
         a["votes"][row, :] = VOTE_NONE
         a["election_tick"][row] = 0
+        if self._quiesce_used:
+            self._wake_mirror_row(row)
         self._purge_row_events(row)
         self._dirty.add(row)
 
@@ -1637,6 +1730,8 @@ class BatchedQuorumEngine:
         a["term"][row] = term
         a["votes"][row, :] = VOTE_NONE
         a["election_tick"][row] = 0
+        if self._quiesce_used:
+            self._wake_mirror_row(row)
         self._purge_row_events(row)
         self._dirty.add(row)
 
@@ -2684,7 +2779,8 @@ class BatchedQuorumEngine:
             if self._devsm_used:
                 self._kv_free_applied()
             res.commit_rows = self._translate_egress(
-                res, committed, prev_committed, row_cid, row_base, bits
+                res, committed, prev_committed, row_cid, row_base, bits,
+                self._flag_bits(),
             )
         if obs is not None and span is not None:
             obs.egress(
@@ -2718,15 +2814,23 @@ class BatchedQuorumEngine:
         self._retired += (egress,)
         return eg
 
+    def _flag_bits(self) -> tuple:
+        """The egress bits this engine's programs can raise: the sleep
+        bit, the last, only with the quiesce latch up (each bit decoded is
+        a numpy pass on the round thread, whatever it finds)."""
+        return _pk.FLAG_BITS if self._quiesce_used else _pk.FLAG_BITS[:-1]
+
     @staticmethod
     def _translate_egress(
-        res, committed, prev_committed, row_cid, row_base, bits
+        res, committed, prev_committed, row_cid, row_base, bits,
+        flags=_pk.FLAG_BITS,
     ) -> np.ndarray:
         """Vectorized row→cluster egress translation, shared by step()'s
         single-round path and the fused harvest: watermark deltas become
         (cid, abs) arrays (dead rows — cid -1 — dropped; the commit dict
-        materializes lazily), the flag bit field (``_pk.FLAG_BITS``)
-        becomes cid lists.  Returns the changed-row index vector."""
+        materializes lazily), the flag bit field (``flags``, low bit
+        first: ``_flag_bits``) becomes cid lists.  Returns the
+        changed-row index vector."""
         changed = np.nonzero(committed != prev_committed)[0]
         if changed.size:
             cids = row_cid[changed]
@@ -2734,7 +2838,7 @@ class BatchedQuorumEngine:
             res._commit_cids = cids[live]
             res._commit_abs = (row_base[changed] + committed[changed])[live]
         if bits.any():
-            for i, name in enumerate(_pk.FLAG_BITS):
+            for i, name in enumerate(flags):
                 idx = np.nonzero(bits & (1 << i))[0]
                 if idx.size:
                     cids = row_cid[idx]
@@ -3087,6 +3191,7 @@ class BatchedQuorumEngine:
     _KV_KEYS = ("kv_value", "kv_ent_index", "kv_ent_key", "kv_ent_val")
     _HIER_KEYS = ("near", "sub_quorum")
     _TELEM_KEYS = ("telem_prev_committed",)
+    _QUIESCE_KEYS = QUIESCE_FIELDS
 
     def _sync_keys(self, read_plane: Optional[bool] = None):
         """Mirror fields the rare-path row syncs move between host and
@@ -3106,6 +3211,8 @@ class BatchedQuorumEngine:
             skip += self._HIER_KEYS
         if not self._telem_used:
             skip += self._TELEM_KEYS
+        if not self._quiesce_used:
+            skip += self._QUIESCE_KEYS
         if not skip:
             return list(self.mirror.arrays)
         return [k for k in self.mirror.arrays if k not in skip]
@@ -3384,7 +3491,7 @@ class BatchedQuorumEngine:
                 self._kv_free_applied()
             changed = self._translate_egress(
                 res, committed, prev_committed, self._row_cid,
-                self._row_base, bits,
+                self._row_base, bits, self._flag_bits(),
             )
         if obs is not None:
             obs.egress(

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from .state import (
+    QUIESCE_MARK_SLEEP, QUIESCE_MARK_WAKE,
     CANDIDATE,
     INDEX_MIN,
     LEADER,
@@ -180,6 +181,9 @@ class TickFlags(NamedTuple):
     elect_due: jax.Array    # (G,) bool — non-leader election timeout fired
     hb_due: jax.Array       # (G,) bool — leader heartbeat due
     checkq_demote: jax.Array  # (G,) bool — CheckQuorum failed, leader must step down
+    # (G,) bool — the row's idle clock crossed its threshold on this tick
+    # and the row went to sleep (all False without ``has_quiesce``)
+    quiesce_enter: jax.Array
 
 
 # Device telemetry fold (ISSUE 20).  Every aggregate shape is STATIC, so
@@ -470,15 +474,65 @@ def _kv_plane(
     return st, read_val, read_idx, applied
 
 
-def tick_step(st: QuorumState) -> tuple[QuorumState, TickFlags]:
+def quiesce_marks(
+    st: QuorumState, mark: jax.Array, election_tick: jax.Array
+) -> tuple[QuorumState, jax.Array]:
+    """Apply a round's sleep / wake marks to the quiesce rows (twin:
+    ``QuiesceManager.record_activity`` / ``try_enter_quiesce``).
+
+    ``mark`` is ``(G,)``: what the round staged on the reserved last peer
+    slot of the ack plane (0 nothing, ``QUIESCE_MARK_SLEEP`` a peer's
+    QUIESCE, ``QUIESCE_MARK_WAKE`` any activity; the greater won the
+    round).  Activity resets the idle clock, and on a sleeping row ends
+    the sleep and starts BOTH raft clocks from the wake: a follower
+    counted ``election_tick`` all through its sleep
+    (``Raft.quiesced_tick``) and would otherwise campaign against a live
+    leader on its first ordinary tick; a leader's check-quorum window
+    restarts with its first heartbeat.  Returns the state and the
+    election clock (the caller's, which ``track_contact`` may have reset
+    already)."""
+    qon = st.live & (st.quiesce_threshold > 0)
+    wake = qon & (mark >= QUIESCE_MARK_WAKE)
+    sleep = qon & (mark == QUIESCE_MARK_SLEEP) & ~st.quiesced
+    election_tick = jnp.where(wake & st.quiesced, 0, election_tick)
+    st = st._replace(
+        idle_tick=jnp.where(wake | sleep, 0, st.idle_tick),
+        quiesced=(st.quiesced | sleep) & ~wake,
+    )
+    return st, election_tick
+
+
+def tick_step(
+    st: QuorumState, has_quiesce: bool = False
+) -> tuple[QuorumState, TickFlags]:
     """Advance per-group clocks one tick (twin: ``Raft.tick``).
 
     Emits *flags* for the rare follow-ups (campaign, heartbeat broadcast,
     leader step-down) which the host executes scalar-side; the dense
     counter arithmetic and CheckQuorum activity scan stay on device.
+
+    ``has_quiesce`` (static) adds a group's sleep (twin:
+    ``QuiesceManager.increase_quiesce_tick`` followed by ``Node._tick``'s
+    choice of ``Raft.quiesced_tick``): an awake quiesce row's idle clock
+    advances and, past the row's threshold, the row goes to sleep and
+    raises ``quiesce_enter`` once; a sleeping row (the tick that put it to
+    sleep included) only counts ``election_tick``: no election-due, no
+    check-quorum window, no heartbeat.  Without it the idle columns pass
+    through untouched and the program is the one built before they
+    existed.
     """
     live = st.live
     is_leader = (st.node_state == LEADER) & live
+    if has_quiesce:
+        awake = live & (st.quiesce_threshold > 0) & ~st.quiesced
+        idle_tick = jnp.where(awake, st.idle_tick + 1, st.idle_tick)
+        quiesce_enter = awake & (idle_tick > st.quiesce_threshold)
+        quiesced = st.quiesced | quiesce_enter
+        ticking = ~quiesced  # a sleeping row fires nothing
+        st = st._replace(idle_tick=idle_tick, quiesced=quiesced)
+    else:
+        quiesce_enter = jnp.zeros_like(live)
+        ticking = True
 
     election_tick = jnp.where(live, st.election_tick + 1, st.election_tick)
 
@@ -488,9 +542,10 @@ def tick_step(st: QuorumState) -> tuple[QuorumState, TickFlags]:
         & ~is_leader
         & st.electable
         & (election_tick >= st.rand_timeout)
+        & ticking
     )
     # leader: CheckQuorum window (raft.go:594-623)
-    checkq_due = is_leader & (election_tick >= st.election_timeout)
+    checkq_due = is_leader & (election_tick >= st.election_timeout) & ticking
     election_tick = jnp.where(elect_due | checkq_due, 0, election_tick)
 
     has_q, cleared_active = check_quorum(
@@ -506,8 +561,9 @@ def tick_step(st: QuorumState) -> tuple[QuorumState, TickFlags]:
     del has_q  # advisory only; the scalar re-check decides
     active = jnp.where(run_checkq[:, None], cleared_active, st.active)
 
-    heartbeat_tick = jnp.where(is_leader, st.heartbeat_tick + 1, st.heartbeat_tick)
-    hb_due = is_leader & (heartbeat_tick >= st.heartbeat_timeout)
+    hb_ticks = is_leader & ticking
+    heartbeat_tick = jnp.where(hb_ticks, st.heartbeat_tick + 1, st.heartbeat_tick)
+    hb_due = hb_ticks & (heartbeat_tick >= st.heartbeat_timeout)
     heartbeat_tick = jnp.where(hb_due, 0, heartbeat_tick)
 
     st = st._replace(
@@ -515,7 +571,7 @@ def tick_step(st: QuorumState) -> tuple[QuorumState, TickFlags]:
         heartbeat_tick=heartbeat_tick,
         active=active,
     )
-    return st, TickFlags(elect_due, hb_due, checkq_demote)
+    return st, TickFlags(elect_due, hb_due, checkq_demote, quiesce_enter)
 
 
 def quorum_step_impl(
@@ -536,6 +592,7 @@ def quorum_step_impl(
     telem_k: int = TELEM_TOPK,
     has_reads: bool = False,
     has_kv: bool = False,
+    has_quiesce: bool = False,
 ) -> StepOutputs:
     """ONE fused dispatch for a whole engine round (SURVEY.md §7).
 
@@ -552,6 +609,16 @@ def quorum_step_impl(
     g_total = st.term.shape[0]
     # route invalid events out of bounds; XLA drops them
     ag = jnp.where(ack_valid, ack_g, g_total)
+    mark = None
+    if has_quiesce:
+        # events on the reserved last peer slot are a quiesce row's sleep
+        # / wake marks, no acknowledgements: taken out of the ack lists
+        is_mark = ack_valid & (ack_p == st.match.shape[1] - 1)
+        mark = (
+            jnp.zeros((g_total + 1,), I32)
+            .at[jnp.where(is_mark, ack_g, g_total)].max(ack_val)[:g_total]
+        )
+        ag = jnp.where(is_mark, g_total, ag)
 
     # --- ack ingestion (twin: handleLeaderReplicateResp raft.go:1671) ---
     match = st.match.at[ag, ack_p].max(ack_val, mode="drop")
@@ -602,7 +669,7 @@ def quorum_step_impl(
 
     out = _finish_step(
         st, match, next_, active, votes, election_tick, last_index, do_tick,
-        has_hier=has_hier,
+        has_hier=has_hier, mark=mark,
     )
     if has_telem:
         # has_reads/has_kv carry no event planes on this path — they are
@@ -627,9 +694,15 @@ def _finish_step(
     last_index: jax.Array,
     do_tick: bool,
     has_hier: bool = False,
+    mark: jax.Array | None = None,
 ) -> StepOutputs:
     """Tally/commit/tick tail shared by the sparse and dense steps — the
-    ingestion front-ends differ, the raft semantics must not."""
+    ingestion front-ends differ, the raft semantics must not.  ``mark``
+    (``has_quiesce`` programs only) is the round's sleep / wake marks,
+    applied before the tick as a step handles its messages before its
+    ticks."""
+    if mark is not None:
+        st, election_tick = quiesce_marks(st, mark, election_tick)
     # --- election tally (twin: handleVoteResp / campaign) ---------------
     granted, rejected = vote_tally(votes, st.voting, st.quorum)
     is_cand = (st.node_state == CANDIDATE) & st.live
@@ -666,10 +739,10 @@ def _finish_step(
     )
 
     if do_tick:
-        st, flags = tick_step(st)
+        st, flags = tick_step(st, has_quiesce=mark is not None)
     else:
         zeros = jnp.zeros_like(won)
-        flags = TickFlags(zeros, zeros, zeros)
+        flags = TickFlags(zeros, zeros, zeros, zeros)
 
     return StepOutputs(st, committed, won, lost, flags)
 
@@ -692,7 +765,7 @@ quorum_step = jax.jit(
     _entry(quorum_step_impl, "quorum_step_unpacked"),
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_hier", "has_telem",
-        "telem_k", "has_reads", "has_kv",
+        "telem_k", "has_reads", "has_kv", "has_quiesce",
     ),
     donate_argnums=(0,),
 )
@@ -718,6 +791,7 @@ def quorum_step_dense_impl(
     has_hier: bool = False,
     has_telem: bool = False,
     telem_k: int = TELEM_TOPK,
+    has_quiesce: bool = False,
 ) -> StepOutputs:
     """Dense-ingestion twin of :func:`quorum_step_impl` — zero scatters.
 
@@ -737,6 +811,14 @@ def quorum_step_dense_impl(
     events (engine.vote dedups within a batch, the kernel guards against
     standing votes).
     """
+    mark = None
+    if has_quiesce:
+        # the reserved last peer slot holds marks, no acknowledgements
+        # (see quorum_step_impl)
+        mark = jnp.where(ack_touched[:, -1], ack_max[:, -1], 0)
+        ack_touched = ack_touched & (
+            jnp.arange(ack_touched.shape[1]) < ack_touched.shape[1] - 1
+        )
     # --- ack ingestion ---------------------------------------------------
     match = jnp.maximum(st.match, jnp.where(ack_touched, ack_max, 0))
     # next >= match + 1 invariant (see quorum_step_impl)
@@ -763,7 +845,7 @@ def quorum_step_dense_impl(
 
     out = _finish_step(
         st, match, next_, active, votes, election_tick, last_index, do_tick,
-        has_hier=has_hier,
+        has_hier=has_hier, mark=mark,
     )
     if has_reads:
         # read plane LAST: stage / echo ingest / confirm / release
@@ -808,7 +890,7 @@ quorum_step_dense = jax.jit(
     _entry(quorum_step_dense_impl, "quorum_step_dense_unpacked"),
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_reads", "has_kv",
-        "has_hier", "has_telem", "telem_k",
+        "has_hier", "has_telem", "telem_k", "has_quiesce",
     ),
     donate_argnums=(0,),
 )
@@ -932,6 +1014,7 @@ def quorum_multiround_impl(
     has_telem: bool = False,
     purge_telem: bool = True,
     telem_k: int = TELEM_TOPK,
+    has_quiesce: bool = False,
 ) -> StepOutputs:
     """K engine rounds — INCLUDING membership churn — in ONE dispatch.
 
@@ -1049,18 +1132,19 @@ def quorum_multiround_impl(
             has_reads=has_reads,
             has_kv=has_kv,
             has_hier=has_hier,
+            has_quiesce=has_quiesce,
         )
         stc = out.state
         if do_tick:
             tm = ev[i]  # () bool — this round's tick decision
-            ticked, tflags = tick_step(stc)
+            ticked, tflags = tick_step(stc, has_quiesce=has_quiesce)
             stc = QuorumState(
                 *(jnp.where(tm, t, o) for t, o in zip(ticked, stc))
             )
             flags = TickFlags(*(f & tm for f in tflags))
         else:
             zeros = jnp.zeros_like(out.won)
-            flags = TickFlags(zeros, zeros, zeros)
+            flags = TickFlags(zeros, zeros, zeros, zeros)
         carry = (stc,)
         if has_reads:
             carry = carry + (
@@ -1144,7 +1228,7 @@ quorum_multiround = jax.jit(
     static_argnames=(
         "do_tick", "track_contact", "has_votes", "has_churn", "has_reads",
         "purge_reads", "has_kv", "purge_kv", "has_hier", "has_telem",
-        "purge_telem", "telem_k",
+        "purge_telem", "telem_k", "has_quiesce",
     ),
     donate_argnums=(0,),
 )

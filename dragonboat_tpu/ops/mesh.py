@@ -270,6 +270,10 @@ class MeshQuorumEngine:
     # group lifecycle
     # ------------------------------------------------------------------
 
+    #: a mesh facade's shards have no idle columns wired: a quiesce group
+    #: keeps scalar ticks here (``TpuQuorumCoordinator.quiesce_on_device``)
+    quiesce_enabled = False
+
     def add_group(
         self,
         cluster_id: int,

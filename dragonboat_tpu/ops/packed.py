@@ -8,7 +8,7 @@ what crosses the host/device boundary of one dispatch is held to:
 * ONE ingress block: everything the dispatch stages, in one flat ``int32``
   host buffer (:class:`Ingress`) whose sections the program slices;
 * ONE egress block: ``(rows, G)`` ``int32`` — the commit watermark, the
-  five flag vectors as one bit field, and the read / kv captures of the
+  six flag vectors as one bit field, and the read / kv captures of the
   planes in use as further rows.
 
 The layout is one rule (:func:`ingress_sections`, :func:`split_egress`)
@@ -35,7 +35,7 @@ from .state import (
 )
 
 #: the egress bit field, low bit first (``StepResult`` field of each)
-FLAG_BITS = ("won", "lost", "elect", "heartbeat", "demote")
+FLAG_BITS = ("won", "lost", "elect", "heartbeat", "demote", "quiesce")
 
 
 class PackedOut(NamedTuple):
@@ -223,7 +223,7 @@ def _step(
     do_tick: bool = True, track_contact: bool = True, has_votes: bool = True,
     has_hier: bool = False, has_telem: bool = False,
     telem_k: int = _k.TELEM_TOPK, has_reads: bool = False,
-    has_kv: bool = False,
+    has_kv: bool = False, has_quiesce: bool = False,
 ) -> PackedOut:
     g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
@@ -233,7 +233,7 @@ def _step(
         *sparse_events(sec, cap, has_votes),
         do_tick=do_tick, track_contact=track_contact, has_votes=has_votes,
         has_hier=has_hier, has_telem=has_telem, telem_k=telem_k,
-        has_reads=has_reads, has_kv=has_kv,
+        has_reads=has_reads, has_kv=has_kv, has_quiesce=has_quiesce,
     ))
 
 
@@ -243,6 +243,7 @@ def _step_dense(
     do_tick: bool = True, track_contact: bool = True, has_votes: bool = True,
     has_reads: bool = False, has_kv: bool = False, has_hier: bool = False,
     has_telem: bool = False, telem_k: int = _k.TELEM_TOPK,
+    has_quiesce: bool = False,
 ) -> PackedOut:
     g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
@@ -257,7 +258,7 @@ def _step_dense(
         *_plane_args(sec, p, has_reads, has_kv),
         do_tick=do_tick, track_contact=track_contact, has_votes=has_votes,
         has_reads=has_reads, has_kv=has_kv, has_hier=has_hier,
-        has_telem=has_telem, telem_k=telem_k,
+        has_telem=has_telem, telem_k=telem_k, has_quiesce=has_quiesce,
     ))
 
 
@@ -269,6 +270,7 @@ def _multiround(
     has_reads: bool = False, purge_reads: bool = True, has_kv: bool = False,
     purge_kv: bool = True, has_hier: bool = False, has_telem: bool = False,
     purge_telem: bool = True, telem_k: int = _k.TELEM_TOPK,
+    has_quiesce: bool = False,
 ) -> PackedOut:
     g, p = block_dims(blocks, dims[:3])
     sec = _split(ingress, ingress_sections(
@@ -291,6 +293,7 @@ def _multiround(
         has_churn=has_churn, has_reads=has_reads, purge_reads=purge_reads,
         has_kv=has_kv, purge_kv=purge_kv, has_hier=has_hier,
         has_telem=has_telem, purge_telem=purge_telem, telem_k=telem_k,
+        has_quiesce=has_quiesce,
     ))
 
 
@@ -298,7 +301,7 @@ quorum_step = jax.jit(
     _step,
     static_argnames=(
         "dims", "cap", "do_tick", "track_contact", "has_votes", "has_hier",
-        "has_telem", "telem_k", "has_reads", "has_kv",
+        "has_telem", "telem_k", "has_reads", "has_kv", "has_quiesce",
     ),
     donate_argnums=(0,),
 )
@@ -307,7 +310,7 @@ quorum_step_dense = jax.jit(
     _step_dense,
     static_argnames=(
         "dims", "do_tick", "track_contact", "has_votes", "has_reads",
-        "has_kv", "has_hier", "has_telem", "telem_k",
+        "has_kv", "has_hier", "has_telem", "telem_k", "has_quiesce",
     ),
     donate_argnums=(0,),
 )
@@ -317,7 +320,7 @@ quorum_multiround = jax.jit(
     static_argnames=(
         "dims", "k", "c", "do_tick", "track_contact", "has_votes",
         "has_churn", "has_reads", "purge_reads", "has_kv", "purge_kv",
-        "has_hier", "has_telem", "purge_telem", "telem_k",
+        "has_hier", "has_telem", "purge_telem", "telem_k", "has_quiesce",
     ),
     donate_argnums=(0,),
 )

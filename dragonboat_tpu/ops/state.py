@@ -86,6 +86,17 @@ READ_PLANE_FIELDS = ("read_index", "read_count", "read_acks")
 DEVSM_PLANE_FIELDS = ("kv_value", "kv_ent_index", "kv_ent_key", "kv_ent_val")
 HIER_PLANE_FIELDS = ("near", "sub_quorum")
 TELEM_PLANE_FIELDS = ("telem_prev_committed",)
+# A group's sleep (``Config.quiesce`` on the device tick plane): the row's
+# threshold (0 = the group does not quiesce), its idle clock and its
+# quiesced flag.  Core quorum-plane state for the ledger; the engine's
+# ``_quiesce_used`` latch gates the tick kernel's use of them and the row
+# syncs' (``BatchedQuorumEngine._QUIESCE_KEYS``).
+QUIESCE_FIELDS = ("quiesce_threshold", "idle_tick", "quiesced")
+# Ack-plane marks of a quiesce row (values on the reserved LAST peer slot
+# of the ack plane, ``kernels._quiesce_marks``): a peer's QUIESCE puts the
+# row to sleep, any activity resets its idle clock and wakes it.  The
+# greater wins a round, so a wake beats a sleep staged beside it.
+QUIESCE_MARK_SLEEP, QUIESCE_MARK_WAKE = 1, 2
 
 
 def field_plane(name: str) -> str:
@@ -206,6 +217,17 @@ class QuorumState(NamedTuple):
     # tenant's watermark.
     telem_prev_committed: jax.Array  # (G,) i32 rel
 
+    # --- a group's sleep (Config.quiesce, ISSUE 44) --------------------
+    # Scalar twin: ``quiesce.QuiesceManager`` (``threshold``,
+    # ``current_tick - idle_since``, ``_quiesced``).  The tick kernel
+    # advances the idle clock of an awake quiesce row and puts the row to
+    # sleep when it crosses the threshold; a sleeping row raises no
+    # heartbeat-due and no election-due flag.  Last in the tuple, so every
+    # older leaf keeps its rows in the packed blocks.
+    quiesce_threshold: jax.Array  # (G,) i32: idle ticks before sleep; 0 = off
+    idle_tick: jax.Array          # (G,) i32: ticks since the last activity
+    quiesced: jax.Array           # (G,) bool: the row sleeps
+
 
 def make_state(
     n_groups: int,
@@ -250,14 +272,17 @@ def make_state(
         near=jnp.zeros((g, p), BOOL),
         sub_quorum=zi,
         telem_prev_committed=zi,
+        quiesce_threshold=zi,
+        idle_tick=zi,
+        quiesced=jnp.zeros((g,), BOOL),
     )
 
 
 # ----------------------------------------------------------------------
 # packed carry: the state between two dispatches
 # ----------------------------------------------------------------------
-# A ``QuorumState`` is 31 device arrays, and a step that takes it donated
-# and hands back the next one makes 31 and retires 31: each birth and each
+# A ``QuorumState`` is 34 device arrays, and a step that takes it donated
+# and hands back the next one makes 34 and retires 34: each birth and each
 # death is a hand-off of the interpreter on the round thread.  Between
 # steps the engine therefore holds the same leaves as TWO blocks, one per
 # storage dtype, each ``(rows, G)``: a leaf's axes behind the group axis

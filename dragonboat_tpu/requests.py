@@ -460,6 +460,18 @@ class PendingReadIndex:
                     wire = ReplTrace(tid=t.tid, origin=t.tracer.host)
         return wire
 
+    def trace_woke(self, ctx: SystemCtx, role: str) -> None:
+        """Tracer on: the batch ``ctx`` covers found its group asleep and
+        woke this replica (``role``); noted on its sampled requests
+        (``Trace.woke``: a ``quiesce_wake`` span at their end)."""
+        with self._mu:
+            batch = self._batches.get(ctx, ())
+        now = time.perf_counter()
+        for rs in batch:
+            t = rs.trace
+            if t.__class__ is _trace.Trace and t.woke is None:
+                t.woke = (now, role)
+
     def pending_ctxs(self) -> List[SystemCtx]:
         """Contexts taken for confirmation but not yet ready — after a
         fast-lane eject these must be re-driven through the scalar

@@ -282,6 +282,16 @@ class TpuQuorumCoordinator:
         # follower ingesting tens of thousands of Replicates per second
         # would stage one event slot per message
         self._contacted: set = set()
+        # a group's sleep (``Config.quiesce`` on the device tick plane):
+        # the same dedup for a quiesce group's activity marks (one idle
+        # clock reset a group a round), and the plane's account: replicas
+        # of this host asleep now, own entries, wakes (plain integers,
+        # written under the replicas' raftMu)
+        self._acted: set = set()
+        self.rows_quiesced = 0
+        self.quiesce_enters = 0
+        self.quiesce_wakes = 0
+        self._quiesce_spanned = (0, 0)
         self._pending = threading.Event()
         self._stopped = threading.Event()
         self._interval = interval_s
@@ -486,10 +496,22 @@ class TpuQuorumCoordinator:
     # node lifecycle
     # ------------------------------------------------------------------
 
+    @property
+    def quiesce_on_device(self) -> bool:
+        """Whether a ``Config.quiesce`` group's idle clock and sleep are
+        the tick kernel's here: a ticking coordinator on one device (a
+        mesh facade's shards have no idle columns wired; there such a
+        group keeps scalar ticks and the host-side manager)."""
+        return self.drive_ticks and self.mesh_devices <= 1
+
     def register(self, node: "Node") -> None:
         """Add the node's group and sync its current raft state into the
         row.  Called after Peer.launch with the raft lock held."""
         with self._mu:
+            if getattr(node, "dev_quiesce", False):
+                # the first quiesce group flips the engine's latch (and
+                # starts a warm-up under way over: has_quiesce programs)
+                self.eng.enable_quiesce()
             self._nodes[node.cluster_id] = node
             self._sync_row_locked(node)
             if self.drive_reads:
@@ -501,7 +523,9 @@ class TpuQuorumCoordinator:
         if self.devsm is not None:
             self.devsm.unregister(cluster_id)
         with self._mu:
-            self._nodes.pop(cluster_id, None)
+            node = self._nodes.pop(cluster_id, None)
+            if node is not None and getattr(node, "_asleep", False):
+                self.rows_quiesced -= 1  # (a stopped replica sleeps no more)
             self._read_pending.pop(cluster_id, None)
             self._read_gone.pop(cluster_id, None)
             if self._read_traces:
@@ -583,6 +607,13 @@ class TpuQuorumCoordinator:
             check_quorum=r.check_quorum,
             witnesses=witnesses,
             observers=observers,
+            **(
+                # (``Soft.quiesce_threshold_factor`` x election ticks,
+                # host-seeded like ``rand_timeout``; a rebuilt row is
+                # awake, as its replica is: what rebuilt it was activity)
+                {"quiesce_threshold": node.quiesce_mgr.threshold}
+                if getattr(node, "dev_quiesce", False) else {}
+            ),
         )
         if r.hier is not None:
             # hier geometry (ISSUE 18) is membership-like: the near mask
@@ -681,6 +712,34 @@ class TpuQuorumCoordinator:
 
     def set_randomized_timeout(self, cluster_id: int, timeout: int) -> None:
         self._stage(("randto", cluster_id, timeout))
+
+    # a group's sleep (called by a quiesce group's node under its raftMu)
+
+    def quiesce_activity(self, cluster_id: int) -> None:
+        """Activity on an AWAKE replica: its row's idle clock restarts
+        (once a round is enough, and a racing duplicate is one more
+        idempotent reset)."""
+        if cluster_id in self._acted:
+            return
+        self._acted.add(cluster_id)
+        self._stage(("qwake", cluster_id))
+
+    def quiesce_woke(self, cluster_id: int) -> None:
+        """A message or a request woke a sleeping replica."""
+        self.rows_quiesced -= 1
+        self.quiesce_wakes += 1
+        with (_annotate("quiesce_wake") if self._obs is not None else _OFF):
+            self._stage(("qwake", cluster_id))
+
+    def quiesce_slept(self, cluster_id: int, own: bool) -> None:
+        """A replica went to sleep: its row's own idle clock crossed
+        (``own``: the row sleeps already) or a peer's QUIESCE said so (the
+        row is put to sleep by the next round)."""
+        self.rows_quiesced += 1
+        if own:
+            self.quiesce_enters += 1
+        else:
+            self._stage(("qsleep", cluster_id))
 
     # ------------------------------------------------------------------
     # batched heartbeat plane
@@ -1001,6 +1060,8 @@ class TpuQuorumCoordinator:
         self._stage(("leader", cluster_id, term, term_start, last_index))
 
     def set_candidate(self, cluster_id: int, term: int) -> None:
+        if self._obs is not None:
+            self._obs.campaign(cluster_id)
         self._stage(("candidate", cluster_id, term))
 
     def set_follower(self, cluster_id: int, term: int) -> None:
@@ -1032,6 +1093,10 @@ class TpuQuorumCoordinator:
         # the dedup set first: a contact that finds the new, empty set is
         # staged (again, at worst), never skipped for an op already taken
         self._contacted = set()
+        self._acted = set()
+        # cid -> wake (True) / sleep: a quiesce row's last mark of this
+        # drain, handed to the engine once the ops are through
+        marks: Dict[int, bool] = {}
         self._round_first_at, self._first_at = self._first_at, None
         staged = self._staged
         ops = [staged.popleft() for _ in range(len(staged))]
@@ -1178,6 +1243,8 @@ class TpuQuorumCoordinator:
                     self.eng.set_follower(cid, term=op[2])
                     if self.devsm is not None:
                         self.devsm.on_unbind(cid)
+                elif kind == "qwake" or kind == "qsleep":
+                    marks[cid] = kind == "qwake"
                 else:  # resync
                     self._read_pending.pop(cid, None)
                     if rt:
@@ -1197,6 +1264,9 @@ class TpuQuorumCoordinator:
                 recover.append(cid)
         if lt is not None and lease_acks:
             lt.note_round(lease_acks, self._tick_seen)
+        for cid, wake in marks.items():
+            if cid in self.eng.groups and cid not in recover:
+                self.eng.quiesce_mark(cid, wake)
         return recover
 
     def _recover_row(self, cluster_id: int) -> None:
@@ -1463,7 +1533,8 @@ class TpuQuorumCoordinator:
                     res.commit.update(extra.commit)
                     self._collect_read_confirms(extra, read_confirms)
                     for field in (
-                        "won", "lost", "elect", "heartbeat", "demote"
+                        "won", "lost", "elect", "heartbeat", "demote",
+                        "quiesce",
                     ):
                         merged = set(getattr(res, field))
                         merged.update(getattr(extra, field))
@@ -1656,6 +1727,14 @@ class TpuQuorumCoordinator:
                     node.offload_tick_demote(**wake_kw)
                     if hp is not None:
                         touched[cid] = node
+            for cid in res.quiesce:
+                # the row went to sleep: the replica's one turn of the
+                # sleep (it tells its peers)
+                node = self._nodes.get(cid)
+                if node is not None:
+                    node.offload_quiesce_enter(**wake_kw)
+                    if hp is not None:
+                        touched[cid] = node
         if hp is not None and touched:
             hp.wake_nodes(touched.values())
         # tag election outcomes with the term the row held when the round
@@ -1707,7 +1786,17 @@ class TpuQuorumCoordinator:
         self._plane_spanned = (
             self.hb_block_rows, self.hb_lite_rows, dict(causes)
         )
+        quiesce = {}
+        if self.eng.quiesce_enabled:
+            enters0, wakes0 = self._quiesce_spanned
+            self._quiesce_spanned = (self.quiesce_enters, self.quiesce_wakes)
+            quiesce = {
+                "rows_quiesced": self.rows_quiesced,
+                "quiesce_enters": self.quiesce_enters - enters0,
+                "quiesce_wakes": self.quiesce_wakes - wakes0,
+            }
         return {
+            **quiesce,
             "ticks_replayed": max(deficit - 1, 0),
             "ticks_dropped": dropped,
             "elect_held": held,

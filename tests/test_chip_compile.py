@@ -130,6 +130,35 @@ def test_live_program_compiles_for_v5e(topo, no_compile_cache, size, variant):
     )
 
 
+#: what ``idle1024x3``'s hosts dispatch: the quiesce latch alone
+QUIESCE_VARIANTS = [
+    ("sparse", True, False, False),
+    ("dense", True, True, False),
+    ("fused", 16, False, False),
+    ("fused", 16, True, False),
+]
+
+
+@pytest.mark.parametrize(
+    "variant", QUIESCE_VARIANTS,
+    ids=[BatchedQuorumEngine.variant_label(*v) for v in QUIESCE_VARIANTS],
+)
+def test_quiesce_program_compiles_for_v5e(topo, no_compile_cache, variant):
+    """The ``has_quiesce`` twins (the tick kernel's idle clocks, the marks
+    taken off the ack plane's last peer slot) at the live size."""
+    groups, peers = SIZES["live"]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    eng = BatchedQuorumEngine(
+        groups, peers, event_cap=max(4 * groups, 4096), device_ticks=True
+    )
+    eng.enable_quiesce()
+    fn, ing, statics = eng._variant_args(*variant, abstract=True)
+    assert statics["has_quiesce"] and not statics["has_telem"]
+    _compile(
+        fn, _blocks(groups, peers, one_chip), (_on(one_chip, ing),), statics
+    )
+
+
 def test_group_sharded_program_compiles_for_v5e_2x2(topo, no_compile_cache):
     """The GSPMD form: state split over the four described devices on the
     group axis, the fused K-round block split the same way."""

@@ -40,6 +40,7 @@ FLAGGERS = {
     "offload_tick_elect": ((), "_off_elect", False, "tick"),
     "offload_tick_heartbeat": ((), "_off_hb", False, "tick"),
     "offload_tick_demote": ((), "_off_demote", False, "tick"),
+    "offload_quiesce_enter": ((), "_off_quiesce", False, "tick"),
 }
 
 
@@ -140,7 +141,7 @@ def leader(the_leader):
 
 
 def test_the_table_names_every_flagger():
-    """An eighth ``offload_*`` on ``Node`` has to be added above, where the
+    """A ninth ``offload_*`` on ``Node`` has to be added above, where the
     tests below hold it to the gate."""
     assert sorted(FLAGGERS) == sorted(
         n for n in vars(Node) if n.startswith("offload_"))

@@ -8,6 +8,11 @@ no message activity for 10x election ticks enters quiesce on every
 replica, stops heartbeating, and wakes on any user activity.  The runs
 use the in-proc chan transport and a small rtt so the 10x window
 elapses in wall-clock seconds.
+
+Every case runs under both quorum engines: ``scalar`` (the host-side
+``QuiesceManager``) and ``tpu`` (the device tick plane: the row's idle
+clock and its sleep are columns of the tick kernel, ``Node.quiesced()``
+is the host's copy of the flag).
 """
 from __future__ import annotations
 
@@ -16,10 +21,13 @@ import time
 import pytest
 
 from dragonboat_tpu import Config, NodeHost, NodeHostConfig, Result
+from dragonboat_tpu.config import ExpertConfig
 from dragonboat_tpu.transport import ChanRouter, ChanTransport
 
 RTT = 5
 CID = 3
+
+pytestmark = pytest.mark.parametrize("engine", ["scalar", "tpu"])
 
 
 class KVSM:
@@ -53,7 +61,7 @@ class KVSM:
         pass
 
 
-def _mk_trio(quiesce=True):
+def _mk_trio(quiesce=True, engine="scalar"):
     addrs = {1: "q1:1", 2: "q2:1", 3: "q3:1"}
     router = ChanRouter()
     nhs = {}
@@ -65,6 +73,10 @@ def _mk_trio(quiesce=True):
                 raft_address=addrs[i],
                 raft_rpc_factory=lambda src, rh, ch: ChanTransport(
                     src, rh, ch, router=router
+                ),
+                expert=ExpertConfig(
+                    quorum_engine=engine, engine_block_groups=16,
+                    engine_warm_fused=False,
                 ),
             )
         )
@@ -90,7 +102,7 @@ def _leader(nhs, timeout=30.0):
 
 def _quiesced(nhs):
     return [
-        nh.get_node(CID).quiesce_mgr.quiesced() for nh in nhs.values()
+        nh.get_node(CID).quiesced() for nh in nhs.values()
     ]
 
 
@@ -110,10 +122,10 @@ def _stop_all(nhs):
         nh.stop()
 
 
-def test_nodes_can_enter_quiesce():
+def test_nodes_can_enter_quiesce(engine):
     """Reference TestNodesCanEnterQuiesce: an idle group quiesces on
     every replica (leader included) after the idle window."""
-    nhs = _mk_trio(quiesce=True)
+    nhs = _mk_trio(quiesce=True, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         _leader(nhs)
@@ -122,10 +134,10 @@ def test_nodes_can_enter_quiesce():
         _stop_all(nhs)
 
 
-def test_quiesce_can_be_disabled():
+def test_quiesce_can_be_disabled(engine):
     """Reference TestRaftNodeQuiesceCanBeDisabled: with quiesce off
     (the default) the idle window never quiesces anybody."""
-    nhs = _mk_trio(quiesce=False)
+    nhs = _mk_trio(quiesce=False, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         _leader(nhs)
@@ -136,10 +148,10 @@ def test_quiesce_can_be_disabled():
         _stop_all(nhs)
 
 
-def test_exit_quiesce_by_proposal():
+def test_exit_quiesce_by_proposal(engine):
     """Reference TestNodesCanExitQuiesceByMakingProposal — and the
     proposal commits, proving replication actually resumed."""
-    nhs = _mk_trio(quiesce=True)
+    nhs = _mk_trio(quiesce=True, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         lid, leader = _leader(nhs)
@@ -147,7 +159,7 @@ def test_exit_quiesce_by_proposal():
         s = leader.get_noop_session(CID)
         rs = leader.propose(s, b"k=v", timeout=30.0)
         assert rs.wait(60.0).completed
-        assert not leader.get_node(CID).quiesce_mgr.quiesced()
+        assert not leader.get_node(CID).quiesced()
         # peers wake too (the exchanged activity exits their quiesce)
         deadline = time.time() + 30
         while time.time() < deadline and any(_quiesced(nhs)):
@@ -157,9 +169,9 @@ def test_exit_quiesce_by_proposal():
         _stop_all(nhs)
 
 
-def test_exit_quiesce_by_read_index():
+def test_exit_quiesce_by_read_index(engine):
     """Reference TestNodesCanExitQuiesceByReadIndex."""
-    nhs = _mk_trio(quiesce=True)
+    nhs = _mk_trio(quiesce=True, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         lid, leader = _leader(nhs)
@@ -168,40 +180,40 @@ def test_exit_quiesce_by_read_index():
         assert _wait_all_quiesced(nhs)
         v = leader.sync_read(CID, "a", timeout=30.0)
         assert v == "b"
-        assert not leader.get_node(CID).quiesce_mgr.quiesced()
+        assert not leader.get_node(CID).quiesced()
     finally:
         _stop_all(nhs)
 
 
-def test_exit_quiesce_by_config_change():
+def test_exit_quiesce_by_config_change(engine):
     """Reference TestNodesCanExitQuiesceByConfigChange: a membership
     request wakes the group and completes."""
-    nhs = _mk_trio(quiesce=True)
+    nhs = _mk_trio(quiesce=True, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         lid, leader = _leader(nhs)
         assert _wait_all_quiesced(nhs)
         rs = leader.request_add_observer(CID, 9, "q9:1", timeout=30.0)
         assert rs.wait(60.0).completed
-        assert not leader.get_node(CID).quiesce_mgr.quiesced()
+        assert not leader.get_node(CID).quiesced()
         members = leader.sync_get_cluster_membership(CID, timeout=30.0)
         assert 9 in members.observers
     finally:
         _stop_all(nhs)
 
 
-def test_requiesce_after_activity_settles():
+def test_requiesce_after_activity_settles(engine):
     """After a wake, a second idle window re-enters quiesce — the cycle
     is repeatable, not one-shot (quiesce.go's tick clock resets on
     activity)."""
-    nhs = _mk_trio(quiesce=True)
+    nhs = _mk_trio(quiesce=True, engine=engine)
     try:
         nhs[1].get_node(CID).request_campaign()
         lid, leader = _leader(nhs)
         assert _wait_all_quiesced(nhs)
         s = leader.get_noop_session(CID)
         assert leader.propose(s, b"x=1", timeout=30.0).wait(60.0).completed
-        assert not leader.get_node(CID).quiesce_mgr.quiesced()
+        assert not leader.get_node(CID).quiesced()
         assert _wait_all_quiesced(nhs), "group never re-quiesced"
     finally:
         _stop_all(nhs)

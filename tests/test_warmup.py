@@ -419,3 +419,90 @@ def test_ingress_buffers_held_are_the_warm_plan_and_one_a_kind():
     assert eng._ingress_for("fused", k=4, c=0, has_churn=False,
                             **fused) is x  # the plan's own is kept
     assert len(eng._ingress) == 3
+
+
+def test_warm_coordinator_of_a_quiesce_cluster_compiles_nothing():
+    """The first quiesce group flips the engine's latch and starts the
+    warm-up over (``has_quiesce`` programs); once that is through, the
+    round thread compiles nothing: not for activity marks, a peer's
+    QUIESCE, the tick that puts rows to sleep, a wake, a tick backlog, a
+    read, nor an election of a group that sleeps."""
+    from dragonboat_tpu.ops.engine import compilation_log
+    from dragonboat_tpu.tpuquorum import TpuQuorumCoordinator
+
+    class QuiesceNode(FakeNode):
+        dev_quiesce = True
+        _asleep = False
+
+        class quiesce_mgr:
+            threshold = 6
+
+        def __init__(self, cid, raft):
+            super().__init__(cid, raft)
+            self.slept = 0
+
+        def offload_quiesce_enter(self):
+            self.slept += 1
+
+    jax.clear_caches()
+    coord = TpuQuorumCoordinator(
+        capacity=64, n_peers=4, drive_ticks=True, interval_s=60.0,
+    )
+    try:
+        first = coord.start_warmup()
+        from dragonboat_tpu.raft import InMemLogDB
+        from tests.raft_harness import new_test_raft
+
+        nodes = {}
+        for cid in (1, 2, 3, 4):
+            r = new_test_raft(1, [1, 2, 3], 10, 1, InMemLogDB())
+            r.cluster_id = cid
+            r.become_candidate()
+            r.become_leader()
+            r.offload = coord
+            nodes[cid] = QuiesceNode(cid, r)
+            coord.register(nodes[cid])
+        assert coord.eng.quiesce_enabled
+        first.join(timeout=600)  # gave up: the latch flipped under it
+        deadline = time.time() + 600
+        while not coord.eng.fused_ready and time.time() < deadline:
+            time.sleep(0.05)
+        assert coord.eng.fused_ready, coord.warmup_stats
+        coord.flush()
+        warmed = len(compilation_log())
+
+        def fresh():
+            return [(e[2], e[3]) for e in compilation_log()[warmed:]]
+
+        _drive_round(coord, nodes, ticks=1)
+        coord.quiesce_activity(1)
+        coord.quiesce_slept(2, own=False)       # a peer's QUIESCE
+        _drive_round(coord, nodes, ticks=0)     # marks, sparse, no tick
+        assert fresh() == [], "marks"
+        for _ in range(8):                      # past the threshold
+            coord.request_tick()
+            coord.flush()
+        assert all(n.slept == 1 for c, n in nodes.items() if c != 2)
+        assert nodes[2].slept == 0              # slept on its peer's word
+        q = coord.eng.read_rows(
+            "quiesced", [coord.eng.groups[c].row for c in nodes])
+        assert q.all()
+        assert fresh() == [], "rows went to sleep"
+        coord.quiesce_woke(3)
+        _drive_round(coord, nodes, ticks=5)     # fused, marks riding
+        r = nodes[1].peer.raft
+        coord.read_stage(1, r.log.committed, 201, 201, r.term)
+        coord.read_ack_hint(1, 2, 201, 201)
+        coord.read_ack_hint(1, 3, 201, 201)
+        coord.request_tick()
+        coord.flush()
+        assert nodes[1].confirms
+        r4 = nodes[4].peer.raft
+        coord.set_candidate(4, r4.term + 1)
+        coord.vote(4, 2, True)
+        coord.vote(4, 3, True)
+        coord.request_tick()
+        coord.flush()
+        assert fresh() == [], "after it all"
+    finally:
+        coord.stop()
