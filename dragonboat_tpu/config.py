@@ -176,8 +176,21 @@ class ExpertConfig:
     # partitioning (execengine.go:654-706).  0 = single device; capped at
     # the available device count; capacity rounds up to a multiple.
     engine_mesh_devices: int = 0
-    step_worker_count: int = 0  # 0 = use Hard.step_engine_worker_count
-    logdb_shards: int = 0  # 0 = use Hard.logdb_pool_size
+    # step workers (and committers, and apply workers) a NodeHost: groups
+    # are partitioned ``cluster_id % count``.  0 = 4 (the interpreter runs
+    # one thread at a time; the reference's 16 goroutines buy nothing here)
+    step_worker_count: int = 0
+    # LogDB shards, over ``LogDBConfig.shards``.  0 = not set: a NEW LogDB
+    # directory then gets as many shards as there are step workers, so a
+    # worker's groups live in ONE shard and a committer cycle is one
+    # durable write batch, one ``fdatasync`` (the reference's
+    # DoubleFixedPartitioner geometry, ``server/partition.go:59``); with
+    # more shards than workers a cycle pays one sync per shard it touches,
+    # one after the other.  A directory that EXISTS is opened with the
+    # shards it has, whatever the default says (the count is what places a
+    # group, ``cluster_id % shards``); a count set here that disagrees with
+    # the directory raises (``logdb.open_logdb``).
+    logdb_shards: int = 0
     # native replication fast lane (fastlane.py + native/natraft.cpp): the
     # steady-state data plane of enrolled groups runs in C++.  Requires the
     # TCP transport and the native LogDB backend; silently unavailable
@@ -260,7 +273,11 @@ class LogDBConfig:
     kv_block_size: int = 32 * 1024
     kv_max_background_compactions: int = 2
     segment_file_size: int = 1024 * 1024 * 1024
-    shards: int = 16
+    # 0 = as many as the NodeHost has step workers (one committer cycle,
+    # one shard, one durable write); an existing directory keeps the count
+    # it was written with.  See ``ExpertConfig.logdb_shards``, which
+    # overrides this.
+    shards: int = 0
     # fsync every committed write batch (the reference always does; turning
     # this off trades durability of the last instants for throughput and is
     # only for benchmarks/tests — results must report it)
@@ -426,6 +443,20 @@ class NodeHostConfig:
 
     def get_listen_address(self) -> str:
         return self.listen_address or self.raft_address
+
+    def step_workers(self) -> int:
+        return self.expert.step_worker_count or 4
+
+    def open_logdb_args(self) -> dict:
+        """The shard geometry ``logdb.open_logdb`` is given, by NodeHost
+        and by every tool that opens a host's LogDB: the count the user
+        set (the expert's over the LogDB's; 0 = none) and, for a new
+        directory, the step-worker count."""
+        return {
+            "shards": self.expert.logdb_shards or self.logdb_config.shards,
+            "default_shards": self.step_workers(),
+            "fsync": self.logdb_config.fsync,
+        }
 
 
 def _valid_address(addr: str) -> bool:

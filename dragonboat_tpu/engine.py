@@ -92,6 +92,9 @@ class _Committer:
         # diagnostics (read by Engine.stats)
         self.cycles = 0
         self.merged = 0
+        # durable write batches the cycles' log saves committed: one a
+        # cycle where the LogDB has a shard per step worker
+        self.sync_batches = 0
         self.commit_s = 0.0
         self.post_s = 0.0
         self._thread = threading.Thread(
@@ -129,6 +132,7 @@ class _Committer:
         t0 = _time.perf_counter()
         merged = [ud for _, updates in batch for ud in updates]
         tr = self.engine.tracer
+        synced = 0
         if merged:
             hp = self.engine.hostplane
             # the log save + fsync, named for the profiler while the
@@ -144,12 +148,15 @@ class _Committer:
                     # rides its owning committer)
                     hp.wal.flush(merged)
                 else:
-                    self.engine.logdb.save_raft_state(merged)
+                    # how many shard batches it committed (a LogDB that
+                    # does not say counts none)
+                    synced = self.engine.logdb.save_raft_state(merged) or 0
         t1 = _time.perf_counter()
         if tr is not None and merged:
             # the merged batch is durable here — whichever tier fsynced
             # it (group-commit WAL or the classic per-committer save)
             tr.mark_updates(merged, "wal")
+            tr.count_wal_cycle(synced, len(merged), t1 - t0)
         after = 0
         for pairs, _ in batch:
             for n, ud in pairs:
@@ -162,6 +169,7 @@ class _Committer:
         self.engine.count_apply_handoffs(self.idx, 0, after)
         self.cycles += 1
         self.merged += len(merged)
+        self.sync_batches += synced
         self.commit_s += t1 - t0
         self.post_s += _time.perf_counter() - t1
 
@@ -469,6 +477,7 @@ class Engine:
                 {
                     "cycles": c.cycles,
                     "merged_updates": c.merged,
+                    "sync_batches": c.sync_batches,
                     "commit_s": round(c.commit_s, 3),
                     "post_s": round(c.post_s, 3),
                 }

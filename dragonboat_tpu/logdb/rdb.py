@@ -123,24 +123,26 @@ class RDB:
 
     # ---- raft state round (the hot write path) ----
 
-    def save_raft_state(self, updates: List[Update], wb: KVWriteBatch) -> None:
+    def save_raft_state(self, updates: List[Update], wb: KVWriteBatch) -> bool:
         """One atomic, fsynced write batch for a worker round
-        (reference ``rdb.go:187-210``)."""
+        (reference ``rdb.go:187-210``).  True when a batch was committed."""
         self.build_raft_state(updates, wb)
         # rounds where every record was suppressed (heartbeat traffic with
         # unchanged State) must not pay a WAL append + fsync for an empty
         # batch — the rdbcache exists precisely to elide these writes
-        if wb.ops:
-            try:
-                self.kv.commit_write_batch(wb)
-            except BaseException:
-                # the build advanced the rdbcache for records this batch
-                # was carrying; a failed commit must drop those entries
-                # or the retry's rebuild suppresses them forever
-                self.cache.invalidate(
-                    {(u.cluster_id, u.node_id) for u in updates}
-                )
-                raise
+        if not wb.ops:
+            return False
+        try:
+            self.kv.commit_write_batch(wb)
+        except BaseException:
+            # the build advanced the rdbcache for records this batch
+            # was carrying; a failed commit must drop those entries
+            # or the retry's rebuild suppresses them forever
+            self.cache.invalidate(
+                {(u.cluster_id, u.node_id) for u in updates}
+            )
+            raise
+        return True
 
     def build_raft_state(self, updates: List[Update], wb: KVWriteBatch) -> None:
         """Fill ``wb`` with the round's records WITHOUT committing — the

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import os
 import queue
+import re
 import threading
 from typing import Callable, List, Optional, Tuple
 
@@ -22,6 +23,7 @@ from .rdb import RDB, NodeInfo, RaftState
 plog = get_logger("logdb")
 
 _STOP = object()
+_SHARD_DIR = re.compile(r"shard-(\d\d+)")
 
 
 class ShardedDB:
@@ -89,21 +91,27 @@ class ShardedDB:
 
     # ---- raft state ----
 
-    def save_raft_state(self, updates: List[Update]) -> None:
+    def save_raft_state(self, updates: List[Update]) -> int:
         """Group updates by shard; one atomic write batch per shard.
+        Returns how many shard batches were committed (each one durable
+        write; a bucket whose records were all suppressed commits none).
 
         The reference passes a per-worker IContext whose write batch covers
         exactly one shard because workers and shards are co-partitioned
-        (``server/partition.go:59``); here updates are bucketed explicitly so
-        any caller threading model works.
+        (``server/partition.go:59``).  A NodeHost opens its LogDB with as
+        many shards as it has step workers (``open_logdb``), so a committer
+        cycle is one bucket here too; updates are still bucketed explicitly
+        so any geometry and any caller threading model stay correct.
         """
         buckets = {}
         for ud in updates:
             buckets.setdefault(ud.cluster_id % len(self._shards), []).append(ud)
+        committed = 0
         for idx, uds in buckets.items():
             shard = self._shards[idx]
             wb = shard.kv.get_write_batch()
-            shard.save_raft_state(uds, wb)
+            committed += shard.save_raft_state(uds, wb)
+        return committed
 
     # ---- host-plane group-commit journal (ISSUE 8) ----
 
@@ -344,12 +352,33 @@ class ShardedDB:
             s.close()
 
 
+def shards_on_disk(dirname: str) -> int:
+    """How many ``shard-NN`` directories ``dirname`` holds (0: none, or no
+    such directory).  The count is recorded nowhere else: a group lives in
+    shard ``cluster_id % count``, so a directory has to be opened with the
+    count it was written with."""
+    if not dirname or not os.path.isdir(dirname):
+        return 0
+    found = sorted(
+        int(m.group(1))
+        for m in map(_SHARD_DIR.fullmatch, os.listdir(dirname))
+        if m is not None
+    )
+    if found != list(range(len(found))):
+        raise RuntimeError(
+            f"LogDB directory {dirname!r} holds shards {found}: not a run "
+            f"from 0, so its shard count cannot be told"
+        )
+    return len(found)
+
+
 def open_logdb(
     dirname: str = "",
     shards: int = 0,
     batched: bool = False,
     kv_factory: Optional[Callable[[str], IKVStore]] = None,
     fsync: bool = True,
+    default_shards: int = 0,
 ) -> ShardedDB:
     """Open (or create) a sharded LogDB.
 
@@ -359,8 +388,23 @@ def open_logdb(
     (``dragonboat_tpu/native``, the analog of the reference's default
     Pebble / optional RocksDB cgo backend) — falling back to the Python
     :class:`WalKV` only where the native library cannot be built.
+
+    The shard count: a directory that exists is opened with the shards it
+    has (``shards_on_disk``), and an explicit ``shards`` that disagrees
+    raises.  A new directory, and the in-memory backend, get ``shards``,
+    else ``default_shards`` (a NodeHost passes its step-worker count: a
+    worker's groups then live in one shard and a committer cycle is one
+    durable write batch), else ``Hard.logdb_pool_size``.
     """
-    n = shards or Hard.logdb_pool_size
+    present = shards_on_disk(dirname)
+    if shards and present and shards != present:
+        raise RuntimeError(
+            f"LogDB directory {dirname!r} was written with {present} "
+            f"shards and {shards} were asked for: a group's records live "
+            f"in shard cluster_id % {present}; open it with {present} (or "
+            f"with no explicit count)"
+        )
+    n = shards or present or default_shards or Hard.logdb_pool_size
     durable_factory: Optional[Callable[[str], IKVStore]] = None
     if kv_factory is None and dirname:
         from .. import native

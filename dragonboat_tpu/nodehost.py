@@ -144,20 +144,18 @@ class NodeHost:
             self.server_ctx.check_nodehost_dir(
                 did, nhconfig.raft_address, "nativekv"
             )
-        # shard-count priority: expert override > logdb config.  Aligning
-        # shards with the step-worker count reproduces the reference's
-        # DoubleFixedPartitioner geometry (server/partition.go:59): one
-        # worker round → one shard → one fsynced write batch
-        shards = nhconfig.expert.logdb_shards or nhconfig.logdb_config.shards
+        # shard-count priority: expert override > logdb config > the
+        # step-worker count; a directory that exists keeps the count it
+        # has (open_logdb).  Shards aligned with the step workers
+        # reproduce the reference's DoubleFixedPartitioner geometry
+        # (server/partition.go:59): one worker round → one shard → one
+        # fsynced write batch
         if nhconfig.logdb_factory is not None:
             self.logdb = nhconfig.logdb_factory(nhconfig)
-        elif in_memory:
-            self.logdb = open_logdb("", shards=shards)
         else:
             self.logdb = open_logdb(
-                os.path.join(data_dir, "logdb"),
-                shards=shards,
-                fsync=nhconfig.logdb_config.fsync,
+                "" if in_memory else os.path.join(data_dir, "logdb"),
+                **nhconfig.open_logdb_args(),
             )
         # delayed snapshot-status feedback (reference feedback.go:23-129):
         # transport-reported send status is parked and released to raft
@@ -566,7 +564,7 @@ class NodeHost:
                     metrics_addr, e,
                 )
         # engine
-        workers = expert.step_worker_count or 4
+        workers = nhconfig.step_workers()
         self.engine = Engine(
             self._get_nodes,
             self.logdb,

@@ -340,6 +340,11 @@ class Tracer:
         # whole second like the outcomes
         self._handoff_q: deque = deque()
         self._handoff_secs: Dict[int, List[int]] = {}
+        # ---- committer cycles (ISSUE 45) ------------------------------
+        # one (perf_counter, 1, sync batches, updates, save seconds) a
+        # cycle of any committer of the engine, folded the same way
+        self._wal_q: deque = deque()
+        self._wal_secs: Dict[int, list] = {}
         # clock anchor: stamps are perf_counter (monotonic); the export
         # maps them onto the wall clock the recorder spans already use
         self._wall0 = time.time()
@@ -617,21 +622,48 @@ class Tracer:
             self._fold_locked()
             return {s: tuple(c) for s, c in self._handoff_secs.items()}
 
+    def count_wal_cycle(
+        self, sync_batches: int, updates: int, commit_s: float
+    ) -> None:
+        """One committer cycle (``engine._Committer._commit``): the durable
+        write batches its log save committed, the updates it merged and the
+        seconds the save took.  No lock: queued for the next fold."""
+        self._wal_q.append(
+            (time.perf_counter(), 1, sync_batches, updates, commit_s)
+        )
+
+    def wal_cycles(self) -> Dict[int, tuple]:
+        """``{int(perf_counter): (cycles, sync_batches, updates,
+        commit_s)}``: the engine's committer cycles by the whole second in
+        which their save returned (the newest ``OUTCOME_SECONDS_KEEP``
+        seconds).  ``sync_batches / cycles`` is 1 where every cycle is one
+        shard, one durable write."""
+        with self._mu:
+            self._fold_locked()
+            return {s: tuple(c) for s, c in self._wal_secs.items()}
+
+    @staticmethod
+    def _fold_seconds(q: deque, secs: Dict[int, list]) -> None:
+        """Add each queued ``(perf_counter, *counts)`` to its whole
+        second's sums."""
+        for _ in range(len(q)):
+            now, *counts = q.popleft()
+            sec = secs.get(int(now))
+            if sec is None:
+                secs[int(now)] = list(counts)
+                if len(secs) > OUTCOME_SECONDS_KEEP:
+                    del secs[min(secs)]
+            else:
+                for i, c in enumerate(counts):
+                    sec[i] += c
+
     def _fold_locked(self) -> None:
         """Account the completions queued since the last fold (caller
         holds ``_mu``): outcome counts, e2e and, for a sampled trace, its
-        stage observations; and the apply hand-offs."""
-        hq = self._handoff_q
-        secs = self._handoff_secs
-        for _ in range(len(hq)):
-            now, early, after = hq.popleft()
-            sec = secs.get(int(now))
-            if sec is None:
-                sec = secs[int(now)] = [0, 0]
-                if len(secs) > OUTCOME_SECONDS_KEEP:
-                    del secs[min(secs)]
-            sec[0] += early
-            sec[1] += after
+        stage observations; and the apply hand-offs and committer
+        cycles."""
+        self._fold_seconds(self._handoff_q, self._handoff_secs)
+        self._fold_seconds(self._wal_q, self._wal_secs)
         q = self._fin_q
         for _ in range(len(q)):
             now, t0, kind, code, evs = q.popleft()
@@ -776,6 +808,7 @@ class Tracer:
         if (
             self._pend_requests or self._fin_q or self._pend_completed
             or self._e2e_acc[2] or self._pend_outcomes or self._handoff_q
+            or self._wal_q
         ):
             self.flush_metrics()
         if not self._by_cluster and not self._by_key:

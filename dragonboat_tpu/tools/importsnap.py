@@ -127,9 +127,11 @@ def import_snapshot(
     env.save_ss_metadata(imported)
     env.finalize_snapshot()
 
+    # the host's own geometry: the shards its directory has, else what its
+    # NodeHost would create (a record in another shard is never read)
     db = open_logdb(
         os.path.join(_host_dir(nhconfig), "logdb"),
-        shards=nhconfig.logdb_config.shards,
+        **nhconfig.open_logdb_args(),
     )
     try:
         # drop stale snapshot records (reference import.go:200-207)
